@@ -4,8 +4,8 @@
 
 .PHONY: ci lint analyze native-test tsan-test asan-test ubsan-test \
         parse-lanes telemetry trace cache range fsfault rig serving slo \
-        device zerocopy pytest liveness elastic mesh bench-smoke dryrun doc \
-        clean
+        device zerocopy pytest liveness elastic mesh bench-smoke chip-smoke \
+        dryrun doc clean
 
 ci: lint analyze native-test tsan-test asan-test ubsan-test parse-lanes \
     telemetry trace cache range fsfault rig serving slo device zerocopy \
@@ -69,14 +69,12 @@ fsfault:
 	$(MAKE) -C cpp asan-fsfault
 	timeout -k 10 300 python3 -m pytest tests/test_fs_fault.py -q
 
-# Device-lane observability (doc/observability.md "Device lane"): the
-# CPU-backend floor of the always-measured device pipeline — span
-# nesting on one clock, overlap ratio bounds, the extended stall-verdict
-# matrix (stage/compile/transfer flips, injected e2e), compile-churn
-# bucket census + clean replay, device_put failure flight dumps, and the
-# bench device lane emitting numbers (device_unavailable is retired).
-# Hard timeout: a hung backend session is exactly the regression this
-# lane exists to catch. JAX_PLATFORMS=cpu pins the deterministic floor.
+# Device-lane observability (doc/observability.md "Device lane"), on
+# the CPU backend: span nesting on one clock, overlap ratio bounds, the
+# extended stall-verdict matrix (stage/compile/transfer flips, injected
+# e2e), compile-churn bucket census + clean replay, device_put failure
+# flight dumps, and the profiler capture. Hard timeout: a hung backend
+# session is exactly the regression this lane exists to catch.
 device:
 	timeout -k 10 300 env JAX_PLATFORMS=cpu \
 	  python3 -m pytest tests/test_device_observability.py -q
@@ -87,7 +85,7 @@ device:
 # counter + recycle-skip gauge semantics, sharded placement on a forced
 # multi-device CPU mesh, and the bf16.h <-> ml_dtypes parity fuzz (RNE
 # ties, NaN quieting, subnormals, infinities) across the C/Python
-# boundary. JAX_PLATFORMS=cpu pins the deterministic floor.
+# boundary. Runs on the CPU backend.
 zerocopy:
 	timeout -k 10 300 env JAX_PLATFORMS=cpu \
 	  python3 -m pytest tests/test_zero_copy.py -q
@@ -193,14 +191,25 @@ mesh:
 	timeout -k 10 300 env JAX_PLATFORMS=cpu \
 	  python3 -m pytest tests/test_elastic_mesh.py -q
 
+# the multi-chip dry run on 8 virtual CPU devices (chip_smoke.py runs the
+# same function on the real devices of a four-chip host)
 dryrun:
-	python3 -c "import __graft_entry__ as g; g.dryrun_multichip(8)"
+	JAX_PLATFORMS=cpu python3 -c \
+	  "import __graft_entry__ as g; g.dryrun_multichip(8)"
 	JAX_PLATFORMS=cpu python3 -c "import jax; \
-	  jax.config.update('jax_platforms', 'cpu'); \
 	  import __graft_entry__ as g; fn, args = g.entry(); \
 	  jax.jit(fn).lower(*args).compile(); \
 	  print('entry() compile-check OK')"
 
+# The two targets below need an accelerator (run them through the chip
+# tool); neither is part of `make ci`. chip-smoke: does the program still
+# start on the chip, through its normal entry points, with every phase
+# on platform=tpu? Exits non-zero, naming the phase, without a chip.
+chip-smoke:
+	python3 chip_smoke.py
+
+# bench-smoke: every bench lane at CI size; its device lanes refuse the
+# CPU backend (host-only metrics: `python3 bench.py --smoke --parse-only`)
 bench-smoke:
 	python3 bench.py --smoke
 

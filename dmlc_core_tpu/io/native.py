@@ -16,6 +16,7 @@ from __future__ import annotations
 import ctypes
 import os
 import subprocess
+import sys
 import threading
 from typing import Iterator, List, Optional, Tuple
 
@@ -93,16 +94,17 @@ class IoRetryStatsC(ctypes.Structure):
 
 
 def _build_native() -> None:
-    sources_newer = True
-    if os.path.exists(_LIB_PATH):
-        lib_mtime = os.path.getmtime(_LIB_PATH)
-        src_dir = os.path.join(_CPP_DIR, "src")
-        sources_newer = any(
-            os.path.getmtime(os.path.join(src_dir, f)) > lib_mtime
-            for f in os.listdir(src_dir))
-    if sources_newer:
-        subprocess.run(["make", "-C", _CPP_DIR], check=True,
-                       capture_output=True)
+    """Bring the library up to date with ``make`` — a no-op when it is
+    fresh; staleness is judged by make's own dependency tracking, which
+    also holds in a copied tree. A failed build shows the compiler's
+    output."""
+    proc = subprocess.run(["make", "-C", _CPP_DIR], capture_output=True,
+                          text=True)
+    if proc.returncode != 0:
+        sys.stderr.write(proc.stdout + proc.stderr)
+        raise DMLCError(
+            f"native build failed: `make -C {_CPP_DIR}` exited "
+            f"{proc.returncode} (compiler output above)")
 
 
 def lib() -> ctypes.CDLL:

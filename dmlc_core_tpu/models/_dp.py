@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
+from dmlc_core_tpu.parallel.varying import mark_varying
 from dmlc_core_tpu.tpu.device_iter import unpack_shard
 
 __all__ = ["DataParallelModel"]
@@ -66,23 +67,17 @@ class DataParallelModel:
                 return self._apply(params, grads, denom), loss_sum / denom
             return jax.jit(step)
 
-        try:
-            from jax import shard_map
-        except ImportError:  # pre-0.5 jax spells it experimental
-            from jax.experimental.shard_map import shard_map
-        mesh = self.mesh
-
-        from dmlc_core_tpu.parallel.varying import shard_map_compat_kwargs
-
-        # the shard loss may reach the Pallas CSR->dense kernel, which the
-        # pre-varying-type replication checker cannot type
-        @functools.partial(shard_map, mesh=mesh,
+        @functools.partial(jax.shard_map, mesh=self.mesh,
                            in_specs=(P(), dict(tree_keys)),
-                           out_specs=(P(), P()),
-                           **shard_map_compat_kwargs())
+                           out_specs=(P(), P()))
         def sharded_step(params, tree):
             shard = shard_view(tree)  # drop device axis + unpack
-            loss_sum, wsum, grads = local_grads(params, shard)
+            # the replicated params are differentiated as device-varying:
+            # typed unvarying, autodiff's transpose psums their cotangent
+            # by itself and the explicit psum below would count the
+            # gradient once per device
+            loss_sum, wsum, grads = local_grads(
+                mark_varying(params, (axis,)), shard)
             # ONE reduction per step over ICI — the Rabit allreduce
             # equivalent (SURVEY §2.5)
             loss_sum = jax.lax.psum(loss_sum, axis)

@@ -26,15 +26,11 @@ from typing import Any, Dict, NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:
-    from jax import shard_map
-except ImportError:  # pre-0.5 jax spells it experimental
-    from jax.experimental.shard_map import shard_map
-
 from dmlc_core_tpu.parallel.ring import ring_attention
+from dmlc_core_tpu.parallel.varying import mark_varying
 
 __all__ = ["TransformerConfig", "TransformerLM"]
 
@@ -145,21 +141,15 @@ class TransformerLM:
         x = _layer_norm(x, params["ln_f"]["scale"], params["ln_f"]["bias"])
         return (x @ params["embed"].T.astype(cfg.dtype)).astype(jnp.float32)
 
-    @staticmethod
-    def _mark_varying(tree, axes):
-        """Type replicated params as device-varying inside the shard body.
-
-        Without this, autodiff treats them as unvarying and the transpose
-        rule inserts an implicit cross-device psum into their cotangents
-        (e.g. through the position-table dynamic_slice), so the explicit
-        psum below would double-count by the axis size."""
-        from dmlc_core_tpu.parallel.varying import mark_varying
-        return mark_varying(tree, axes)
-
     def _shard_step(self, params: Params, tokens: jnp.ndarray,
                     labels: jnp.ndarray):
         axes = ("data", "seq")
-        vparams = self._mark_varying(params, axes)
+        # replicated params must be typed device-varying inside the shard
+        # body: otherwise autodiff's transpose rule inserts an implicit
+        # cross-device psum into their cotangents (e.g. through the
+        # position-table dynamic_slice) and the explicit psum below
+        # double-counts by the axis size
+        vparams = mark_varying(params, axes)
 
         def local_loss(p):
             logits = self._forward_local(p, tokens)

@@ -54,9 +54,9 @@ def csr_to_dense(row: jnp.ndarray, col: jnp.ndarray, val: jnp.ndarray,
     onto the systolic array instead of scatter units.
 
     impl: "xla" (scatter-add, the default), "pallas" (the scatter-as-
-    matmul TPU kernel, ops/pallas_kernels.py), or None to read the
-    DCT_CSR_TO_DENSE env var (trace-time; the opt-in switch for the
-    device-side batch-formatting path)."""
+    matmul kernel, ops/pallas_kernels.py, compiled by Mosaic — TPU only),
+    or None to read the DCT_CSR_TO_DENSE env var (trace-time; the opt-in
+    switch for the device-side batch-formatting path)."""
     if impl is None:
         impl = os.environ.get("DCT_CSR_TO_DENSE", "xla")
     if impl == "pallas":

@@ -12,13 +12,6 @@ of ppermute is the reverse ppermute).
 
 ``pipeline_apply`` is the generic schedule; it runs inside ``shard_map``
 over the "pipe" axis and composes with a "data" axis outside it.
-
-Compatibility: the BACKWARD pipeline requires a varying-typed jax
-(native ``jax.shard_map``). On a pre-0.5 jax the transpose of the
-replicated loss output seeds a full cotangent on every pipe rank and
-stage gradients come out scaled by the axis size — with or without
-``check_rep`` (tests/test_pipeline_parallel.py pins the skip). The
-forward schedule is exact everywhere.
 """
 
 from __future__ import annotations

@@ -34,13 +34,8 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:
-    from jax import shard_map
-except ImportError:  # pre-0.5 jax spells it experimental
-    from jax.experimental.shard_map import shard_map
 
 __all__ = ["ring_allreduce", "ring_attention", "ring_attention_zigzag",
            "sequence_parallel_attention", "zigzag_permutation"]

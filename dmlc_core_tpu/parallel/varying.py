@@ -4,9 +4,7 @@ Under shard_map's varying-type discipline, values entering a shard body as
 replicated must be explicitly cast to device-varying before they mix with
 collective outputs (ppermute carries, psum'd cotangents) — otherwise
 autodiff's transpose rule inserts implicit cross-device psums that
-double-count by the axis size, or scan rejects the carry type. JAX renamed
-the API (lax.pvary -> lax.pcast(..., to='varying')); this is the single
-probe point so the next rename is a one-place change.
+double-count by the axis size, or scan rejects the carry type.
 """
 
 from __future__ import annotations
@@ -14,48 +12,10 @@ from __future__ import annotations
 import jax
 from jax import lax
 
-__all__ = ["mark_varying", "shard_map_compat_kwargs"]
-
-# Does THIS jax enforce the varying-type discipline at all? A native
-# ``jax.shard_map`` (the post-experimental graduation) implies typed
-# values; a jax that only ships ``jax.experimental.shard_map`` tracks
-# replication via check_rep and its transpose rule needs no explicit
-# cast. Probed once at import; tests monkeypatch it to pin the
-# renamed-again failure mode below.
-_VARYING_TYPED = hasattr(jax, "shard_map")
+__all__ = ["mark_varying"]
 
 
 def mark_varying(tree, axes):
     """Cast every leaf of `tree` to device-varying over `axes` (a tuple of
     mesh axis names). Accepts a single array or any pytree."""
-    if hasattr(lax, "pcast"):  # probe pcast first: pvary is deprecated
-        return jax.tree.map(lambda t: lax.pcast(t, axes, to="varying"),
-                            tree)
-    if hasattr(lax, "pvary"):
-        return jax.tree.map(lambda t: lax.pvary(t, axes), tree)
-    if _VARYING_TYPED:
-        # a varying-typed jax with BOTH cast APIs missing means the API
-        # moved again: silently skipping the cast would let autodiff's
-        # transpose rule insert implicit psums that double-count by the
-        # axis size (ADVICE r1) — refuse loudly, here, the one probe point
-        raise RuntimeError(
-            "mark_varying: this jax has neither lax.pcast nor lax.pvary; "
-            "the varying-type cast API was renamed again — update "
-            "dmlc_core_tpu.parallel.varying")
-    # pre-varying-type jax (experimental shard_map, untyped values):
-    # replication is tracked by check_rep and the transpose rule needs no
-    # explicit cast, so the identity is the CORRECT behavior here, not a
-    # silent degrade
-    return tree
-
-
-def shard_map_compat_kwargs():
-    """Extra shard_map kwargs for bodies that lower a ``pallas_call``.
-
-    The pre-varying-type replication checker has no rule for pallas_call,
-    so shard_maps whose body may reach a Pallas kernel must disable it
-    (``check_rep=False`` — jax's own documented workaround). Outputs stay
-    genuinely replicated — every reduced output crosses a psum — only the
-    static checker is off. A varying-typed jax needs nothing (and no
-    longer accepts ``check_rep``)."""
-    return {} if _VARYING_TYPED else {"check_rep": False}
+    return jax.tree.map(lambda t: lax.pcast(t, axes, to="varying"), tree)

@@ -219,6 +219,31 @@ def parse_buckets(spec: str) -> Tuple[int, ...]:
     return buckets
 
 
+def _nnz_bucket(nnz: int, min_nnz: int) -> int:
+    """Next power of two at or above ``max(nnz, min_nnz, 1)``."""
+    bucket = 1
+    while bucket < max(int(min_nnz), 1, nnz):
+        bucket *= 2
+    return bucket
+
+
+def warm_shapes(rows_buckets: Sequence[int], min_nnz: int
+                ) -> List[Tuple[int, int]]:
+    """The ``(rows_bucket, nnz_bucket)`` shapes :func:`pad_to_bucket`
+    yields for batches no denser than the floor shape — ``min_nnz``
+    nonzeros per ``rows_buckets[0]`` rows: for each rows bucket, every
+    nnz bucket from the floor up to that density."""
+    floor = _nnz_bucket(0, min_nnz)
+    shapes = []
+    for rows in rows_buckets:
+        top = _nnz_bucket(floor * rows // rows_buckets[0], min_nnz)
+        nnz = floor
+        while nnz <= top:
+            shapes.append((rows, nnz))
+            nnz *= 2
+    return shapes
+
+
 def pad_to_bucket(group: ParsedGroup, rows_buckets: Sequence[int],
                   min_nnz: int
                   ) -> Tuple[np.ndarray, np.ndarray, np.ndarray,
@@ -240,10 +265,7 @@ def pad_to_bucket(group: ParsedGroup, rows_buckets: Sequence[int],
     if rows_bucket == 0:
         raise HttpError(413, f"batch of {group.num_rows} rows exceeds "
                              f"the largest bucket {rows_buckets[-1]}")
-    nnz = max(int(min_nnz), 1, len(group.val))
-    nnz_bucket = 1
-    while nnz_bucket < nnz:
-        nnz_bucket *= 2
+    nnz_bucket = _nnz_bucket(len(group.val), min_nnz)
     pad = nnz_bucket - len(group.val)
     row = np.concatenate([group.row, np.full(pad, rows_bucket,
                                              dtype=np.int64)])

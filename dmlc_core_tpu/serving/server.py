@@ -32,10 +32,13 @@ import threading
 import time
 from typing import Deque, List, Optional, Union
 
+import numpy as np
+
 from dmlc_core_tpu import telemetry
 from dmlc_core_tpu.serving import batching
 from dmlc_core_tpu.serving.frontend import HttpFrontend, PENDING, Request
 from dmlc_core_tpu.serving.model import ScoringModel
+from dmlc_core_tpu.tpu.runtime import compile_report, device_report
 from dmlc_core_tpu.tracker.minihttp import HttpError
 from dmlc_core_tpu.tracker.rendezvous import _EventLog
 from dmlc_core_tpu.tracker.wire import env_float, env_int, env_str
@@ -210,6 +213,28 @@ class ScoringServer:
     def port(self) -> int:
         """The bound TCP port (useful with ``port=0``)."""
         return self.frontend.port
+
+    def warm(self) -> int:
+        """Compile the forward for the bucket ladder before any request
+        can arrive (call before :meth:`start`), so that ready means
+        ready: a first-sight compile takes far longer than the lateness
+        budget and the latency objective allow, and at low traffic one
+        slow answer is enough to page. Covers every rows bucket at every
+        nnz bucket up to the density of the floor shape
+        (``min_nnz_bucket`` nonzeros per ``rows_buckets[0]`` rows); denser
+        batches still compile on first sight and show in
+        ``serve_distinct_shapes``. Returns the number of shapes."""
+        if self._model is None:
+            self._model = ScoringModel.load(self._model_uri)
+        shapes = batching.warm_shapes(self.config.rows_buckets,
+                                      self.config.min_nnz_bucket)
+        for rows, nnz in shapes:
+            # all padding: every nonzero sits in the sacrificial segment
+            self._model.scores(np.full(nnz, rows, np.int32),
+                               np.zeros(nnz, np.int32),
+                               np.zeros(nnz, np.float32), rows)
+        telemetry.emit_event("serve-warm", shapes=len(shapes))
+        return len(shapes)
 
     def start(self) -> None:
         """Load the model if needed, then start the scorer and loop."""
@@ -715,4 +740,6 @@ class ScoringServer:
             "shed_lateness_ms": self.config.shed_lateness_ms,
             "rows_buckets": list(self.config.rows_buckets),
             "model": self._model.describe() if self._model else None,
+            "device": device_report(),
+            "compile": compile_report(),
         }

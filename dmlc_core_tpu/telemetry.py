@@ -1063,17 +1063,20 @@ METRIC_HELP: Dict[str, str] = {
         "aliased host staging buffers dropped from the deferred-recycle "
         "parking lot because the consumer held more batches than its "
         "depth (zero-copy backends)",
+    "device_alias_probe_total":
+        "one probe per iterator of whether device arrays alias the host "
+        "staging buffers, by verdict (no_alias, aliases, unprobeable)",
     "device_jit_compiles_total":
-        "XLA compilations observed via the jax.monitoring hook",
-    "device_compile_us": "one XLA compilation (us, jax.monitoring)",
+        "trips through the backend-compile stage (jax.monitoring), "
+        "persistent-cache hits included",
+    "device_compile_cache_hits_total":
+        "backend-compile trips the persistent compilation cache answered",
+    "device_compile_us":
+        "one XLA compilation phase: trace, lower or backend compile "
+        "(us, jax.monitoring)",
     "device_overlap_ratio":
         "fraction of transfer time hidden behind consumer compute "
         "(-1 before any transfer)",
-    "device_probe_attempts_total": "bench device-probe subprocess attempts",
-    "device_probe_timeouts_total": "bench device-probe attempt timeouts",
-    "device_probe_state":
-        "bench device-probe verdict (0 unknown, 1 ok, 2 unavailable, "
-        "3 cached unavailable)",
     "tracker_num_workers": "workers the tracker expects",
     "tracker_alive": "1 while the tracker thread is serving",
     "tracker_finished": "1 once every worker checked out",

@@ -51,6 +51,7 @@ from dmlc_core_tpu.base import DMLCError
 from dmlc_core_tpu.io.native import (NativeBatcher, NativeCsrRecBatcher,
                                      NativeDenseRecBatcher, NativeParser,
                                      _bf16_dtype)
+from dmlc_core_tpu.tpu.runtime import install_compile_monitor
 from dmlc_core_tpu.tpu.sharding import batch_sharding
 from dmlc_core_tpu.tracker.wire import TrackerAbortedError, env_int
 
@@ -134,39 +135,6 @@ def _reset_shape_census() -> None:
         _shapes_seen.clear()
 
 
-_monitor_installed = False
-
-
-def _install_compile_monitor() -> None:
-    """Best-effort jax.monitoring hook: XLA compilation events land in
-    the telemetry plane (device_jit_compiles_total / device_compile_us)
-    when this jax exposes duration listeners; the shape census above is
-    the portable fallback either way. Installed once per process, never
-    raises — observability must not sink the lane."""
-    global _monitor_installed
-    if _monitor_installed:
-        return
-    _monitor_installed = True
-    try:
-        from jax import monitoring as _mon
-        compiles = telemetry.counter("device_jit_compiles_total")
-        compile_us = telemetry.histogram("device_compile_us")
-
-        def _on_duration(event, duration, **_kw):
-            # jax emits several phases per compilation (jaxpr trace,
-            # mlir lower, backend compile) — every phase's duration
-            # lands in the histogram, but only the backend_compile
-            # event counts as ONE compilation
-            if "compil" in event:
-                compile_us.observe(duration * 1e6)
-                if "backend_compile" in event:
-                    compiles.inc()
-
-        _mon.register_event_duration_secs_listener(_on_duration)
-    except Exception:
-        pass
-
-
 @contextlib.contextmanager
 def jax_profiler_capture():
     """Optional XLA-timeline capture, wall-clock-anchored to our
@@ -176,39 +144,25 @@ def jax_profiler_capture():
     monotonic) clock-anchor pairs at start and stop — the same anchors
     ``telemetry.trace_json()`` shifts by, so the XLA timeline and the
     ``/trace`` span timeline line up on one wall clock. Yields True when
-    a capture is running, False otherwise (env unset, or the profiler
-    refused — profiling must never sink the lane; every failure is
-    swallowed)."""
+    a capture is running, False when the env is unset. A profiler that
+    was asked for and will not start (or stop) raises: the caller wanted
+    the trace, and a run without it is not the run they asked for."""
     out_dir = os.environ.get("DMLC_JAX_PROFILE")
     if not out_dir:
         yield False
         return
     anchors = {"pid": os.getpid(), "start": telemetry.clock_anchor()}
-    started = False
+    os.makedirs(out_dir, exist_ok=True)
+    jax.profiler.start_trace(out_dir)
     try:
-        os.makedirs(out_dir, exist_ok=True)
-        jax.profiler.start_trace(out_dir)
-        started = True
-    except Exception:
-        pass
-    try:
-        yield started
+        yield True
     finally:
-        if started:
-            try:
-                jax.profiler.stop_trace()
-            except Exception:
-                pass
+        jax.profiler.stop_trace()
         anchors["stop"] = telemetry.clock_anchor()
-        try:
-            path = os.path.join(out_dir,
-                                f"dmlc_anchor_{os.getpid()}.json")
-            with open(path, "w") as f:
-                json.dump(anchors, f)
-            telemetry.emit_event("jax-profile", dir=out_dir,
-                                 started=started)
-        except Exception:
-            pass
+        path = os.path.join(out_dir, f"dmlc_anchor_{os.getpid()}.json")
+        with open(path, "w") as f:
+            json.dump(anchors, f)
+        telemetry.emit_event("jax-profile", dir=out_dir, started=True)
 
 
 def _dense_dtype_of(d) -> np.dtype:
@@ -515,8 +469,12 @@ def _tree_aliases_host(host_tree: Dict[str, Any],
     memory span — i.e. device_put aliased instead of copied, so recycling
     the host buffer would corrupt live device data. Probes the actual
     buffer addresses (unsafe_buffer_pointer) instead of trusting backend
-    names; anything unprobeable is treated as aliasing (recycling is an
-    optimization, correctness must not depend on it)."""
+    names. A backend that will not give an address is treated as
+    aliasing (recycling is an optimization, correctness must not depend
+    on it); ``device_alias_probe_total{verdict=}`` says which of the
+    three answers — ``no_alias``, ``aliases``, ``unprobeable`` — this
+    iterator's one probe got."""
+    verdict = "no_alias"
     try:
         for k, h in host_tree.items():
             if not isinstance(h, np.ndarray):
@@ -527,10 +485,14 @@ def _tree_aliases_host(host_tree: Dict[str, Any],
             for s in getattr(d, "addressable_shards", ()):
                 p = s.data.unsafe_buffer_pointer()
                 if lo <= p < hi:
-                    return True
-        return False
-    except Exception:
-        return True
+                    verdict = "aliases"
+    except Exception as e:  # the backend's own error type is unknowable
+        verdict = "unprobeable"
+        telemetry.emit_event("device-alias-probe", verdict=verdict,
+                             error=f"{type(e).__name__}: {e}"[:200])
+    telemetry.counter("device_alias_probe_total",
+                      {"verdict": verdict}).inc()
+    return verdict != "no_alias"
 
 
 class _HostBufferPool:
@@ -1278,10 +1240,9 @@ class DeviceRowBlockIter:
         # records it so restore() can replay the exact visit order — a
         # batch prefix under a different permutation is different data.
         self._epoch = 0
-        # compile-churn observability: best-effort jax.monitoring
-        # listener (the shape census in _note_shape is the portable
-        # fallback); once per process, never raises
-        _install_compile_monitor()
+        # compile-churn observability: the jax.monitoring listener, once
+        # per process (a no-op when the entry point already installed it)
+        install_compile_monitor()
 
     # -- staging threads -----------------------------------------------------
     # Queue ops are stop-aware: a blocking put/get could otherwise race the

@@ -25,17 +25,11 @@ Smoke-testable on CPU:  JAX_PLATFORMS=cpu \
 """
 
 import argparse
+import json
 import os
 import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
-# same site-config workaround as examples/train.py: JAX_PLATFORMS must be
-# applied through jax.config to outrank platform-pinning site plugins
-if os.environ.get("JAX_PLATFORMS"):
-    import jax  # noqa: E402
-
-    jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
 
 import numpy as np  # noqa: E402
 
@@ -115,8 +109,12 @@ def main() -> int:
     import jax
     from jax.sharding import Mesh
     from dmlc_core_tpu.parallel import init_from_env
+    from dmlc_core_tpu.tpu.runtime import (compile_report, device_banner,
+                                           device_report,
+                                           enable_compile_cache)
     from dmlc_core_tpu.tpu.sharding import process_part
 
+    enable_compile_cache()
     init_from_env()  # multi-host: jax.distributed under dmlc-submit
 
     # elastic-mesh check-in (doc/robustness.md "Elastic mesh training"):
@@ -146,6 +144,7 @@ def main() -> int:
                          f"have {len(devs)}")
     mesh = Mesh(np.array(devs[:need]).reshape([n for _, n in axes]),
                 tuple(name for name, _ in axes))
+    print(device_banner(device_report(mesh)))
     names = dict(axes)
     n_data = names.get("data", 1)
     batch = args.batch or n_data
@@ -310,6 +309,7 @@ def main() -> int:
     if last is None:
         print(f"nothing to do: resume step {start} >= --steps {args.steps}")
         return 0
+    print("compile: " + json.dumps(compile_report()))
     print(f"done: loss {first:.4f} -> {last:.4f} over steps "
           f"{start}..{args.steps - 1} (mesh {args.mesh}, seq {args.seq}, "
           f"part {part}/{npart})")

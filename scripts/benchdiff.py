@@ -18,9 +18,9 @@ overhead guard, PR 7's scaling floor) settled on: a difference only
 counts when it exceeds what the host's own variation explains.  Here
 the variation is estimated from the ledger itself — the trailing
 coefficient of variation per metric when ``--trailing`` history exists
-— and floored by ``--band`` (default 0.25: doc/bench.md documents
-minute-to-minute host swings up to ±40%, so small deltas between
-single runs are weather, not signal).  A same-record self-compare is
+— and floored by ``--band`` (default 0.25: the hosts so far swung up
+to ±40% minute to minute, so small deltas between single runs are
+weather, not signal).  A same-record self-compare is
 exactly ratio 1.0 everywhere and always exits 0.
 """
 

@@ -42,6 +42,7 @@ MODULE_GROUPS = [
     ]),
     ("TPU device bridge", [
         "dmlc_core_tpu.tpu.device_iter",
+        "dmlc_core_tpu.tpu.runtime",
         "dmlc_core_tpu.tpu.sharding",
     ]),
     ("Ops & models", [
@@ -339,14 +340,13 @@ def gen_index() -> str:
         "circuit breaker), last-good model reloads, draining shutdown, "
         "bucket padding + compile census, endpoint/knob tables, the "
         "bench serving lane |",
-        "| [bench.md](bench.md) | benchmark methodology and bottleneck "
-        "analysis |",
         "| [benchmarking.md](benchmarking.md) | the honest measurement "
         "plane: out-of-process origin rig (pre-forked mock backends, "
         "one config surface), open-loop load generator "
         "(coordinated-omission-safe intended-time capture, shed "
-        "policy), host resource evidence, the bench provenance + "
-        "regression ledger and benchdiff noise bands |",
+        "policy), host resource evidence, how bench.py runs (one "
+        "process per chip, device lanes as children), the bench "
+        "provenance + regression ledger and benchdiff noise bands |",
         "",
         "Build: `make doc` (part of `make ci`) regenerates api.md and "
         "parameters.md and fails on any undocumented public symbol — the "

@@ -1,0 +1,173 @@
+"""One process per chip, pinned on the CPU: the parents of chip_smoke.py and
+bench.py stay off jax, a missing chip or a failed phase is a non-zero exit,
+and the persistent compile cache lives where the contract says."""
+
+import json
+import os
+import subprocess
+import sys
+
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+POISON = "poisoned jax: this process must stay off jax"
+
+
+@pytest.fixture
+def poisoned_env(tmp_path):
+    """An environment whose import path answers `import jax` with an
+    error — in the parent and in every child it starts."""
+    (tmp_path / "poison" / "jax").mkdir(parents=True)
+    (tmp_path / "poison" / "jax" / "__init__.py").write_text(
+        f"raise ImportError({POISON!r})\n")
+    env = dict(os.environ, PYTHONPATH=str(tmp_path / "poison"),
+               DMLC_BENCH_HISTORY="0")
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    return env
+
+
+def _run(argv, env, timeout=240):
+    return subprocess.run([sys.executable] + argv, cwd=REPO, env=env,
+                          capture_output=True, text=True, timeout=timeout)
+
+
+# -- chip_smoke.py ------------------------------------------------------------
+def test_chip_smoke_parent_survives_poisoned_jax(poisoned_env):
+    """The parent reaches its first child and reports that child's
+    failure: had the parent imported jax it would have died on the
+    poison itself, with a traceback and no phase name."""
+    out = _run(["chip_smoke.py"], poisoned_env)
+    assert out.returncode != 0
+    assert "chip_smoke: FAILED phase=device" in out.stderr
+    assert POISON in out.stderr          # the child's error, relayed
+    assert "Traceback" not in out.stderr.split("FAILED phase=device")[0]
+    assert '"ok"' not in out.stdout
+
+
+def test_chip_smoke_without_a_chip_names_phase_and_platform():
+    """On a CPU-only machine the first phase fails inside jax (the
+    children run with JAX_PLATFORMS=tpu: no fallback), the exit code is
+    non-zero and no result line is printed."""
+    out = _run(["chip_smoke.py"], dict(os.environ))
+    assert out.returncode != 0
+    assert "chip_smoke: FAILED phase=device" in out.stderr
+    assert "found no 'tpu' platform" in out.stderr
+    assert '"ok"' not in out.stdout
+
+
+def test_chip_smoke_failed_phase_propagates(tmp_path, monkeypatch, capsys):
+    """Any phase's non-zero child ends the run non-zero, names the
+    phase, runs no later phase and prints no result."""
+    import chip_smoke
+    monkeypatch.setattr(chip_smoke, "WORK", str(tmp_path / "work"))
+    monkeypatch.setattr(chip_smoke, "LOGS", str(tmp_path / "logs"))
+    ran = []
+
+    def fine(run):
+        ran.append("fine")
+        run.device = {"platform": "tpu", "kind": "fake", "count": 1}
+
+    def boom(run):
+        ran.append("boom")
+        run.child([sys.executable, "-c",
+                   "import sys; print('half done'); sys.exit(3)"])
+
+    def never(run):
+        ran.append("never")
+
+    rc = chip_smoke.run_phases([("fine", fine), ("boom", boom),
+                                ("never", never)])
+    io = capsys.readouterr()
+    assert rc != 0 and ran == ["fine", "boom"]
+    assert "FAILED phase=boom" in io.err and "exit 3" in io.err
+    assert '"ok"' not in io.out
+    # the child's whole output is kept for the builder
+    logs = os.listdir(tmp_path / "logs")
+    assert len(logs) == 1 and "boom" in logs[0]
+    assert "half done" in (tmp_path / "logs" / logs[0]).read_text()
+
+
+def test_chip_smoke_failed_check_propagates(tmp_path, monkeypatch, capsys):
+    import chip_smoke
+    monkeypatch.setattr(chip_smoke, "WORK", str(tmp_path / "work"))
+    monkeypatch.setattr(chip_smoke, "LOGS", str(tmp_path / "logs"))
+
+    def cpu_found(run):
+        chip_smoke.check(False, "jax found platform='cpu', not 'tpu'")
+
+    assert chip_smoke.run_phases([("device", cpu_found)]) != 0
+    err = capsys.readouterr().err
+    assert "FAILED phase=device" in err and "platform='cpu'" in err
+
+
+# -- bench.py -----------------------------------------------------------------
+def test_bench_parent_survives_poisoned_jax(poisoned_env):
+    """Host-only run: the parent does its whole job — data, scaling
+    table, the headline child, the host probes, the result line —
+    without jax on the path."""
+    out = _run(["bench.py", "--smoke", "--rows", "2000", "--parse-only",
+                "--no-rec-lane", "--no-ledger"], poisoned_env)
+    assert out.returncode == 0, out.stderr[-2000:]
+    result = json.loads(out.stdout.strip().splitlines()[-1])
+    assert result["metric"] == "higgs_libsvm_ingest_rows_per_sec"
+    assert result["value"] > 0
+    assert "hbm_ingest_bw_util" not in result["extras"]
+
+
+def test_bench_lane_failure_fails_the_run(poisoned_env):
+    """Device run: the first device lane's child dies (here on the
+    poison); the parent names the lane, exits non-zero and prints no
+    result — and it got that far without importing jax itself."""
+    out = _run(["bench.py", "--smoke", "--rows", "2000", "--no-ledger",
+                "--no-scaling-table"], poisoned_env)
+    assert out.returncode != 0
+    assert "bench: libsvm lane failed" in out.stderr
+    assert POISON in out.stderr
+    assert out.stdout.strip() == ""
+
+
+def test_bench_device_lane_refuses_cpu():
+    """A lane that writes device-named metrics does not run on the CPU
+    backend: no CPU number under an hbm_* name."""
+    out = _run(["bench.py", "--device-lane", "--rows", "2000"],
+               dict(os.environ, JAX_PLATFORMS="cpu"))
+    assert out.returncode != 0
+    assert "platform=cpu" in out.stderr
+    assert out.stdout.strip() == ""
+
+
+# -- the compile cache --------------------------------------------------------
+_SHOW_CACHE = (
+    "import jax\n"
+    "from dmlc_core_tpu.tpu.runtime import enable_compile_cache\n"
+    "print(enable_compile_cache())\n"
+    "print(jax.config.jax_compilation_cache_dir)\n"
+    "print(jax.config.jax_persistent_cache_min_compile_time_secs)\n")
+
+
+def _show_cache(env, cwd):
+    out = subprocess.run([sys.executable, "-c", _SHOW_CACHE], cwd=cwd,
+                         env=dict(env, PYTHONPATH=REPO),
+                         capture_output=True, text=True, timeout=240)
+    assert out.returncode == 0, out.stderr[-2000:]
+    return out.stdout.split()
+
+
+def test_compile_cache_env_set_means_code_sets_nothing(tmp_path):
+    where = str(tmp_path / "elsewhere")
+    got = _show_cache(dict(os.environ, JAX_COMPILATION_CACHE_DIR=where),
+                      str(tmp_path))
+    # jax read the variable itself; the helper set no path and left
+    # jax's own caching floor alone
+    assert got == [where, where, "1.0"]
+
+
+def test_compile_cache_default_is_one_fixed_path(tmp_path):
+    env = dict(os.environ)
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    (tmp_path / "a").mkdir()
+    (tmp_path / "b").mkdir()
+    first = _show_cache(env, str(tmp_path / "a"))
+    second = _show_cache(env, str(tmp_path / "b"))
+    want = os.path.join(REPO, ".jax_cache")
+    assert first == second == [want, want, "0.0"]

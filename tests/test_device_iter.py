@@ -184,6 +184,35 @@ def test_linear_learner_converges(tmp_path):
     assert abs(w[0]) > 3 * np.abs(w[1:]).max()
 
 
+@pytest.mark.parametrize("model", ["linear", "fm"])
+def test_data_parallel_step_is_the_global_batch_step(tmp_path, model):
+    """Eight devices or none, the same global batches must take the same
+    steps: the gradient is reduced over the mesh exactly once (replicated
+    params differentiated unvarying get psum'd by autodiff itself, and the
+    step's own psum then counts the gradient once per device)."""
+    from dmlc_core_tpu.models.fm import FMLearner
+    p = write_libsvm(tmp_path / "dp.libsvm", rows=2048, features=8)
+
+    def train(mesh):
+        learner = (LinearLearner(8, mesh=mesh, learning_rate=0.5)
+                   if model == "linear" else
+                   FMLearner(8, k=4, mesh=mesh, learning_rate=0.2))
+        params = learner.init()
+        losses = []
+        with DeviceRowBlockIter(str(p), batch_rows=512, mesh=mesh,
+                                layout="csr", min_nnz_bucket=512) as it:
+            for batch in it:
+                params, loss = learner.step(params, batch)
+                losses.append(float(loss))
+        return losses, np.asarray(params.w)
+
+    losses_one, w_one = train(None)
+    losses_dp, w_dp = train(data_mesh())
+    assert losses_one[-1] < losses_one[0]
+    np.testing.assert_allclose(losses_dp, losses_one, rtol=1e-5)
+    np.testing.assert_allclose(w_dp, w_one, rtol=1e-4, atol=1e-6)
+
+
 def test_linear_learner_single_device(tmp_path):
     p = write_libsvm(tmp_path / "g.libsvm", rows=512, features=4)
     learner = LinearLearner(4, mesh=None, learning_rate=0.5)

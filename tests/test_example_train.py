@@ -1,6 +1,7 @@
 """The shipped end-to-end example must actually run: train, checkpoint,
 resume — as a real subprocess, the way a user would invoke it."""
 
+import json
 import os
 import subprocess
 import sys
@@ -38,6 +39,16 @@ def test_example_trains_checkpoints_resumes(tmp_path):
               for line in out.splitlines() if "mean loss" in line]
     assert len(losses) == 2 and losses[1] < losses[0], out
     assert os.path.exists(ckpt)
+    # every run says where it ran, once, and ends in one summary line
+    assert "device: platform=cpu device_kind='cpu' count=8 mesh=data=8" \
+        in out
+    summary = json.loads(out.splitlines()[-1].split("summary: ", 1)[1])
+    assert summary["device"]["platform"] == "cpu"
+    assert [e["rows"] for e in summary["epochs"]] == [1200, 1200]
+    assert summary["epochs"][1]["new_shapes"] == 0
+    assert all(len(ids) == 8 for ids in
+               summary["first_batch_devices"].values())
+    assert summary["compile"]["cache_dir"]
 
     # resume continues from epoch 2 (one more epoch only)
     out2 = _run([str(data), "--epochs", "3", "--batch-rows", "256",

@@ -1,13 +1,28 @@
-"""Pallas CSR->dense kernel vs the XLA scatter oracle (interpret mode on
-the CPU mesh; the same kernel compiles for TPU)."""
+"""Pallas CSR->dense kernel vs the XLA scatter oracle. These tests ask for
+interpret mode by name; the compiled kernel is checked on the chip by
+chip_smoke.py."""
+
+import functools
 
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
-from dmlc_core_tpu.ops.pallas_kernels import csr_to_dense_pallas
+from dmlc_core_tpu.ops import pallas_kernels
 from dmlc_core_tpu.ops.sparse import csr_to_dense
+
+csr_to_dense_pallas = functools.partial(pallas_kernels.csr_to_dense_pallas,
+                                        interpret=True)
+
+
+@pytest.fixture
+def interpreted_switch(monkeypatch):
+    """Route the csr_to_dense(impl="pallas") switch through interpret
+    mode: there is no Mosaic on the CPU backend."""
+    monkeypatch.setattr(pallas_kernels, "csr_to_dense_pallas",
+                        csr_to_dense_pallas)
 
 
 def random_csr(rng, R, F, nnz, pad=0):
@@ -21,12 +36,14 @@ def random_csr(rng, R, F, nnz, pad=0):
     return jnp.asarray(row), jnp.asarray(col), jnp.asarray(val)
 
 
+# the last case spans several row tiles, feature tiles and nnz chunks
 @pytest.mark.parametrize("R,F,nnz", [(8, 28, 100), (17, 130, 999),
-                                     (3, 5, 1), (64, 256, 4096)])
+                                     (3, 5, 1), (64, 256, 4096),
+                                     (300, 700, 2500)])
 def test_matches_xla_scatter(R, F, nnz):
     rng = np.random.default_rng(R * F + nnz)
     row, col, val = random_csr(rng, R, F, nnz)
-    got = csr_to_dense_pallas(row, col, val, R, F, chunk=128)
+    got = csr_to_dense_pallas(row, col, val, R, F)
     want = csr_to_dense(row, col, val, R, F)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-6, atol=1e-6)
@@ -36,7 +53,7 @@ def test_padding_rows_dropped():
     # entries with row == num_rows are the PaddedBatch sacrificial slot
     rng = np.random.default_rng(0)
     row, col, val = random_csr(rng, 8, 16, 50, pad=30)
-    got = csr_to_dense_pallas(row, col, val, 8, 16, chunk=64)
+    got = csr_to_dense_pallas(row, col, val, 8, 16)
     want = csr_to_dense(row, col, val, 8, 16)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-6, atol=1e-6)
@@ -60,7 +77,7 @@ def test_empty_matrix():
     assert float(np.abs(np.asarray(got)).sum()) == 0.0
 
 
-def test_csr_to_dense_impl_switch(monkeypatch):
+def test_csr_to_dense_impl_switch(monkeypatch, interpreted_switch):
     # the opt-in device-side formatting path: explicit impl= and the
     # DCT_CSR_TO_DENSE env both dispatch to the Pallas kernel
     rng = np.random.default_rng(4)
@@ -77,7 +94,8 @@ def test_csr_to_dense_impl_switch(monkeypatch):
         csr_to_dense(row, col, val, 16, 24)
 
 
-def test_linear_dense_margin_path_matches_segment(tmp_path, monkeypatch):
+def test_linear_dense_margin_path_matches_segment(tmp_path, monkeypatch,
+                                                  interpreted_switch):
     # training through margin_path="dense" with the Pallas formatter must
     # follow the same trajectory as the segment-sum path (the kernel only
     # formats batch data — gradients never flow through it)
@@ -109,45 +127,30 @@ def test_linear_dense_margin_path_matches_segment(tmp_path, monkeypatch):
     np.testing.assert_allclose(w_dense, w_seg, rtol=1e-5, atol=1e-7)
 
 
-def test_oversized_output_falls_back_to_xla():
-    # [R_pad, F_pad] f32 must stay VMEM-resident; a shard too large for
-    # that silently takes the XLA scatter with identical values
-    rng = np.random.default_rng(6)
-    R, F = 4096, 1024  # 16 MB accumulator > the 12 MB guard
-    row, col, val = random_csr(rng, R, F, 500)
-    got = csr_to_dense_pallas(row, col, val, R, F)
-    want = csr_to_dense(row, col, val, R, F)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=1e-6, atol=1e-6)
+def test_interpret_mode_refuses_shard_map():
+    # the interpreted body cannot type-check under varying manual axes;
+    # it must say so, not hand the shard to another implementation
+    from jax.sharding import Mesh, PartitionSpec as P
+    mesh = Mesh(np.array(jax.devices()[:2]), ("data",))
+    rng = np.random.default_rng(1)
+    row, col, val = (jnp.stack([a, a]) for a in random_csr(rng, 8, 16, 64))
 
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=P("data"),
+                       out_specs=P("data"))
+    def fmt(r, c, v):
+        return csr_to_dense_pallas(r[0], c[0], v[0], 8, 16)[None]
 
-def test_bench_probe_shape_stays_on_kernel(monkeypatch):
-    # bench.py's pallas probe shape must pass the VMEM guard — a silent
-    # fallback would time the XLA scatter against itself
-    import dmlc_core_tpu.ops.sparse as sparse_mod
-    from bench import pallas_format_probe
-    import inspect
-    R = inspect.signature(pallas_format_probe).parameters[
-        "batch_rows"].default
-
-    def boom(*a, **k):
-        raise AssertionError("probe shape fell back to the XLA scatter")
-
-    monkeypatch.setattr(sparse_mod, "csr_to_dense", boom)
-    rng = np.random.default_rng(2)
-    row, col, val = random_csr(rng, R, 28, R * 28)
-    out = csr_to_dense_pallas(row, col, val, R, 28)  # interpret on CPU
-    assert out.shape == (R, 28)
+    with pytest.raises(ValueError, match="cannot run inside shard_map"):
+        fmt(row, col, val)
 
 
 def test_tpu_mosaic_lowering_exports():
     # the kernel must survive the real TPU lowering pipeline (Mosaic)
     # even on a host with no chip — block-spec/layout bugs surface here
-    import jax
     from jax import export
 
     def fmt(r, c, v):
-        return csr_to_dense_pallas(r, c, v, 64, 28, interpret=False)
+        return pallas_kernels.csr_to_dense_pallas(r, c, v, 64, 28)
 
     i32 = jax.ShapeDtypeStruct((2048,), jnp.int32)
     exp = export.export(jax.jit(fmt), platforms=["tpu"])(

@@ -1,7 +1,7 @@
 """Multi-core parse-scaling guard (VERDICT r3 item 8).
 
 The worker fan-out (parser.cc FillBlocks tiling) has correctness coverage
-under TSan but the bench host exposes ONE core (doc/bench.md), so its
+under TSan but the first bench host exposed ONE core, so its
 thread_scaling table is structurally flat and a serialization bug that
 only shows up multi-core would go unnoticed. This test asserts real
 scaling the day the suite runs on a multi-core host and auto-skips on
@@ -46,13 +46,12 @@ def _usable_cpus() -> int:
 # (measured on a 2-core container: prefetch reader + 2 parse workers + the
 # consuming thread cap the sync fan-out at ~1.0-1.3x, and the pipelined
 # path at ~1.2-1.7x, regardless of correctness — a threshold there only
-# measures the scheduler). The bench host has ONE core (doc/bench.md), so
+# measures the scheduler). The first bench host had ONE core, so
 # this continues to auto-skip until the suite lands on a real multi-core
 # host.
 @pytest.mark.skipif(_usable_cpus() < 4,
                     reason="parse scaling needs >= 4 schedulable cores "
-                           "(stage threads contend below that; single-core "
-                           "bench host: doc/bench.md)")
+                           "(stage threads contend below that)")
 def test_parse_throughput_scales_with_cores(tmp_path):
     rng = np.random.default_rng(12)
     path = tmp_path / "scale.libsvm"

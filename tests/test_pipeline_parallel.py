@@ -9,14 +9,10 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-try:
-    from jax import shard_map
-except ImportError:  # pre-0.5 jax spells it experimental
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from dmlc_core_tpu.parallel.pipeline_parallel import pipeline_apply
-from dmlc_core_tpu.parallel import varying
 
 
 def stage_fn(w, x):
@@ -100,15 +96,6 @@ def test_pipeline_composes_with_data_axis():
                                    atol=1e-5)
 
 
-@pytest.mark.skipif(
-    not varying._VARYING_TYPED,
-    reason="pipeline BACKWARD needs the varying-type discipline: on a "
-           "pre-0.5 jax (experimental shard_map, untyped values) the "
-           "transpose of the replicated loss output seeds a full "
-           "cotangent on every pipe rank, double-counting stage "
-           "gradients by exactly the axis size — with or without "
-           "check_rep. Forward scheduling (the tests above) is "
-           "unaffected.")
 def test_pipeline_backward_trains():
     """Autodiff through the schedule: per-stage gradients match the
     sequential program's, and a few SGD steps reduce the loss."""

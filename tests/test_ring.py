@@ -13,10 +13,7 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-try:
-    from jax import shard_map
-except ImportError:  # pre-0.5 jax spells it experimental
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from dmlc_core_tpu.ops.attention import blockwise_attention, mha_reference

@@ -33,7 +33,8 @@ import pytest
 from dmlc_core_tpu import telemetry
 from dmlc_core_tpu.serving import batching
 from dmlc_core_tpu.serving import model as serving_model
-from dmlc_core_tpu.serving.server import BREAKER_CLOSED, BREAKER_OPEN
+from dmlc_core_tpu.serving.server import (BREAKER_CLOSED, BREAKER_OPEN,
+                                          ScoringServer, ServingConfig)
 from dmlc_core_tpu.tracker import minihttp
 from tests.serving_util import (AsyncReq, Client, ForwardGate,
                                 expect_scores, raw_http, save_linear,
@@ -131,6 +132,10 @@ def test_endpoints_and_4xx_edges(tmp_path):
             doc = json.loads(body)
             assert doc["rows_buckets"] == [4]
             assert doc["model"]["kind"] == "linear"
+            assert doc["device"] == {"platform": "cpu",
+                                     "device_kind": "cpu",
+                                     "device_count": 8}
+            assert doc["compile"]["backend_compiles"] >= 0
             status, body = cli.request("GET", "/metrics")
             assert status == 200
             assert b"serve_requests_total" in body
@@ -379,6 +384,41 @@ def test_draining_answers_admitted_sheds_rest(tmp_path):
 # ---------------------------------------------------------------------------
 # bucket padding / compile-churn census
 # ---------------------------------------------------------------------------
+def test_warm_compiles_the_ladder_before_start(tmp_path):
+    """Ready means ready: warm() runs the forward at every shape of the
+    ladder, so traffic no denser than the floor shape meets no new
+    shape — on the chip a first-sight compile costs more than the
+    lateness budget and the latency objective allow."""
+    assert batching.warm_shapes((4, 16), 32) == [
+        (4, 32), (16, 32), (16, 64), (16, 128)]
+    uri, w, b = save_linear(tmp_path)
+    srv = ScoringServer(model_uri=uri, config=ServingConfig(
+        rows_buckets="4,16", min_nnz_bucket=32, batch_delay_ms=0.0))
+    serving_model._reset_shape_census()
+    assert srv.warm() == 4
+    assert serving_model.distinct_shapes() == 4
+    srv.start()
+    try:
+        cli = Client(srv.port)
+        try:
+            rng = np.random.default_rng(3)
+            for rows in (1, 4, 5, 16, 9):       # 8 nonzeros per row
+                lines = ["1 " + " ".join(
+                    f"{j}:{rng.uniform(-1, 1):.4f}"
+                    for j in sorted(rng.choice(32, 8, replace=False)))
+                    for _ in range(rows)]
+                status, body = cli.score(lines)
+                assert status == 200
+                np.testing.assert_allclose(json.loads(body)["scores"],
+                                           expect_scores(lines, w, b),
+                                           atol=1e-5)
+            assert serving_model.distinct_shapes() == 4
+        finally:
+            cli.close()
+    finally:
+        srv.stop(drain=False, grace_s=3.0)
+
+
 def test_ragged_traffic_steady_new_shapes_zero(tmp_path):
     """After one warmup per bucket, ragged row counts produce ZERO new
     forward shapes: the serving analogue of the PR 15 device-lane

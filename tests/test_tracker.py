@@ -405,8 +405,6 @@ def test_local_cluster_workers_cover_dataset_exactly(tmp_path):
 import os, sys
 sys.path.insert(0, {str(REPO)!r})
 os.environ['JAX_PLATFORMS'] = 'cpu'
-import jax
-jax.config.update('jax_platforms', 'cpu')
 from dmlc_core_tpu.tpu.sharding import process_part
 from dmlc_core_tpu.io.native import NativeParser
 from dmlc_core_tpu.tracker.client import RendezvousClient

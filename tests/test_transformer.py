@@ -9,7 +9,6 @@ import numpy as np
 import pytest
 
 import jax
-import jax.numpy as jnp
 from jax.sharding import Mesh
 
 from dmlc_core_tpu.models.transformer import TransformerConfig, TransformerLM
@@ -85,27 +84,3 @@ def test_params_stay_replicated():
     params, _ = model.step(params, toks, labels)
     emb = params["embed"]
     assert emb.sharding.is_fully_replicated
-
-
-def test_mark_varying_unsupported_jax_raises(monkeypatch):
-    # On a VARYING-TYPED jax (native jax.shard_map), neither lax.pcast
-    # nor lax.pvary means the cast API was renamed again: silently
-    # skipping the cast would double-count gradients (ADVICE r1); must
-    # raise instead. The probe lives in the shared parallel.varying
-    # helper (one place for the next JAX API rename).
-    import dmlc_core_tpu.parallel.varying as vmod
-
-    class _BareLax:  # stands in for a JAX version lacking both APIs
-        pass
-
-    monkeypatch.setattr(vmod, "lax", _BareLax())
-    monkeypatch.setattr(vmod, "_VARYING_TYPED", True)
-    with pytest.raises(RuntimeError, match="pcast nor lax.pvary"):
-        TransformerLM._mark_varying({"w": jnp.ones(2)}, ("data",))
-
-    # on a pre-varying-type jax (experimental shard_map, untyped
-    # values) the identity is the CORRECT behavior: check_rep tracks
-    # replication and the transpose rule needs no explicit cast
-    monkeypatch.setattr(vmod, "_VARYING_TYPED", False)
-    tree = {"w": jnp.ones(2)}
-    assert TransformerLM._mark_varying(tree, ("data",)) is tree

@@ -220,6 +220,44 @@ def test_deferred_recycle_reuses_pool_for_prompt_consumer(tmp_path,
     assert _gauges().get("device_recycle_skipped", 0) == 0
 
 
+def _alias_verdicts():
+    return {labels["verdict"]: value
+            for name, labels, value in _counters(labeled=True)
+            if name == "device_alias_probe_total" and value}
+
+
+def test_alias_probe_verdict_is_counted():
+    """Probed-and-aliases, probed-and-copies and could-not-probe are
+    three answers; the last one still defers recycling (correctness must
+    not depend on the probe) but no longer hides in the first."""
+    from dmlc_core_tpu.tpu.device_iter import _tree_aliases_host
+    telemetry.reset()
+    host = _aligned_empty((4, 64), np.int32)
+    host[...] = 7
+    other = _aligned_empty((4, 64), np.int32)
+    other[...] = 7
+
+    # XLA:CPU aliases a 64-byte-aligned host buffer on device_put
+    assert _tree_aliases_host({"a": host}, {"a": jax.device_put(host)})
+    assert _alias_verdicts() == {"aliases": 1}
+    # a device array that lives elsewhere (what a DMA to HBM looks like)
+    assert not _tree_aliases_host({"a": host},
+                                  {"a": jax.device_put(other)})
+    assert _alias_verdicts() == {"aliases": 1, "no_alias": 1}
+
+    class NoPointer:                       # a backend that will not say
+        @property
+        def addressable_shards(self):
+            raise RuntimeError("no host-visible address on this backend")
+
+    assert _tree_aliases_host({"a": host}, {"a": NoPointer()})
+    assert _alias_verdicts() == {"aliases": 1, "no_alias": 1,
+                                 "unprobeable": 1}
+    assert any(e.get("event") == "device-alias-probe"
+               and "no host-visible address" in e.get("error", "")
+               for e in telemetry.events())
+
+
 def test_prefetch0_sync_mode_matches_pipelined(tmp_path, monkeypatch):
     """prefetch=0 (no pipeline threads) must land byte-identical batches
     and the same counters as the default threaded pipeline."""
