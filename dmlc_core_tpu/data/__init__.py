@@ -569,17 +569,17 @@ class RowBlockIter:
         batch_us, batches, skips = _get_batch_metrics()
         while True:
             try:
-                t0 = time.perf_counter() if telemetry.enabled() else None
-                b = self._parser.next_block()
-                if t0 is not None:
-                    dur_us = (time.perf_counter() - t0) * 1e6
-                    batch_us.observe(dur_us)
-                    # same measurement, second surface: the span ring
-                    # (doc/observability.md "Distributed tracing")
-                    telemetry.emit_span(
-                        "rowblock.next", t0 * 1e6, dur_us,
-                        rows=getattr(b, "num_rows", 0) if b is not None
-                        else 0)
+                if telemetry.enabled():
+                    # same measurement, second surface: an opened span
+                    # (ring + profiler annotation, doc/observability.md
+                    # "Distributed tracing")
+                    with telemetry.span("rowblock.next") as sp:
+                        b = self._parser.next_block()
+                        batch_us.observe(sp.elapsed_us)
+                        sp.set_arg("rows", getattr(b, "num_rows", 0)
+                                   if b is not None else 0)
+                else:
+                    b = self._parser.next_block()
                 if b is not None:
                     batches.inc()
                 return b

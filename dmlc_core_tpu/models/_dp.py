@@ -7,6 +7,12 @@ value_and_grad of the shard loss, psum the (loss, weight, grad) triple
 once over ICI (the Rabit allreduce equivalent, SURVEY §2.5), apply the
 update, and jit-cache per batch shape. That harness lives here once.
 
+The phases of the jitted step carry ``jax.named_scope``s (``dp.unpack``,
+``dp.loss_grad``, ``dp.allreduce``, ``dp.apply``; the models add their own
+inside ``dp.loss_grad``), which change the operations' ``op_name`` metadata
+only: a device trace then names its time by phase whatever the compiler
+numbers its fusions (doc/observability.md "Device lane").
+
 Subclasses implement:
   _shard_loss(params, shard, rows_per_shard) -> (loss_sum, weight_sum)
   _apply(params, grads, denom) -> new params
@@ -21,6 +27,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
+from dmlc_core_tpu import telemetry
 from dmlc_core_tpu.parallel.varying import mark_varying
 from dmlc_core_tpu.tpu.device_iter import unpack_shard
 
@@ -49,22 +56,27 @@ class DataParallelModel:
         def shard_view(tree):
             """Drop the device axis and unpack aux/big into named arrays
             (a bitcast+slice — free inside the jitted step)."""
-            local = {k: v[0] for k, v in tree.items()}
-            return unpack_shard(local)
+            with jax.named_scope("dp.unpack"):
+                local = {k: v[0] for k, v in tree.items()}
+                return unpack_shard(local)
 
         def local_grads(params, shard):
             def loss_fn(p):
                 return self._shard_loss(p, shard, rows_per_shard)
-            (loss_sum, wsum), grads = jax.value_and_grad(
-                loss_fn, has_aux=True)(params)
+            with jax.named_scope("dp.loss_grad"):
+                (loss_sum, wsum), grads = jax.value_and_grad(
+                    loss_fn, has_aux=True)(params)
             return loss_sum, wsum, grads
+
+        def apply(params, grads, loss_sum, wsum):
+            with jax.named_scope("dp.apply"):
+                denom = jnp.maximum(wsum, 1.0)
+                return self._apply(params, grads, denom), loss_sum / denom
 
         if self.mesh is None:
             def step(params, tree):
-                shard = shard_view(tree)
-                loss_sum, wsum, grads = local_grads(params, shard)
-                denom = jnp.maximum(wsum, 1.0)
-                return self._apply(params, grads, denom), loss_sum / denom
+                loss_sum, wsum, grads = local_grads(params, shard_view(tree))
+                return apply(params, grads, loss_sum, wsum)
             return jax.jit(step)
 
         @functools.partial(jax.shard_map, mesh=self.mesh,
@@ -80,11 +92,11 @@ class DataParallelModel:
                 mark_varying(params, (axis,)), shard)
             # ONE reduction per step over ICI — the Rabit allreduce
             # equivalent (SURVEY §2.5)
-            loss_sum = jax.lax.psum(loss_sum, axis)
-            wsum = jax.lax.psum(wsum, axis)
-            grads = jax.tree.map(lambda g: jax.lax.psum(g, axis), grads)
-            denom = jnp.maximum(wsum, 1.0)
-            return self._apply(params, grads, denom), loss_sum / denom
+            with jax.named_scope("dp.allreduce"):
+                loss_sum = jax.lax.psum(loss_sum, axis)
+                wsum = jax.lax.psum(wsum, axis)
+                grads = jax.tree.map(lambda g: jax.lax.psum(g, axis), grads)
+            return apply(params, grads, loss_sum, wsum)
 
         return jax.jit(sharded_step)
 
@@ -105,7 +117,18 @@ class DataParallelModel:
                 f"build the batch with num_shards={n_dev}")
         sig = tuple((k, tuple(v.shape)) for k, v in sorted(tree.items()))
         fn = self._step_fn.get(sig)
-        if fn is None:
+        built = fn is None
+        if built:
             fn = self._step_fn[sig] = self._build_step(
                 batch.rows_per_shard, tuple(sorted(tree.keys())))
-        return fn(params, tree)
+            telemetry.counter("model_step_builds_total",
+                              {"model": type(self).__name__}).inc()
+        if not telemetry.enabled():
+            return fn(params, tree)
+        # model.step: the host's hand-over of one step to the runtime (the
+        # call that built the function also traces and compiles inside it)
+        with telemetry.span("model.step", built=int(built)) as sp:
+            out = fn(params, tree)
+            telemetry.histogram("model_step_dispatch_us").observe(
+                sp.elapsed_us)
+        return out

@@ -46,21 +46,28 @@ def _fm_margin_csr(params: FMParams, row, col, val, num_rows: int
     seg = functools.partial(jax.ops.segment_sum,
                             num_segments=num_rows + 1,
                             indices_are_sorted=True)
-    linear = csr_matvec(row, col, val, params.w, num_rows)
-    vx = params.v[col] * val[:, None]          # [NNZ, K]
-    s1 = seg(vx, row)[:num_rows]               # Σ V x   per row  [R, K]
-    s2 = seg(vx * vx, row)[:num_rows]          # Σ V²x²  per row  [R, K]
-    inter = 0.5 * jnp.sum(s1 * s1 - s2, axis=-1)
+    # named scopes: op_name metadata only; the backward ops read
+    # .../transpose(jvp(fm.gather))/..., which is how a trace tells the
+    # scatter into the dense gradient from the forward gather
+    with jax.named_scope("fm.linear"):
+        linear = csr_matvec(row, col, val, params.w, num_rows)
+    with jax.named_scope("fm.gather"):
+        vx = params.v[col] * val[:, None]          # [NNZ, K]
+    with jax.named_scope("fm.interaction"):
+        s1 = seg(vx, row)[:num_rows]               # Σ V x   per row  [R, K]
+        s2 = seg(vx * vx, row)[:num_rows]          # Σ V²x²  per row  [R, K]
+        inter = 0.5 * jnp.sum(s1 * s1 - s2, axis=-1)
     return params.b + linear + inter
 
 
 def _fm_margin_dense(params: FMParams, x) -> jnp.ndarray:
-    xf = x.astype(jnp.float32)
-    linear = xf @ params.w
-    s1 = xf @ params.v                         # [R, K] (MXU)
-    s2 = (xf * xf) @ (params.v * params.v)     # [R, K] (MXU)
-    inter = 0.5 * jnp.sum(s1 * s1 - s2, axis=-1)
-    return params.b + linear + inter
+    with jax.named_scope("fm.dense"):
+        xf = x.astype(jnp.float32)
+        linear = xf @ params.w
+        s1 = xf @ params.v                         # [R, K] (MXU)
+        s2 = (xf * xf) @ (params.v * params.v)     # [R, K] (MXU)
+        inter = 0.5 * jnp.sum(s1 * s1 - s2, axis=-1)
+        return params.b + linear + inter
 
 
 def _margin(params: FMParams, shard, num_rows: int) -> jnp.ndarray:
@@ -140,6 +147,7 @@ class FMLearner(DataParallelModel):
         fwd = self._fwd_fn.get(R)
         if fwd is None:
             @jax.jit
+            @jax.named_scope("fm.predict")
             def fwd(params, tree):
                 tree = unpack_tree(tree)
                 if "x" in tree:

@@ -85,16 +85,17 @@ def _shard_loss(params: LinearParams, shard: Dict[str, jnp.ndarray],
     Pallas kernel) and takes one matmul. The materialization depends only
     on batch data, never on params, so autodiff does not differentiate
     through the formatting kernel."""
-    if "x" in shard:  # dense layout: one MXU matvec
-        margin = shard["x"].astype(jnp.float32) @ params.w + params.b
-    elif margin_path == "dense":
-        from dmlc_core_tpu.ops.sparse import csr_to_dense
-        dense = csr_to_dense(shard["row"], shard["col"], shard["val"],
-                             num_rows, params.w.shape[0])
-        margin = dense @ params.w + params.b
-    else:
-        margin = csr_matvec(shard["row"], shard["col"], shard["val"],
-                            params.w, num_rows) + params.b
+    with jax.named_scope("linear.margin"):
+        if "x" in shard:  # dense layout: one MXU matvec
+            margin = shard["x"].astype(jnp.float32) @ params.w + params.b
+        elif margin_path == "dense":
+            from dmlc_core_tpu.ops.sparse import csr_to_dense
+            dense = csr_to_dense(shard["row"], shard["col"], shard["val"],
+                                 num_rows, params.w.shape[0])
+            margin = dense @ params.w + params.b
+        else:
+            margin = csr_matvec(shard["row"], shard["col"], shard["val"],
+                                params.w, num_rows) + params.b
     return objective_loss(margin, shard, num_rows, objective)
 
 
@@ -157,6 +158,7 @@ class LinearLearner(DataParallelModel):
         fwd = self._fwd_fn.get(R)
         if fwd is None:
             @jax.jit
+            @jax.named_scope("linear.predict")
             def fwd(params, tree):
                 tree = unpack_tree(tree)  # packed batches: bitcast + slice
                 if "x" in tree:

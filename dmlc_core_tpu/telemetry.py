@@ -31,6 +31,7 @@ import json
 import math
 import os
 import re
+import sys
 import threading
 import time
 from typing import Callable, Dict, List, Optional, Tuple
@@ -73,6 +74,11 @@ _spans_dropped = 0
 _span_seq = 0
 _tids: Dict[int, int] = {}
 _tls = threading.local()
+# jax.profiler.TraceAnnotation, looked up once jax is loaded (never
+# imported from here: the tracker imports this module and must stay off
+# jax); every annotation's name carries this prefix in the profiler's trace
+_annotation_cls = None
+ANNOTATION_PREFIX = "dmlc."
 
 
 def _labels_key(labels: Optional[Dict[str, str]]
@@ -417,21 +423,40 @@ def emit_span(name: str, start_us: float, dur_us: float,
     _append_span(name, span_id, parent, start_us, dur_us, args or None)
 
 
+def _trace_annotation():
+    """``jax.profiler.TraceAnnotation`` where this process has jax loaded,
+    else None. The class is a no-op ``TraceMe`` while no profile runs, so
+    an opened span needs no "is a profile running" switch."""
+    global _annotation_cls
+    if _annotation_cls is None:
+        jax = sys.modules.get("jax")
+        # mid-import jax has no `profiler` attribute yet: look again later
+        _annotation_cls = getattr(getattr(jax, "profiler", None),
+                                  "TraceAnnotation", None)
+    return _annotation_cls
+
+
 class _Span:
     """Context manager behind :func:`span`; exposes ``set_arg`` for the
-    dominant dimension of the work (bytes, rows, shard id)."""
+    dominant dimension of the work (bytes, rows, shard id). While open it
+    is also a profiler annotation ``dmlc.<name>`` (see :func:`span`)."""
 
-    __slots__ = ("name", "args", "_start", "_id", "_parent", "_active")
+    __slots__ = ("name", "args", "_start", "_id", "_parent", "_active",
+                 "_ann")
 
     def __init__(self, name: str, args: Optional[dict]):
         self.name = name
         self.args = args
+        self._active = False
+        self._ann = None
 
     def set_arg(self, key: str, value) -> None:
         """Attach one key/value to the span's args."""
         if self.args is None:
             self.args = {}
         self.args[key] = value
+        if self._ann is not None:
+            self._ann.set_metadata(**{key: value})
 
     def __enter__(self) -> "_Span":
         self._active = enabled()
@@ -443,13 +468,26 @@ class _Span:
             self._id = _span_seq
         self._parent = getattr(_tls, "open_span", 0)
         _tls.open_span = self._id
+        cls = _trace_annotation()
+        if cls is not None:
+            self._ann = cls(ANNOTATION_PREFIX + self.name,
+                            **(self.args or {}))
+            self._ann.__enter__()
         self._start = _perf_us()
         return self
+
+    @property
+    def elapsed_us(self) -> float:
+        """Microseconds since the span opened (0 when telemetry is off):
+        the one reading a site's histogram shares with its span."""
+        return _perf_us() - self._start if self._active else 0.0
 
     def __exit__(self, exc_type, exc, tb) -> None:
         if not self._active:
             return
         dur = _perf_us() - self._start
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
         _tls.open_span = self._parent
         _append_span(self.name, self._id, self._parent, self._start, dur,
                      self.args)
@@ -460,7 +498,14 @@ def span(name: str, **args) -> _Span:
     records one completed span (perf-counter clock, µs) into the process
     span ring at scope exit, parented under the thread's currently open
     span. Disabled (:func:`enabled` False) cost: one attribute read.
-    Extra kwargs become the span's ``args``."""
+    Extra kwargs become the span's ``args``.
+
+    One clock for host and device: in a process that has jax loaded an
+    open span is also a ``jax.profiler.TraceAnnotation("dmlc." + name)``
+    carrying the kwargs, so under ``jax.profiler.start_trace`` it lands in
+    the trace's ``/host:CPU`` plane on the profiler's clock, beside the
+    device planes (no-op ``TraceMe`` while no profile runs). Spans emitted
+    post hoc (:func:`emit_span`) live in the ring only."""
     return _Span(name, args or None)
 
 
@@ -1047,6 +1092,18 @@ METRIC_HELP: Dict[str, str] = {
         "thread (us)",
     "device_wait_us":
         "consumer head-of-line wait for the next device batch (us)",
+    "device_first_batch_wait_us":
+        "consumer wait for the first batch after a (re)start: how late an "
+        "epoch's first batch comes (us)",
+    "device_turnover_us":
+        "one before_first(): staging threads joined and the batcher "
+        "reset (us)",
+    "model_step_dispatch_us":
+        "one learner step handed to the runtime, host side; the call that "
+        "built the step function traces and compiles inside it (us)",
+    "model_step_builds_total":
+        "jitted step functions built, one per new batch signature, by "
+        "model class",
     "device_put_failures_total": "device_put calls that raised",
     "device_host_q_depth": "staged host batches queued for transfer",
     "device_ready_q_depth": "device batches queued for the consumer",

@@ -31,7 +31,6 @@ staging thread overlaps parse+pad with XLA compute on the main thread.
 from __future__ import annotations
 
 import contextlib
-import json
 import os
 import queue
 import re
@@ -60,6 +59,7 @@ from dmlc_core_tpu.tracker.wire import TrackerAbortedError, env_int
 # registry lock on the staging/transfer threads); lazy so importing this
 # module registers nothing
 _lane_metrics = None
+_NO_SPAN = contextlib.nullcontext()
 
 
 def _get_lane_metrics():
@@ -71,6 +71,9 @@ def _get_lane_metrics():
             "block_us": telemetry.histogram("device_put_block_us"),
             "stage_us": telemetry.histogram("device_stage_us"),
             "wait_us": telemetry.histogram("device_wait_us"),
+            "first_wait_us": telemetry.histogram(
+                "device_first_batch_wait_us"),
+            "turnover_us": telemetry.histogram("device_turnover_us"),
             "batches": telemetry.counter("device_batches_total"),
             "bytes": telemetry.counter("device_transfer_bytes_total"),
             "failures": telemetry.counter("device_put_failures_total"),
@@ -137,31 +140,24 @@ def _reset_shape_census() -> None:
 
 @contextlib.contextmanager
 def jax_profiler_capture():
-    """Optional XLA-timeline capture, wall-clock-anchored to our
-    Chrome-trace export: with ``DMLC_JAX_PROFILE=<dir>`` set, wraps the
-    body in ``jax.profiler.start_trace/stop_trace`` and writes
-    ``<dir>/dmlc_anchor_<pid>.json`` holding this process's (wall,
-    monotonic) clock-anchor pairs at start and stop — the same anchors
-    ``telemetry.trace_json()`` shifts by, so the XLA timeline and the
-    ``/trace`` span timeline line up on one wall clock. Yields True when
-    a capture is running, False when the env is unset. A profiler that
-    was asked for and will not start (or stop) raises: the caller wanted
-    the trace, and a run without it is not the run they asked for."""
+    """Optional XLA-timeline capture: with ``DMLC_JAX_PROFILE=<dir>`` set,
+    wraps the body in ``jax.profiler.start_trace/stop_trace``. Every
+    ``telemetry.span`` opened inside lands in the capture's ``/host:CPU``
+    plane as ``dmlc.<name>``, on the profiler's clock beside the device
+    planes (doc/observability.md "Device lane"). Yields True when a
+    capture is running, False when the env is unset. A profiler that was
+    asked for and will not start (or stop) raises: the caller wanted the
+    trace, and a run without it is not the run they asked for."""
     out_dir = os.environ.get("DMLC_JAX_PROFILE")
     if not out_dir:
         yield False
         return
-    anchors = {"pid": os.getpid(), "start": telemetry.clock_anchor()}
     os.makedirs(out_dir, exist_ok=True)
     jax.profiler.start_trace(out_dir)
     try:
         yield True
     finally:
         jax.profiler.stop_trace()
-        anchors["stop"] = telemetry.clock_anchor()
-        path = os.path.join(out_dir, f"dmlc_anchor_{os.getpid()}.json")
-        with open(path, "w") as f:
-            json.dump(anchors, f)
         telemetry.emit_event("jax-profile", dir=out_dir, started=True)
 
 
@@ -1234,6 +1230,9 @@ class DeviceRowBlockIter:
         # mid-epoch resume position (state()/restore())
         self.batches_consumed = 0
         self._skip_batches = 0
+        # the next batch is the first after a (re)start: its wait is the
+        # one device_first_batch_wait_us keeps
+        self._first_wait = True
         # epoch ordinal: selects the shuffle permutation for shuffled URIs
         # (?shuffle_parts= / ?index=&shuffle=1). The split samples epoch 0's
         # permutation at construction; before_first() advances it. state()
@@ -1271,6 +1270,19 @@ class DeviceRowBlockIter:
                 if self._stop.is_set():
                     return self._SHUTDOWN
 
+    def _stage_next(self, m) -> Optional[PaddedBatch]:
+        """One ``batcher.next_batch()`` under the ``device.stage`` span
+        (the end-of-data pull reads ``rows=0`` and is left out of the
+        histogram)."""
+        if not telemetry.enabled():
+            return self.batcher.next_batch()
+        with telemetry.span("device.stage") as sp:
+            batch = self.batcher.next_batch()
+            if batch is not None:
+                m["stage_us"].observe(sp.elapsed_us)
+            sp.set_arg("rows", 0 if batch is None else batch.total_rows)
+        return batch
+
     def _parse_loop(self) -> None:
         try:
             # mid-epoch resume: burn the recorded prefix on this thread —
@@ -1292,20 +1304,10 @@ class DeviceRowBlockIter:
             m = _get_lane_metrics()
             while not self._stop.is_set():
                 # device.stage: one host batch assembly (parse+pad+bucket
-                # +pinned pack) on the staging thread — perf_counter like
-                # every span clock; gated so DMLC_TELEMETRY=0 costs one
-                # branch here
-                if telemetry.enabled():
-                    t0 = time.perf_counter()
-                    batch = self.batcher.next_batch()
-                    dur_us = (time.perf_counter() - t0) * 1e6
-                    if batch is not None:
-                        m["stage_us"].observe(dur_us)
-                        telemetry.emit_span("device.stage", t0 * 1e6,
-                                            dur_us,
-                                            rows=batch.total_rows)
-                else:
-                    batch = self.batcher.next_batch()
+                # +pinned pack) on the staging thread — an OPENED span, so
+                # it is a profiler annotation too; gated so
+                # DMLC_TELEMETRY=0 costs one branch here
+                batch = self._stage_next(m)
                 if batch is not None:
                     # compile-churn census: a new shape key here is the
                     # batch that re-traces every jitted consumer
@@ -1566,20 +1568,19 @@ class DeviceRowBlockIter:
             if hasattr(self.batcher, "recycle"):
                 self.batcher.recycle(batch)
         while True:
-            if telemetry.enabled():
-                t0 = time.perf_counter()
-                host = self.batcher.next_batch()
-                dur_us = (time.perf_counter() - t0) * 1e6
-                if host is not None:
-                    m["stage_us"].observe(dur_us)
-                    telemetry.emit_span("device.stage", t0 * 1e6, dur_us,
-                                        rows=host.total_rows)
-            else:
-                host = self.batcher.next_batch()
-            if host is None:
-                return
-            _note_shape(host)
-            item = self._device_put(host)
+            # inline, every batch is waited for in full; only the first
+            # after a (re)start is recorded as a device.wait, for
+            # device_first_batch_wait_us
+            first, self._first_wait = self._first_wait, False
+            with (telemetry.span("device.wait", first=1)
+                  if first and telemetry.enabled() else _NO_SPAN) as sp:
+                host = self._stage_next(m)
+                if host is None:
+                    return
+                _note_shape(host)
+                item = self._device_put(host)
+                if sp is not None:
+                    m["first_wait_us"].observe(sp.elapsed_us)
             self.batches_consumed += 1
             if recycle_ok and item is not host:
                 # same alias-probed direct-or-deferred recycling as the
@@ -1602,13 +1603,19 @@ class DeviceRowBlockIter:
             # next READY batch. The complement of these intervals is the
             # consumer's compute time, which is what the overlap ratio
             # (telemetry.device_overlap_ratio) intersects device.put
-            # spans against.
+            # spans against. An OPENED span (a profiler annotation too);
+            # the first wait after a (re)start carries first=1 and also
+            # feeds device_first_batch_wait_us: how late an epoch's first
+            # batch comes.
             if telemetry.enabled():
-                t0 = time.perf_counter()
-                item = self._queue.get()
-                dur_us = (time.perf_counter() - t0) * 1e6
+                first, self._first_wait = self._first_wait, False
+                with telemetry.span("device.wait",
+                                    **({"first": 1} if first else {})) as sp:
+                    item = self._queue.get()
+                    dur_us = sp.elapsed_us
                 m["wait_us"].observe(dur_us)
-                telemetry.emit_span("device.wait", t0 * 1e6, dur_us)
+                if first:
+                    m["first_wait_us"].observe(dur_us)
             else:
                 item = self._queue.get()
             m["ready_q"].set(self._queue.qsize())
@@ -1689,11 +1696,18 @@ class DeviceRowBlockIter:
         """Restart iteration as the next epoch (reference
         DataIter::BeforeFirst; shuffled URIs resample their permutation)."""
         self._epoch += 1
-        self._reset_stream()
+        if not telemetry.enabled():
+            self._reset_stream()
+            return
+        # device.epoch_turnover: thread join + batcher reset, per epoch
+        with telemetry.span("device.epoch_turnover", epoch=self._epoch) as sp:
+            self._reset_stream()
+            _get_lane_metrics()["turnover_us"].observe(sp.elapsed_us)
 
     def _reset_stream(self) -> None:
         """Rewind to the start of epoch ``self._epoch``."""
         self._join_threads()
+        self._first_wait = True
         if hasattr(self.batcher, "set_epoch"):
             # pin the permutation deterministically to the epoch ordinal
             # (instead of the split's own BeforeFirst counter, which a

@@ -32,8 +32,17 @@ def enable_compile_cache() -> str:
     a directory that moves never hits), and programs that compile in
     under jax's default one-second floor are cached too, since a cold
     chip start is mostly many small programs. Call before the first
-    compilation; also installs the compile monitor."""
+    compilation; also installs the compile monitor.
+
+    The operations' metadata is part of the cache key. By jax's default
+    it is not, and an executable loaded from the cache then carries the
+    ``op_name``s and source lines of whichever program compiled it first:
+    a profile of a step whose ``jax.named_scope``s are newer than the
+    cache entry shows none of them (seen on the chip, PERF.md section 6,
+    PR 26). A change of scopes or line numbers compiles once more; a
+    profile never lies."""
     install_compile_monitor()
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         return jax.config.jax_compilation_cache_dir
     path = os.path.join(_CHECKOUT, ".jax_cache")

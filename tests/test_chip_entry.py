@@ -171,3 +171,36 @@ def test_compile_cache_default_is_one_fixed_path(tmp_path):
     second = _show_cache(env, str(tmp_path / "b"))
     want = os.path.join(REPO, ".jax_cache")
     assert first == second == [want, want, "0.0"]
+
+
+_SCOPED = (
+    "import sys, jax, jax.numpy as jnp\n"
+    "from dmlc_core_tpu.tpu.runtime import enable_compile_cache\n"
+    "if sys.argv[2] == 'program': enable_compile_cache()\n"
+    "jax.config.update('jax_persistent_cache_min_compile_time_secs', 0.0)\n"
+    "def f(x):\n"
+    "    with jax.named_scope(sys.argv[1]):\n"
+    "        return jnp.sin(x) * 2\n"
+    "print(jax.jit(f).lower(jnp.ones(8)).compile().as_text())\n")
+
+
+@pytest.mark.parametrize("how", ["program", "jax_default"])
+def test_cached_executable_carries_the_scopes_of_this_program(tmp_path, how):
+    """Two programs that differ in a ``jax.named_scope`` only, one cache:
+    with the program's ``enable_compile_cache`` the second compiles anew and
+    its operations carry its own scope; by jax's default (the control) the
+    second is handed the first's executable, stale names and all."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=REPO,
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"))
+    texts = []
+    for scope in ("first.scope", "second.scope"):
+        out = subprocess.run([sys.executable, "-c", _SCOPED, scope, how],
+                             cwd=str(tmp_path), env=env, capture_output=True,
+                             text=True, timeout=240)
+        assert out.returncode == 0, out.stderr[-2000:]
+        texts.append(out.stdout)
+    assert "first.scope" in texts[0]
+    if how == "program":
+        assert "second.scope" in texts[1] and "first.scope" not in texts[1]
+    else:
+        assert "first.scope" in texts[1] and "second.scope" not in texts[1]

@@ -19,6 +19,12 @@ Covers the ISSUE 15 acceptance surface on the deterministic CPU backend:
 - `_device_put` failures: counted and flight-dumped like host aborts.
 - The bench device lane: emits numbers on this (device-less) host, and
   two of its ledger records diff cleanly through `benchdiff`.
+- One clock for host and device (ISSUE 26): under `jax.profiler` the
+  program's opened spans are `dmlc.*` events of the trace's host plane;
+  the learner's step counts its builds and times its dispatch; turnover
+  and the first batch's wait are observed once per `before_first()`; the
+  jitted steps carry their named scopes; with telemetry off none of it
+  runs and the step's results are bit-identical.
 """
 
 from __future__ import annotations
@@ -325,21 +331,204 @@ def test_device_put_failure_counted_and_flight_dumped(tmp_path,
     assert any(d["reason"] == "device-put-failure" for d in docs)
 
 
-# -- jax profiler anchoring ---------------------------------------------------
-def test_jax_profiler_capture_writes_clock_anchors(tmp_path, monkeypatch):
+# -- one clock for host and device ---------------------------------------------
+def _hist(name):
+    snap = telemetry.snapshot(native=False)
+    return sum(h["count"] for h in snap["histograms"] if h["name"] == name)
+
+
+def _learner(model, mesh, features=8):
+    from dmlc_core_tpu.models import FMLearner, LinearLearner
+    if model == "fm":
+        return FMLearner(features, k=4, mesh=mesh, learning_rate=0.2)
+    return LinearLearner(features, mesh=mesh, learning_rate=0.5)
+
+
+def test_profiler_trace_holds_the_program_spans(tmp_path, monkeypatch):
+    """A run of the iterator and the learner under the profiler leaves the
+    program's own spans in the trace's host plane, on the profiler's
+    clock: each lies inside the capture's span of time."""
+    import glob
+    from jax.profiler import ProfileData
     out = tmp_path / "xprof"
     monkeypatch.setenv("DMLC_JAX_PROFILE", str(out))
-    with jax_profiler_capture():
-        jax.jit(lambda x: x + 1)(np.ones(4, np.float32)).block_until_ready()
-    anchor_files = [f for f in os.listdir(out)
-                    if f.startswith("dmlc_anchor_")]
-    assert len(anchor_files) == 1
-    doc = json.load(open(out / anchor_files[0]))
-    # both anchor pairs, each the (wall, monotonic) convention /trace
-    # shifts by — what lines the XLA timeline up with our export
-    for k in ("start", "stop"):
-        assert set(doc[k]) == {"wall_us", "perf_us"}
-    assert doc["stop"]["wall_us"] >= doc["start"]["wall_us"]
+    path = write_libsvm(tmp_path / "p.libsvm", rows=700)
+    learner = _learner("fm", None)
+    params = learner.init()
+    with jax_profiler_capture() as on:
+        assert on
+        with jax.profiler.TraceAnnotation("test.capture"):
+            with DeviceRowBlockIter(path, batch_rows=256, layout="csr",
+                                    min_nnz_bucket=128) as it:
+                for _ in range(2):
+                    for batch in it:
+                        params, loss = learner.step(params, batch)
+                    it.before_first()
+            float(loss)
+    assert not [f for f in os.listdir(out) if f.startswith("dmlc_")]
+    [xplane] = glob.glob(str(out / "plugins" / "profile" / "*" /
+                             "*.xplane.pb"))
+    pd = ProfileData.from_file(xplane)
+    host = [p for p in pd.planes if p.name == "/host:CPU"]
+    assert len(host) == 1
+    found, lines_with_spans, capture = {}, 0, None
+    for line in host[0].lines:
+        mine = [e for e in line.events if e.name.startswith("dmlc.")]
+        lines_with_spans += bool(mine)
+        for e in mine:
+            found.setdefault(e.name, []).append(e)
+        for e in line.events:
+            if e.name == "test.capture":
+                capture = (e.start_ns, e.start_ns + e.duration_ns)
+    assert {"dmlc.device.stage", "dmlc.device.put", "dmlc.device.wait",
+            "dmlc.model.step", "dmlc.device.epoch_turnover"} <= set(found)
+    # consumer, staging and transfer threads each have a line of their own
+    assert lines_with_spans >= 3
+    assert capture is not None
+    for name, evs in found.items():
+        for e in evs:
+            assert capture[0] <= e.start_ns and \
+                e.start_ns + e.duration_ns <= capture[1], name
+    assert len(found["dmlc.device.epoch_turnover"]) == 2
+    assert len(found["dmlc.model.step"]) == 6
+    # the args ride along as the event's stats
+    assert dict(found["dmlc.model.step"][0].stats).get("built") == 1
+    assert {dict(e.stats).get("rows") for e in
+            found["dmlc.device.stage"]} >= {256, 188}
+
+
+def test_model_step_counts_builds_and_times_every_dispatch(tmp_path):
+    path = write_libsvm(tmp_path / "s.libsvm", rows=700)
+    learner = _learner("linear", None)
+    params = learner.init()
+    builds = telemetry.counter("model_step_builds_total",
+                               {"model": "LinearLearner"})
+    seen = []
+    with DeviceRowBlockIter(path, batch_rows=256, layout="csr",
+                            min_nnz_bucket=128) as it:
+        for _ in range(2):
+            for batch in it:
+                params, _ = learner.step(params, batch)
+                seen.append(builds.value)
+            it.before_first()
+    # 256, 256, 188 rows pad to one signature: built on the first step
+    # only, never on a repeat or in the second epoch
+    assert seen == [1] * 6
+    assert _hist("model_step_dispatch_us") == 6
+    steps = [s for s in telemetry.spans() if s["name"] == "model.step"]
+    assert [s["args"]["built"] for s in steps] == [1, 0, 0, 0, 0, 0]
+    # each new batch signature builds once more, a repeat never
+    sigs = set()
+    with DeviceRowBlockIter(path, batch_rows=128, layout="csr",
+                            min_nnz_bucket=128) as it:
+        for batch in it:
+            sigs.add(tuple(sorted((k, v.shape)
+                                  for k, v in batch.tree().items())))
+            params, _ = learner.step(params, batch)
+            assert builds.value == 1 + len(sigs)
+    assert len(sigs) >= 1
+    assert telemetry.counter("model_step_builds_total",
+                             {"model": "FMLearner"}).value == 0
+
+
+@pytest.mark.parametrize("prefetch", [0, 2])
+def test_turnover_and_first_wait_count_once_per_before_first(tmp_path,
+                                                             prefetch):
+    path = write_libsvm(tmp_path / "t.libsvm", rows=700)
+    with DeviceRowBlockIter(path, batch_rows=256, layout="csr",
+                            min_nnz_bucket=128, prefetch=prefetch) as it:
+        assert sum(b.total_rows for b in it) == 700
+        assert _hist("device_turnover_us") == 0
+        assert _hist("device_first_batch_wait_us") == 1
+        for epoch in (1, 2, 3):
+            it.before_first()
+            assert _hist("device_turnover_us") == epoch
+            assert _hist("device_first_batch_wait_us") == epoch
+            assert sum(b.total_rows for b in it) == 700
+            assert _hist("device_first_batch_wait_us") == epoch + 1
+    spans = telemetry.spans()
+    turns = [s for s in spans if s["name"] == "device.epoch_turnover"]
+    assert [s["args"]["epoch"] for s in turns] == [1, 2, 3]
+    firsts = [s for s in spans if s["name"] == "device.wait"
+              and s.get("args", {}).get("first") == 1]
+    assert len(firsts) == 4
+    if prefetch:  # every wait is a span there; one per epoch is the first
+        assert _hist("device_wait_us") > 4
+
+
+@pytest.mark.parametrize("mesh_devices", [0, 2], ids=["nomesh", "mesh"])
+@pytest.mark.parametrize("model", ["fm", "linear"])
+def test_jitted_step_carries_the_named_scopes(tmp_path, model, mesh_devices):
+    from dmlc_core_tpu.tpu import data_mesh
+    mesh = data_mesh(mesh_devices) if mesh_devices else None
+    path = write_libsvm(tmp_path / "n.libsvm", rows=256)
+    learner = _learner(model, mesh)
+    params = learner.init()
+    with DeviceRowBlockIter(path, batch_rows=256, layout="csr", mesh=mesh,
+                            min_nnz_bucket=128) as it:
+        batch = next(iter(it))
+    tree = batch.tree()
+    step = learner._build_step(batch.rows_per_shard,
+                               tuple(sorted(tree.keys())))
+    text = step.lower(params, tree).as_text(debug_info=True)
+    want = {"dp.unpack", "dp.loss_grad", "dp.apply"}
+    want |= ({"fm.linear", "fm.gather", "fm.interaction"} if model == "fm"
+             else {"linear.margin"})
+    if mesh is not None:
+        want.add("dp.allreduce")
+    for scope in sorted(want):
+        assert scope in text, scope
+    if mesh is None:
+        assert "dp.allreduce" not in text
+    # the backward of a model scope nests under dp.loss_grad
+    inner = "fm.gather" if model == "fm" else "linear.margin"
+    assert f"dp.loss_grad/transpose(jvp({inner}))" in text
+    # predict carries its own scope
+    learner.predict(params, batch)
+    fwd = next(iter(learner._fwd_fn.values()))
+    assert f"{model}.predict" in fwd.lower(params, tree).as_text(
+        debug_info=True)
+
+
+def test_telemetry_off_times_nothing_and_steps_bit_identically(
+        tmp_path, monkeypatch):
+    path = write_libsvm(tmp_path / "o.libsvm", rows=700)
+    made = []
+
+    class Recording(jax.profiler.TraceAnnotation):
+        def __init__(self, name, **kw):
+            made.append(name)
+            super().__init__(name, **kw)
+
+    monkeypatch.setattr(telemetry, "_annotation_cls", Recording)
+
+    def run():
+        learner = _learner("fm", None)
+        params = learner.init(3)
+        losses = []
+        with DeviceRowBlockIter(path, batch_rows=256, layout="csr",
+                                min_nnz_bucket=128) as it:
+            for batch in it:
+                params, loss = learner.step(params, batch)
+                losses.append(np.asarray(loss))
+            it.before_first()
+        return jax.tree.map(np.asarray, params), losses
+
+    p_on, l_on = run()
+    assert "dmlc.model.step" in made and "dmlc.device.wait" in made
+    assert telemetry.spans()
+    telemetry.reset()
+    made.clear()
+    telemetry.enable(False)
+    p_off, l_off = run()
+    assert made == [] and telemetry.spans() == []
+    for name in ("model_step_dispatch_us", "device_turnover_us",
+                 "device_first_batch_wait_us", "device_wait_us",
+                 "device_stage_us"):
+        assert _hist(name) == 0, name
+    for a, b in zip(jax.tree.leaves(p_on), jax.tree.leaves(p_off)):
+        assert a.tobytes() == b.tobytes()
+    assert [x.tobytes() for x in l_on] == [x.tobytes() for x in l_off]
 
 
 def test_jax_profiler_capture_raises_when_it_cannot_start(tmp_path,

@@ -71,6 +71,83 @@ def _libsvm_file(tmp_path, rows=2000, features=12, name="t.libsvm"):
 
 
 # -- the Python span ring -----------------------------------------------------
+class _FakeAnnotation:
+    """Stands in for jax.profiler.TraceAnnotation (this file stays off
+    jax): records what telemetry does with the class it found."""
+
+    log = []
+
+    def __init__(self, name, **kw):
+        self.name = name
+        self.log.append(("init", name, kw))
+
+    def __enter__(self):
+        self.log.append(("enter", self.name))
+        return self
+
+    def __exit__(self, *exc):
+        self.log.append(("exit", self.name))
+
+    def set_metadata(self, **kw):
+        self.log.append(("meta", self.name, kw))
+
+
+@pytest.fixture
+def fake_annotation(monkeypatch):
+    monkeypatch.setattr(_FakeAnnotation, "log", [])
+    monkeypatch.setattr(telemetry, "_annotation_cls", _FakeAnnotation)
+    return _FakeAnnotation.log
+
+
+def test_open_span_is_a_profiler_annotation(fake_annotation):
+    with telemetry.span("outer", shard=3) as outer:
+        outer.set_arg("bytes", 42)
+        with telemetry.span("inner"):
+            pass
+    # completed after the fact: the ring only, never an annotation
+    telemetry.emit_span("posthoc", 1.0, 2.0)
+    assert fake_annotation == [
+        ("init", "dmlc.outer", {"shard": 3}), ("enter", "dmlc.outer"),
+        ("meta", "dmlc.outer", {"bytes": 42}),
+        ("init", "dmlc.inner", {}), ("enter", "dmlc.inner"),
+        ("exit", "dmlc.inner"), ("exit", "dmlc.outer")]
+    # the ring keeps its own names
+    assert [s["name"] for s in telemetry.spans()] == ["inner", "outer",
+                                                      "posthoc"]
+
+
+def test_disabled_span_makes_no_record_and_no_annotation(fake_annotation):
+    telemetry.enable(False)
+    with telemetry.span("quiet", rows=1) as sp:
+        sp.set_arg("bytes", 2)
+        assert sp.elapsed_us == 0.0
+    assert fake_annotation == [] and telemetry.spans() == []
+    telemetry.enable(True)
+    with telemetry.span("loud") as sp:
+        assert sp.elapsed_us >= 0.0
+    assert [e[0] for e in fake_annotation] == ["init", "enter", "exit"]
+
+
+def test_telemetry_span_leaves_jax_unimported():
+    """The tracker imports telemetry and must stay off jax: a process
+    that never loaded jax opens spans with no annotation and no import."""
+    code = (
+        "import sys\n"
+        "from dmlc_core_tpu import telemetry\n"
+        "with telemetry.span('x', rows=1) as sp:\n"
+        "    sp.set_arg('bytes', 2)\n"
+        "assert [s['name'] for s in telemetry.spans()] == ['x']\n"
+        "assert telemetry._trace_annotation() is None\n"
+        "bad = sorted(m for m in sys.modules if m == 'jax' or "
+        "m.startswith(('jax.', 'jaxlib')))\n"
+        "assert not bad, bad\n"
+        "print('NOJAX')\n")
+    r = subprocess.run([sys.executable, "-c", code], cwd=REPO,
+                       capture_output=True, text=True, timeout=120)
+    assert r.returncode == 0, r.stderr[-2000:]
+    assert "NOJAX" in r.stdout
+
+
 def test_span_nesting_and_parenting():
     with telemetry.span("outer", shard=3) as outer:
         outer.set_arg("bytes", 42)
