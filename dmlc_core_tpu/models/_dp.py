@@ -3,9 +3,27 @@
 LinearLearner and FMLearner differ only in their parameter pytrees, margin
 computation, and SGD update; everything about running a step over a device
 batch is identical — unpack the packed two-leaf batch per shard, take
-value_and_grad of the shard loss, psum the (loss, weight, grad) triple
-once over ICI (the Rabit allreduce equivalent, SURVEY §2.5), apply the
-update, and jit-cache per batch shape. That harness lives here once.
+value_and_grad of the shard loss, apply the update, and jit-cache per batch
+shape. That harness lives here once.
+
+The gradient has one of two forms, chosen when the step is built from what
+the batch and the mesh show (``_takes_row_form``):
+
+- table form (the default; every dense batch, every mesh of more than one
+  device): value_and_grad with respect to the parameters, so the gradient
+  is a pytree of the parameters' shapes; on a mesh the (loss, weight, grad)
+  triple is psummed once over ICI (the Rabit allreduce equivalent, SURVEY
+  §2.5) and ``_apply`` writes the new tables from the old ones and it.
+- row form (a CSR batch on one device, for a model that has the two row
+  hooks): the model gathers the rows its shard reads, value_and_grad is
+  taken of the same ``_shard_loss`` with respect to those rows, and
+  ``_apply_rows`` scatter-adds the gradient's rows into the tables at the
+  shard's ``col``: the gradient as (indices, rows). No table of the
+  parameters' shape is made beside the parameters in and out, and nothing
+  is reduced: there is one shard. (Across devices the row form would
+  all_gather every shard's rows and scatter all of them on every device;
+  whether that beats the all-reduce of a table waits for a four-chip
+  cell, PERF.md section 7.)
 
 The phases of the jitted step carry ``jax.named_scope``s (``dp.unpack``,
 ``dp.loss_grad``, ``dp.allreduce``, ``dp.apply``; the models add their own
@@ -16,6 +34,10 @@ numbers its fusions (doc/observability.md "Device lane").
 Subclasses implement:
   _shard_loss(params, shard, rows_per_shard) -> (loss_sum, weight_sum)
   _apply(params, grads, denom) -> new params
+and may implement, for CSR shards (both or neither):
+  _gather_rows(params, shard) -> rows, a pytree that ``_shard_loss`` takes
+      in the place of ``params``
+  _apply_rows(params, col, row_grads, denom) -> new params
 """
 
 from __future__ import annotations
@@ -47,6 +69,18 @@ class DataParallelModel:
     def _apply(self, params, grads, denom):
         raise NotImplementedError
 
+    # the row hooks; None: the model has the table form only
+    _gather_rows = None
+    _apply_rows = None
+
+    def _takes_row_form(self, keys) -> bool:
+        """Whether a batch tree of these leaves steps in the row form: the
+        model has the hooks, the batch is CSR (packed ``big`` or named
+        ``col``, and no dense ``x``) and there is one device."""
+        return (self._gather_rows is not None
+                and ("big" in keys or "col" in keys) and "x" not in keys
+                and (self.mesh is None or self.mesh.devices.size == 1))
+
     def _build_step(self, rows_per_shard: int, keys: tuple):
         axis = self.axis_name
         # every batch leaf is shard-major (device axis leads) since the
@@ -61,6 +95,8 @@ class DataParallelModel:
                 return unpack_shard(local)
 
         def local_grads(params, shard):
+            """``params``: the parameters, or the rows the shard gathered
+            from them."""
             def loss_fn(p):
                 return self._shard_loss(p, shard, rows_per_shard)
             with jax.named_scope("dp.loss_grad"):
@@ -68,15 +104,27 @@ class DataParallelModel:
                     loss_fn, has_aux=True)(params)
             return loss_sum, wsum, grads
 
-        def apply(params, grads, loss_sum, wsum):
+        def apply(update, loss_sum, wsum):
             with jax.named_scope("dp.apply"):
                 denom = jnp.maximum(wsum, 1.0)
-                return self._apply(params, grads, denom), loss_sum / denom
+                return update(denom), loss_sum / denom
+
+        if self._takes_row_form(keys):
+            # the benchmark finds the step's module by this name
+            def sharded_step(params, tree):
+                shard = shard_view(tree)
+                with jax.named_scope("dp.loss_grad"):
+                    rows = self._gather_rows(params, shard)
+                loss_sum, wsum, row_grads = local_grads(rows, shard)
+                return apply(lambda denom: self._apply_rows(
+                    params, shard["col"], row_grads, denom), loss_sum, wsum)
+            return jax.jit(sharded_step)
 
         if self.mesh is None:
             def step(params, tree):
                 loss_sum, wsum, grads = local_grads(params, shard_view(tree))
-                return apply(params, grads, loss_sum, wsum)
+                return apply(lambda denom: self._apply(params, grads, denom),
+                             loss_sum, wsum)
             return jax.jit(step)
 
         @functools.partial(jax.shard_map, mesh=self.mesh,
@@ -96,7 +144,8 @@ class DataParallelModel:
                 loss_sum = jax.lax.psum(loss_sum, axis)
                 wsum = jax.lax.psum(wsum, axis)
                 grads = jax.tree.map(lambda g: jax.lax.psum(g, axis), grads)
-            return apply(params, grads, loss_sum, wsum)
+            return apply(lambda denom: self._apply(params, grads, denom),
+                         loss_sum, wsum)
 
         return jax.jit(sharded_step)
 
@@ -131,4 +180,9 @@ class DataParallelModel:
             out = fn(params, tree)
             telemetry.histogram("model_step_dispatch_us").observe(
                 sp.elapsed_us)
+        # counted beside the histogram: the two counts give the share of
+        # steps that took the row form
+        if self._takes_row_form(tree):
+            telemetry.counter("model_step_row_updates_total",
+                              {"model": type(self).__name__}).inc()
         return out

@@ -6,7 +6,10 @@ wormhole/difacto lineage) on `label field:feature:value` rows; like the
 linear learner it ships no model itself. This module is that consumer,
 TPU-native: second-order FM over PaddedBatch CSR shards (or DenseBatch
 matrices, where the interaction term becomes two MXU matmuls),
-data-parallel under ``shard_map`` with one psum per step.
+data-parallel under ``shard_map`` with one psum per step on a mesh of
+several devices. On one device a CSR step keeps the gradient in the rows
+the batch gathered and scatter-adds it straight into ``w`` and ``v``
+(the row form of models/_dp.py): no ``[F, K]`` gradient table is made.
 
 Margin (Rendle's O(NNZ·K) identity):
 
@@ -29,7 +32,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from dmlc_core_tpu.models._dp import DataParallelModel
 from dmlc_core_tpu.models.linear import objective_loss
-from dmlc_core_tpu.ops.sparse import csr_matvec
 from dmlc_core_tpu.tpu.device_iter import unpack_tree
 
 __all__ = ["FMParams", "FMLearner"]
@@ -41,23 +43,43 @@ class FMParams(NamedTuple):
     v: jnp.ndarray   # [F, K] interaction factors
 
 
-def _fm_margin_csr(params: FMParams, row, col, val, num_rows: int
-                   ) -> jnp.ndarray:
+class FMRows(NamedTuple):
+    """What a CSR shard reads of the parameters: the rows at its ``col``
+    (a feature that recurs in the shard recurs here)."""
+    b: jnp.ndarray   # []
+    w: jnp.ndarray   # [NNZ]
+    v: jnp.ndarray   # [NNZ, K]
+
+
+# named scopes: op_name metadata only. In the table form the backward ops
+# read .../transpose(jvp(fm.gather))/..., which is how a trace tells the
+# scatter into the dense gradient from the forward gather; in the row form
+# the gathers are outside the differentiated function and have no backward
+def _fm_gather(params: FMParams, col) -> FMRows:
+    with jax.named_scope("fm.linear"):
+        w_rows = jnp.take(params.w, col, axis=0)
+    with jax.named_scope("fm.gather"):
+        v_rows = params.v[col]
+    return FMRows(params.b, w_rows, v_rows)
+
+
+def _fm_margin_rows(rows: FMRows, row, val, num_rows: int) -> jnp.ndarray:
     seg = functools.partial(jax.ops.segment_sum,
                             num_segments=num_rows + 1,
                             indices_are_sorted=True)
-    # named scopes: op_name metadata only; the backward ops read
-    # .../transpose(jvp(fm.gather))/..., which is how a trace tells the
-    # scatter into the dense gradient from the forward gather
     with jax.named_scope("fm.linear"):
-        linear = csr_matvec(row, col, val, params.w, num_rows)
-    with jax.named_scope("fm.gather"):
-        vx = params.v[col] * val[:, None]          # [NNZ, K]
+        linear = seg(val * rows.w, row)[:num_rows]
     with jax.named_scope("fm.interaction"):
+        vx = rows.v * val[:, None]                 # [NNZ, K]
         s1 = seg(vx, row)[:num_rows]               # Σ V x   per row  [R, K]
         s2 = seg(vx * vx, row)[:num_rows]          # Σ V²x²  per row  [R, K]
         inter = 0.5 * jnp.sum(s1 * s1 - s2, axis=-1)
-    return params.b + linear + inter
+    return rows.b + linear + inter
+
+
+def _fm_margin_csr(params: FMParams, row, col, val, num_rows: int
+                   ) -> jnp.ndarray:
+    return _fm_margin_rows(_fm_gather(params, col), row, val, num_rows)
 
 
 def _fm_margin_dense(params: FMParams, x) -> jnp.ndarray:
@@ -70,14 +92,16 @@ def _fm_margin_dense(params: FMParams, x) -> jnp.ndarray:
         return params.b + linear + inter
 
 
-def _margin(params: FMParams, shard, num_rows: int) -> jnp.ndarray:
+def _margin(params, shard, num_rows: int) -> jnp.ndarray:
+    """``params``: FMParams, or the FMRows a CSR shard gathered from them."""
     if "x" in shard:
         return _fm_margin_dense(params, shard["x"])
-    return _fm_margin_csr(params, shard["row"], shard["col"], shard["val"],
-                          num_rows)
+    rows = params if isinstance(params, FMRows) else \
+        _fm_gather(params, shard["col"])
+    return _fm_margin_rows(rows, shard["row"], shard["val"], num_rows)
 
 
-def _fm_shard_loss(params: FMParams, shard, num_rows: int, objective: str
+def _fm_shard_loss(params, shard, num_rows: int, objective: str
                    ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """(weighted loss sum, weight sum) — the shared objective zoo
     (models/linear.py objective_loss) over the FM margin."""
@@ -136,6 +160,27 @@ class FMLearner(DataParallelModel):
             b=params.b - lr * grads.b / denom,
             w=params.w - lr * (grads.w / denom + l2 * params.w),
             v=params.v - lr * (grads.v / denom + l2 * params.v))
+
+    def _gather_rows(self, params, shard):
+        return _fm_gather(params, shard["col"])
+
+    def _apply_rows(self, params, col, g, denom):
+        """``_apply`` with the gradient as FMRows at ``col``: a row's
+        gradient is the sum of the entries that name it, which the
+        scatter-add takes (in whatever order: ``col`` is neither sorted nor
+        unique); padded entries carry zeros. Weight decay still reaches
+        every row. Each entry is added into the parameter by itself, so a
+        feature that recurs n times in the batch takes n roundings at the
+        parameter's magnitude where ``_apply`` takes one (PERF.md section
+        6, PR 27 has what that costs against the reference)."""
+        lr, l2 = self.learning_rate, self.l2
+
+        def decayed(table):
+            return table if l2 == 0 else (1.0 - lr * l2) * table
+        return FMParams(
+            b=params.b - lr * g.b / denom,
+            w=decayed(params.w).at[col].add(-lr * (g.w / denom)),
+            v=decayed(params.v).at[col].add(-lr * (g.v / denom)))
 
     def predict(self, params: FMParams, batch) -> jnp.ndarray:
         """Margins [D, R] (apply sigmoid for probabilities)."""

@@ -105,3 +105,170 @@ def test_fm_sharded_step_on_mesh(tmp_path):
 def test_fm_rejects_bad_rank():
     with pytest.raises(ValueError, match="k must be positive"):
         FMLearner(num_features=4, k=0)
+
+
+# -- the row form of the CSR step on one device (models/_dp.py) ---------------
+F_ROWS, K_ROWS = 11, 3   # shapes no batch leaf has: found by shape below
+
+
+def write_recurring_libsvm(path, rows=300, seed=5):
+    """Few features, so every one recurs across a batch's rows (and the
+    scatter-add has duplicates to sum); 1 to 5 nonzeros a row, so every
+    nnz bucket holds padded entries; 300 rows in batches of 256 leave a
+    last batch with padding rows of weight 0."""
+    rng = np.random.default_rng(seed)
+    with open(path, "w") as f:
+        for _ in range(rows):
+            cols = sorted(set(rng.integers(0, F_ROWS,
+                                           size=rng.integers(1, 6)).tolist()))
+            feats = " ".join(f"{c}:{rng.normal():.4f}" for c in cols)
+            f.write(f"{rng.integers(0, 2)} {feats}\n")
+    return str(path)
+
+
+def _batches(uri, mesh=None):
+    with DeviceRowBlockIter(uri, batch_rows=256, layout="csr", mesh=mesh,
+                            min_nnz_bucket=2048) as it:
+        return list(it)
+
+
+def _table_ops(lowered, shapes):
+    """(op name, location) of every operation in the lowered module with a
+    result of one of ``shapes``."""
+    from jaxlib.mlir import ir
+    found = []
+
+    def visit(op):
+        for r in op.results:
+            if isinstance(r.type, ir.RankedTensorType) and \
+                    tuple(r.type.shape) in shapes:
+                found.append((op.name, str(op.location)))
+        return ir.WalkResult.ADVANCE
+
+    lowered.compiler_ir(dialect="stablehlo").operation.walk(visit)
+    return found
+
+
+@pytest.mark.parametrize("objective", ["logistic", "squared"])
+@pytest.mark.parametrize("l2", [0.0, 0.03])
+def test_fm_row_step_equals_the_table_step(tmp_path, l2, objective):
+    from dmlc_core_tpu.models.fm import _fm_shard_loss
+    from dmlc_core_tpu.tpu.device_iter import unpack_shard
+    learner = FMLearner(F_ROWS, k=K_ROWS, objective=objective,
+                        learning_rate=0.3, l2=l2, init_scale=0.2)
+    params = learner.init(seed=7)
+    # a linear part and a bias that are not zero, so that decay shows
+    rng = np.random.default_rng(1)
+    params = params._replace(
+        b=jax.numpy.float32(0.25),
+        w=jax.numpy.asarray(rng.normal(size=F_ROWS).astype(np.float32)))
+    batches = _batches(write_recurring_libsvm(tmp_path / "r.libsvm"))
+    assert [b.total_rows for b in batches] == [256, 44]
+    for batch in batches:
+        tree = batch.tree()
+        assert learner._takes_row_form(tree)
+        shard = unpack_shard({k: v[0] for k, v in tree.items()})
+        col = np.asarray(shard["col"])
+        val = np.asarray(shard["val"])
+        assert (val == 0).sum() > 0                     # padded nonzeros
+        assert len(set(col[val != 0])) < (val != 0).sum()   # duplicates
+        R = batch.rows_per_shard
+        (loss_sum, wsum), grads = jax.value_and_grad(
+            lambda p: _fm_shard_loss(p, shard, R, objective),
+            has_aux=True)(params)
+        denom = jax.numpy.maximum(wsum, 1.0)
+        want = learner._apply(params, grads, denom)
+        got, loss = learner.step(params, batch)
+        np.testing.assert_allclose(float(loss), float(loss_sum / denom),
+                                   rtol=1e-6)
+        for leaf in ("b", "w", "v"):
+            a, b = np.asarray(getattr(got, leaf)), \
+                np.asarray(getattr(want, leaf))
+            assert np.abs(a - b).max() <= 1e-6 * np.abs(b).max(), leaf
+        # every row of the tables moved when l2 decays them, only the
+        # batch's rows when it does not
+        moved = np.any(np.asarray(got.v) != np.asarray(params.v), axis=1)
+        assert moved.all() if l2 else \
+            set(np.flatnonzero(moved)) <= set(col[val != 0])
+        params = got
+
+
+@pytest.mark.parametrize("devices", [2, 8])
+def test_fm_one_device_and_mesh_steps_agree(tmp_path, devices):
+    uri = write_recurring_libsvm(tmp_path / "m.libsvm")
+
+    def epoch(mesh):
+        learner = FMLearner(F_ROWS, k=K_ROWS, mesh=mesh, learning_rate=0.3,
+                            l2=0.01, init_scale=0.2)
+        params = learner.init(seed=7)
+        for batch in _batches(uri, mesh):
+            params, _ = learner.step(params, batch)
+        return jax.tree.map(np.asarray, params)
+
+    one, many = epoch(None), epoch(data_mesh(devices))
+    for a, b in zip(one, many):
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("mesh_devices", [0, 1], ids=["nomesh", "mesh1"])
+@pytest.mark.parametrize("l2", [0.0, 0.03])
+def test_fm_row_step_makes_no_table_but_the_parameters(tmp_path, l2,
+                                                       mesh_devices):
+    mesh = data_mesh(mesh_devices) if mesh_devices else None
+    learner = FMLearner(F_ROWS, k=K_ROWS, mesh=mesh, l2=l2)
+    params = learner.init()
+    batch = _batches(write_recurring_libsvm(tmp_path / "l.libsvm"), mesh)[0]
+    tree = batch.tree()
+    step = learner._build_step(batch.rows_per_shard,
+                               tuple(sorted(tree.keys())))
+    # the benchmark finds the step's module by this name
+    assert step.__name__ == "sharded_step"
+    lowered = step.lower(params, tree)
+    text = lowered.as_text(debug_info=True)
+    assert "transpose(jvp(fm.gather))" not in text
+    assert "dp.allreduce" not in text
+    made = _table_ops(lowered, {(F_ROWS, K_ROWS), (F_ROWS,)})
+    # one scatter-add into each table, under dp.apply; with l2 the decayed
+    # operand of each (a scalar's broadcast and a multiply) and nothing else
+    names = sorted(name for name, _ in made)
+    decay = ["stablehlo.broadcast_in_dim", "stablehlo.multiply"] * 2
+    assert names == sorted(["stablehlo.scatter"] * 2 + (decay if l2 else []))
+    for name, loc in made:
+        assert "dp.apply" in loc, (name, loc)
+        if name == "stablehlo.scatter":
+            assert "scatter-add" in loc
+
+
+@pytest.mark.parametrize("layout,mesh_devices", [("dense", 0), ("csr", 2)],
+                         ids=["dense-nomesh", "csr-mesh2"])
+def test_fm_dense_and_mesh_steps_keep_the_table_form(tmp_path, layout,
+                                                     mesh_devices):
+    mesh = data_mesh(mesh_devices) if mesh_devices else None
+    learner = FMLearner(F_ROWS, k=K_ROWS, mesh=mesh)
+    params = learner.init()
+    uri = write_recurring_libsvm(tmp_path / "d.libsvm")
+    with DeviceRowBlockIter(uri, batch_rows=256, layout=layout, mesh=mesh,
+                            min_nnz_bucket=2048,
+                            dense_dtype="float32") as it:
+        batch = next(iter(it))
+    tree = batch.tree()
+    assert not learner._takes_row_form(tree)
+    lowered = learner._build_step(
+        batch.rows_per_shard, tuple(sorted(tree.keys()))).lower(params, tree)
+    text = lowered.as_text(debug_info=True)
+    inner = "fm.dense" if layout == "dense" else "fm.gather"
+    assert f"dp.loss_grad/transpose(jvp({inner}))" in text
+    assert ("dp.allreduce" in text) == bool(mesh_devices)
+    # the gradient is a table, and _apply writes the new ones from it
+    names = {name for name, _ in
+             _table_ops(lowered, {(F_ROWS, K_ROWS), (F_ROWS,)})}
+    assert "stablehlo.subtract" in names
+    if mesh_devices:
+        assert "stablehlo.all_reduce" in names
+    # and the step counts as no row update
+    from dmlc_core_tpu import telemetry
+    rows = telemetry.counter("model_step_row_updates_total",
+                             {"model": "FMLearner"})
+    before = rows.value
+    learner.step(params, batch)
+    assert rows.value == before
