@@ -22,8 +22,8 @@ the batch and the mesh show (``_takes_row_form``):
   parameters' shape is made beside the parameters in and out, and nothing
   is reduced: there is one shard. (Across devices the row form would
   all_gather every shard's rows and scatter all of them on every device;
-  whether that beats the all-reduce of a table waits for a four-chip
-  cell, PERF.md section 7.)
+  cell kdd2012-fm-dp4.libfm times the all-reduce of the table it would
+  have to beat, PERF.md section 6, PR 28.)
 
 The phases of the jitted step carry ``jax.named_scope``s (``dp.unpack``,
 ``dp.loss_grad``, ``dp.allreduce``, ``dp.apply``; the models add their own
@@ -172,6 +172,10 @@ class DataParallelModel:
                 batch.rows_per_shard, tuple(sorted(tree.keys())))
             telemetry.counter("model_step_builds_total",
                               {"model": type(self).__name__}).inc()
+            # what a mesh step hands to its psums: loss sum, weight sum and
+            # a gradient of the parameters' shapes; nothing on one device
+            self._allreduce_bytes = 0 if n_dev == 1 else 8 + sum(
+                p.size * p.dtype.itemsize for p in jax.tree.leaves(params))
         if not telemetry.enabled():
             return fn(params, tree)
         # model.step: the host's hand-over of one step to the runtime (the
@@ -185,4 +189,9 @@ class DataParallelModel:
         if self._takes_row_form(tree):
             telemetry.counter("model_step_row_updates_total",
                               {"model": type(self).__name__}).inc()
+        # with the device time under scope dp.allreduce, the exchange's rate
+        if self._allreduce_bytes:
+            telemetry.counter("model_step_allreduce_bytes_total",
+                              {"model": type(self).__name__}).inc(
+                                  self._allreduce_bytes)
         return out
