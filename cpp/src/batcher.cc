@@ -5,6 +5,7 @@
 
 #include "base.h"
 #include "bf16.h"
+#include "nnz_bucket.h"
 #include "telemetry.h"
 
 namespace dct {
@@ -97,9 +98,11 @@ bool PaddedBatcher::NextMeta(uint64_t* take, uint64_t* bucket,
   if (avail_rows_ == 0) return false;
   take_ = std::min<uint64_t>(batch_rows_, avail_rows_);
 
-  // per-shard nnz -> bucket = next pow2 of the max, floored at min_bucket_
+  // per-shard nnz -> bucket = the ladder rung at or above the fullest
+  // shard's count, floored at min_bucket_ (nnz_bucket.h)
   const uint64_t R = batch_rows_ / num_shards_;
   uint64_t max_shard = 0;
+  batch_nnz_ = 0;
   for (uint32_t d = 0; d < num_shards_; ++d) {
     const uint64_t lo = d * R;
     const uint64_t hi = std::min<uint64_t>((d + 1) * R, take_);
@@ -110,11 +113,9 @@ bool PaddedBatcher::NextMeta(uint64_t* take, uint64_t* bucket,
       shard_nnz += RowRangeNnz(b, r0, r1);
     });
     max_shard = std::max(max_shard, shard_nnz);
+    batch_nnz_ += shard_nnz;
   }
-  uint64_t bkt = min_bucket_;
-  while (bkt < max_shard) bkt <<= 1;
-
-  bucket_ = bkt;
+  bucket_ = NnzBucket(max_shard, min_bucket_);
   staged_ = true;
   *take = take_;
   *bucket = bucket_;

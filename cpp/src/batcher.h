@@ -2,8 +2,9 @@
 //
 // The reference pipeline ends at host CSR views (RowBlockIter, reference
 // include/dmlc/data.h:267); the TPU-native pipeline must emit *static-shape*
-// batches (fixed rows per batch, power-of-two nnz buckets) so XLA compiles a
-// bounded set of programs (SURVEY §7 hard part 1, "ragged → device").
+// batches (fixed rows per batch, nnz buckets from the eighth-of-an-octave
+// ladder of nnz_bucket.h) so XLA compiles a bounded set of programs (SURVEY
+// §7 hard part 1, "ragged → device").
 //
 // This module does that reshaping in C++ on the parser side of the ctypes
 // boundary: Python asks for the next batch's metadata (row count, nnz
@@ -44,7 +45,7 @@ class PaddedBatcher {
 
   // Stage the next batch. Returns false at end of data. On success:
   //   *take      true (unpadded) row count, <= batch_rows
-  //   *bucket    per-shard nnz capacity (next pow2 of max shard nnz)
+  //   *bucket    per-shard nnz capacity (NnzBucket of the max shard nnz)
   //   *max_index running max feature id (drives the dense/csr auto choice)
   //   *has_qid   1 when any parsed block carried query/group ids
   //   *has_field 1 when any parsed block carried per-nonzero field ids
@@ -92,6 +93,10 @@ class PaddedBatcher {
 
   void BeforeFirst();
   size_t BytesRead() const { return parser_->BytesRead(); }
+  // Real nonzeros of the batch NextMeta last staged, all shards (the sum
+  // it takes to find the fullest shard): with num_shards * bucket, the
+  // device lane's fill share.
+  uint64_t BatchNnz() const { return batch_nnz_; }
   // Pin the shuffle permutation the next BeforeFirst samples (mid-epoch
   // resume; Parser::SetShuffleEpoch). False when nothing shuffles.
   bool SetShuffleEpoch(unsigned epoch) {
@@ -156,6 +161,7 @@ class PaddedBatcher {
   // staged by NextMeta for the following Fill* call
   uint64_t take_ = 0;
   uint64_t bucket_ = 0;
+  uint64_t batch_nnz_ = 0;
   bool staged_ = false;
 };
 

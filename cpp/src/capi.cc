@@ -30,6 +30,7 @@
 #include "hdfs_filesys.h"
 #include "http.h"
 #include "input_split.h"
+#include "nnz_bucket.h"
 #include "parser.h"
 #include "recordio.h"
 #include "retry.h"
@@ -651,6 +652,12 @@ int dct_batcher_create(const char* uri, unsigned part, unsigned npart,
   });
 }
 
+// The rule both batchers size a CSR batch's nnz capacity by (nnz_bucket.h),
+// exported so a test can hold the Python statement of it equal.
+int dct_nnz_bucket(uint64_t n, uint64_t floor, uint64_t* out) {
+  return Guard([&] { *out = dct::NnzBucket(n, floor); });
+}
+
 int dct_batcher_next_meta(dct_batcher_t h, uint64_t* take, uint64_t* bucket,
                           uint64_t* max_index, int* has_qid, int* has_field,
                           int* has) {
@@ -721,6 +728,11 @@ int dct_batcher_set_epoch(dct_batcher_t h, unsigned epoch,
 int dct_batcher_bytes_read(dct_batcher_t h, size_t* out) {
   return Guard(
       [&] { *out = static_cast<dct::PaddedBatcher*>(h)->BytesRead(); });
+}
+
+int dct_batcher_batch_nnz(dct_batcher_t h, uint64_t* out) {
+  return Guard(
+      [&] { *out = static_cast<dct::PaddedBatcher*>(h)->BatchNnz(); });
 }
 
 int dct_batcher_free(dct_batcher_t h) {
@@ -847,6 +859,11 @@ int dct_csrrec_set_epoch(dct_csrrec_t h, unsigned epoch,
 int dct_csrrec_bytes_read(dct_csrrec_t h, size_t* out) {
   return Guard(
       [&] { *out = static_cast<dct::CsrRecBatcher*>(h)->BytesRead(); });
+}
+
+int dct_csrrec_batch_nnz(dct_csrrec_t h, uint64_t* out) {
+  return Guard(
+      [&] { *out = static_cast<dct::CsrRecBatcher*>(h)->BatchNnz(); });
 }
 
 int dct_csrrec_free(dct_csrrec_t h) {

@@ -4,6 +4,7 @@
 #include <cstring>
 
 #include "base.h"
+#include "nnz_bucket.h"
 #include "recordio.h"
 #include "serializer.h"
 #include "stream.h"
@@ -87,18 +88,17 @@ bool CsrRecBatcher::AdvanceRecord() {
     has_field_ = hf;
     // the per-shard nnz capacity: any R consecutive rows carry at most
     // win_max[ceil_log2(R)] nonzeros (the converter's GLOBAL sliding
-    // bound), so one pow2 bucket serves every batch of the epoch
+    // bound), so one ladder bucket (nnz_bucket.h) serves every batch of
+    // the epoch
     const uint64_t R = batch_rows_ / num_shards_;
     uint32_t wi = 0;
     while ((1ull << wi) < R && wi + 1 < nwin) ++wi;
     const uint64_t bound = LoadU64LE(p + 32 + 8 * wi);
     // same sanity bound as nnz: a flipped high bit in the table must die
-    // here, not drive the pow2 loop into overflow or a multi-GB alloc
+    // here, not drive the bucket rule into overflow or a multi-GB alloc
     DCT_CHECK(bound <= (1ull << 34))
         << "corrupt csr rec window table: bound " << bound;
-    uint64_t bkt = min_bucket_;
-    while (bkt < bound) bkt <<= 1;
-    bucket_ = bkt;
+    bucket_ = NnzBucket(bound, min_bucket_);
   } else {
     DCT_CHECK(hw == has_weight_ && hq == has_qid_ && hf == has_field_)
         << "csr rec record flag drift: got w/q/f=" << hw << hq << hf
@@ -200,6 +200,7 @@ uint64_t CsrRecBatcher::FillImpl(const Targets& t, int32_t* nrows) {
   const uint64_t B = bucket_;
   uint64_t filled = 0;                   // rows placed into this batch
   uint64_t shard_written = 0;            // nnz in the current shard's plane
+  batch_nnz_ = 0;
   while (filled < batch_rows_) {
     if (!have_record_ || row_in_rec_ >= rec_rows_) {
       if (eof_ || !AdvanceRecord()) break;
@@ -260,6 +261,7 @@ uint64_t CsrRecBatcher::FillImpl(const Targets& t, int32_t* nrows) {
       }
     }
     shard_written += span_nnz;
+    batch_nnz_ += span_nnz;
     nnz_in_rec_ += span_nnz;
     row_in_rec_ += n;
     filled += n;

@@ -47,7 +47,8 @@ class CsrRecBatcher {
                 uint64_t min_nnz_bucket);
 
   // Static batch shape, valid before any Fill: bucket is the per-shard
-  // nnz capacity (pow2 of the window bound, floored at min_nnz_bucket).
+  // nnz capacity (NnzBucket of the window bound, floored at
+  // min_nnz_bucket: nnz_bucket.h).
   void Meta(uint64_t* bucket, int* has_weight, int* has_qid, int* has_field);
 
   // Fill one batch into caller planes (PaddedBatcher::FillCSR layout):
@@ -68,6 +69,10 @@ class CsrRecBatcher {
 
   void BeforeFirst();
   size_t BytesRead() const { return bytes_read_; }
+  // Real nonzeros of the batch the last Fill wrote, all shards (what the
+  // fill counts span by span): with num_shards * bucket, the device
+  // lane's fill share.
+  uint64_t BatchNnz() const { return batch_nnz_; }
   bool SetShuffleEpoch(unsigned epoch) {
     return split_->SetShuffleEpoch(epoch);
   }
@@ -117,6 +122,7 @@ class CsrRecBatcher {
   int has_qid_ = -1;
   int has_field_ = -1;
   uint64_t bucket_ = 0;
+  uint64_t batch_nnz_ = 0;
 
   bool have_record_ = false;
   bool eof_ = false;

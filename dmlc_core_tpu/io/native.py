@@ -196,6 +196,8 @@ def _declare_signatures(cdll: ctypes.CDLL) -> None:
         "dct_batcher_create": (i, [c.c_char_p, u, u, c.c_char_p, i, i,
                                    c.c_uint64, c.c_uint32, c.c_uint64,
                                    c.POINTER(vp)]),
+        "dct_nnz_bucket": (i, [c.c_uint64, c.c_uint64,
+                               c.POINTER(c.c_uint64)]),
         "dct_batcher_next_meta": (i, [vp, c.POINTER(c.c_uint64),
                                       c.POINTER(c.c_uint64),
                                       c.POINTER(c.c_uint64), c.POINTER(i),
@@ -211,6 +213,7 @@ def _declare_signatures(cdll: ctypes.CDLL) -> None:
         "dct_batcher_before_first": (i, [vp]),
         "dct_batcher_set_epoch": (i, [vp, u, c.POINTER(c.c_int32)]),
         "dct_batcher_bytes_read": (i, [vp, c.POINTER(sz)]),
+        "dct_batcher_batch_nnz": (i, [vp, c.POINTER(c.c_uint64)]),
         "dct_batcher_free": (i, [vp]),
         "dct_denserec_create": (i, [c.c_char_p, u, u, c.c_uint64,
                                     c.c_uint32, c.POINTER(vp)]),
@@ -238,6 +241,7 @@ def _declare_signatures(cdll: ctypes.CDLL) -> None:
         "dct_csrrec_before_first": (i, [vp]),
         "dct_csrrec_set_epoch": (i, [vp, u, c.POINTER(c.c_int32)]),
         "dct_csrrec_bytes_read": (i, [vp, c.POINTER(sz)]),
+        "dct_csrrec_batch_nnz": (i, [vp, c.POINTER(c.c_uint64)]),
         "dct_csrrec_free": (i, [vp]),
         "dct_bf16_convert": (i, [vp, vp, c.c_uint64]),
         "dct_bf16_upcast": (i, [vp, vp, c.c_uint64]),
@@ -993,6 +997,14 @@ class NativeParser:
 
 
 # -- batcher ----------------------------------------------------------------
+def native_nnz_bucket(n: int, floor: int) -> int:
+    """The native statement of the nnz-capacity rule (cpp/src/nnz_bucket.h;
+    the Python one is dmlc_core_tpu.tpu.device_iter.nnz_bucket)."""
+    out = ctypes.c_uint64()
+    _check(lib().dct_nnz_bucket(n, floor, ctypes.byref(out)))
+    return out.value
+
+
 class NativeBatcher:
     """Static-shape padded-batch assembly in C++ (cpp/src/batcher.h).
 
@@ -1154,6 +1166,13 @@ class NativeBatcher:
         _check(lib().dct_batcher_bytes_read(self._h, ctypes.byref(out)))
         return out.value
 
+    def batch_nnz(self) -> int:
+        """Real nonzeros of the batch next_meta() last staged, all shards
+        (what the staging summed to find the fullest shard)."""
+        out = ctypes.c_uint64()
+        _check(lib().dct_batcher_batch_nnz(self._h, ctypes.byref(out)))
+        return out.value
+
     def close(self) -> None:
         """Free the native batcher handle (idempotent)."""
         if self._h:
@@ -1262,6 +1281,13 @@ class NativeCsrRecBatcher:
         """Record bytes consumed from the source so far."""
         out = ctypes.c_size_t()
         _check(lib().dct_csrrec_bytes_read(self._h, ctypes.byref(out)))
+        return out.value
+
+    def batch_nnz(self) -> int:
+        """Real nonzeros of the batch the last fill wrote, all shards
+        (what the fill counted span by span)."""
+        out = ctypes.c_uint64()
+        _check(lib().dct_csrrec_batch_nnz(self._h, ctypes.byref(out)))
         return out.value
 
     def close(self) -> None:

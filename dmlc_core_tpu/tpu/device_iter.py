@@ -11,9 +11,10 @@ Static-shape strategy (XLA compiles one program per shape — SURVEY §7 hard
 part 1 "ragged → device"):
 - rows per batch is fixed (`batch_rows`); the final partial batch is padded
   with zero-weight rows, so row count never varies.
-- nnz is bucketed to the next power of two of the batch's true nnz (floor
-  `min_nnz_bucket`), so the number of distinct compiled shapes is
-  O(log max_nnz).
+- nnz is bucketed on an eighth-of-an-octave ladder (`nnz_bucket`: the
+  fullest shard's true nnz rounded up to a sixteenth of its next power of
+  two, floor `min_nnz_bucket`), so padding stays under 12.5% and the number
+  of distinct compiled shapes is O(log max_nnz).
 - CSR offsets become per-nonzero `row` segment ids (int32, TPU-friendly);
   padding nonzeros point at row == rows_per_shard, a sacrificial segment
   sliced off by the ops in dmlc_core_tpu.ops.sparse.
@@ -75,6 +76,8 @@ def _get_lane_metrics():
                 "device_first_batch_wait_us"),
             "turnover_us": telemetry.histogram("device_turnover_us"),
             "batches": telemetry.counter("device_batches_total"),
+            "nnz_sent": telemetry.counter("device_nnz_sent_total"),
+            "nnz_real": telemetry.counter("device_nnz_real_total"),
             "bytes": telemetry.counter("device_transfer_bytes_total"),
             "failures": telemetry.counter("device_put_failures_total"),
             "host_q": telemetry.gauge("device_host_q_depth"),
@@ -175,7 +178,7 @@ def _dense_dtype_of(d) -> np.dtype:
 __all__ = ["PaddedBatch", "DenseBatch", "DeviceRowBlockIter", "HostBatcher",
            "NativeHostBatcher", "DenseRecHostBatcher", "CsrRecHostBatcher",
            "unpack_tree", "unpack_shard", "match_placement_rules",
-           "jax_profiler_capture"]
+           "jax_profiler_capture", "nnz_bucket"]
 
 
 @dataclass
@@ -216,6 +219,9 @@ class PaddedBatch:
     # host-side true row count (not part of the device tree; avoids a
     # device->host sync when consumers just need progress accounting)
     total_rows: int = 0
+    # host-side true nonzero count, all shards, as the fill counted it:
+    # against D * nnz_bucket it is the batch's fill share
+    total_nnz: int = 0
     qid: Any = None
     field: Any = None
     big: Any = None  # [D, Kb, NNZ] packed row/col[/val][/field]
@@ -418,11 +424,33 @@ def _bitcast_f32(a):
     return jax.lax.bitcast_convert_type(a, jnp.float32)
 
 
-def _next_pow2(n: int, floor: int) -> int:
-    b = floor
-    while b < n:
-        b <<= 1
-    return b
+def nnz_bucket(n: int, floor: int) -> int:
+    """The nnz capacity of a CSR batch whose fullest shard holds ``n``
+    entries: the one rule that chooses it (stated once more natively,
+    cpp/src/nnz_bucket.h; tests/test_nnz_bucket.py holds the two equal).
+
+    At or under ``floor`` (``min_nnz_bucket``) the capacity is the floor.
+    Above it, with ``p`` the smallest power of two >= ``n``, it is ``n``
+    rounded up to a multiple of ``p / 16``: eight rungs an octave, so
+    padding stays under 12.5% of ``n`` where the next power of two left up
+    to 100%, a power of two maps to itself, and the count of distinct
+    compiled shapes stays O(log max_nnz). The granule never falls under
+    ``min(floor, 128)`` entries, so small shapes and lane rows stay whole.
+
+    The step's gathers and scatters cost per entry sent, padding included
+    (PERF.md section 5), which is why the ladder is fine. The trade: a
+    corpus whose batch nnz wanders across rungs compiles up to eight shapes
+    an octave where it compiled one, and an epoch's short last batch lands
+    on a rung of its own; ``device_distinct_shapes`` and
+    ``model_step_builds_total`` show it. At thousands of rows a batch the
+    count is steady to a fraction of a percent."""
+    floor = max(int(floor), 1)
+    n = int(n)
+    if n <= floor:
+        return floor
+    p = 1 << (n - 1).bit_length()
+    g = max(p >> 4, min(floor, 128))
+    return -(-n // g) * g
 
 
 # -- spec-driven placement ---------------------------------------------------
@@ -702,7 +730,7 @@ class HostBatcher:
         shard_starts = np.concatenate(
             [[0], np.cumsum(lens.reshape(D, R).sum(axis=1))]).astype(np.int64)
         shard_nnz = np.diff(shard_starts)
-        bucket = _next_pow2(int(shard_nnz.max()) if take else 1,
+        bucket = nnz_bucket(int(shard_nnz.max()) if take else 1,
                             self.min_nnz_bucket)
 
         # assemble straight into the packed two-leaf layout (the same
@@ -741,6 +769,7 @@ class HostBatcher:
             row=row, col=colp, val=valp,
             label=label_v, weight=weight_v,
             nrows=nrows, total_rows=int(take),
+            total_nnz=int(shard_starts[-1]),
             qid=qid_v, field=fldp, big=big, aux=aux)
 
     def _emit_dense(self, take, label, weight, lens, col, val, qid):
@@ -906,6 +935,7 @@ class NativeHostBatcher:
                            val=val16 if sep_val else val,
                            label=label, weight=weight,
                            nrows=nrows, total_rows=int(take),
+                           total_nnz=self._b.batch_nnz(),
                            qid=qid, field=field, big=big, aux=aux,
                            val16=val16)
 
@@ -1016,6 +1046,7 @@ class CsrRecHostBatcher:
         return PaddedBatch(row=row, col=col, val=val,
                            label=label, weight=weight,
                            nrows=nrows, total_rows=int(take),
+                           total_nnz=self._b.batch_nnz(),
                            qid=qid, field=field, big=big, aux=aux)
 
     def reset(self) -> None:
@@ -1462,6 +1493,13 @@ class DeviceRowBlockIter:
         m["bytes"].inc(nbytes)
         cls = type(batch)
         kwargs = dict(tree)
+        if cls is PaddedBatch:
+            # the fill share: entries sent against entries that were real,
+            # both from what the fill counted (no pass over the batch)
+            plane = batch.big[:, 0] if batch.row is None else batch.row
+            m["nnz_sent"].inc(plane.size)
+            m["nnz_real"].inc(batch.total_nnz)
+            kwargs["total_nnz"] = batch.total_nnz
         if "val" in kwargs and "aux" in kwargs:
             # the packed tree's separate bf16 value leaf rides the val16
             # field so the device batch's tree() re-emits it

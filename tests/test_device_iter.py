@@ -9,7 +9,8 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from dmlc_core_tpu.tpu.device_iter import DeviceRowBlockIter, HostBatcher
+from dmlc_core_tpu.tpu.device_iter import (DeviceRowBlockIter, HostBatcher,
+                                           nnz_bucket)
 from dmlc_core_tpu.tpu.sharding import data_mesh, process_part
 from dmlc_core_tpu.io.native import NativeParser
 from dmlc_core_tpu.models.linear import LinearLearner
@@ -46,7 +47,9 @@ def test_host_batcher_shapes_and_padding(tmp_path):
         assert b.label.shape == (4, 64)
         assert b.row.shape == b.col.shape == b.val.shape
         assert b.row.shape[0] == 4
-        assert (b.row.shape[1] & (b.row.shape[1] - 1)) == 0  # pow2 bucket
+        # the bucket is the ladder's rung for the fullest shard
+        fullest = int((b.row < 64).sum(axis=1).max())
+        assert b.row.shape[1] == nnz_bucket(fullest, 64)
     # padding rows have zero weight; true rows weight 1
     total_weight = sum(float(b.weight.sum()) for b in batches)
     assert total_weight == 1000

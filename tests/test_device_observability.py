@@ -14,8 +14,9 @@ Covers the ISSUE 15 acceptance surface on the deterministic CPU backend:
   throttled batcher must read `stage_bound`, an injected `device_put`
   stall `transfer_bound`.
 - Compile-churn telemetry: a growing-nnz corpus crosses exactly the
-  expected power-of-two buckets; replaying the same corpus reports zero
-  new shapes.
+  expected buckets of the nnz ladder (powers of two map to themselves;
+  counts between them land on an eighth-of-an-octave rung); replaying the
+  same corpus reports zero new shapes.
 - `_device_put` failures: counted and flight-dumped like host aborts.
 - The bench device lane: emits numbers on this (device-less) host, and
   two of its ledger records diff cleanly through `benchdiff`.
@@ -271,16 +272,28 @@ def _bucket_of_key(key: str) -> int:
     return int(big.rstrip(")").split(",")[-1])
 
 
-def test_compile_churn_crosses_expected_buckets_and_replays_clean(tmp_path):
-    """A growing-nnz corpus crosses exactly the expected power-of-two
-    buckets; replaying the same corpus reports zero new shapes."""
-    # 64-row batches whose per-batch nnz grows: 1, 2, 4, 8 features per
-    # row -> batch nnz 64, 128, 256, 512 -> buckets (floor 16, pow2)
-    # exactly {64, 128, 256, 512}
+@pytest.mark.parametrize("nfeats,want", [
+    # batch nnz 64, 128, 256, 512: powers of two are rungs of the ladder
+    ((1, 2, 4, 8), {64, 128, 256, 512}),
+    # batch nnz 192 and 704 (11 a row, kdd2012's count) lie between powers
+    # of two, on rungs of 256/16 and 1024/16: no padding at all
+    ((3, 11), {192, 704}),
+    # 320 + 1 rounds up one granule of 512/16
+    ((5, 5.015625), {320, 352}),
+])
+def test_compile_churn_crosses_expected_buckets_and_replays_clean(
+        tmp_path, nfeats, want):
+    """A growing-nnz corpus crosses exactly the expected buckets of the
+    nnz ladder (floor 16); replaying the same corpus reports zero new
+    shapes."""
+    # 64-row batches whose per-batch nnz grows with the features per row
+    # (a fractional count gives that share of the rows one feature more)
     lines = []
-    for nfeat in (1, 2, 4, 8):
+    for nfeat in nfeats:
+        extra = round((nfeat - int(nfeat)) * 64)
         for i in range(64):
-            feats = " ".join(f"{j}:1.0" for j in range(nfeat))
+            feats = " ".join(f"{j}:1.0"
+                             for j in range(int(nfeat) + (i < extra)))
             lines.append(f"{i % 2} {feats}")
     path = tmp_path / "grow.libsvm"
     path.write_text("\n".join(lines) + "\n")
@@ -298,17 +311,18 @@ def test_compile_churn_crosses_expected_buckets_and_replays_clean(tmp_path):
                   if g["name"] == "device_distinct_shapes"]
         return events, (shapes[0] if shapes else 0)
 
-    assert _run_iter(str(path), batch_rows=64, min_nnz_bucket=16) == 256
+    rows = 64 * len(nfeats)
+    assert _run_iter(str(path), batch_rows=64, min_nnz_bucket=16) == rows
     events, distinct = census()
-    assert {_bucket_of_key(k) for k in events} == {64, 128, 256, 512}
-    assert len(events) == 4 and distinct == 4
+    assert {_bucket_of_key(k) for k in events} == want
+    assert len(events) == len(want) and distinct == len(want)
     assert all(v == 1 for v in events.values())
     # replay the SAME corpus through a fresh iterator: the census is
     # process-wide (jit-cache semantics) — zero new shapes, zero new
     # compile events
-    assert _run_iter(str(path), batch_rows=64, min_nnz_bucket=16) == 256
+    assert _run_iter(str(path), batch_rows=64, min_nnz_bucket=16) == rows
     events2, distinct2 = census()
-    assert events2 == events and distinct2 == 4
+    assert events2 == events and distinct2 == len(want)
 
 
 # -- device_put failures ------------------------------------------------------
@@ -411,12 +425,13 @@ def test_model_step_counts_builds_and_times_every_dispatch(tmp_path):
                 params, _ = learner.step(params, batch)
                 seen.append(builds.value)
             it.before_first()
-    # 256, 256, 188 rows pad to one signature: built on the first step
-    # only, never on a repeat or in the second epoch
-    assert seen == [1] * 6
+    # 256, 256 rows pad to one signature and the epoch's short last batch
+    # (188 rows, 1,504 entries) to the ladder's rung below it: each built
+    # on its first step only, never on a repeat or in the second epoch
+    assert seen == [1, 1, 2, 2, 2, 2]
     assert _hist("model_step_dispatch_us") == 6
     steps = [s for s in telemetry.spans() if s["name"] == "model.step"]
-    assert [s["args"]["built"] for s in steps] == [1, 0, 0, 0, 0, 0]
+    assert [s["args"]["built"] for s in steps] == [1, 0, 1, 0, 0, 0]
     # each new batch signature builds once more, a repeat never
     sigs = set()
     with DeviceRowBlockIter(path, batch_rows=128, layout="csr",
@@ -425,7 +440,7 @@ def test_model_step_counts_builds_and_times_every_dispatch(tmp_path):
             sigs.add(tuple(sorted((k, v.shape)
                                   for k, v in batch.tree().items())))
             params, _ = learner.step(params, batch)
-            assert builds.value == 1 + len(sigs)
+            assert builds.value == 2 + len(sigs)
     assert len(sigs) >= 1
     assert telemetry.counter("model_step_builds_total",
                              {"model": "FMLearner"}).value == 0

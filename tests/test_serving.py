@@ -195,8 +195,11 @@ def test_raw_socket_edges(tmp_path):
 # ---------------------------------------------------------------------------
 def test_bounded_queue_sheds_503(tmp_path):
     uri, _, _ = save_linear(tmp_path)
+    # lateness shedding off: r2 waits out r1's gated forward, whose first
+    # call may compile for longer than the 200 ms budget, and this test is
+    # about the bounded queue's 503 alone
     with serving_server(uri, rows_buckets="4", queue_max=1,
-                        batch_delay_ms=0.0,
+                        batch_delay_ms=0.0, shed_lateness_ms=0.0,
                         breaker_threshold=1000) as srv:
         gate = ForwardGate(srv._model)
         gate.arm()
