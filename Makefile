@@ -4,7 +4,7 @@
 
 .PHONY: ci lint analyze native-test tsan-test asan-test ubsan-test \
         parse-lanes telemetry trace cache range fsfault rig serving slo \
-        device zerocopy pytest liveness elastic mesh bench-smoke chip-smoke \
+        device zerocopy pytest liveness elastic mesh chip-smoke \
         dryrun doc clean
 
 ci: lint analyze native-test tsan-test asan-test ubsan-test parse-lanes \
@@ -79,7 +79,7 @@ device:
 	timeout -k 10 300 env JAX_PLATFORMS=cpu \
 	  python3 -m pytest tests/test_device_observability.py -q
 
-# Zero-copy ingest lane (doc/benchmarking.md "Zero-copy ingest"): staging
+# Zero-copy ingest lane (doc/observability.md "Zero-copy ingest"): staging
 # buffers 64-byte aligned (pool reuse included), byte-identity of the
 # zero-copy vs copying device paths for csr/dense x f32/bf16, fallback
 # counter + recycle-skip gauge semantics, sharded placement on a forced
@@ -92,12 +92,10 @@ zerocopy:
 
 # Measurement-rig lane (doc/benchmarking.md): out-of-process origin
 # byte-identity against the in-process mocks for all four backends, a
-# 5 s open-loop smoke at fixed QPS, the coordinated-omission pin
+# 5 s open-loop smoke at fixed QPS and the coordinated-omission pin
 # (injected origin stall visible in intended-time p99, invisible in the
-# naive service-time capture), and benchdiff against the seeded
-# regression fixture (must exit nonzero) + a self-compare (must exit
-# zero). Hard timeout: a wedged origin or generator is exactly the
-# regression this lane exists to catch.
+# naive service-time capture). Hard timeout: a wedged origin or
+# generator is exactly the regression this lane exists to catch.
 rig:
 	timeout -k 10 300 python3 -m pytest tests/test_loadrig.py -q
 
@@ -201,17 +199,13 @@ dryrun:
 	  jax.jit(fn).lower(*args).compile(); \
 	  print('entry() compile-check OK')"
 
-# The two targets below need an accelerator (run them through the chip
-# tool); neither is part of `make ci`. chip-smoke: does the program still
-# start on the chip, through its normal entry points, with every phase
-# on platform=tpu? Exits non-zero, naming the phase, without a chip.
+# chip-smoke needs an accelerator (run it through the chip tool) and is
+# not part of `make ci`: does the program still start on the chip, through
+# its normal entry points, with every phase on platform=tpu? Exits
+# non-zero, naming the phase, without a chip. Speed is measured by
+# `python3 benchmarks/run.py` (doc/benchmarking.md), one cell a call.
 chip-smoke:
 	python3 chip_smoke.py
-
-# bench-smoke: every bench lane at CI size; its device lanes refuse the
-# CPU backend (host-only metrics: `python3 bench.py --smoke --parse-only`)
-bench-smoke:
-	python3 bench.py --smoke
 
 clean:
 	$(MAKE) -C cpp clean

@@ -498,7 +498,7 @@ def child_save_model(uri: str, bias: str) -> None:
 
 def child_kernel(libsvm: str) -> None:
     """The Pallas kernel compiled by Mosaic: against the XLA scatter at
-    the bench probe shape, then inside the dense-margin DP step's
+    1024 x 28 alone, then inside the dense-margin DP step's
     shard_map over real text batches of 1024 rows x 28 features per
     shard, against the same step formatting with the XLA scatter."""
     import numpy as np

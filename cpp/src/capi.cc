@@ -549,7 +549,7 @@ int dct_parser_bytes_read(dct_parser_t h, size_t* out) {
 }
 
 // Mirror of dct::ParsePipelineStats (parser.h) — occupancy/stall counters
-// of the multi-chunk parse pipeline, for bench/ops introspection.
+// of the multi-chunk parse pipeline, for an operator's introspection.
 // APPEND-ONLY contract: the struct is caller-allocated and versionless
 // (the in-tree ctypes mirror in dmlc_core_tpu/io/native.py ships in
 // lockstep with this .so); new fields go at the END only, and out-of-tree

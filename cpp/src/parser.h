@@ -33,7 +33,7 @@ class TextParserBase;
 
 // Occupancy/stall counters for the multi-chunk parse pipeline
 // (PipelinedParser below), exposed through the C ABI
-// (dct_parser_pipeline_stats) so the bench harness can see which stage
+// (dct_parser_pipeline_stats) so a caller can see which stage
 // binds: a starved reader (reader_waits low, consumer_waits high) means
 // parse-bound; a full queue (reader_waits high) means consume-bound.
 struct ParsePipelineStats {
@@ -361,7 +361,7 @@ class DiskCacheParser : public Parser<IndexType> {
 // pipelined exactly ONE chunk against consumption and fanned each chunk out
 // behind a barrier (FillBlocks), so the producer thread serialized the
 // InputSplit read against the straggler slice of every round and added
-// workers mostly waited (BENCH_r05 thread_scaling: +2% at 4 threads).
+// workers mostly waited (+2% at 4 threads when it was last measured).
 // Here the stages are decoupled:
 //
 //   reader thread ──> bounded in-flight chunk queue ──> worker pool

@@ -47,7 +47,7 @@ namespace dct {
 
 // Dispatch tiers, ordered by preference. The numeric values are stable:
 // they ride the C ABI (dct_parse_pipeline_stats_t.simd_tier) and the
-// DMLC_PARSE_SIMD override env understood by bench/CI lanes.
+// DMLC_PARSE_SIMD override env understood by the CI lanes.
 enum SimdTier {
   kSimdScalar = 0,  // byte-at-a-time parsers, no tape
   kSimdSWAR = 1,    // 64-bit SWAR blocks (any little-endian CPU)

@@ -1,5 +1,5 @@
 // Host-parse microbenchmark: times ParseBlock on synthetic corpora shaped
-// like the bench.py datasets (HIGGS-ish libsvm, dense csv, libfm triples).
+// like the reference's data sets (HIGGS-ish libsvm, dense csv, libfm triples).
 // Build:  make -C cpp benchparse   Run: ./dmlc_core_tpu/_native/bench_parse
 // This is the fast inner loop for parser optimization work — it isolates
 // the single-core ParseBlock cost from the split/pipeline/device stages

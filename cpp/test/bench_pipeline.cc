@@ -1,8 +1,8 @@
 // Full native-pipeline benchmark: file -> InputSplit(prefetch) ->
 // ThreadedParser -> consumed blocks, all in C++ — the stage between the
-// ParseBlock microbench (bench_parse.cc) and the Python e2e number
-// (bench.py --parse-only). The spread between the three locates the
-// pipeline overhead: IO+split+threading here, ctypes/Python above.
+// ParseBlock microbench (bench_parse.cc) and the Python iterators. The
+// spread between the two locates the pipeline overhead: IO+split+threading
+// here, ctypes/Python above.
 // Build: make -C cpp benchpipeline
 // Run:   ./dmlc_core_tpu/_native/bench_pipeline FILE [nthread] [reps]
 #include <chrono>
@@ -18,8 +18,8 @@ namespace {
 
 // `bench_pipeline rt N PAYLOAD PATH`: native RecordIO write+read
 // round-trip — the BASELINE.md parity row measured engine-to-engine
-// (the Python-facade probe in bench.py pays one ctypes call per record,
-// which measures the binding, not the format).
+// (through the Python facade a record pays one ctypes call, which
+// measures the binding, not the format).
 int RoundTrip(int n, int payload, const char* path) {
   using Clock = std::chrono::steady_clock;
   std::string blob(payload, 'x');

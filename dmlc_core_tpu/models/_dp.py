@@ -111,6 +111,7 @@ class DataParallelModel:
 
         if self._takes_row_form(keys):
             # the benchmark finds the step's module by this name
+            # (tests/test_benchmark_names.py holds it, here and below)
             def sharded_step(params, tree):
                 shard = shard_view(tree)
                 with jax.named_scope("dp.loss_grad"):
@@ -127,6 +128,8 @@ class DataParallelModel:
                              loss_sum, wsum)
             return jax.jit(step)
 
+        # the benchmark finds the step's module by this name
+        # (tests/test_benchmark_names.py)
         @functools.partial(jax.shard_map, mesh=self.mesh,
                            in_specs=(P(), dict(tree_keys)),
                            out_specs=(P(), P()))

@@ -1,7 +1,7 @@
 """Standalone scoring server: ``python -m dmlc_core_tpu.serving``.
 
-The out-of-process entry the bench serving lane and the chaos suite
-drive: compiles the bucket ladder, binds the port, prints one
+The out-of-process entry the chaos suite and chip_smoke.py drive:
+compiles the bucket ladder, binds the port, prints one
 ``SERVE_READY port=<p> pid=<p> platform=<p> devices=<n> device_kind=<k>``
 handshake line on stdout, and serves until SIGTERM/SIGINT — which
 triggers the draining shutdown (answer every admitted request, shed the

@@ -12,7 +12,7 @@ plane (doc/serving.md):
   since ARRIVAL — not time in service) exceeds its lateness budget is
   answered 429 without being scored. Under overload this holds the
   admitted-request p99 at the configured target; the shed rate is the
-  honest signal (coordinated-omission discipline, doc/benchmarks.md).
+  honest signal (coordinated-omission discipline, doc/benchmarking.md).
 - **Circuit breaker**: consecutive model-forward failures open the
   breaker; while open, scores are shed 503 for a cooldown, then one
   half-open batch probes recovery.

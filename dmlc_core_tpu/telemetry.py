@@ -30,7 +30,6 @@ from __future__ import annotations
 import json
 import math
 import os
-import re
 import sys
 import threading
 import time
@@ -47,8 +46,7 @@ __all__ = ["Counter", "Gauge", "Histogram", "HIST_BUCKETS",
            "stall_attribution", "straggler_attribution", "VERDICT_CODES",
            "flight_dump", "device_overlap_ratio", "quantile_from_buckets",
            "WindowedView", "SloMonitor", "start_windowed_view",
-           "stop_windowed_view", "windowed_view", "slo_page_active",
-           "HostResourceSampler"]
+           "stop_windowed_view", "windowed_view", "slo_page_active"]
 
 SNAPSHOT_VERSION = 1
 # must match cpp/src/telemetry.h kHistBuckets (le 2^0..2^27, then +Inf)
@@ -1199,9 +1197,6 @@ METRIC_HELP: Dict[str, str] = {
     "rig_service_us":
         "request latency from the actual send time (us; hides queueing "
         "behind a stalled origin — kept for the divergence itself)",
-    # host resource sampler (HostResourceSampler, doc/benchmarking.md)
-    "host_cpu_busy_frac": "whole-host CPU busy fraction, last interval",
-    "host_rss_bytes": "sampling process RSS, last sample",
     # online scoring plane (dmlc_core_tpu/serving/, doc/serving.md)
     "serve_requests_total": "HTTP requests parsed by the front end",
     "serve_admitted_total": "score requests admitted to the queue",
@@ -1684,267 +1679,3 @@ def slo_page_active() -> bool:
     signal the serving admission gate and ``/readyz`` read."""
     v = _view
     return v is not None and v.slo is not None and v.slo.paging
-
-
-# ---------------------------------------------------------------------------
-# Host resource sampler (doc/benchmarking.md): the evidence half of every
-# harness-bound verdict.  "host swings +/-40%" stops being folklore when
-# every bench lane carries the CPU/RSS/page-cache/net/disk envelope it ran
-# under — extras.host_resources in bench.py, per-lane via section().
-# ---------------------------------------------------------------------------
-class HostResourceSampler:
-    """Lightweight /proc-based host sampler for bench lanes.
-
-    A daemon thread samples per-core CPU jiffies (``/proc/stat``), this
-    process's RSS (``/proc/self/statm``), the host page cache
-    (``/proc/meminfo`` Cached), and cumulative network/disk bytes
-    (``/proc/net/dev``, ``/proc/diskstats``) every ``interval_s``.
-    :meth:`summary` reduces any time window to an envelope — mean/max
-    per-core busy fraction, peak RSS, byte deltas — and
-    :meth:`section` names a window after the lane that ran inside it,
-    so a remote-lane verdict can say *which* cores were saturated
-    (client parse vs origin serve) instead of guessing.
-
-    Degrades to ``{"unavailable": reason}`` summaries on hosts without
-    /proc.  Overhead: one thread, a handful of small file reads per
-    tick — nothing on the measured path.
-    """
-
-    def __init__(self, interval_s: float = 0.25):
-        self.interval_s = max(0.05, float(interval_s))
-        self.samples: List[dict] = []   # append-only; GIL-safe reads
-        self.sections: Dict[str, dict] = {}
-        self._stop = threading.Event()
-        self._thread: Optional[threading.Thread] = None
-        self._page = os.sysconf("SC_PAGESIZE") if hasattr(
-            os, "sysconf") else 4096
-        self._tick = os.sysconf("SC_CLK_TCK") if hasattr(
-            os, "sysconf") else 100
-        self._err: Optional[str] = None
-        # label -> [pids]: per-process CPU attribution (sandboxed /proc
-        # implementations zero the aggregate per-core jiffies while
-        # per-pid clocks still tick — and the remote-lane verdict needs
-        # the client-vs-origin CPU split either way)
-        self._watch: Dict[str, List[int]] = {"self": [os.getpid()]}
-        self._pid_last: Dict[int, int] = {}
-
-    def watch(self, label: str, *pids: int) -> None:
-        """Attribute the CPU of ``pids`` (e.g. a rig origin's workers, a
-        client subprocess) to ``label`` in every later summary."""
-        self._watch.setdefault(label, []).extend(int(p) for p in pids)
-
-    # -- raw readers (each guarded: a missing file disables, not crashes) --
-    def _read_cpu(self):
-        out = []
-        with open("/proc/stat") as f:
-            for line in f:
-                if not line.startswith("cpu") or line.startswith("cpu "):
-                    continue
-                parts = line.split()
-                vals = [int(x) for x in parts[1:11]]
-                idle = vals[3] + (vals[4] if len(vals) > 4 else 0)
-                out.append((sum(vals) - idle, sum(vals)))
-        return out
-
-    def _read_rss(self):
-        with open("/proc/self/statm") as f:
-            return int(f.read().split()[1]) * self._page
-
-    def _read_cached(self):
-        with open("/proc/meminfo") as f:
-            for line in f:
-                if line.startswith("Cached:"):
-                    return int(line.split()[1]) * 1024
-        return 0
-
-    def _read_net(self):
-        total = 0
-        with open("/proc/net/dev") as f:
-            for line in f.readlines()[2:]:
-                _, _, rest = line.partition(":")
-                if not rest:
-                    continue
-                v = rest.split()
-                total += int(v[0]) + int(v[8])  # rx + tx bytes
-        return total
-
-    # whole PHYSICAL devices only: partitions (sda1, nvme0n1p1) would
-    # double-count their disk, and stacked devices (dm-*, md*) would
-    # double-count their member disks
-    _DISK_RE = re.compile(
-        r"^(?:sd[a-z]+|vd[a-z]+|xvd[a-z]+|hd[a-z]+|nvme\d+n\d+"
-        r"|mmcblk\d+)$")
-
-    def _read_disk(self):
-        total = 0
-        with open("/proc/diskstats") as f:
-            for line in f:
-                v = line.split()
-                if len(v) < 14:
-                    continue
-                if not self._DISK_RE.match(v[2]):
-                    continue
-                total += (int(v[5]) + int(v[9])) * 512  # sectors r+w
-        return total
-
-    def _read_pid_cpu(self, pid: int) -> int:
-        # utime+stime jiffies; field 2 (comm) may contain spaces — split
-        # after the closing paren
-        with open(f"/proc/{pid}/stat") as f:
-            rest = f.read().rsplit(")", 1)[1].split()
-        return int(rest[11]) + int(rest[12])
-
-    def _sample(self) -> dict:
-        s = {"t": time.monotonic()}
-        s["cpu"] = self._read_cpu()
-        s["rss"] = self._read_rss()
-        s["cached"] = self._read_cached()
-        pids = {}
-        for label, plist in list(self._watch.items()):
-            total = 0
-            for p in plist:
-                try:
-                    v = self._read_pid_cpu(p)
-                    self._pid_last[p] = v
-                except (OSError, IndexError, ValueError):
-                    # pid exited: charge its last-seen cumulative so the
-                    # label's total never drops mid-window
-                    v = self._pid_last.get(p, 0)
-                total += v
-            pids[label] = total
-        s["pids"] = pids
-        try:
-            s["net"] = self._read_net()
-        except OSError:
-            s["net"] = 0
-        try:
-            s["disk"] = self._read_disk()
-        except OSError:
-            s["disk"] = 0
-        return s
-
-    def _loop(self):
-        cpu_gauge = gauge("host_cpu_busy_frac")
-        rss_gauge = gauge("host_rss_bytes")
-        prev = None
-        while not self._stop.is_set():
-            try:
-                s = self._sample()
-            except OSError as e:  # /proc went away: disable, don't spin
-                self._err = str(e)
-                return
-            self.samples.append(s)
-            if prev is not None:
-                db = sum(b for b, _ in s["cpu"]) - sum(
-                    b for b, _ in prev["cpu"])
-                dt = sum(t for _, t in s["cpu"]) - sum(
-                    t for _, t in prev["cpu"])
-                if dt > 0:
-                    cpu_gauge.set(round(db / dt, 4))
-            rss_gauge.set(s["rss"])
-            prev = s
-            self._stop.wait(self.interval_s)
-
-    def start(self) -> "HostResourceSampler":
-        """Take a first sample and start the sampling thread (no-op off
-        Linux: the first failed /proc read records the reason and every
-        summary reports ``unavailable``)."""
-        try:
-            self.samples.append(self._sample())
-        except OSError as e:
-            self._err = str(e)
-            return self
-        self._thread = threading.Thread(target=self._loop, daemon=True,
-                                        name="host-resource-sampler")
-        self._thread.start()
-        return self
-
-    def stop(self) -> dict:
-        """Stop sampling (one final sample) and return the whole-run
-        summary."""
-        self._stop.set()
-        if self._thread is not None:
-            self._thread.join(timeout=5.0)
-            try:
-                self.samples.append(self._sample())
-            except OSError:
-                pass
-        return self.summary()
-
-    def summary(self, t0: Optional[float] = None,
-                t1: Optional[float] = None) -> dict:
-        """Reduce the samples in ``[t0, t1]`` (monotonic; default: all)
-        to the envelope dict bench lanes record."""
-        if self._err is not None:
-            return {"unavailable": self._err}
-        window = [s for s in list(self.samples)
-                  if (t0 is None or s["t"] >= t0)
-                  and (t1 is None or s["t"] <= t1)]
-        if len(window) < 2:
-            return {"unavailable": "fewer than 2 samples in window"}
-        a, b = window[0], window[-1]
-        wall = b["t"] - a["t"]
-        ncores = max(len(a["cpu"]), 1)
-        per_core = []
-        for (b0, t0_), (b1, t1_) in zip(a["cpu"], b["cpu"]):
-            dt = t1_ - t0_
-            per_core.append(round((b1 - b0) / dt, 4) if dt > 0 else 0.0)
-        # peak = busiest consecutive interval (overall, all cores)
-        peak = 0.0
-        for p, s in zip(window, window[1:]):
-            db = sum(x for x, _ in s["cpu"]) - sum(x for x, _ in p["cpu"])
-            dt = sum(x for _, x in s["cpu"]) - sum(
-                x for _, x in p["cpu"])
-            if dt > 0:
-                peak = max(peak, db / dt)
-        # watched-process CPU seconds over the window
-        proc_cpu = {}
-        for label in b.get("pids", {}):
-            d = b["pids"].get(label, 0) - a.get("pids", {}).get(label, 0)
-            proc_cpu[label] = round(max(d, 0) / self._tick, 3)
-        out = {
-            "wall_s": round(wall, 3),
-            "samples": len(window),
-            "cpu_source": "stat",
-            "cpu_busy_frac": round(sum(per_core) / max(len(per_core), 1),
-                                   4),
-            "cpu_busy_frac_peak": round(peak, 4),
-            "cpu_per_core": per_core,
-            "ncores": ncores,
-            "proc_cpu_s": proc_cpu,
-            "rss_max_bytes": max(s["rss"] for s in window),
-            "page_cache_delta_bytes": b["cached"] - a["cached"],
-            "net_bytes": b["net"] - a["net"],
-            "net_bytes_per_sec": round((b["net"] - a["net"]) / wall, 1)
-            if wall > 0 else 0.0,
-            "disk_bytes": b["disk"] - a["disk"],
-        }
-        total_jiffies = (sum(t for _, t in b["cpu"])
-                         - sum(t for _, t in a["cpu"]))
-        if total_jiffies <= 0 and wall > 0:
-            # sandboxed /proc: the aggregate per-core clocks are zeroed
-            # while per-pid clocks tick — derive the busy fraction from
-            # the watched processes instead of reporting a false idle
-            out["cpu_source"] = "pids"
-            out["cpu_busy_frac"] = round(
-                min(sum(proc_cpu.values()) / (wall * ncores), 1.0), 4)
-            out.pop("cpu_per_core")
-            out.pop("cpu_busy_frac_peak")
-        return out
-
-    def section(self, name: str):
-        """Context manager: summarize the samples taken while the body
-        ran and stash the envelope under ``sections[name]``."""
-        sampler = self
-
-        class _Section:
-            def __enter__(self):
-                self.t0 = time.monotonic()
-                return sampler
-
-            def __exit__(self, *exc):
-                sampler.sections[name] = sampler.summary(
-                    self.t0, time.monotonic())
-                return False
-
-        return _Section()

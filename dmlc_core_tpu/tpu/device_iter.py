@@ -201,7 +201,7 @@ class PaddedBatch:
     int32 stacks label(f32 bits)/weight(f32 bits)[/qid]/nrows-plane per
     shard, so a batch crosses host->HBM in TWO transfers instead of one
     RPC per leaf — on high-latency links the per-transfer dispatch, not
-    bandwidth, was the recd/rec-lane ceiling (BENCH_r03). The packs are
+    bandwidth, bounds the binary formats. The packs are
     SHARD-MAJOR (device axis leads): under a NamedSharding every shard's
     bytes are one contiguous leading-axis slice of the host buffer, which
     is what lets the zero-copy device_put path hand each device its slab
@@ -1155,8 +1155,8 @@ class DeviceRowBlockIter:
 
     ``prefetch=0`` runs the whole path synchronously on the caller's
     thread — no pipeline threads, no queues. The right mode when there is
-    nothing to overlap with (single-core hosts, or calibration benches
-    measuring the ingest path itself): each double-buffer handoff is a
+    nothing to overlap with (single-core hosts, or a measurement of the
+    ingest path by itself): each double-buffer handoff is a
     thread wakeup that buys nothing there and can cost more than the
     fused fill it hands over.
     """
@@ -1224,8 +1224,7 @@ class DeviceRowBlockIter:
                 dense_dtype=dense_dtype, csr_val_dtype=csr_val_dtype)
         # per-leaf sharding derived from _PLACEMENT_RULES (every leaf is
         # shard-major, so all take the leading device axis); materialized
-        # lazily from the first batch's tree structure — exposed for
-        # bench probes
+        # lazily from the first batch's tree structure
         self.sharding = None
         self._leading_sharding = (None if mesh is None
                                   else batch_sharding(mesh))

@@ -338,15 +338,16 @@ def gen_index() -> str:
         "| [serving.md](serving.md) | batched online scoring: the "
         "admission model (bounded queue, intended-time lateness shed, "
         "circuit breaker), last-good model reloads, draining shutdown, "
-        "bucket padding + compile census, endpoint/knob tables, the "
-        "bench serving lane |",
-        "| [benchmarking.md](benchmarking.md) | the honest measurement "
-        "plane: out-of-process origin rig (pre-forked mock backends, "
-        "one config surface), open-loop load generator "
+        "bucket padding + compile census, endpoint/knob tables |",
+        "| [benchmarking.md](benchmarking.md) | how speed is measured: "
+        "the cell benchmark (`python3 benchmarks/run.py`, the cells of "
+        "`BENCHMARK.json`, configuration / traffic / metric files and "
+        "their readers, the result line, exit codes, no CPU fallback, "
+        "comparing two commits) and the load rig that stays for cells "
+        "to come: out-of-process origins (pre-forked mock backends, one "
+        "config surface) and the open-loop generator "
         "(coordinated-omission-safe intended-time capture, shed "
-        "policy), host resource evidence, how bench.py runs (one "
-        "process per chip, device lanes as children), the bench "
-        "provenance + regression ledger and benchdiff noise bands |",
+        "policy) |",
         "",
         "Build: `make doc` (part of `make ci`) regenerates api.md and "
         "parameters.md and fails on any undocumented public symbol — the "

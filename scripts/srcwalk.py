@@ -12,8 +12,8 @@ import os
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 # build outputs, caches, and generated docs are never linted or analyzed
-SKIP_DIRS = {".git", ".bench_cache", "_native", "__pycache__",
-             ".pytest_cache", ".claude", "doc"}
+SKIP_DIRS = {".git", "_native", "__pycache__", ".pytest_cache", ".claude",
+             "doc"}
 
 SOURCE_SUFFIXES = (".py", ".cc", ".h")
 

@@ -1,6 +1,7 @@
-"""One process per chip, pinned on the CPU: the parents of chip_smoke.py and
-bench.py stay off jax, a missing chip or a failed phase is a non-zero exit,
-and the persistent compile cache lives where the contract says."""
+"""One process per chip, pinned on the CPU: chip_smoke.py's parent stays off
+jax, a missing chip or a failed phase is a non-zero exit of chip_smoke.py and
+of every cell of benchmarks/run.py, and the persistent compile cache lives
+where the contract says."""
 
 import json
 import os
@@ -20,8 +21,7 @@ def poisoned_env(tmp_path):
     (tmp_path / "poison" / "jax").mkdir(parents=True)
     (tmp_path / "poison" / "jax" / "__init__.py").write_text(
         f"raise ImportError({POISON!r})\n")
-    env = dict(os.environ, PYTHONPATH=str(tmp_path / "poison"),
-               DMLC_BENCH_HISTORY="0")
+    env = dict(os.environ, PYTHONPATH=str(tmp_path / "poison"))
     env.pop("JAX_COMPILATION_CACHE_DIR", None)
     return env
 
@@ -100,40 +100,34 @@ def test_chip_smoke_failed_check_propagates(tmp_path, monkeypatch, capsys):
     assert "FAILED phase=device" in err and "platform='cpu'" in err
 
 
-# -- bench.py -----------------------------------------------------------------
-def test_bench_parent_survives_poisoned_jax(poisoned_env):
-    """Host-only run: the parent does its whole job — data, scaling
-    table, the headline child, the host probes, the result line —
-    without jax on the path."""
-    out = _run(["bench.py", "--smoke", "--rows", "2000", "--parse-only",
-                "--no-rec-lane", "--no-ledger"], poisoned_env)
-    assert out.returncode == 0, out.stderr[-2000:]
-    result = json.loads(out.stdout.strip().splitlines()[-1])
-    assert result["metric"] == "higgs_libsvm_ingest_rows_per_sec"
-    assert result["value"] > 0
-    assert "hbm_ingest_bw_util" not in result["extras"]
+# -- benchmarks/run.py --------------------------------------------------------
+def _cells():
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        return [w["name"] for w in json.load(f)["workloads"]]
 
 
-def test_bench_lane_failure_fails_the_run(poisoned_env):
-    """Device run: the first device lane's child dies (here on the
-    poison); the parent names the lane, exits non-zero and prints no
-    result — and it got that far without importing jax itself."""
-    out = _run(["bench.py", "--smoke", "--rows", "2000", "--no-ledger",
-                "--no-scaling-table"], poisoned_env)
+def _run_cell(workload):
+    return _run(["benchmarks/run.py", "--workload", workload, "--seed", "0",
+                 "--seconds", "1", "--trace", "0"],
+                dict(os.environ, JAX_PLATFORMS="cpu"))
+
+
+@pytest.mark.parametrize("workload", _cells())
+def test_cell_benchmark_refuses_cpu(workload):
+    """No CPU number under a device metric's name: every cell of
+    BENCHMARK.json, run where jax finds no chip, exits EXIT_NO_CHIP before
+    it writes any data, names the platform it found and prints no result."""
+    out = _run_cell(workload)
+    assert out.returncode == 3, out.stderr[-2000:]
+    assert "jax found platform 'cpu'" in out.stderr
+    assert out.stdout == ""
+
+
+def test_cell_benchmark_unknown_workload_prints_no_result():
+    out = _run_cell("no-such.cell")
     assert out.returncode != 0
-    assert "bench: libsvm lane failed" in out.stderr
-    assert POISON in out.stderr
-    assert out.stdout.strip() == ""
-
-
-def test_bench_device_lane_refuses_cpu():
-    """A lane that writes device-named metrics does not run on the CPU
-    backend: no CPU number under an hbm_* name."""
-    out = _run(["bench.py", "--device-lane", "--rows", "2000"],
-               dict(os.environ, JAX_PLATFORMS="cpu"))
-    assert out.returncode != 0
-    assert "platform=cpu" in out.stderr
-    assert out.stdout.strip() == ""
+    assert "no-such.cell" in out.stderr
+    assert out.stdout == ""
 
 
 # -- the compile cache --------------------------------------------------------

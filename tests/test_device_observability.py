@@ -18,8 +18,6 @@ Covers the ISSUE 15 acceptance surface on the deterministic CPU backend:
   counts between them land on an eighth-of-an-octave rung); replaying the
   same corpus reports zero new shapes.
 - `_device_put` failures: counted and flight-dumped like host aborts.
-- The bench device lane: emits numbers on this (device-less) host, and
-  two of its ledger records diff cleanly through `benchdiff`.
 - One clock for host and device (ISSUE 26): under `jax.profiler` the
   program's opened spans are `dmlc.*` events of the trace's host plane;
   the learner's step counts its builds and times its dispatch; turnover
@@ -33,7 +31,6 @@ from __future__ import annotations
 import json
 import os
 import random
-import sys
 import time
 
 import numpy as np
@@ -574,34 +571,3 @@ def test_jax_profiler_capture_noop_without_env(monkeypatch):
     monkeypatch.delenv("DMLC_JAX_PROFILE", raising=False)
     with jax_profiler_capture() as started:
         assert started is False
-
-
-# -- the bench device lane ----------------------------------------------------
-def test_benchdiff_compares_two_device_lane_runs(tmp_path):
-    sys.path.insert(0, os.path.join(REPO, "scripts"))
-    import benchdiff
-
-    def record(rps, overlap, sha):
-        result = {"metric": "higgs_libsvm_ingest_rows_per_sec",
-                  "value": 100000.0, "unit": "rows/s",
-                  "extras": {"device_lane": {
-                      "hbm_ingest_rows_per_sec": rps,
-                      "overlap_ratio": overlap,
-                      "device_transfer_p50_us": 1024,
-                      "stall_verdict": "stage_bound"}}}
-        return benchdiff.make_record(result, git_sha=sha, ts=1.0)
-
-    history = str(tmp_path / "hist.jsonl")
-    benchdiff.append_record(record(200000.0, 0.8, "a" * 40), history)
-    benchdiff.append_record(record(195000.0, 0.78, "b" * 40), history)
-    # inside the band -> exit 0, and the lane's metrics are compared
-    assert benchdiff.main(["--history", history, "--a", "-2",
-                           "--b", "-1"]) == 0
-    rec = benchdiff.load_history(history)[0]
-    flat = benchdiff.flat_metrics(rec)
-    assert flat["device_lane.hbm_ingest_rows_per_sec"] == 200000.0
-    assert flat["device_lane.overlap_ratio"] == 0.8
-    # a real regression in the lane -> exit 1
-    benchdiff.append_record(record(40000.0, 0.1, "c" * 40), history)
-    assert benchdiff.main(["--history", history, "--a", "-2",
-                           "--b", "-1"]) == 1

@@ -242,8 +242,7 @@ def test_latency_capped_origin_ranged_beats_sequential():
     """With latency_ms injected (per request AND per 256 KiB body block —
     a latency-bandwidth-capped connection), N concurrent ranges must beat
     one sequential stream by a wide margin. This is the observable proof
-    that range concurrency actually happens; the bench remote_lane pins
-    the same effect as a number."""
+    that range concurrency actually happens."""
     payload = pseudo_bytes(4 << 20, seed=39)
     s3_put("lat/blob.bin", payload)
     S3_STATE.latency_ms = 25

@@ -12,10 +12,7 @@ Pins the honesty properties the rig exists for:
   intended-time p99 and invisible in the naive service-time p99 — the
   coordinated-omission pin (Tene / HdrHistogram);
 - open-loop and closed-loop measurements diverge under saturation: the
-  closed loop's throughput quietly caps while its latency looks healthy;
-- ``benchdiff`` exits nonzero on the seeded regression fixture and zero
-  on a same-record self-compare, and the backfilled ledger carries the
-  r01..r05 trajectory under their historical shas.
+  closed loop's throughput quietly caps while its latency looks healthy.
 """
 
 import json
@@ -33,11 +30,6 @@ if SCRIPTS not in sys.path:
 
 import loadrig  # noqa: E402
 from tests import mock_origin  # noqa: E402
-
-BENCHDIFF = os.path.join(SCRIPTS, "benchdiff.py")
-FIXTURE = os.path.join(REPO, "tests", "data",
-                       "benchdiff_regression.jsonl")
-LEDGER = os.path.join(REPO, "bench_history.jsonl")
 
 
 def fetch_sha(origin, key) -> dict:
@@ -212,92 +204,6 @@ def test_shed_policy_bounds_lateness():
     assert r["completed"] + r["shed"] == r["arrivals"]
 
 
-# ---------------------------------------------------------------------------
-# bench ledger + benchdiff
-# ---------------------------------------------------------------------------
-def run_benchdiff(*args):
-    return subprocess.run([sys.executable, BENCHDIFF, *args],
-                          capture_output=True, text=True, timeout=120)
-
-
-def test_benchdiff_seeded_regression_exits_nonzero():
-    out = run_benchdiff("--history", FIXTURE, "--a", "0", "--b", "1")
-    assert out.returncode == 1, out.stdout + out.stderr
-    assert "REGRESSION" in out.stdout
-
-
-def test_benchdiff_self_compare_exits_zero():
-    out = run_benchdiff("--history", FIXTURE, "--a", "1", "--b", "1")
-    assert out.returncode == 0, out.stdout + out.stderr
-    assert "REGRESSION" not in out.stdout
-    assert "0 regression(s)" in out.stdout
-
-
-def test_benchdiff_trailing_and_round_refs():
-    """The backfilled repo ledger: r01..r05 under their historical shas,
-    resolvable by round tag, and a trailing compare runs clean."""
-    import benchdiff
-    records = benchdiff.load_history(LEDGER)
-    rounds = [r.get("round") for r in records[:5]]
-    assert rounds == [1, 2, 3, 4, 5]
-    assert all(len(r.get("git_sha") or "") == 40 for r in records[:5])
-    assert all(r.get("metric") == "higgs_libsvm_ingest_rows_per_sec"
-               for r in records[:5])
-    r3 = benchdiff.resolve(records, "r3")
-    assert r3["round"] == 3
-    by_sha = benchdiff.resolve(records, r3["git_sha"][:10])
-    assert by_sha is r3
-    out = run_benchdiff("--history", LEDGER, "--a", "r4", "--b", "r5")
-    assert out.returncode in (0, 1)  # a verdict, not a crash
-    assert "shared metrics" in out.stdout
-
-
-def test_ledger_append_record_schema(tmp_path):
-    """bench.py's ledger append: a normalized record lands with the
-    provenance, env, and lane slices benchdiff needs."""
-    import benchdiff
-    result = {"metric": "m", "value": 10.0, "unit": "rows/s",
-              "vs_baseline": 1.5,
-              "extras": {"bottleneck": "parse_bound",
-                         "csv_lane": {"rows_per_sec": 5.0,
-                                      "error": "nope"},
-                         "remote_lane": {"ranged_rows_per_sec": 7.0,
-                                         "range_scheduler": {"x": 1}}}}
-    rec = benchdiff.make_record(
-        result, git_sha="f" * 40, git_dirty=False,
-        host={"host": "h", "cpus": 2}, env_overrides={"DMLC_X": "1"},
-        host_resources={"overall": {"cpu_busy_frac": 0.5}},
-        smoke=True, argv=["--smoke"])
-    history = tmp_path / "hist.jsonl"
-    benchdiff.append_record(rec, str(history))
-    benchdiff.append_record(rec, str(history))
-    back = benchdiff.load_history(str(history))
-    assert len(back) == 2
-    got = back[0]
-    assert got["schema"] == benchdiff.SCHEMA
-    assert got["git_sha"] == "f" * 40 and got["smoke"] is True
-    assert got["stall_verdict"] == "parse_bound"
-    # numeric leaves only: error strings and nested dicts are dropped
-    assert got["lanes"]["csv_lane"] == {"rows_per_sec": 5.0}
-    assert got["lanes"]["remote_lane"] == {"ranged_rows_per_sec": 7.0}
-    # a self-compare of the appended record is clean
-    out = run_benchdiff("--history", str(history), "--a", "0", "--b",
-                        "1")
-    assert out.returncode == 0
-
-
-def test_ledger_tolerates_torn_tail(tmp_path):
-    """A half-written last line (crashed run) is skipped, not fatal."""
-    import benchdiff
-    history = tmp_path / "hist.jsonl"
-    rec = benchdiff.make_record({"metric": "m", "value": 1.0,
-                                 "unit": "u", "extras": {}})
-    benchdiff.append_record(rec, str(history))
-    with open(history, "a") as f:
-        f.write('{"schema": 1, "value": 2.0, "metr')
-    assert len(benchdiff.load_history(str(history))) == 1
-
-
 def test_quantile_from_log2_buckets():
     """The bucket-scheme quantile the generator reports percentiles
     from: upper bounds, overflow to inf, empty to 0."""
@@ -315,27 +221,3 @@ def test_quantile_from_log2_buckets():
     assert h2.quantile(0.5) == float("inf")
     with pytest.raises(ValueError):
         h2.quantile(0.0)
-
-
-def test_host_resource_sampler_sections():
-    """The sampler's per-lane envelope: a watched busy subprocess (the
-    rig's own usage — origins and clients are processes) shows up in
-    the section's CPU attribution while this process idles."""
-    from dmlc_core_tpu import telemetry
-    s = telemetry.HostResourceSampler(0.05).start()
-    child = subprocess.Popen(
-        [sys.executable, "-c",
-         "import time\n"
-         "d = time.monotonic() + 0.8\n"
-         "while time.monotonic() < d:\n"
-         "    sum(i * i for i in range(10000))\n"])
-    s.watch("busychild", child.pid)
-    with s.section("busy"):
-        child.wait()
-    out = s.stop()
-    assert out["samples"] >= 2
-    assert out["cpu_source"] in ("stat", "pids")
-    busy = s.sections["busy"]
-    assert busy["proc_cpu_s"]["busychild"] > 0.2
-    assert busy["proc_cpu_s"]["self"] < busy["proc_cpu_s"]["busychild"]
-    assert busy["rss_max_bytes"] > 0

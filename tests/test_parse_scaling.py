@@ -74,7 +74,7 @@ def test_simd_lane_single_thread_floor(tmp_path):
     """The ISSUE 3 acceptance lane, host-noise-proof edition: unlike the
     >=4-core scaling guards above, this runs on the 1-2 core bench host,
     so a regression of the SIMD text-ingest lane (doc/parsing.md) fails
-    tier-1 instead of only showing in bench.
+    tier-1.
 
     Measured in PROCESS CPU TIME with interleaved A/B batches and bounded
     re-measure — the PR 5 overhead-guard recipe (tests/test_telemetry.py).
@@ -161,7 +161,7 @@ def test_simd_lane_single_thread_floor(tmp_path):
                     reason="pipeline scaling needs >= 4 schedulable cores")
 def test_pipelined_parse_scales_with_cores(tmp_path):
     """The ISSUE 1 acceptance lane: the multi-chunk in-flight pipeline
-    (threaded=True, the bench's thread_scaling path) must deliver >=2x
+    (threaded=True) must deliver >=2x
     rows/s at 4 workers vs 1 on a host with cores to spare."""
     rng = np.random.default_rng(12)
     path = tmp_path / "scale.libsvm"

@@ -183,9 +183,9 @@ def test_simd_equals_scalar_block_boundaries(tmp_path):
 
 
 def test_simd_lane_reported(tmp_path):
-    """The chosen lane rides dct_parser_pipeline_stats into Python (and
-    bench.py extras); unset env means best-supported, which on any
-    little-endian host is at least the SWAR tier."""
+    """The chosen lane rides dct_parser_pipeline_stats into Python; unset
+    env means best-supported, which on any little-endian host is at least
+    the SWAR tier."""
     path = tmp_path / "t.libsvm"
     path.write_bytes(b"1 0:1 1:2\n" * 500)
     with NativeParser(str(path), nthread=1) as p:

@@ -16,9 +16,7 @@ Pins the tentpole properties end to end against real sockets:
 - the tracker's scrape surface gained the same hardening (431 for
   oversized heads, 405 for sniffed non-GET methods) when the HTTP
   plumbing was extracted into ``tracker/minihttp.py``;
-- the loadrig POST plane and the benchdiff ``serving_lane`` ledger
-  schema carry the new measurements (``sustained_qps`` good-leaf,
-  ``open_loop_p99_ms`` lower-is-better leaf).
+- the loadrig POST plane drives ``/score`` open-loop.
 """
 
 import json
@@ -45,7 +43,6 @@ SCRIPTS = os.path.join(REPO, "scripts")
 if SCRIPTS not in sys.path:
     sys.path.insert(0, SCRIPTS)
 
-import benchdiff  # noqa: E402
 import loadrig  # noqa: E402
 
 
@@ -575,50 +572,6 @@ def test_open_loop_post_against_live_server(tmp_path):
         assert all(s == 200 for s in statuses)
         assert out["intended_us"]["p99"] >= out["service_us"]["p99"] \
             or out["intended_us"]["p99"] > 0
-
-
-# ---------------------------------------------------------------------------
-# benchdiff serving_lane ledger schema
-# ---------------------------------------------------------------------------
-def _serving_record(sustained, p99, sha):
-    result = {"metric": "rows_per_sec", "value": 1000.0, "unit": "rps",
-              "extras": {"serving_lane": {
-                  "sustained_qps": sustained,
-                  "open_loop_qps": sustained * 0.7,
-                  "open_loop_p50_ms": p99 / 4.0,
-                  "open_loop_p99_ms": p99,
-                  "errors": 0,
-                  "note": "strings are dropped from the ledger",
-              }}}
-    return benchdiff.make_record(result, git_sha=sha, git_dirty=False,
-                                 round_no=1, ts=1.0)
-
-
-def test_serving_lane_ledger_schema():
-    rec = _serving_record(500.0, 20.0, "aaa")
-    lane = rec["lanes"]["serving_lane"]
-    assert lane["sustained_qps"] == 500.0
-    assert lane["open_loop_p99_ms"] == 20.0
-    assert "note" not in lane, "non-numeric leaves must not ride"
-    flat = benchdiff.flat_metrics(rec)
-    assert flat["serving_lane.sustained_qps"] == 500.0
-    assert flat["serving_lane.open_loop_p99_ms"] == 20.0
-    assert "sustained_qps" in benchdiff.GOOD_LEAVES
-    assert "open_loop_p99_ms" in benchdiff.LOW_LEAVES
-
-
-def test_serving_lane_compare_direction(capsys):
-    """p99 DOUBLING is a regression (lower-is-better inversion); qps
-    halving is a regression; both improving is zero regressions."""
-    base = _serving_record(500.0, 20.0, "aaa")
-    worse_p99 = _serving_record(500.0, 60.0, "bbb")
-    worse_qps = _serving_record(200.0, 20.0, "ccc")
-    better = _serving_record(800.0, 10.0, "ddd")
-    assert benchdiff.compare(base, worse_p99, 0.1, []) == 1
-    assert benchdiff.compare(base, worse_qps, 0.1, []) == 1
-    assert benchdiff.compare(base, better, 0.1, []) == 0
-    out = capsys.readouterr().out
-    assert "serving_lane.open_loop_p99_ms" in out
 
 
 # ---------------------------------------------------------------------------

@@ -1,4 +1,4 @@
-"""Zero-copy ingest lane (doc/benchmarking.md "Zero-copy ingest").
+"""Zero-copy ingest lane (doc/observability.md "Zero-copy ingest").
 
 Pins the contracts the zero-copy cache-replay->device path rests on:
 
