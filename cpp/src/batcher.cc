@@ -150,7 +150,7 @@ void PaddedBatcher::FillRowArrays(float* label, float* weight,
 }
 
 template <typename CopyVals, typename PadVals>
-void PaddedBatcher::FillShardNnz(uint32_t d, int32_t* rowd, int32_t* cold,
+uint64_t PaddedBatcher::FillShardNnz(uint32_t d, int32_t* rowd, int32_t* cold,
                                  int32_t* fieldd, CopyVals&& copy_vals,
                                  PadVals&& pad_vals) {
   const uint64_t R = batch_rows_ / num_shards_;
@@ -197,6 +197,7 @@ void PaddedBatcher::FillShardNnz(uint32_t d, int32_t* rowd, int32_t* cold,
   if (fieldd != nullptr) {
     std::memset(fieldd + written, 0, (bucket_ - written) * sizeof(int32_t));
   }
+  return written;
 }
 
 void PaddedBatcher::FillCSR(int32_t* row, int32_t* col, float* val,
@@ -294,6 +295,7 @@ void PaddedBatcher::FillPacked(int32_t* big, int32_t kb, void* val,
       << "bf16 packed fill needs a separate val buffer";
   telemetry::TraceSpan trace("batch.fill");
   trace.set_arg(take_);
+  std::vector<uint64_t> written(num_shards_);
   for (uint32_t d = 0; d < num_shards_; ++d) {
     int32_t* based = big + static_cast<uint64_t>(d) * kb * bucket_;
     int32_t* rowd = based;
@@ -303,7 +305,7 @@ void PaddedBatcher::FillPacked(int32_t* big, int32_t kb, void* val,
                     : nullptr;
     if (val_dtype == 0) {
       float* vald = reinterpret_cast<float*>(based + 2 * bucket_);
-      FillShardNnz(
+      written[d] = FillShardNnz(
           d, rowd, cold, fieldd,
           [&](const Block& b, uint64_t p0, uint64_t w, uint64_t n) {
             if (b.value_dtype == 0 && !b.value.empty()) {
@@ -320,7 +322,7 @@ void PaddedBatcher::FillPacked(int32_t* big, int32_t kb, void* val,
     } else {
       uint16_t* vald =
           static_cast<uint16_t*>(val) + static_cast<uint64_t>(d) * bucket_;
-      FillShardNnz(
+      written[d] = FillShardNnz(
           d, rowd, cold, fieldd,
           [&](const Block& b, uint64_t p0, uint64_t w, uint64_t n) {
             if (b.value_dtype == 0 && !b.value.empty()) {
@@ -341,6 +343,10 @@ void PaddedBatcher::FillPacked(int32_t* big, int32_t kb, void* val,
           });
     }
   }
+  // the col planes become the slot planes (col_slots.h); the padded
+  // entries' zeros already read slot 0
+  slots_.Run(big + bucket_, static_cast<uint64_t>(kb) * bucket_,
+             written.data(), num_shards_);
   FillRowWisePacked(aux, ka, nrows);
   Consume();
 }

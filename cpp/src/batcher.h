@@ -33,6 +33,7 @@
 #include <memory>
 #include <vector>
 
+#include "col_slots.h"
 #include "parser.h"
 
 namespace dct {
@@ -72,8 +73,10 @@ class PaddedBatcher {
 
   // Fused packed-batch fill: ONE pass writes the shard-major transfer
   // packs the device lane ships as-is, so Python never touches a plane.
-  //   big [D, kb, bucket] int32  per shard: row, col, [val f32 bits when
-  //                              val_dtype==0], [field]
+  //   big [D, kb, bucket] int32  per shard: row, slot, [val f32 bits when
+  //                              val_dtype==0], [field]; an entry's column
+  //                              is cols[slot] of the shard's distinct
+  //                              list, which FillCols writes (col_slots.h)
   //   val [D, bucket] uint16     bf16 values, only when val_dtype==1 (the
   //                              separate leaf keeps the pack int32-pure)
   //   aux [D, ka, R] int32       per shard: label bits, weight bits,
@@ -86,6 +89,15 @@ class PaddedBatcher {
   // downstream device_put zero-copy (device_iter.py `_device_put`).
   void FillPacked(int32_t* big, int32_t kb, void* val, int32_t val_dtype,
                   int32_t* aux, int32_t ka, int32_t* nrows);
+  // The distinct-column lists of the batch FillPacked last wrote: their
+  // capacity (the ladder rung of the fullest shard's count, same floor as
+  // the nnz bucket), the batch's count of distinct columns, and the
+  // [D, capacity] lists themselves, padded as col_slots.h says.
+  uint64_t ColsCapacity() const { return slots_.Capacity(min_bucket_); }
+  uint64_t ColsDistinct() const { return slots_.Distinct(); }
+  void FillCols(int32_t* cols, uint64_t cap) const {
+    slots_.Write(cols, cap);
+  }
   // Dense twin: x as FillDense, label/weight/qid/nrows fused into the
   // shard-major aux pack.
   void FillDensePacked(void* x, int x_dtype, uint64_t num_features,
@@ -122,8 +134,9 @@ class PaddedBatcher {
   // value store abstracted out: copy_vals(block, p0, written, n) writes n
   // normalized values, pad_vals(written) zeroes [written, bucket_). Shared
   // by FillCSR (f32 planes) and FillPacked (f32-in-big or separate bf16).
+  // Returns the shard's count of real entries.
   template <typename CopyVals, typename PadVals>
-  void FillShardNnz(uint32_t d, int32_t* rowd, int32_t* cold,
+  uint64_t FillShardNnz(uint32_t d, int32_t* rowd, int32_t* cold,
                     int32_t* fieldd, CopyVals&& copy_vals,
                     PadVals&& pad_vals);
   // Shard-major row-wise planes of the packed layout: label/weight bits,
@@ -163,6 +176,7 @@ class PaddedBatcher {
   uint64_t bucket_ = 0;
   uint64_t batch_nnz_ = 0;
   bool staged_ = false;
+  ColSlots slots_;  // of the batch FillPacked last wrote
 };
 
 }  // namespace dct

@@ -735,6 +735,38 @@ int dct_batcher_batch_nnz(dct_batcher_t h, uint64_t* out) {
       [&] { *out = static_cast<dct::PaddedBatcher*>(h)->BatchNnz(); });
 }
 
+// The distinct-column lists of the batch fill_packed last wrote
+// (col_slots.h): capacity and count first, then the [D, cap] lists.
+int dct_batcher_cols_meta(dct_batcher_t h, uint64_t* cap,
+                          uint64_t* distinct) {
+  return Guard([&] {
+    auto* b = static_cast<dct::PaddedBatcher*>(h);
+    *cap = b->ColsCapacity();
+    *distinct = b->ColsDistinct();
+  });
+}
+
+int dct_batcher_fill_cols(dct_batcher_t h, int32_t* cols, uint64_t cap) {
+  return Guard(
+      [&] { static_cast<dct::PaddedBatcher*>(h)->FillCols(cols, cap); });
+}
+
+// The dedupe both batchers run (col_slots.h), exported so a test can hold
+// the Python statement of it equal: col is [D, stride] with n[d] real
+// entries in shard d and becomes the slot plane; cols takes the [D, *cap]
+// lists and must hold D * NnzBucket(max n, floor) entries.
+int dct_col_slots(int32_t* col, const uint64_t* n, uint32_t num_shards,
+                  uint64_t stride, uint64_t floor, int32_t* cols,
+                  uint64_t* cap, uint64_t* distinct) {
+  return Guard([&] {
+    dct::ColSlots slots;
+    slots.Run(col, stride, n, num_shards);
+    *cap = slots.Capacity(floor);
+    *distinct = slots.Distinct();
+    slots.Write(cols, *cap);
+  });
+}
+
 int dct_batcher_free(dct_batcher_t h) {
   return Guard([&] { delete static_cast<dct::PaddedBatcher*>(h); });
 }
@@ -864,6 +896,19 @@ int dct_csrrec_bytes_read(dct_csrrec_t h, size_t* out) {
 int dct_csrrec_batch_nnz(dct_csrrec_t h, uint64_t* out) {
   return Guard(
       [&] { *out = static_cast<dct::CsrRecBatcher*>(h)->BatchNnz(); });
+}
+
+int dct_csrrec_cols_meta(dct_csrrec_t h, uint64_t* cap, uint64_t* distinct) {
+  return Guard([&] {
+    auto* b = static_cast<dct::CsrRecBatcher*>(h);
+    *cap = b->ColsCapacity();
+    *distinct = b->ColsDistinct();
+  });
+}
+
+int dct_csrrec_fill_cols(dct_csrrec_t h, int32_t* cols, uint64_t cap) {
+  return Guard(
+      [&] { static_cast<dct::CsrRecBatcher*>(h)->FillCols(cols, cap); });
 }
 
 int dct_csrrec_free(dct_csrrec_t h) {

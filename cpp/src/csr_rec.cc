@@ -192,7 +192,12 @@ uint64_t CsrRecBatcher::FillPacked(int32_t* big, int32_t kb, int32_t* aux,
   t.qid = has_qid_ == 1 ? aux + 2 * R : nullptr;
   t.nrows_plane = aux + static_cast<uint64_t>(ka - 1) * R;
   t.row_stride = static_cast<uint64_t>(ka) * R;
-  return FillImpl(t, nrows);
+  const uint64_t filled = FillImpl(t, nrows);
+  if (filled == 0) return 0;
+  // the col planes become the slot planes (col_slots.h); the padded
+  // entries' zeros already read slot 0
+  slots_.Run(t.col, t.nnz_stride, shard_nnz_.data(), num_shards_);
+  return filled;
 }
 
 uint64_t CsrRecBatcher::FillImpl(const Targets& t, int32_t* nrows) {
@@ -201,6 +206,7 @@ uint64_t CsrRecBatcher::FillImpl(const Targets& t, int32_t* nrows) {
   uint64_t filled = 0;                   // rows placed into this batch
   uint64_t shard_written = 0;            // nnz in the current shard's plane
   batch_nnz_ = 0;
+  shard_nnz_.assign(num_shards_, 0);
   while (filled < batch_rows_) {
     if (!have_record_ || row_in_rec_ >= rec_rows_) {
       if (eof_ || !AdvanceRecord()) break;
@@ -261,6 +267,7 @@ uint64_t CsrRecBatcher::FillImpl(const Targets& t, int32_t* nrows) {
       }
     }
     shard_written += span_nnz;
+    shard_nnz_[d] = shard_written;
     batch_nnz_ += span_nnz;
     nnz_in_rec_ += span_nnz;
     row_in_rec_ += n;

@@ -32,7 +32,9 @@
 #include <cstdint>
 #include <memory>
 #include <string>
+#include <vector>
 
+#include "col_slots.h"
 #include "input_split.h"
 
 namespace dct {
@@ -60,12 +62,21 @@ class CsrRecBatcher {
 
   // Fused shard-major fill (PaddedBatcher::FillPacked layout, f32 values
   // in-pack since the record stores f32): big is [num_shards, kb, bucket]
-  // int32 (row, col, val bits, [field]), aux is [num_shards, ka, R] int32
-  // (label bits, weight bits, [qid], nrows plane). kb must be
+  // int32 (row, slot, val bits, [field]; an entry's column is cols[slot]
+  // of the shard's distinct list: col_slots.h), aux is [num_shards, ka, R]
+  // int32 (label bits, weight bits, [qid], nrows plane). kb must be
   // 3 + has_field, ka must be 3 + has_qid. Returns the true row count;
   // 0 at end.
   uint64_t FillPacked(int32_t* big, int32_t kb, int32_t* aux, int32_t ka,
                       int32_t* nrows);
+  // The distinct-column lists of the batch FillPacked last wrote, as
+  // PaddedBatcher has them: capacity, count, the [num_shards, capacity]
+  // lists.
+  uint64_t ColsCapacity() const { return slots_.Capacity(min_bucket_); }
+  uint64_t ColsDistinct() const { return slots_.Distinct(); }
+  void FillCols(int32_t* cols, uint64_t cap) const {
+    slots_.Write(cols, cap);
+  }
 
   void BeforeFirst();
   size_t BytesRead() const { return bytes_read_; }
@@ -123,6 +134,8 @@ class CsrRecBatcher {
   int has_field_ = -1;
   uint64_t bucket_ = 0;
   uint64_t batch_nnz_ = 0;
+  std::vector<uint64_t> shard_nnz_;  // real entries of each shard, last fill
+  ColSlots slots_;                   // of the batch FillPacked last wrote
 
   bool have_record_ = false;
   bool eof_ = false;

@@ -214,6 +214,12 @@ def _declare_signatures(cdll: ctypes.CDLL) -> None:
         "dct_batcher_set_epoch": (i, [vp, u, c.POINTER(c.c_int32)]),
         "dct_batcher_bytes_read": (i, [vp, c.POINTER(sz)]),
         "dct_batcher_batch_nnz": (i, [vp, c.POINTER(c.c_uint64)]),
+        "dct_batcher_cols_meta": (i, [vp, c.POINTER(c.c_uint64),
+                                      c.POINTER(c.c_uint64)]),
+        "dct_batcher_fill_cols": (i, [vp, vp, c.c_uint64]),
+        "dct_col_slots": (i, [vp, vp, c.c_uint32, c.c_uint64, c.c_uint64,
+                              vp, c.POINTER(c.c_uint64),
+                              c.POINTER(c.c_uint64)]),
         "dct_batcher_free": (i, [vp]),
         "dct_denserec_create": (i, [c.c_char_p, u, u, c.c_uint64,
                                     c.c_uint32, c.POINTER(vp)]),
@@ -242,6 +248,9 @@ def _declare_signatures(cdll: ctypes.CDLL) -> None:
         "dct_csrrec_set_epoch": (i, [vp, u, c.POINTER(c.c_int32)]),
         "dct_csrrec_bytes_read": (i, [vp, c.POINTER(sz)]),
         "dct_csrrec_batch_nnz": (i, [vp, c.POINTER(c.c_uint64)]),
+        "dct_csrrec_cols_meta": (i, [vp, c.POINTER(c.c_uint64),
+                                     c.POINTER(c.c_uint64)]),
+        "dct_csrrec_fill_cols": (i, [vp, vp, c.c_uint64]),
         "dct_csrrec_free": (i, [vp]),
         "dct_bf16_convert": (i, [vp, vp, c.c_uint64]),
         "dct_bf16_upcast": (i, [vp, vp, c.c_uint64]),
@@ -1005,6 +1014,39 @@ def native_nnz_bucket(n: int, floor: int) -> int:
     return out.value
 
 
+def native_col_slots(col: np.ndarray, n, floor: int):
+    """The native statement of the dedupe (cpp/src/col_slots.h; the Python
+    one is dmlc_core_tpu.tpu.device_iter.col_slots): ``col`` [D, NNZ] int32
+    with ``n[d]`` real entries in shard d becomes the slot plane in place;
+    returns (cols [D, U], distinct count)."""
+    D, stride = col.shape
+    n = np.ascontiguousarray(n, np.uint64)
+    worst = native_nnz_bucket(int(n.max(initial=0)), floor)
+    cols = np.empty(D * worst, np.int32)
+    cap = ctypes.c_uint64()
+    distinct = ctypes.c_uint64()
+    _check(lib().dct_col_slots(
+        NativeBatcher._ptr(col, np.int32, D * stride),
+        ctypes.c_void_p(n.ctypes.data), D, stride, floor,
+        ctypes.c_void_p(cols.ctypes.data), ctypes.byref(cap),
+        ctypes.byref(distinct)))
+    return cols[:D * cap.value].reshape(D, cap.value), distinct.value
+
+
+# the two native batchers' distinct-column lists (cpp/src/col_slots.h):
+# one pair of calls behind both classes' cols_meta() / fill_cols()
+def _cols_meta(fn, handle):
+    cap = ctypes.c_uint64()
+    distinct = ctypes.c_uint64()
+    _check(fn(handle, ctypes.byref(cap), ctypes.byref(distinct)))
+    return cap.value, distinct.value
+
+
+def _fill_cols(fn, handle, cols: np.ndarray, num_shards: int) -> None:
+    U = cols.shape[1]
+    _check(fn(handle, NativeBatcher._ptr(cols, np.int32, num_shards * U), U))
+
+
 class NativeBatcher:
     """Static-shape padded-batch assembly in C++ (cpp/src/batcher.h).
 
@@ -1080,7 +1122,8 @@ class NativeBatcher:
                     nrows: np.ndarray,
                     val: Optional[np.ndarray] = None) -> None:
         """Fused shard-major fill (batcher.h FillPacked): ``big`` is
-        [D, kb, bucket] int32 (row, col, [val f32 bits], [field]), ``aux``
+        [D, kb, bucket] int32 (row, slot, [val f32 bits], [field]; the
+        shard's distinct columns follow by cols_meta()/fill_cols()), ``aux``
         is [D, ka, R] int32 (label bits, weight bits, [qid], nrows plane).
         Passing a separate bfloat16 ``val`` plane [D, bucket] converts
         values natively and drops big's f32 val plane. One GIL-free pass
@@ -1099,6 +1142,16 @@ class NativeBatcher:
             0 if val is None else 1,
             self._ptr(aux, np.int32, D * ka * R), ka,
             self._ptr(nrows, np.int32, D)))
+
+    def cols_meta(self):
+        """(capacity U, distinct count) of the distinct-column lists of the
+        batch fill_packed last wrote (cpp/src/col_slots.h)."""
+        return _cols_meta(lib().dct_batcher_cols_meta, self._h)
+
+    def fill_cols(self, cols: np.ndarray) -> None:
+        """Write those lists into ``cols`` [D, U] int32."""
+        _fill_cols(lib().dct_batcher_fill_cols, self._h, cols,
+                   self._num_shards)
 
     def fill_dense_packed(self, x: np.ndarray, aux: np.ndarray,
                           nrows: np.ndarray) -> None:
@@ -1249,7 +1302,8 @@ class NativeCsrRecBatcher:
     def fill_packed(self, big: np.ndarray, aux: np.ndarray,
                     nrows: np.ndarray) -> int:
         """Fused shard-major fill (csr_rec.h FillPacked): big is
-        [D, kb, bucket] int32 (row, col, val f32 bits, [field]), aux is
+        [D, kb, bucket] int32 (row, slot, val f32 bits, [field]; the
+        distinct columns follow by cols_meta()/fill_cols()), aux is
         [D, ka, R] int32 (label bits, weight bits, [qid], nrows plane).
         Returns the true row count (0 = end)."""
         if self._bucket == 0:
@@ -1282,6 +1336,16 @@ class NativeCsrRecBatcher:
         out = ctypes.c_size_t()
         _check(lib().dct_csrrec_bytes_read(self._h, ctypes.byref(out)))
         return out.value
+
+    def cols_meta(self):
+        """(capacity U, distinct count) of the distinct-column lists of the
+        batch fill_packed last wrote (cpp/src/col_slots.h)."""
+        return _cols_meta(lib().dct_csrrec_cols_meta, self._h)
+
+    def fill_cols(self, cols: np.ndarray) -> None:
+        """Write those lists into ``cols`` [D, U] int32."""
+        _fill_cols(lib().dct_csrrec_fill_cols, self._h, cols,
+                   self._num_shards)
 
     def batch_nnz(self) -> int:
         """Real nonzeros of the batch the last fill wrote, all shards

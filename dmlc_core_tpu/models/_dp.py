@@ -2,7 +2,7 @@
 
 LinearLearner and FMLearner differ only in their parameter pytrees, margin
 computation, and SGD update; everything about running a step over a device
-batch is identical — unpack the packed two-leaf batch per shard, take
+batch is identical — unpack the packed batch per shard, take
 value_and_grad of the shard loss, apply the update, and jit-cache per batch
 shape. That harness lives here once.
 
@@ -18,7 +18,8 @@ the batch and the mesh show (``_takes_row_form``):
   hooks): the model gathers the rows its shard reads, value_and_grad is
   taken of the same ``_shard_loss`` with respect to those rows, and
   ``_apply_rows`` scatter-adds the gradient's rows into the tables at the
-  shard's ``col``: the gradient as (indices, rows). No table of the
+  shard's distinct columns ``cols``: the gradient as (indices, rows), one
+  row a feature however often the shard names it. No table of the
   parameters' shape is made beside the parameters in and out, and nothing
   is reduced: there is one shard. (Across devices the row form would
   all_gather every shard's rows and scatter all of them on every device;
@@ -37,7 +38,7 @@ Subclasses implement:
 and may implement, for CSR shards (both or neither):
   _gather_rows(params, shard) -> rows, a pytree that ``_shard_loss`` takes
       in the place of ``params``
-  _apply_rows(params, col, row_grads, denom) -> new params
+  _apply_rows(params, cols, row_grads, denom) -> new params
 """
 
 from __future__ import annotations
@@ -75,10 +76,11 @@ class DataParallelModel:
 
     def _takes_row_form(self, keys) -> bool:
         """Whether a batch tree of these leaves steps in the row form: the
-        model has the hooks, the batch is CSR (packed ``big`` or named
-        ``col``, and no dense ``x``) and there is one device."""
+        model has the hooks, the batch is CSR (the distinct columns
+        ``cols`` travel with it, and no dense ``x``) and there is one
+        device."""
         return (self._gather_rows is not None
-                and ("big" in keys or "col" in keys) and "x" not in keys
+                and "cols" in keys and "x" not in keys
                 and (self.mesh is None or self.mesh.devices.size == 1))
 
     def _build_step(self, rows_per_shard: int, keys: tuple):
@@ -118,7 +120,7 @@ class DataParallelModel:
                     rows = self._gather_rows(params, shard)
                 loss_sum, wsum, row_grads = local_grads(rows, shard)
                 return apply(lambda denom: self._apply_rows(
-                    params, shard["col"], row_grads, denom), loss_sum, wsum)
+                    params, shard["cols"], row_grads, denom), loss_sum, wsum)
             return jax.jit(sharded_step)
 
         if self.mesh is None:

@@ -10,14 +10,18 @@ data-parallel under ``shard_map`` with one psum per step on a mesh of
 several devices. On one device a CSR step keeps the gradient in the rows
 the batch gathered and scatter-adds it straight into ``w`` and ``v``
 (the row form of models/_dp.py): no ``[F, K]`` gradient table is made.
+A feature is gathered and scattered once a batch: the rows are those at
+the shard's DISTINCT columns (``cols``, which every assembler sends:
+tpu/device_iter.py col_slots), expanded to the entries by ``slot``.
 
 Margin (Rendle's O(NNZ·K) identity):
 
     y(x) = b + Σ_i w_i x_i + ½ Σ_f [ (Σ_i V_{i,f} x_i)² − Σ_i V_{i,f}² x_i² ]
 
-CSR shards compute the two inner sums with one gather ``V[col]`` and two
-segment-sums over the row ids — the same segment-op layout the sparse ops
-use (ops/sparse.py); padding nonzeros (val 0, sacrificial row id) vanish.
+CSR shards compute the two inner sums with one gather ``V[cols]``, its
+expansion by ``slot`` and two segment-sums over the row ids — the same
+segment-op layout the sparse ops use (ops/sparse.py); padding nonzeros
+(val 0, sacrificial row id) vanish.
 Dense batches compute them as ``(x @ V)² − x² @ V²`` — pure MXU work.
 """
 
@@ -30,6 +34,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from dmlc_core_tpu.base import DMLCError
 from dmlc_core_tpu.models._dp import DataParallelModel
 from dmlc_core_tpu.models.linear import objective_loss
 from dmlc_core_tpu.tpu.device_iter import unpack_tree
@@ -44,42 +49,67 @@ class FMParams(NamedTuple):
 
 
 class FMRows(NamedTuple):
-    """What a CSR shard reads of the parameters: the rows at its ``col``
-    (a feature that recurs in the shard recurs here)."""
+    """What a CSR shard reads of the parameters: the rows at its distinct
+    columns ``cols`` (a feature that recurs in the shard is here once)."""
     b: jnp.ndarray   # []
-    w: jnp.ndarray   # [NNZ]
-    v: jnp.ndarray   # [NNZ, K]
+    w: jnp.ndarray   # [U]
+    v: jnp.ndarray   # [U, K]
 
 
 # named scopes: op_name metadata only. In the table form the backward ops
 # read .../transpose(jvp(fm.gather))/..., which is how a trace tells the
 # scatter into the dense gradient from the forward gather; in the row form
 # the gathers are outside the differentiated function and have no backward
-def _fm_gather(params: FMParams, col) -> FMRows:
+def _fm_gather(params: FMParams, cols) -> FMRows:
+    """The rows at ``cols``: ascending to the list's end, its padding
+    beyond the tables (col_slots), where the gather reads zeros and its
+    transpose, the table form's scatter, drops."""
+    def at(table):
+        return table.at[cols].get(mode="fill", fill_value=0,
+                                  indices_are_sorted=True)
     with jax.named_scope("fm.linear"):
-        w_rows = jnp.take(params.w, col, axis=0)
+        w_rows = at(params.w)
     with jax.named_scope("fm.gather"):
-        v_rows = params.v[col]
+        v_rows = at(params.v)
     return FMRows(params.b, w_rows, v_rows)
 
 
-def _fm_margin_rows(rows: FMRows, row, val, num_rows: int) -> jnp.ndarray:
+def _fm_margin_entries(b, w, v, row, val, num_rows: int) -> jnp.ndarray:
+    """The margin from each entry's own row of the parameters ([NNZ],
+    [NNZ, K])."""
     seg = functools.partial(jax.ops.segment_sum,
                             num_segments=num_rows + 1,
                             indices_are_sorted=True)
     with jax.named_scope("fm.linear"):
-        linear = seg(val * rows.w, row)[:num_rows]
+        linear = seg(val * w, row)[:num_rows]
     with jax.named_scope("fm.interaction"):
-        vx = rows.v * val[:, None]                 # [NNZ, K]
+        vx = v * val[:, None]                      # [NNZ, K]
         s1 = seg(vx, row)[:num_rows]               # Σ V x   per row  [R, K]
         s2 = seg(vx * vx, row)[:num_rows]          # Σ V²x²  per row  [R, K]
         inter = 0.5 * jnp.sum(s1 * s1 - s2, axis=-1)
-    return rows.b + linear + inter
+    return b + linear + inter
+
+
+def _fm_margin_rows(rows: FMRows, slot, row, val, num_rows: int
+                    ) -> jnp.ndarray:
+    # the expansion reads a [U, K] intermediate, not the tables; its
+    # transpose sums an entry's gradient into its column's row, at the
+    # gradient's magnitude: the merge of a feature's repeats
+    with jax.named_scope("fm.expand"):
+        w = rows.w.at[slot].get(mode="promise_in_bounds")
+        v = rows.v.at[slot].get(mode="promise_in_bounds")
+    return _fm_margin_entries(rows.b, w, v, row, val, num_rows)
 
 
 def _fm_margin_csr(params: FMParams, row, col, val, num_rows: int
                    ) -> jnp.ndarray:
-    return _fm_margin_rows(_fm_gather(params, col), row, val, num_rows)
+    """The margin from raw columns, every entry reading its own row: for
+    batches that carry no distinct list (the scoring server's)."""
+    with jax.named_scope("fm.linear"):
+        w = jnp.take(params.w, col, axis=0)
+    with jax.named_scope("fm.gather"):
+        v = params.v[col]
+    return _fm_margin_entries(params.b, w, v, row, val, num_rows)
 
 
 def _fm_margin_dense(params: FMParams, x) -> jnp.ndarray:
@@ -96,9 +126,15 @@ def _margin(params, shard, num_rows: int) -> jnp.ndarray:
     """``params``: FMParams, or the FMRows a CSR shard gathered from them."""
     if "x" in shard:
         return _fm_margin_dense(params, shard["x"])
+    if "cols" not in shard:
+        raise DMLCError(
+            "FMLearner reads a CSR batch by its distinct columns, which "
+            f"every assembler sends; this one holds {sorted(shard)} and no "
+            "'cols' / 'slot' (tpu/device_iter.py col_slots makes them)")
     rows = params if isinstance(params, FMRows) else \
-        _fm_gather(params, shard["col"])
-    return _fm_margin_rows(rows, shard["row"], shard["val"], num_rows)
+        _fm_gather(params, shard["cols"])
+    return _fm_margin_rows(rows, shard["slot"], shard["row"], shard["val"],
+                           num_rows)
 
 
 def _fm_shard_loss(params, shard, num_rows: int, objective: str
@@ -162,25 +198,26 @@ class FMLearner(DataParallelModel):
             v=params.v - lr * (grads.v / denom + l2 * params.v))
 
     def _gather_rows(self, params, shard):
-        return _fm_gather(params, shard["col"])
+        return _fm_gather(params, shard["cols"])
 
-    def _apply_rows(self, params, col, g, denom):
-        """``_apply`` with the gradient as FMRows at ``col``: a row's
-        gradient is the sum of the entries that name it, which the
-        scatter-add takes (in whatever order: ``col`` is neither sorted nor
-        unique); padded entries carry zeros. Weight decay still reaches
-        every row. Each entry is added into the parameter by itself, so a
-        feature that recurs n times in the batch takes n roundings at the
-        parameter's magnitude where ``_apply`` takes one (PERF.md section
-        6, PR 27 has what that costs against the reference)."""
+    def _apply_rows(self, params, cols, g, denom):
+        """``_apply`` with the gradient as FMRows at ``cols``: a row's
+        gradient is already the sum of the entries that name it (the
+        expansion's transpose), so the scatter-add lands one row a feature,
+        ascending and distinct, with one rounding at the parameter's
+        magnitude as ``_apply`` has; the list's padding lies beyond the
+        tables and is dropped (it repeats one id, so the scatter is told
+        its indices are sorted and no more; the hint of uniqueness bought
+        nothing on the chip: PERF.md section 6, PR 31). Weight decay still
+        reaches every row."""
         lr, l2 = self.learning_rate, self.l2
 
-        def decayed(table):
-            return table if l2 == 0 else (1.0 - lr * l2) * table
-        return FMParams(
-            b=params.b - lr * g.b / denom,
-            w=decayed(params.w).at[col].add(-lr * (g.w / denom)),
-            v=decayed(params.v).at[col].add(-lr * (g.v / denom)))
+        def update(table, grad):
+            table = table if l2 == 0 else (1.0 - lr * l2) * table
+            return table.at[cols].add(-lr * (grad / denom),
+                                      indices_are_sorted=True)
+        return FMParams(b=params.b - lr * g.b / denom,
+                        w=update(params.w, g.w), v=update(params.v, g.v))
 
     def predict(self, params: FMParams, batch) -> jnp.ndarray:
         """Margins [D, R] (apply sigmoid for probabilities)."""
@@ -194,12 +231,7 @@ class FMLearner(DataParallelModel):
             @jax.jit
             @jax.named_scope("fm.predict")
             def fwd(params, tree):
-                tree = unpack_tree(tree)
-                if "x" in tree:
-                    return jax.vmap(
-                        lambda x: _fm_margin_dense(params, x))(tree["x"])
-                return jax.vmap(
-                    lambda r, c, v: _fm_margin_csr(params, r, c, v, R))(
-                        tree["row"], tree["col"], tree["val"])
+                return jax.vmap(lambda shard: _margin(params, shard, R))(
+                    unpack_tree(tree))
             self._fwd_fn[R] = fwd
         return fwd(params, batch.tree())

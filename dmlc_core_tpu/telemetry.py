@@ -1085,6 +1085,16 @@ METRIC_HELP: Dict[str, str] = {
     "device_put_block_us": "dispatch-to-ready DMA wait (us)",
     "device_batches_total": "batches dispatched to the device",
     "device_transfer_bytes_total": "host bytes handed to device_put",
+    "device_nnz_sent_total":
+        "nnz entries of the CSR batches dispatched to the device, padding "
+        "included: shards x the batch's nnz bucket",
+    "device_nnz_real_total":
+        "of the entries sent, the real nonzeros, as the batcher's fill "
+        "counted them",
+    "device_cols_distinct_total":
+        "distinct columns of the CSR batches dispatched, summed over their "
+        "shards, as the batcher's dedupe counted them: the rows a step "
+        "gathers and scatters",
     "device_stage_us":
         "one host batch assembly (parse+pad+bucket+pack) on the staging "
         "thread (us)",

@@ -78,6 +78,7 @@ def _get_lane_metrics():
             "batches": telemetry.counter("device_batches_total"),
             "nnz_sent": telemetry.counter("device_nnz_sent_total"),
             "nnz_real": telemetry.counter("device_nnz_real_total"),
+            "cols_distinct": telemetry.counter("device_cols_distinct_total"),
             "bytes": telemetry.counter("device_transfer_bytes_total"),
             "failures": telemetry.counter("device_put_failures_total"),
             "host_q": telemetry.gauge("device_host_q_depth"),
@@ -178,7 +179,7 @@ def _dense_dtype_of(d) -> np.dtype:
 __all__ = ["PaddedBatch", "DenseBatch", "DeviceRowBlockIter", "HostBatcher",
            "NativeHostBatcher", "DenseRecHostBatcher", "CsrRecHostBatcher",
            "unpack_tree", "unpack_shard", "match_placement_rules",
-           "jax_profiler_capture", "nnz_bucket"]
+           "jax_profiler_capture", "nnz_bucket", "col_slots"]
 
 
 @dataclass
@@ -186,6 +187,11 @@ class PaddedBatch:
     """Static-shape CSR batch; named arrays lead with the device axis D.
 
     row/col/val: [D, NNZ]  per-nonzero segment id (local), column, value
+    cols: [D, U] int32     each shard's DISTINCT columns, ascending, padded
+                           to the ladder rung U of the fullest shard's count
+                           by an id beyond any table (``col_slots``)
+    slot: [D, NNZ] int32   per-nonzero position of its column in ``cols``:
+                           ``col == cols[slot]``; 0 on padding nonzeros
     label/weight: [D, R]   weight 0 marks padding rows
     nrows: [D]             true row count per shard
     qid: [D, R] int32      optional query/group ids (ranking); -1 on padding
@@ -196,13 +202,22 @@ class PaddedBatch:
     qid/field continue the reference RowBlock's optional columns
     (data.h:174-236) into the device layout.
 
+    A feature that recurs in a shard recurs in ``col`` and once in ``cols``:
+    a consumer that gathers and scatters parameter rows does so at ``cols``
+    and expands by ``slot`` (models/fm.py), one read and one update a feature
+    a batch. Every assembler emits both, always; ``col`` is then neither
+    kept nor sent (the field stays None): unpack_shard/unpack_tree give
+    ``col = cols[slot]`` in-jit to whoever still reads it, so there is one
+    truth. A hand-built batch of named leaves still carries ``col``.
+
     Packed transfer layout (native batchers): `big` [D, Kb, NNZ] int32
-    stacks row/col/val(f32 bits)[/field] per shard and `aux` [D, K, R]
-    int32 stacks label(f32 bits)/weight(f32 bits)[/qid]/nrows-plane per
-    shard, so a batch crosses host->HBM in TWO transfers instead of one
-    RPC per leaf — on high-latency links the per-transfer dispatch, not
-    bandwidth, bounds the binary formats. The packs are
-    SHARD-MAJOR (device axis leads): under a NamedSharding every shard's
+    stacks row/slot/val(f32 bits)[/field] per shard, `cols` [D, U] int32
+    travels beside it, and `aux` [D, K, R] int32 stacks label(f32 bits)/
+    weight(f32 bits)[/qid]/nrows-plane per shard, so a batch crosses
+    host->HBM in THREE transfers instead of one RPC per leaf — on
+    high-latency links the per-transfer dispatch, not bandwidth, bounds
+    the binary formats. The packs and `cols` are SHARD-MAJOR (device axis
+    leads): under a NamedSharding every shard's
     bytes are one contiguous leading-axis slice of the host buffer, which
     is what lets the zero-copy device_put path hand each device its slab
     without a host gather. With ``csr_val_dtype="bf16"`` values travel as
@@ -222,11 +237,17 @@ class PaddedBatch:
     # host-side true nonzero count, all shards, as the fill counted it:
     # against D * nnz_bucket it is the batch's fill share
     total_nnz: int = 0
+    # host-side count of distinct columns, summed over the shards, as the
+    # dedupe counted it: against total_nnz, the share of the gathers and
+    # scatters that is left
+    total_distinct: int = 0
     qid: Any = None
     field: Any = None
-    big: Any = None  # [D, Kb, NNZ] packed row/col[/val][/field]
+    big: Any = None  # [D, Kb, NNZ] packed row/slot[/val][/field]
     aux: Any = None  # [D, K, R] packed label/weight[/qid]/nrows
     val16: Any = None  # [D, NNZ] bfloat16 values (csr_val_dtype="bf16")
+    slot: Any = None
+    cols: Any = None
 
     @property
     def rows_per_shard(self) -> int:
@@ -242,6 +263,8 @@ class PaddedBatch:
         the packed leaves when packed, the named leaves otherwise."""
         if self.aux is not None:
             t = {"big": self.big, "aux": self.aux}
+            if self.cols is not None:  # always, from an assembler
+                t["cols"] = self.cols
             if self.val16 is not None:
                 t["val"] = self.val16
             return t
@@ -253,6 +276,15 @@ class PaddedBatch:
         if self.field is not None:
             t["field"] = self.field
         return t
+
+
+def _expand_cols(cols, slot):
+    """``col`` from the distinct list and the slot plane, along the last
+    axis (one shard or all of them; host numpy or in-jit)."""
+    if isinstance(cols, np.ndarray):
+        return np.take_along_axis(cols, slot, axis=-1)
+    return jnp.take_along_axis(cols, slot, axis=-1,
+                               mode="promise_in_bounds")
 
 
 @dataclass
@@ -298,9 +330,10 @@ class DenseBatch:
 # Shard-major packs (device axis LEADS): aux [D, K, R], big [D, Kb, NNZ].
 # Per-shard plane order, aux: 0=label (f32 bits), 1=weight (f32 bits),
 # [2=qid], last=nrows plane (entry [d, -1, 0] holds shard d's true row
-# count). big: 0=row, 1=col, [2=val (f32 bits) unless a separate bf16
-# `val` leaf travels], [last=field]. Both are int32 containers; float
-# planes travel as raw bits and are bitcast back on device (a
+# count). big: 0=row, 1=slot, [2=val (f32 bits) unless a separate bf16
+# `val` leaf travels], [last=field]; `cols` [D, U] beside it holds each
+# shard's distinct columns (col == cols[slot]). Both packs are int32
+# containers; float planes travel as raw bits and are bitcast back on device (a
 # dtype-preserving reinterpretation, not a cast). Shard-major means shard
 # d's bytes are the contiguous slice pack[d] — the layout the zero-copy
 # sharded device_put path requires.
@@ -330,19 +363,19 @@ def _view_aux(aux: np.ndarray):
 
 
 def _view_big(big: np.ndarray, has_val: bool = True):
-    """Named [D, NNZ] row/col[/val][/field] views over a shard-major
+    """Named [D, NNZ] row/slot[/val][/field] views over a shard-major
     [D, Kb, NNZ] pack (val viewed float32; pass has_val=False when values
     travel as a separate bf16 leaf and the pack carries no val plane)."""
     D, Kb, bucket = big.shape
     row = big[:, 0]
-    col = big[:, 1]
+    slot = big[:, 1]
     if has_val:
         val = big[:, 2].view(np.float32)
         field = big[:, 3] if Kb == 4 else None
     else:
         val = None
         field = big[:, 2] if Kb == 3 else None
-    return row, col, val, field
+    return row, slot, val, field
 
 
 def _finish_aux(aux, nrows) -> None:
@@ -382,8 +415,17 @@ def _unpack(tree: Dict[str, Any], sel, nrows_of) -> Dict[str, Any]:
     if "big" in tree:
         big = tree["big"]
         kb = big.shape[-2]
+        if "cols" not in tree:
+            raise DMLCError(
+                "a packed CSR batch holds each entry's slot where its column "
+                f"stood and needs the 'cols' leaf beside {sorted(tree)} "
+                "(col_slots makes both from a col plane)")
         out["row"] = sel(big, 0)
-        out["col"] = sel(big, 1)
+        out["slot"] = sel(big, 1)
+        out["cols"] = tree["cols"]
+        # for whoever still reads columns entry by entry (the linear
+        # learner, predict); a step that never asks compiles it away
+        out["col"] = _expand_cols(out["cols"], out["slot"])
         if "val" in tree:  # separate bf16 value leaf; pack has no val plane
             out["val"] = tree["val"]
             if kb == 3:
@@ -451,6 +493,35 @@ def nnz_bucket(n: int, floor: int) -> int:
     p = 1 << (n - 1).bit_length()
     g = max(p >> 4, min(floor, 128))
     return -(-n // g) * g
+
+
+def col_slots(col: np.ndarray, n, floor: int):
+    """The distinct columns of each CSR shard and every entry's slot among
+    them: the dedupe every assembler runs, stated here by ``np.unique`` as
+    the oracle and once natively (cpp/src/col_slots.h, whose header has the
+    why; tests/test_col_slots.py holds the two equal).
+
+    ``col`` [D, NNZ] int32 holds ``n[d]`` real entries in shard d and
+    becomes the slot plane in place (padded entries: slot 0). Returns
+    ``(cols, distinct)``: ``cols`` [D, U] int32, each shard's distinct
+    columns ascending, ``U = nnz_bucket(fullest shard's count, floor)``,
+    the tail padded by 2**31 - 1 repeated (beyond any table: a filling
+    gather reads zeros there, a scatter drops them, no slot names them,
+    and the list stays sorted to its end); a shard without entries lists
+    column 0 once, so slot 0 always names a real row. ``distinct`` is the
+    batch's count of distinct columns, summed over the shards."""
+    lists = []
+    for d, nd in enumerate(n):
+        uniq, inv = np.unique(col[d, :nd], return_inverse=True)
+        col[d, :nd] = inv
+        col[d, nd:] = 0
+        lists.append(uniq if nd else np.zeros(1, np.int32))
+    U = nnz_bucket(max(len(u) for u in lists), floor)
+    cols = _aligned_empty((len(lists), U), np.int32)
+    cols[:] = np.iinfo(np.int32).max
+    for d, uniq in enumerate(lists):
+        cols[d, :len(uniq)] = uniq
+    return cols, sum(len(u) for u, nd in zip(lists, n) if nd)
 
 
 # -- spec-driven placement ---------------------------------------------------
@@ -733,9 +804,9 @@ class HostBatcher:
         bucket = nnz_bucket(int(shard_nnz.max()) if take else 1,
                             self.min_nnz_bucket)
 
-        # assemble straight into the packed two-leaf layout (the same
-        # big/aux contract the native batchers emit, so index64 batches
-        # also cross host->HBM in two transfers); pooled packs are fully
+        # assemble straight into the packed layout (the same big/aux
+        # contract the native batchers emit, so index64 batches cross
+        # host->HBM in as few transfers); pooled packs are fully
         # rewritten below, so reuse needs no clearing beyond the fills
         Kb = 4 if self._emit_field else 3
         big = aux_buf = None
@@ -746,9 +817,8 @@ class HostBatcher:
                 big = None
         if big is None:
             big = _aligned_empty((D, Kb, bucket), np.int32)
-        row, colp, valp, fldp = _view_big(big)
+        row, slotp, valp, fldp = _view_big(big)
         row[:] = R  # R = padding segment
-        colp[:] = 0
         valp[:] = 0.0
         if fldp is not None:
             fldp[:] = 0
@@ -756,20 +826,22 @@ class HostBatcher:
             lo, hi = shard_starts[d], shard_starts[d + 1]
             n = hi - lo
             row[d, :n] = row_of[lo:hi] - d * R  # local row ids
-            colp[d, :n] = col[lo:hi]
+            slotp[d, :n] = col[lo:hi]
             valp[d, :n] = val[lo:hi]
             if fldp is not None:
                 fldp[d, :n] = fld[lo:hi]
+        # the columns become the distinct lists, the plane their slots
+        cols, distinct = col_slots(slotp, shard_nnz, self.min_nnz_bucket)
 
         nrows = np.minimum(
             np.maximum(take - np.arange(D) * R, 0), R).astype(np.int32)
         aux, label_v, weight_v, qid_v = _pack_aux(
             label, weight, qid, nrows, D, R, self._emit_qid, aux=aux_buf)
         return PaddedBatch(
-            row=row, col=colp, val=valp,
+            row=row, slot=slotp, cols=cols, val=valp,
             label=label_v, weight=weight_v,
             nrows=nrows, total_rows=int(take),
-            total_nnz=int(shard_starts[-1]),
+            total_nnz=int(shard_starts[-1]), total_distinct=distinct,
             qid=qid_v, field=fldp, big=big, aux=aux)
 
     def _emit_dense(self, take, label, weight, lens, col, val, qid):
@@ -814,6 +886,18 @@ class HostBatcher:
         """Pin the shuffle permutation the next reset() samples (mid-epoch
         resume). False when the underlying split chain does not shuffle."""
         return self.parser.set_epoch(epoch)
+
+
+def _native_cols(native, pool: _HostBufferPool, D: int):
+    """The distinct-column lists of the batch a native ``fill_packed`` just
+    wrote (its col plane now holds the slots), in a pooled [D, U] buffer;
+    returns (cols, distinct count)."""
+    U, distinct = native.cols_meta()
+    cols = pool.pop(("cols", U))
+    if cols is None:
+        cols = _aligned_empty((D, U), np.int32)
+    native.fill_cols(cols)
+    return cols, distinct
 
 
 class NativeHostBatcher:
@@ -929,13 +1013,15 @@ class NativeHostBatcher:
             aux = _aligned_empty((D, 4 if has_qid else 3, R), np.int32)
         # one fused native pass assembles the whole shard-major batch
         self._b.fill_packed(big, aux, nrows, val=val16 if sep_val else None)
-        row, col, val, field = _view_big(big, has_val=not sep_val)
+        cols, distinct = _native_cols(self._b, self._pool, D)
+        row, slot, val, field = _view_big(big, has_val=not sep_val)
         _, label, weight, qid = _view_aux(aux)
-        return PaddedBatch(row=row, col=col,
+        return PaddedBatch(row=row, slot=slot, cols=cols,
                            val=val16 if sep_val else val,
                            label=label, weight=weight,
                            nrows=nrows, total_rows=int(take),
                            total_nnz=self._b.batch_nnz(),
+                           total_distinct=distinct,
                            qid=qid, field=field, big=big, aux=aux,
                            val16=val16)
 
@@ -967,6 +1053,7 @@ class NativeHostBatcher:
         else:
             key = ("csr", batch.big.shape[-1])
             arrs = (batch.big, batch.val16, batch.aux, batch.nrows)
+            self._pool.put(("cols", batch.cols.shape[-1]), batch.cols)
         self._pool.put(key, arrs)
 
     def reset(self) -> None:
@@ -1020,6 +1107,7 @@ class CsrRecHostBatcher:
             return
         self._pool.put(("crec", batch.big.shape[-1]),
                        (batch.big, batch.aux, batch.nrows))
+        self._pool.put(("cols", batch.cols.shape[-1]), batch.cols)
 
     def next_batch(self) -> Optional[PaddedBatch]:
         """Next static-shape PaddedBatch of host numpy arrays (None at
@@ -1041,12 +1129,14 @@ class CsrRecHostBatcher:
         take = self._b.fill_packed(big, aux, nrows)
         if take == 0:
             return None
-        row, col, val, field = _view_big(big)
+        cols, distinct = _native_cols(self._b, self._pool, D)
+        row, slot, val, field = _view_big(big)
         _, label, weight, qid = _view_aux(aux)
-        return PaddedBatch(row=row, col=col, val=val,
+        return PaddedBatch(row=row, slot=slot, cols=cols, val=val,
                            label=label, weight=weight,
                            nrows=nrows, total_rows=int(take),
                            total_nnz=self._b.batch_nnz(),
+                           total_distinct=distinct,
                            qid=qid, field=field, big=big, aux=aux)
 
     def reset(self) -> None:
@@ -1498,7 +1588,9 @@ class DeviceRowBlockIter:
             plane = batch.big[:, 0] if batch.row is None else batch.row
             m["nnz_sent"].inc(plane.size)
             m["nnz_real"].inc(batch.total_nnz)
+            m["cols_distinct"].inc(batch.total_distinct)
             kwargs["total_nnz"] = batch.total_nnz
+            kwargs["total_distinct"] = batch.total_distinct
         if "val" in kwargs and "aux" in kwargs:
             # the packed tree's separate bf16 value leaf rides the val16
             # field so the device batch's tree() re-emits it

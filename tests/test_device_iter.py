@@ -10,7 +10,7 @@ import jax
 import jax.numpy as jnp
 
 from dmlc_core_tpu.tpu.device_iter import (DeviceRowBlockIter, HostBatcher,
-                                           nnz_bucket)
+                                           _expand_cols, nnz_bucket)
 from dmlc_core_tpu.tpu.sharding import data_mesh, process_part
 from dmlc_core_tpu.io.native import NativeParser
 from dmlc_core_tpu.models.linear import LinearLearner
@@ -45,7 +45,7 @@ def test_host_batcher_shapes_and_padding(tmp_path):
     assert len(batches) == 4
     for b in batches:
         assert b.label.shape == (4, 64)
-        assert b.row.shape == b.col.shape == b.val.shape
+        assert b.row.shape == b.slot.shape == b.val.shape
         assert b.row.shape[0] == 4
         # the bucket is the ladder's rung for the fullest shard
         fullest = int((b.row < 64).sum(axis=1).max())
@@ -94,7 +94,7 @@ def test_batch_reconstruction_exact(tmp_path):
             for r in range(int(b.nrows[d])):
                 mask = b.row[d] == r
                 got.append((float(b.label[d, r]),
-                            dict(zip(b.col[d][mask].tolist(),
+                            dict(zip(b.cols[d][b.slot[d][mask]].tolist(),
                                      np.round(b.val[d][mask], 4).tolist()))))
     assert len(got) == len(want)
     for (gl, gf), (wl, wf) in zip(got, want):
@@ -113,16 +113,19 @@ def test_device_iter_sharding(tmp_path):
         batches = list(it)
     assert len(batches) == 2
     b = batches[0]
-    # a batch crosses host->device as exactly TWO packed shard-major
-    # transfers whose LEADING device axis is sharded over the mesh (each
-    # shard's bytes are one contiguous slab — the zero-copy placement
-    # contract)
-    assert set(b.tree()) == {"big", "aux"}
+    # a batch crosses host->device as exactly THREE shard-major transfers
+    # (the two packs and the shards' distinct columns) whose LEADING device
+    # axis is sharded over the mesh (each shard's bytes are one contiguous
+    # slab — the zero-copy placement contract)
+    assert set(b.tree()) == {"big", "cols", "aux"}
     assert isinstance(b.big, jax.Array) and isinstance(b.aux, jax.Array)
     leading_data = jax.sharding.PartitionSpec("data")
     assert b.big.sharding.spec == leading_data
+    assert b.cols.sharding.spec == leading_data
     assert b.aux.sharding.spec == leading_data
     assert b.big.shape[0] == 8 and b.aux.shape[0] == 8
+    # 8 features: every shard lists all of them and pads to the floor
+    assert b.cols.shape == (8, 512)
     # unpack recovers the named planes bit-exactly vs the host staging
     from dmlc_core_tpu.tpu.device_iter import unpack_tree
     with DeviceRowBlockIter(str(p), batch_rows=1024, mesh=mesh,
@@ -131,7 +134,7 @@ def test_device_iter_sharding(tmp_path):
         hb = next(iter(hit))
     named = unpack_tree({k: np.asarray(v) for k, v in b.tree().items()})
     assert np.array_equal(named["row"], hb.row)
-    assert np.array_equal(named["col"], hb.col)
+    assert np.array_equal(named["col"], _expand_cols(hb.cols, hb.slot))
     assert np.array_equal(named["val"], hb.val)
     assert np.array_equal(named["label"], hb.label)
     assert np.array_equal(named["weight"], hb.weight)
@@ -291,7 +294,7 @@ def test_dense_matches_csr_reconstruction(tmp_path):
     want = np.zeros((D, R, F), np.float32)
     for d in range(D):
         np.add.at(want[d], (csr.row[d][csr.row[d] < R],
-                            csr.col[d][csr.row[d] < R]),
+                            csr.cols[d][csr.slot[d][csr.row[d] < R]]),
                   csr.val[d][csr.row[d] < R])
     np.testing.assert_allclose(dense.x, want, rtol=1e-6)
     np.testing.assert_array_equal(dense.label, csr.label)
@@ -349,7 +352,7 @@ def test_native_batcher_matches_python_csr(tmp_path):
     assert len(pb) == len(nb) == 4
     for a, b in zip(pb, nb):
         assert a.total_rows == b.total_rows
-        for k in ("row", "col", "val", "label", "weight", "nrows"):
+        for k in ("row", "slot", "cols", "val", "label", "weight", "nrows"):
             va, vb = getattr(a, k), getattr(b, k)
             assert va.shape == vb.shape, k
             np.testing.assert_array_equal(va, vb, err_msg=k)
@@ -431,7 +434,7 @@ def test_index64_path_emits_packed_batches(tmp_path):
                             min_nnz_bucket=512) as it:
         for _ in range(3):
             for b in it:
-                assert set(b.tree()) == {"big", "aux"}
+                assert set(b.tree()) == {"big", "cols", "aux"}
                 params, loss = learner.step(params, b)
                 losses.append(float(loss))
             it.before_first()

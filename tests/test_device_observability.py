@@ -31,6 +31,7 @@ from __future__ import annotations
 import json
 import os
 import random
+import re
 import time
 
 import numpy as np
@@ -262,11 +263,12 @@ def test_stall_verdict_transfer_bound_under_injected_stall(tmp_path,
 
 
 # -- compile-churn telemetry --------------------------------------------------
-def _bucket_of_key(key: str) -> int:
-    # key format: "aux(K, D, R),big(Kb, D, NNZ)" — the big leaf's last
-    # dim is the nnz bucket
-    big = key.split("big(")[1]
-    return int(big.rstrip(")").split(",")[-1])
+def _bucket_of_key(key: str, leaf: str = "big") -> int:
+    # key format: "aux(D, K, R),big(D, Kb, NNZ),cols(D, U)" — the big
+    # leaf's last dim is the nnz bucket, the cols leaf's the capacity of
+    # the distinct-column list
+    return int(re.search(leaf + r"\(([0-9, ]+)\)", key).group(1)
+               .split(",")[-1])
 
 
 @pytest.mark.parametrize("nfeats,want", [
@@ -312,6 +314,8 @@ def test_compile_churn_crosses_expected_buckets_and_replays_clean(
     assert _run_iter(str(path), batch_rows=64, min_nnz_bucket=16) == rows
     events, distinct = census()
     assert {_bucket_of_key(k) for k in events} == want
+    # at most 9 distinct columns a batch: the list stays on its floor
+    assert {_bucket_of_key(k, "cols") for k in events} == {16}
     assert len(events) == len(want) and distinct == len(want)
     assert all(v == 1 for v in events.values())
     # replay the SAME corpus through a fresh iterator: the census is

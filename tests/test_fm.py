@@ -94,7 +94,7 @@ def test_fm_sharded_step_on_mesh(tmp_path):
                             min_nnz_bucket=512) as it:
         for _ in range(10):
             for batch in it:
-                assert set(batch.tree()) == {"big", "aux"}
+                assert set(batch.tree()) == {"big", "cols", "aux"}
                 params, loss = learner.step(params, batch)
                 losses.append(float(loss))
             it.before_first()
@@ -193,6 +193,156 @@ def test_fm_row_step_equals_the_table_step(tmp_path, l2, objective):
         params = got
 
 
+def write_repeating_libsvm(path, rows=16384, seed=9):
+    """Every row names one of 3 columns, one of 7 and three of 60,000 (a
+    cubed draw, as the benchmark's generators skew): the 3-valued field
+    alone puts 16,384 of a batch's entries on 3 rows of the tables."""
+    rng = np.random.default_rng(seed)
+    small = rng.integers(0, 3, rows)
+    mid = 3 + rng.integers(0, 7, rows)
+    big = 10 + (60000 * rng.random((rows, 3)) ** 3).astype(np.int64)
+    with open(path, "w") as f:
+        for r in range(rows):
+            ids = sorted({int(small[r]), int(mid[r]), *big[r].tolist()})
+            f.write(f"{r % 2} " + " ".join(f"{c}:1" for c in ids) + "\n")
+    return str(path)
+
+
+def test_fm_row_step_merges_a_features_repeats(tmp_path):
+    """On a batch whose features recur by the thousand the row form's
+    update is the table form's: the expansion's transpose sums a feature's
+    entries at the gradient's magnitude and the scatter rounds once a
+    feature. Adding every entry into the parameter by itself, as the row
+    form did before the batch carried its distinct columns, lands an
+    order of magnitude further off."""
+    from dmlc_core_tpu.models.fm import (FMRows, _fm_margin_entries,
+                                         _fm_shard_loss)
+    from dmlc_core_tpu.models.linear import objective_loss
+    from dmlc_core_tpu.tpu.device_iter import unpack_shard
+    F, K, R = 60010, 8, 16384
+    learner = FMLearner(F, k=K, learning_rate=0.1, init_scale=0.1)
+    params = learner.init(seed=3)
+    params = params._replace(w=0.1 * jax.random.normal(
+        jax.random.PRNGKey(4), (F,)))
+    with DeviceRowBlockIter(write_repeating_libsvm(tmp_path / "h.libsvm"),
+                            batch_rows=R, layout="csr") as it:
+        batch = next(iter(it))
+    tree = batch.tree()
+    assert learner._takes_row_form(tree)
+    shard = unpack_shard({k: v[0] for k, v in tree.items()})
+    real = np.asarray(shard["val"]) != 0
+    counts = np.bincount(np.asarray(shard["col"])[real], minlength=F)
+    assert counts[:3].sum() == R and counts[:3].min() > 5000
+    assert batch.total_distinct < 0.6 * batch.total_nnz
+
+    (_, wsum), grads = jax.value_and_grad(
+        lambda p: _fm_shard_loss(p, shard, R, "logistic"),
+        has_aux=True)(params)
+    denom = jax.numpy.maximum(wsum, 1.0)
+    want = learner._apply(params, grads, denom)
+    got, _ = learner.step(params, batch)
+
+    # the row form as it was: each entry's own row, scattered by itself
+    col = shard["col"]
+    entry_grads = jax.grad(lambda rows: objective_loss(
+        _fm_margin_entries(rows.b, rows.w, rows.v, shard["row"],
+                           shard["val"], R), shard, R, "logistic")[0])(
+        FMRows(params.b, params.w[col], params.v[col]))
+    by_entry = {"w": params.w.at[col].add(-0.1 * entry_grads.w / denom),
+                "v": params.v.at[col].add(-0.1 * entry_grads.v / denom)}
+
+    def off(a, leaf):
+        """Largest error of an update, in units of the update's largest
+        step."""
+        before, b = np.asarray(getattr(params, leaf)), \
+            np.asarray(getattr(want, leaf))
+        return np.abs(np.asarray(a) - b).max() / np.abs(b - before).max()
+
+    for leaf in ("w", "v"):
+        merged, entrywise = off(getattr(got, leaf), leaf), \
+            off(by_entry[leaf], leaf)
+        assert merged <= 2e-5, (leaf, merged)
+        assert entrywise > 10 * merged, (leaf, merged, entrywise)
+
+
+@pytest.mark.parametrize("what", ["linear-step", "linear-predict",
+                                  "fm-predict"])
+def test_reading_col_back_from_the_list_changes_no_bit(tmp_path, what):
+    """The linear learner and both ``predict``s still read a column per
+    entry: ``cols[slot]``, expanded by the unpack. Against the same batch
+    handed over with its columns as the assemblers used to send them, a
+    named ``col`` plane, nothing differs."""
+    from dmlc_core_tpu.models.fm import _fm_margin_csr
+    from dmlc_core_tpu.tpu.device_iter import PaddedBatch, _expand_cols
+    uri = write_recurring_libsvm(tmp_path / "b.libsvm")
+    with DeviceRowBlockIter(uri, batch_rows=256, layout="csr",
+                            min_nnz_bucket=2048, to_device=False) as it:
+        batches = list(it)
+    assert len(batches) == 2
+    for b in batches:
+        assert b.tree().keys() == {"big", "cols", "aux"}
+        named = PaddedBatch(row=b.row, col=_expand_cols(b.cols, b.slot),
+                            val=b.val, label=b.label,
+                            weight=b.weight, nrows=b.nrows,
+                            total_rows=b.total_rows)
+        assert named.tree().keys() == {"row", "col", "val", "label",
+                                       "weight", "nrows"}
+        if what == "fm-predict":
+            learner = FMLearner(F_ROWS, k=K_ROWS, init_scale=0.3)
+            params = learner.init(seed=2)._replace(
+                w=jax.numpy.linspace(-1.0, 1.0, F_ROWS))
+            got = learner.predict(params, b)
+            want = jax.vmap(lambda r, c, v: _fm_margin_csr(
+                params, r, c, v, 256))(named.row, named.col, named.val)
+        else:
+            learner = LinearLearner(F_ROWS, learning_rate=0.3, l2=0.01)
+            params = learner.init()._replace(
+                w=jax.numpy.linspace(-1.0, 1.0, F_ROWS))
+            if what == "linear-predict":
+                got, want = learner.predict(params, b), \
+                    learner.predict(params, named)
+            else:
+                got, want = learner.step(params, b), \
+                    learner.step(params, named)
+        for a, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            assert np.asarray(a).tobytes() == np.asarray(w).tobytes()
+
+
+@pytest.mark.parametrize("call", ["step", "predict"])
+def test_a_packed_batch_without_its_list_is_named(tmp_path, call):
+    """The two packs alone, as they travelled before the list did: plane 1
+    holds slots now, so no consumer can read them."""
+    from dmlc_core_tpu.base import DMLCError
+    from dmlc_core_tpu.tpu.device_iter import PaddedBatch
+    uri = write_recurring_libsvm(tmp_path / "p.libsvm")
+    with DeviceRowBlockIter(uri, batch_rows=256, layout="csr",
+                            min_nnz_bucket=2048, to_device=False) as it:
+        b = next(iter(it))
+    two = PaddedBatch(big=b.big, aux=b.aux, total_rows=b.total_rows)
+    for learner in (FMLearner(F_ROWS, k=K_ROWS), LinearLearner(F_ROWS)):
+        with pytest.raises(DMLCError, match="col_slots"):
+            getattr(learner, call)(learner.init(), two)
+
+
+@pytest.mark.parametrize("call", ["step", "predict"])
+def test_fm_names_the_list_a_csr_batch_came_without(tmp_path, call):
+    """A hand-built batch of named row/col/val leaves, which the linear
+    learner takes, has no distinct list: the FM says so by name."""
+    from dmlc_core_tpu.base import DMLCError
+    from dmlc_core_tpu.tpu.device_iter import PaddedBatch, _expand_cols
+    uri = write_recurring_libsvm(tmp_path / "n.libsvm")
+    with DeviceRowBlockIter(uri, batch_rows=256, layout="csr",
+                            min_nnz_bucket=2048, to_device=False) as it:
+        b = next(iter(it))
+    named = PaddedBatch(row=b.row, col=_expand_cols(b.cols, b.slot),
+                        val=b.val, label=b.label, weight=b.weight,
+                        nrows=b.nrows, total_rows=b.total_rows)
+    learner = FMLearner(F_ROWS, k=K_ROWS)
+    with pytest.raises(DMLCError, match="col_slots"):
+        getattr(learner, call)(learner.init(seed=1), named)
+    LinearLearner(F_ROWS).step(LinearLearner(F_ROWS).init(), named)
+
+
 @pytest.mark.parametrize("devices", [2, 8])
 def test_fm_one_device_and_mesh_steps_agree(tmp_path, devices):
     uri = write_recurring_libsvm(tmp_path / "m.libsvm")
@@ -227,6 +377,10 @@ def test_fm_row_step_makes_no_table_but_the_parameters(tmp_path, l2,
     text = lowered.as_text(debug_info=True)
     assert "transpose(jvp(fm.gather))" not in text
     assert "dp.allreduce" not in text
+    # the expansion by slot and its transpose, the merge of a feature's
+    # repeats, carry a scope of their own
+    assert "dp.loss_grad/jvp(fm.expand)/gather" in text
+    assert "dp.loss_grad/transpose(jvp(fm.expand))" in text
     made = _table_ops(lowered, {(F_ROWS, K_ROWS), (F_ROWS,)})
     # one scatter-add into each table, under dp.apply; with l2 the decayed
     # operand of each (a scalar's broadcast and a multiply) and nothing else

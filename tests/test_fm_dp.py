@@ -3,10 +3,12 @@ loss, weight and gradient a step) against the benchmark's plain reference
 (benchmarks/reference/fm.py: FM by its definition on the global batch), as
 cell kdd2012-fm-dp4.libfm compares them on the chip, here at a small size on
 2, 4 and 8 host devices: losses, first gradient norms and change norms after
-three steps inside that cell's own limits, shards of unequal weight and a
-shard of padding rows alone included; the replicas bit-identical after every
-step; and ``model_step_allreduce_bytes_total`` counting what the psums are
-handed."""
+three steps inside that cell's own limits, shards of unequal weight, a
+shard of padding rows alone and rows that all name one of three columns
+included; the replicas bit-identical after every step;
+``model_step_allreduce_bytes_total`` counting what the psums are handed; and
+the benchmark's three configurations landing every batch of an epoch on one
+rung of the distinct-column list (ISSUE 31)."""
 
 import json
 import os
@@ -19,14 +21,14 @@ import jax
 
 from dmlc_core_tpu import telemetry
 from dmlc_core_tpu.models import FMLearner
-from dmlc_core_tpu.tpu.device_iter import DeviceRowBlockIter
+from dmlc_core_tpu.tpu.device_iter import DeviceRowBlockIter, nnz_bucket
 from dmlc_core_tpu.tpu.sharding import data_mesh
 
 BENCH = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "benchmarks")
 sys.path.insert(0, BENCH)
 
-from harness import check  # noqa: E402
+from harness import check, datagen  # noqa: E402
 from reference import fm as ref  # noqa: E402
 
 F, K, LR, SCALE, SEED = 3000, 4, 0.1, 0.1, 11
@@ -43,7 +45,11 @@ CASES = {
     # 192 rows in batches of 256: the fourth shard is padding rows alone,
     # and an epoch is one step
     "padding-4": (4, 192, lambda r: 6),
+    # every row's first feature is one of three (FEW): each shard's backward
+    # scatters its distinct columns, a third of the entries a row
+    "repeats-4": (4, STEPS * BATCH, lambda r: 3),
 }
+FEW = {"repeats-4": 3}
 
 
 def limits():
@@ -51,14 +57,22 @@ def limits():
         return json.load(f)["limits"]
 
 
-def write_rows(path, rows, nnz_of):
+def write_rows(path, rows, nnz_of, few=0):
     """Seeded libfm rows with distinct features within a row and values a
-    text round trip keeps; returns them as the reference takes them."""
+    text round trip keeps; returns them as the reference takes them. With
+    ``few``, a row's first feature is one of the first ``few`` and the
+    others lie above them."""
     rng = np.random.default_rng(SEED)
     lens = np.array([nnz_of(r) for r in range(rows)])
     label = rng.integers(0, 2, size=rows).astype(np.float32)
-    col = np.concatenate([rng.choice(F, size=n, replace=False)
-                          for n in lens])
+    if few:
+        col = np.concatenate([np.concatenate(
+            [rng.integers(0, few, 1),
+             few + rng.choice(F - few, size=n - 1, replace=False)])
+            for n in lens])
+    else:
+        col = np.concatenate([rng.choice(F, size=n, replace=False)
+                              for n in lens])
     val = rng.choice(np.array([0.5, 1.0, 1.5, 2.0], np.float32),
                      size=col.size)
     with open(path, "w") as f:
@@ -130,7 +144,8 @@ def norms(a, b):
 def test_mesh_step_agrees_with_the_plain_reference(tmp_path, case):
     shards, rows, nnz_of = CASES[case]
     uri = str(tmp_path / "rows.libfm")
-    reference = reference_readings(*write_rows(uri, rows, nnz_of))
+    reference = reference_readings(*write_rows(uri, rows, nnz_of,
+                                               FEW.get(case, 0)))
     states = list(program_steps(uri, shards))
     p0, p1, p3 = states[0][0], states[1][0], states[-1][0]
     program = check.Readings([loss for _, loss in states[1:]],
@@ -146,7 +161,7 @@ def test_mesh_step_agrees_with_the_plain_reference(tmp_path, case):
 def test_replicas_are_bit_identical_after_every_step(tmp_path, case):
     shards, rows, nnz_of = CASES[case]
     uri = str(tmp_path / "rows.libfm")
-    write_rows(uri, rows, nnz_of)
+    write_rows(uri, rows, nnz_of, FEW.get(case, 0))
     seen = 0
     for params, _ in program_steps(uri, shards):
         for copies in replicas(params):
@@ -189,3 +204,41 @@ def test_allreduce_bytes_counter_counts_what_the_psums_get(tmp_path, shards):
             seen.append(counter.value)
     rises = set(np.diff(seen))
     assert rises == ({0} if shards == 1 else {a_step}), rises
+
+
+# -- the cells' files and the ladder of the distinct-column list ----------------
+@pytest.mark.parametrize("config,traffic,chips,rung", [
+    ("kdd2012-fm", "libfm", 1, 106496),
+    ("kdd2010b-fm", "libsvm", 1, 262144),
+    ("kdd2012-fm-dp4", "libfm", 4, 106496),
+])
+def test_an_epoch_of_the_cells_file_lands_on_one_distinct_rung(
+        config, traffic, chips, rung):
+    """A second rung in a cell's epoch is a second compiled shape inside the
+    benchmark's window (``compiles_in_window``, limit 0): the generator's
+    own rows, shard by shard as the assemblers cut them, say before any
+    chip time whether a file straddles one."""
+    with open(os.path.join(BENCH, "configs", config + ".json")) as f:
+        cfg = json.load(f)
+    with open(os.path.join(BENCH, "traffic", traffic + ".json")) as f:
+        batches = int(json.load(f)["epoch_batches"])
+    R = int(cfg["batch_rows"])
+    counts = []
+    carry = None
+    for block in datagen.iter_blocks(cfg["data"], 31, batches * chips * R):
+        if carry is not None:
+            block = datagen.concat_blocks([carry, block])
+        whole = block.rows // R
+        ends = np.concatenate([[0], np.cumsum(block.lens)])
+        for i in range(whole):
+            lo, hi = ends[i * R], ends[(i + 1) * R]
+            counts.append(np.unique(block.col[lo:hi]).size)
+        carry = block.slice_rows(whole * R, block.rows) \
+            if block.rows % R else None
+    assert carry is None and len(counts) == batches * chips
+    # a batch's capacity is its fullest shard's rung
+    fullest = np.array(counts).reshape(batches, chips).max(axis=1)
+    rungs = {nnz_bucket(int(c), 4096) for c in fullest}
+    assert rungs == {rung}, (
+        f"{config}: distinct columns a shard {min(counts)} to "
+        f"{max(counts)} land on rungs {sorted(rungs)}")

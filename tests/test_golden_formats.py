@@ -74,7 +74,7 @@ def test_crec_golden_decodes(tmp_path):
         batch = b.next_batch()
         assert batch.total_rows == 2
         assert batch.label.reshape(-1).tolist() == [1.0, 0.0]
-        assert batch.col.reshape(-1)[:3].tolist() == [0, 2, 1]
+        assert batch.cols[0][batch.slot[0, :3]].tolist() == [0, 2, 1]
         np.testing.assert_allclose(batch.val.reshape(-1)[:3],
                                    [0.5, -1.5, 2.0])
         assert b.next_batch() is None

@@ -4,7 +4,9 @@ once in Python (device_iter.nnz_bucket), called by ``PaddedBatcher``,
 ``CsrRecBatcher`` and ``HostBatcher``.
 
 - the rule's properties over a sweep of counts and floors, and the two
-  statements equal on every point;
+  statements equal on every point (and, at each floor, the two statements
+  of the dedupe that sizes its list by the same rule: ISSUE 31,
+  tests/test_col_slots.py has its own cases);
 - streams shaped like the benchmark's corpora through all three batchers:
   kdd2012's 11 nonzeros a row fill the bucket exactly, kdd2010b's
   24 + Bernoulli x 12 stay on one rung for 50 batches;
@@ -25,14 +27,15 @@ import jax
 
 from dmlc_core_tpu import telemetry
 from dmlc_core_tpu.io.convert import rows_to_csr_recordio
-from dmlc_core_tpu.io.native import (NativeParser, native_nnz_bucket,
+from dmlc_core_tpu.io.native import (NativeParser, native_col_slots,
+                                     native_nnz_bucket,
                                      native_telemetry_snapshot)
 from dmlc_core_tpu.models import FMLearner, LinearLearner
 from dmlc_core_tpu.tpu import device_iter
 from dmlc_core_tpu.tpu.device_iter import (CsrRecHostBatcher,
                                            DeviceRowBlockIter, HostBatcher,
                                            NativeHostBatcher, PaddedBatch,
-                                           nnz_bucket)
+                                           col_slots, nnz_bucket)
 from dmlc_core_tpu.tpu.sharding import data_mesh
 
 
@@ -55,6 +58,19 @@ def test_rule_properties_and_native_equals_python(floor):
     granule_floor = min(eff, 128)
     got = [nnz_bucket(n, floor) for n in ns]
     assert got == [native_nnz_bucket(n, floor) for n in ns]
+    # col_slots.h includes nnz_bucket.h: the distinct list's capacity is
+    # this rule at this floor, in both statements of the dedupe
+    rng = np.random.default_rng(floor)
+    real = [3000, 0, 1]
+    col = np.zeros((3, 3000), np.int32)
+    col[0] = rng.integers(0, 2500, 3000)
+    col[2, 0] = 7
+    py, nat = col.copy(), col.copy()
+    py_cols, py_n = col_slots(py, real, floor)
+    nat_cols, nat_n = native_col_slots(nat, real, floor)
+    assert np.array_equal(py, nat) and np.array_equal(py_cols, nat_cols)
+    assert py_n == nat_n == np.unique(col[0]).size + 1
+    assert py_cols.shape == (3, nnz_bucket(py_n - 1, floor))
     by_octave = {}
     for n, b in zip(ns, got):
         assert b >= n and b >= eff, (n, b)
@@ -204,7 +220,8 @@ def test_shard_cache_replays_at_the_text_epochs_capacity(kdd2012_like,
 def _hand_batch(D, R, per_row, cap, features, seed=4):
     """One packed CSR batch built by hand: ``per_row`` tokens in each of
     the D x R rows, every shard padded to ``cap`` entries the way the
-    batchers pad (row = R, col = 0, val = 0)."""
+    batchers pad (row = R, slot = 0, val = 0), its columns sent as the
+    distinct list and the slots (``col_slots``)."""
     rng = np.random.default_rng(seed)
     n = R * per_row
     big = np.zeros((D, 3, cap), np.int32)
@@ -217,8 +234,9 @@ def _hand_batch(D, R, per_row, cap, features, seed=4):
     aux[:, 0] = rng.integers(0, 2, (D, R)).astype(np.float32).view(np.int32)
     aux[:, 1] = np.ones((D, R), np.float32).view(np.int32)
     aux[:, 2, 0] = R
-    return PaddedBatch(big=big, aux=aux, total_rows=D * R,
-                       total_nnz=D * n)
+    cols, distinct = col_slots(big[:, 1], [n] * D, 128)
+    return PaddedBatch(big=big, cols=cols, aux=aux, total_rows=D * R,
+                       total_nnz=D * n, total_distinct=distinct)
 
 
 @pytest.mark.parametrize("model,devices", [("fm", 1), ("fm", 4),

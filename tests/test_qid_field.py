@@ -87,13 +87,13 @@ def test_native_batcher_carries_field(tmp_path):
                           num_shards=1, min_nnz_bucket=64)
     batch = b.next_batch()
     assert batch is not None and batch.field is not None
-    assert batch.field.shape == batch.col.shape
+    assert batch.field.shape == batch.slot.shape
     assert batch.field.dtype == np.int32
     # reconstruct per-row triples from the device layout
     R = batch.rows_per_shard
     rows = {}
-    for r, c, f, v in zip(batch.row[0], batch.col[0], batch.field[0],
-                          batch.val[0]):
+    for r, c, f, v in zip(batch.row[0], batch.cols[0][batch.slot[0]],
+                          batch.field[0], batch.val[0]):
         if v != 0:
             rows.setdefault(int(r), []).append((int(f), int(c), float(v)))
     for i, triples in enumerate(expect):
@@ -121,7 +121,8 @@ def test_host_batcher_python_path_parity(tmp_path):
     parser.close()
     assert python.field is not None and native.field is not None
     np.testing.assert_array_equal(python.row, native.row)
-    np.testing.assert_array_equal(python.col, native.col)
+    np.testing.assert_array_equal(python.slot, native.slot)
+    np.testing.assert_array_equal(python.cols, native.cols)
     np.testing.assert_array_equal(python.field, native.field)
     np.testing.assert_allclose(python.val, native.val, rtol=1e-6)
 
@@ -173,12 +174,13 @@ def test_field_aware_matvec_matches_numpy(tmp_path):
     rng = np.random.default_rng(7)
     W = rng.normal(size=(4, 16)).astype(np.float32)
     R = batch.rows_per_shard
+    col = batch.cols[0][batch.slot[0]]
     y = jax.jit(field_aware_matvec, static_argnames="num_rows")(
-        jnp.asarray(batch.row[0]), jnp.asarray(batch.col[0]),
+        jnp.asarray(batch.row[0]), jnp.asarray(col),
         jnp.asarray(batch.field[0]), jnp.asarray(batch.val[0]),
         jnp.asarray(W), num_rows=R)
     y_np = np.zeros(R, np.float32)
-    for r, c, f, v in zip(batch.row[0], batch.col[0], batch.field[0],
+    for r, c, f, v in zip(batch.row[0], col, batch.field[0],
                           batch.val[0]):
         if r < R:
             y_np[r] += v * W[f, c]

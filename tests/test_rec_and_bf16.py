@@ -12,7 +12,7 @@ from dmlc_core_tpu.base import DMLCError
 from dmlc_core_tpu.io.convert import rows_to_recordio
 from dmlc_core_tpu.io.native import NativeParser
 from dmlc_core_tpu.tpu.device_iter import (DeviceRowBlockIter, HostBatcher,
-                                           NativeHostBatcher)
+                                           NativeHostBatcher, _expand_cols)
 
 
 def write_libsvm(path, rows, features=12, seed=3, qid=False):
@@ -224,14 +224,29 @@ def test_index_overflow_raises_native_batcher(tmp_path):
     b.close()
 
 
-def test_index_below_limit_ok(tmp_path):
-    p = _write_big_index(tmp_path / "p.libsvm", 2 ** 31 - 1)
-    parser = NativeParser(str(p), index64=True)
-    hb = HostBatcher(parser, batch_rows=4, num_shards=1, layout="csr")
+# the largest int32 id is a column like another; the distinct list's
+# padding repeats it (col_slots) and no slot names the padding
+_TOP_ID = 2 ** 31 - 1
+
+
+def _batcher_of(kind, path):
+    if kind == "native":
+        return NativeHostBatcher(str(path), batch_rows=4, layout="csr"), None
+    parser = NativeParser(str(path), index64=True)
+    return HostBatcher(parser, batch_rows=4, num_shards=1,
+                       layout="csr"), parser
+
+
+@pytest.mark.parametrize("kind", ["python", "native"])
+def test_index_below_limit_ok(tmp_path, kind):
+    p = _write_big_index(tmp_path / "p.libsvm", _TOP_ID)
+    hb, parser = _batcher_of(kind, p)
     batch = hb.next_batch()
     assert batch is not None
-    assert int(batch.col.max()) == 2 ** 31 - 1
-    parser.close()
+    assert int(_expand_cols(batch.cols, batch.slot).max()) == _TOP_ID
+    assert batch.cols[0, :4].tolist() == [3, 5, _TOP_ID, _TOP_ID]
+    assert int(batch.slot.max()) == 2
+    (parser or hb).close()
 
 
 # -- recd: zero-parse dense row-matrix lane ---------------------------------

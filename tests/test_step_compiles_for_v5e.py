@@ -1,0 +1,101 @@
+"""The FM step of each benchmark configuration, compiled (not run) for the
+v5e at the cell's own shapes: the batch as the assemblers send it since
+ISSUE 31 (the packs, and the shards' distinct columns at their rung). What
+the chip's compiler refuses, and what does not fit beside the check's spare
+table, shows here and costs no chip time. One file, one fixture: only the
+worker that gets this file loads the TPU's library."""
+
+import json
+import os
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from dmlc_core_tpu.models import FMLearner
+from dmlc_core_tpu.models.fm import FMParams
+
+CONFIGS = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "benchmarks", "configs")
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def no_compile_cache():
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep it out
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+# (configuration, chips, planes of the big pack, nnz rung, distinct rung):
+# the rungs tests/test_fm_dp.py finds for an epoch of each cell's file
+@pytest.mark.slow
+@pytest.mark.parametrize("config,chips,planes,nnz,distinct", [
+    ("kdd2012-fm", 1, 4, 180224, 106496),
+    ("kdd2010b-fm", 1, 3, 491520, 262144),
+    ("kdd2012-fm-dp4", 4, 4, 180224, 106496),
+])
+def test_step_compiles_and_fits_beside_the_checks_table(
+        topo, no_compile_cache, config, chips, planes, nnz, distinct):
+    with open(os.path.join(CONFIGS, config + ".json")) as f:
+        cfg = json.load(f)
+    mesh = Mesh(np.array(topo.devices[:chips]), ("data",))
+    learner = FMLearner(num_features=cfg["num_features"], k=cfg["fm_rank"],
+                        mesh=mesh, objective=cfg["objective"],
+                        learning_rate=cfg["learning_rate"])
+    rep, row = NamedSharding(mesh, P()), NamedSharding(mesh, P("data"))
+    F, K, R = cfg["num_features"], cfg["fm_rank"], cfg["batch_rows"]
+    params = FMParams(jax.ShapeDtypeStruct((), jnp.float32, sharding=rep),
+                      jax.ShapeDtypeStruct((F,), jnp.float32, sharding=rep),
+                      jax.ShapeDtypeStruct((F, K), jnp.float32, sharding=rep))
+    tree = {"aux": jax.ShapeDtypeStruct((chips, 3, R), jnp.int32,
+                                        sharding=row),
+            "big": jax.ShapeDtypeStruct((chips, planes, nnz), jnp.int32,
+                                        sharding=row),
+            "cols": jax.ShapeDtypeStruct((chips, distinct), jnp.int32,
+                                         sharding=row)}
+    assert learner._takes_row_form(tree) == (chips == 1)
+    compiled = learner._build_step(R, tuple(sorted(tree))).lower(
+        params, tree).compile()
+    m = compiled.memory_analysis()
+    table = F * (K + 1) * 4
+    peak = (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    print(f"{config}: arguments {m.argument_size_in_bytes} outputs "
+          f"{m.output_size_in_bytes} temp {m.temp_size_in_bytes} "
+          f"alias {m.alias_size_in_bytes}")
+    assert m.argument_size_in_bytes >= table
+    # no third table: the temporaries are the batch's [NNZ, K] and [U, K]
+    # intermediates (0.28 GB and 0.77 GB in the one-chip cells)
+    assert m.temp_size_in_bytes < 0.25 * table
+    # a quarter of the chip at least, and room for the table the
+    # benchmark's check regenerates beside the state
+    assert 0.25 * 16e9 < peak < 16e9 - table
+    text = compiled.as_text()
+    # the tables are updated at the distinct columns alone: every scatter
+    # into a table is told its indices ascend, as that list's do
+    into_tables = [line for line in text.splitlines()
+                   if " scatter(" in line and f"= f32[{F}" in line]
+    assert len(into_tables) == 2, into_tables
+    for line in into_tables:
+        assert "indices_are_sorted=true" in line, line
+    assert ("dp.allreduce/psum" in text) == (chips == 4)

@@ -98,7 +98,7 @@ def test_aligned_empty_is_64_byte_aligned():
 
 
 def _assert_staging_aligned(b):
-    for name in ("big", "aux", "val16", "x"):
+    for name in ("big", "cols", "aux", "val16", "x"):
         v = getattr(b, name, None)
         if isinstance(v, np.ndarray) and v.size:
             assert v.ctypes.data % 64 == 0, name
@@ -304,9 +304,13 @@ def test_fallback_counted_per_reason(tmp_path, monkeypatch):
                               .reshape(1, 3, 8))
         aux = _unaligned_like(np.arange(3 * 4, dtype=np.int32)
                               .reshape(1, 3, 4))
-        got = it._device_put(PaddedBatch(big=big, aux=aux, total_rows=2))
+        cols = _unaligned_like(np.arange(16, dtype=np.int32)
+                               .reshape(1, 16))
+        got = it._device_put(PaddedBatch(big=big, cols=cols, aux=aux,
+                                         total_rows=2))
         # the fallback still LANDS the batch, bit-exactly
         assert np.array_equal(np.asarray(got.big), big)
+        assert np.array_equal(np.asarray(got.cols), cols)
         assert np.array_equal(np.asarray(got.aux), aux)
         total, per = _fallbacks()
         assert per.get("unaligned") == 1 and total == 1
@@ -315,14 +319,18 @@ def test_fallback_counted_per_reason(tmp_path, monkeypatch):
         big_t = np.asfortranarray(np.zeros((2, 3, 8), np.int32))
         aux_c = _aligned_empty((2, 3, 4), np.int32)
         aux_c.fill(0)
-        it._device_put(PaddedBatch(big=big_t, aux=aux_c, total_rows=0))
+        cols_c = _aligned_empty((2, 16), np.int32)
+        cols_c.fill(0)
+        it._device_put(PaddedBatch(big=big_t, cols=cols_c, aux=aux_c,
+                                   total_rows=0))
         assert _fallbacks()[1].get("non_contiguous_host") == 1
         # an aligned, contiguous tree goes zero-copy on the same iterator
         big_a = _aligned_empty((1, 3, 8), np.int32)
         big_a.fill(1)
         aux_a = _aligned_empty((1, 3, 4), np.int32)
         aux_a.fill(0)
-        it._device_put(PaddedBatch(big=big_a, aux=aux_a, total_rows=0))
+        it._device_put(PaddedBatch(big=big_a, cols=cols_c[:1], aux=aux_a,
+                                   total_rows=0))
         assert _counters()["device_zero_copy_batches_total"] == 1
         assert _fallbacks()[0] == 2  # unchanged
     finally:
