@@ -23,6 +23,7 @@
 
 #include "batcher.h"
 #include "bf16.h"
+#include "criteo_hash.h"
 #include "csr_rec.h"
 #include "dense_rec.h"
 #include "filesys.h"
@@ -609,6 +610,23 @@ int dct_parser_free(dct_parser_t h) {
   return Guard([&] { delete static_cast<ParserHandle*>(h); });
 }
 
+// The names of the native parser-format registry, comma-joined: the one
+// list of formats (Python's data.Parser.create reads it, so a format is
+// registered once, in RegisterBuiltinParsers).
+int dct_parser_format_names(char** out) {
+  return Guard([&] {
+    std::string s;
+    for (const std::string& name :
+         dct::Registry<dct::ParserFactoryReg<uint32_t>>::Get()
+             ->ListAllNames()) {
+      s += (s.empty() ? "" : ",") + name;
+    }
+    char* buf = new char[s.size() + 1];
+    std::memcpy(buf, s.c_str(), s.size() + 1);
+    *out = buf;
+  });
+}
+
 // Render the native parser-format registry as markdown (name, description,
 // argument tables from each format's reflection params) — the doc lane's
 // source of truth (scripts/gendoc.py; reference doc/parameter.md documents
@@ -656,6 +674,16 @@ int dct_batcher_create(const char* uri, unsigned part, unsigned npart,
 // exported so a test can hold the Python statement of it equal.
 int dct_nnz_bucket(uint64_t n, uint64_t floor, uint64_t* out) {
   return Guard([&] { *out = dct::NnzBucket(n, floor); });
+}
+
+// The rule the `criteo` format hashes a cell by (criteo_hash.h), exported so
+// a test can hold the Python statements of it equal.
+int dct_criteo_id(uint32_t column, const char* cell, uint64_t len,
+                  int hash_bits, uint64_t* out) {
+  return Guard([&] {
+    DCT_CHECK(hash_bits >= 1 && hash_bits <= 63) << "hash_bits out of range";
+    *out = dct::CriteoFold(dct::CriteoHash64(column, cell, len), hash_bits);
+  });
 }
 
 int dct_batcher_next_meta(dct_batcher_t h, uint64_t* take, uint64_t* bucket,

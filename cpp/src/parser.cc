@@ -11,6 +11,7 @@
 #include <limits>
 #include <thread>
 
+#include "criteo_hash.h"
 #include "fs_fault.h"
 #include "numparse.h"
 #include "parameter.h"
@@ -170,6 +171,20 @@ struct LibFMParserParam : public Parameter<LibFMParserParam> {
         .add_enum("one_based", 1)
         .describe("indexing heuristic over field and feature ids "
                   "(reference libfm_parser.h:24-40)");
+  }
+};
+
+struct CriteoParserParam : public Parameter<CriteoParserParam> {
+  std::string format;
+  int hash_bits;
+  DCT_DECLARE_PARAMETER(CriteoParserParam) {
+    DCT_DECLARE_FIELD(format).set_default("criteo");
+    DCT_DECLARE_FIELD(hash_bits)
+        .set_range(1, 63)
+        .describe("feature ids are the cells' 64-bit hashes folded to this "
+                  "many bits (criteo_hash.h), so the feature space is "
+                  "2^hash_bits; at most 31 with 32-bit indices (the device "
+                  "layout); no default: the model's table is sized by it");
   }
 };
 
@@ -923,6 +938,121 @@ void LibFMParser<IndexType>::ParseBlockSimd(
   out->label.reserve(n_eol + 1);
   out->offset.reserve(n_eol + 2);
   ParseLibFMBlockImpl<true>(begin, end, indexing_mode_, out);
+}
+
+// --------------------------------------------------------------------------
+template <typename IndexType>
+CriteoParser<IndexType>::CriteoParser(
+    InputSplit* source, const std::map<std::string, std::string>& args,
+    int nthread)
+    : TextParserBase<IndexType>(source, nthread) {
+  CriteoParserParam param;
+  param.Init(args, ParamInitOption::kAllowUnknown);
+  DCT_CHECK_EQ(param.format, std::string("criteo")) << "format mismatch";
+  DCT_CHECK(sizeof(IndexType) == 8 || param.hash_bits <= 31)
+      << "criteo: hash_bits=" << param.hash_bits
+      << " does not fit 32-bit indices (1..31; ids are int32 on the device)";
+  hash_bits_ = param.hash_bits;
+}
+
+namespace {
+struct CriteoTelemetry {
+  telemetry::Counter* cells;
+  telemetry::Counter* missing;
+};
+
+const CriteoTelemetry& CriteoTel() {
+  static const CriteoTelemetry t = {
+      telemetry::GetCounter("parse_cells_total", {{"format", "criteo"}}),
+      telemetry::GetCounter("parse_cells_missing_total",
+                            {{"format", "criteo"}}),
+  };
+  return t;
+}
+
+// A line the format refuses: never a short or a skipped row.
+[[noreturn]] void CriteoRefuse(const char* begin, const char* line,
+                               const char* end, const std::string& why) {
+  const char* eol = line;
+  while (eol != end && !IsEolChar(*eol)) ++eol;
+  const size_t shown = std::min<size_t>(static_cast<size_t>(eol - line), 60);
+  throw Error("criteo: the line at byte offset " +
+              std::to_string(line - begin) + " of its block " + why +
+              " (a line is the label, 13 integer and 26 categorical cells, "
+              "tab-separated): '" + std::string(line, shown) +
+              (shown < static_cast<size_t>(eol - line) ? "...'" : "'"));
+}
+
+// Single-pass tokenizer: the label where the cursor stands, then 39 cells
+// each led by its tab; a cell's bytes are hashed as they stand (no trim, no
+// number parse). Blank lines are skipped, as by every text parser here
+// (the LF of a CRLF pair is one).
+template <bool kFused, typename IndexType>
+void ParseCriteoBlockImpl(const char* begin, const char* end, int hash_bits,
+                          RowBlockContainer<IndexType>* out) {
+  const char* p = SkipUTF8BOM(begin, end);
+  while (p != end) {
+    if (IsEolChar(*p)) {
+      ++p;
+      continue;
+    }
+    const char* line = p;
+    float label;
+    const char* after;
+    if (*p == '\t' || !ParseNumF<kFused, float>(p, end, &after, &label) ||
+        (after != end && *after != '\t' && !IsEolChar(*after))) {
+      CriteoRefuse(begin, line, end, "has a label that is not a number");
+    }
+    p = after;
+    int column = 0;
+    for (; column < kCriteoColumns && p != end && *p == '\t'; ++column) {
+      const char* cell = ++p;
+      while (p != end && *p != '\t' && !IsEolChar(*p)) ++p;
+      if (p != cell) {
+        const IndexType id = static_cast<IndexType>(CriteoFold(
+            CriteoHash64(static_cast<uint32_t>(column), cell,
+                         static_cast<size_t>(p - cell)),
+            hash_bits));
+        out->index.push_back(id);
+        out->max_index = std::max<uint64_t>(out->max_index, id);
+      }
+    }
+    if (column != kCriteoColumns || (p != end && !IsEolChar(*p))) {
+      size_t cells = 1;
+      for (const char* q = line; q != end && !IsEolChar(*q); ++q) {
+        cells += *q == '\t';
+      }
+      CriteoRefuse(begin, line, end,
+                   "has " + std::to_string(cells) + " cells, not " +
+                       std::to_string(kCriteoCells));
+    }
+    if (p != end) ++p;  // the EOL character; a last line may lack it
+    out->label.push_back(label);
+    out->offset.push_back(out->index.size());
+  }
+  DCT_CHECK_EQ(out->label.size() + 1, out->offset.size());
+}
+}  // namespace
+
+template <typename IndexType>
+void CriteoParser<IndexType>::ParseBlock(const char* begin, const char* end,
+                                         RowBlockContainer<IndexType>* out) {
+  out->Clear();
+  if (this->simd_tier_ != kSimdScalar) {
+    size_t n_tab = 0, n_eol = 0;
+    CountSepEol(begin, end, '\t', static_cast<SimdTier>(this->simd_tier_),
+                &n_tab, &n_eol);
+    out->index.reserve(n_tab);  // every feature cell owns the tab before it
+    out->label.reserve(n_eol + 1);
+    out->offset.reserve(n_eol + 2);
+    ParseCriteoBlockImpl<true>(begin, end, hash_bits_, out);
+  } else {
+    ParseCriteoBlockImpl<false>(begin, end, hash_bits_, out);
+  }
+  // per block, from the counts the block already holds
+  const uint64_t cells = uint64_t(out->label.size()) * kCriteoColumns;
+  CriteoTel().cells->Add(cells);
+  CriteoTel().missing->Add(cells - out->index.size());
 }
 
 // --------------------------------------------------------------------------
@@ -1686,6 +1816,8 @@ template class CSVParser<uint32_t>;
 template class CSVParser<uint64_t>;
 template class LibFMParser<uint32_t>;
 template class LibFMParser<uint64_t>;
+template class CriteoParser<uint32_t>;
+template class CriteoParser<uint64_t>;
 template class RecParser<uint32_t>;
 template class RecParser<uint64_t>;
 template class PipelinedParser<uint32_t>;
@@ -1720,6 +1852,14 @@ void RegisterBuiltinParsers() {
       .add_arguments(LibFMParserParam::__FIELDS__())
       .set_body([](InputSplit* s, const Map& args, int nthread) {
         return new LibFMParser<IndexType>(s, args, nthread);
+      });
+  reg->__REGISTER__("criteo")
+      .describe("Criteo click logs: `label \\t 13 integer \\t 26 categorical` "
+                "cells a line; every present cell hashed with its column "
+                "to an id below 2^hash_bits, value 1, empty cells skipped")
+      .add_arguments(CriteoParserParam::__FIELDS__())
+      .set_body([](InputSplit* s, const Map& args, int nthread) {
+        return new CriteoParser<IndexType>(s, args, nthread);
       });
   reg->__REGISTER__("rec")
       .describe("binary RecordIO-framed row blocks (rows_to_recordio)")

@@ -110,7 +110,8 @@ class Parser {
   }
 
   // Factory (reference src/data.cc:62-85 CreateParser_): format is
-  // "libsvm" | "csv" | "libfm" | "auto" (resolved from ?format= URI arg).
+  // "libsvm" | "csv" | "libfm" | "criteo" | "rec" | "auto" (resolved from
+  // the ?format= URI arg).
   // `threaded` pipelines parsing against consumption (PipelinedParser).
   // `chunks_in_flight` bounds the pipeline's outstanding chunks (0 = auto;
   // also settable per-URI via `?chunks_in_flight=K`). Caching sugar
@@ -285,6 +286,28 @@ class LibFMParser : public TextParserBase<IndexType> {
   void ParseBlockSimd(const char* begin, const char* end,
                       RowBlockContainer<IndexType>* out);
   int indexing_mode_;
+};
+
+// criteo: the Criteo click logs as published — `label \t I1..I13 \t
+// C1..C26`, 40 tab-separated cells a line, an empty cell a missing value
+// (the format dmlc/wormhole reads with learn/base/criteo_parser.h). Every
+// present feature cell, integer cells too, becomes the id
+// fold(hash64(column, cell bytes), hash_bits) of criteo_hash.h; rows carry
+// label, offset and index and no value (every value is 1). A line with
+// another count of cells than 40, or a label that is not a number, throws,
+// naming the format and where the line starts in its block. Both lanes run
+// one tokenizer and the one hash: the SIMD tier only sizes the reserves
+// and decodes the label, so ids cannot differ by tier.
+template <typename IndexType>
+class CriteoParser : public TextParserBase<IndexType> {
+ public:
+  CriteoParser(InputSplit* source,
+               const std::map<std::string, std::string>& args, int nthread);
+  void ParseBlock(const char* begin, const char* end,
+                  RowBlockContainer<IndexType>* out) override;
+
+ private:
+  int hash_bits_;
 };
 
 // rec: binary ingest — RecordIO records whose payloads are serialized

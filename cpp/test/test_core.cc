@@ -31,6 +31,7 @@
 
 #include "../src/concurrency.h"
 #include "../src/config.h"
+#include "../src/criteo_hash.h"
 #include "../src/csr_rec.h"
 #include "../src/dense_rec.h"
 #include "../src/lockfree.h"
@@ -568,12 +569,16 @@ void TestRegistry() {
   EXPECT(e->arguments.size() == 1 && e->arguments[0].name == "x");
   EXPECT(reg->Find("absent") == nullptr);
   EXPECT(reg->ListAllNames().size() == 1);
-  // the built-in parsers registered themselves (libsvm/csv/libfm)
+  // the built-in parsers registered themselves (libsvm/csv/libfm/criteo)
   auto* preg = dct::Registry<dct::ParserFactoryReg<uint32_t>>::Get();
   EXPECT(preg->Find("libsvm") != nullptr);
   EXPECT(preg->Find("csv") != nullptr);
   EXPECT(preg->Find("libfm") != nullptr);
+  EXPECT(preg->Find("criteo") != nullptr);
   EXPECT(!preg->Find("csv")->arguments.empty());
+  // the worked id of doc/parsing.md: column 13 (C1), cell 68fd1e64
+  EXPECT(dct::CriteoHash64(13, "68fd1e64", 8) == 0x91FB01B9CF143E61ULL);
+  EXPECT(dct::CriteoFold(0x91FB01B9CF143E61ULL, 25) == 15679448);
 }
 
 void TestConfig() {

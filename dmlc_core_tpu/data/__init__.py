@@ -18,8 +18,8 @@ Differences from the reference are deliberate:
   written by the C++ core (cpp/src/rowblock.h Save/Load), so caches
   round-trip across languages.
 - Custom formats register with ``@register_parser`` (reference
-  DMLC_REGISTER_DATA_PARSER, data.h:358); the built-in libsvm/csv/libfm
-  formats dispatch to the multithreaded native parsers.
+  DMLC_REGISTER_DATA_PARSER, data.h:358); the built-in libsvm/csv/libfm/
+  criteo/rec formats dispatch to the multithreaded native parsers.
 - Elastic data-plane (doc/robustness.md): ``ElasticRowBlockIter`` iterates
   tracker-granted shard leases instead of a static part index —
   ``DMLC_ELASTIC_SHARDS=1`` / ``?elastic=1`` opt in through
@@ -36,7 +36,8 @@ import numpy as np
 
 from dmlc_core_tpu import telemetry
 from dmlc_core_tpu.base import DMLCError, log_info, log_warning
-from dmlc_core_tpu.io.native import NativeParser, RowBlock
+from dmlc_core_tpu.io.native import (NativeParser, RowBlock,
+                                     parser_format_names)
 from dmlc_core_tpu.registry import Registry
 from dmlc_core_tpu.serializer import BinaryReader, BinaryWriter
 
@@ -351,8 +352,6 @@ class RowBlockContainer:
 # parsers; Python callables can register additional formats.
 PARSER_REGISTRY: Registry = Registry.get("data_parser")
 
-_NATIVE_FORMATS = ("libsvm", "csv", "libfm")
-
 # batch-path metric objects resolved ONCE (the registry contract: resolve,
 # keep the pointer — per-batch re-resolution would take the registry lock
 # on every pull); lazy so importing this module registers nothing
@@ -401,7 +400,10 @@ class Parser:
         (DMLC_DATA_CACHE_DIR, DMLC_DATA_CACHE)."""
         args = _uri_query_args(uri)
         resolved = args.get("format", "libsvm") if fmt == "auto" else fmt
-        if resolved in _NATIVE_FORMATS:
+        # the native registry is the one list of native formats
+        # (cpp/src/parser.cc RegisterBuiltinParsers)
+        native_formats = parser_format_names()
+        if resolved in native_formats:
             if kwargs:
                 # native parser options travel as ?k=v URI args (reference
                 # URISpec → param_.Init); don't silently drop kwargs
@@ -416,7 +418,7 @@ class Parser:
         if entry is None:
             raise DMLCError(
                 f"unknown data format {resolved!r}; known: "
-                f"{list(_NATIVE_FORMATS) + PARSER_REGISTRY.list_names()}")
+                f"{list(native_formats) + PARSER_REGISTRY.list_names()}")
         uri_cache = args.get("cache", "")
         frag = uri.split("#", 1)[1] if "#" in uri else ""
         if (cache_dir or (cache and cache != "never")

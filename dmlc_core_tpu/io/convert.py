@@ -293,9 +293,9 @@ def rows_to_csr_recordio(src_uri: str, dst_uri: str, fmt: str = "auto",
 def rows_to_recordio(src_uri: str, dst_uri: str, fmt: str = "auto",
                      rows_per_record: int = 4096, index64: bool = False,
                      part: int = 0, npart: int = 1, nthread: int = 0) -> int:
-    """Parse `src_uri` (libsvm/csv/libfm) and write binary row-block records
-    to `dst_uri`; returns the number of rows converted. The output ingests
-    via format "rec" (auto-detected for a .rec suffix)."""
+    """Parse `src_uri` (libsvm/csv/libfm/criteo) and write binary row-block
+    records to `dst_uri`; returns the number of rows converted. The output
+    ingests via format "rec" (auto-detected for a .rec suffix)."""
     if rows_per_record <= 0:
         raise DMLCError("rows_per_record must be positive")
     total = 0
@@ -318,15 +318,16 @@ def _main(argv=None) -> int:
     .idx file that unlocks ?index=1&shuffle=1 on .rec outputs."""
     import argparse
     ap = argparse.ArgumentParser(
-        description="Convert text datasets (libsvm/csv/libfm) to the "
+        description="Convert text datasets (libsvm/csv/libfm/criteo) to the "
                     "binary ingest lanes")
     ap.add_argument("src", help="source URI (any supported filesystem)")
     ap.add_argument("dst", help="destination: *.rec (CSR row blocks), "
                                 "*.crec (CSR device planes), *.drec "
                                 "(dense matrices)")
     ap.add_argument("--format", default="auto",
-                    help="source format (auto/libsvm/csv/libfm; "
-                         "?format= URI sugar also works)")
+                    help="source format (auto/libsvm/csv/libfm/criteo; "
+                         "?format= URI sugar also works, and carries a "
+                         "format's options: ?hash_bits=25)")
     ap.add_argument("--rows-per-record", type=int, default=4096)
     ap.add_argument("--dtype", default=None,
                     help="dense (.drec) element dtype: bf16 (default) or "

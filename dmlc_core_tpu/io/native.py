@@ -193,6 +193,9 @@ def _declare_signatures(cdll: ctypes.CDLL) -> None:
         "dct_io_set_timeout_ms": (i, [i]),
         "dct_fs_set_fault_plan": (i, [c.c_char_p]),
         "dct_parser_formats_doc": (i, [c.POINTER(c.c_char_p)]),
+        "dct_parser_format_names": (i, [c.POINTER(c.c_char_p)]),
+        "dct_criteo_id": (i, [c.c_uint32, c.c_char_p, c.c_uint64, i,
+                              c.POINTER(c.c_uint64)]),
         "dct_batcher_create": (i, [c.c_char_p, u, u, c.c_char_p, i, i,
                                    c.c_uint64, c.c_uint32, c.c_uint64,
                                    c.POINTER(vp)]),
@@ -424,6 +427,27 @@ def parser_formats_doc() -> str:
         return ctypes.string_at(out).decode()
     finally:
         lib().dct_str_free(out)
+
+
+def parser_format_names() -> tuple:
+    """The formats of the native parser registry (cpp/src/parser.cc
+    RegisterBuiltinParsers), in its order: where a format is registered."""
+    out = ctypes.c_char_p()
+    _check(lib().dct_parser_format_names(ctypes.byref(out)))
+    try:
+        return tuple(ctypes.string_at(out).decode().split(","))
+    finally:
+        lib().dct_str_free(out)
+
+
+def native_criteo_id(column: int, cell: bytes, hash_bits: int) -> int:
+    """The native statement of the ``criteo`` format's rule
+    (cpp/src/criteo_hash.h; the numpy one is dmlc_core_tpu.data.criteo):
+    the feature id of ``cell`` in feature column ``column`` (0..38)."""
+    out = ctypes.c_uint64()
+    _check(lib().dct_criteo_id(column, cell, len(cell), hash_bits,
+                               ctypes.byref(out)))
+    return out.value
 
 
 # -- telemetry ---------------------------------------------------------------

@@ -1061,6 +1061,9 @@ METRIC_HELP: Dict[str, str] = {
     "parse_worker_waits_total": "worker slept with no claimable slice",
     "parse_consumer_waits_total":
         "consumer slept on the head-of-line chunk",
+    "parse_cells_total":
+        "feature cells of the lines a cell-counting text format parsed",
+    "parse_cells_missing_total": "of those cells, the empty ones (skipped)",
     "parse_stage_fill_us": "one ReadChunk, source to owned bytes (us)",
     "parse_stage_scan_us": "one TileCuts slice pre-tiling (us)",
     "parse_stage_parse_us": "one worker slice decode (us)",

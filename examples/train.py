@@ -8,6 +8,7 @@ Walks the full TPU-native pipeline surface in ~60 lines of user code:
   python examples/train.py "data.libsvm?shuffle_parts=16" --objective pairwise
   python examples/train.py s3://bucket/train.drec --batch-rows 8192
   python examples/train.py data.rec --resume ckpt.bin   # after preemption
+  python examples/train.py "day_0.tsv?hash_bits=25" --format criteo --model fm
 
 Under dmlc-submit the same script runs per-host with its own partition:
 
@@ -49,8 +50,12 @@ def _labeled(snap, name: str, label: str) -> dict:
 
 def main() -> int:
     ap = argparse.ArgumentParser()
-    ap.add_argument("uri", help="libsvm/csv/libfm/rec/drec data URI "
-                               "(file://, s3://, hdfs://, azure://)")
+    ap.add_argument("uri", help="libsvm/csv/libfm/criteo/rec/crec/drec data "
+                               "URI (file://, s3://, hdfs://, azure://); a "
+                               "format's options ride it (?hash_bits=25)")
+    ap.add_argument("--format", default="auto",
+                    help="data format; auto: ?format= of the URI, the "
+                         "suffix of a binary file, else libsvm")
     ap.add_argument("--num-features", type=int, default=0,
                     help="0 = discover from the first epoch's max index")
     ap.add_argument("--model", default="linear", choices=("linear", "fm"),
@@ -81,7 +86,8 @@ def main() -> int:
         # passes --num-features; feature spaces are part-invariant)
         from dmlc_core_tpu.io import NativeParser
         mx = 0
-        with NativeParser(args.uri, part=part, npart=npart) as p:
+        with NativeParser(args.uri, part=part, npart=npart,
+                          fmt=args.format) as p:
             for b in p:
                 mx = max(mx, int(b.max_index))
         args.num_features = mx + 1
@@ -114,7 +120,8 @@ def main() -> int:
                           "npart", "uri", "fmt", "epoch") if k in extra}
 
     it = DeviceRowBlockIter(args.uri, part=part, npart=npart, mesh=mesh,
-                            batch_rows=args.batch_rows, dense_dtype="bf16")
+                            fmt=args.format, batch_rows=args.batch_rows,
+                            dense_dtype="bf16")
     epochs = []
     first_batch_devices = None
     shapes = telemetry.gauge("device_distinct_shapes")

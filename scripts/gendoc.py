@@ -36,6 +36,7 @@ MODULE_GROUPS = [
     ]),
     ("Data & I/O", [
         "dmlc_core_tpu.data",
+        "dmlc_core_tpu.data.criteo",
         "dmlc_core_tpu.io.native",
         "dmlc_core_tpu.io.convert",
         "dmlc_core_tpu.io.tls_proxy",

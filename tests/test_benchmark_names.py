@@ -31,10 +31,12 @@ from dmlc_core_tpu.tpu import DeviceRowBlockIter, data_mesh, device_iter
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH = os.path.join(REPO, "benchmarks")
 # the keys of a metric file that name something of the program
-NAME_KEYS = ("histograms", "counter", "spans", "module", "any")
+NAME_KEYS = ("histograms", "counter", "less", "spans", "module", "any")
 # more features than `dense_max_features`: the iterator's layout="auto" then
 # makes CSR batches, as it does of every cell's data
 ROWS, BATCH, FIELDS, CARD = 600, 256, 4, 200
+# the hashed format's feature space: its ids are below 2**HASH_BITS
+HASH_BITS = 10
 
 
 def _json(*path):
@@ -68,13 +70,20 @@ def _cases():
 
 
 def _write_rows(path, fmt):
+    """A small file of the format; returns the URI arguments it needs."""
     rng = np.random.default_rng(0)
     with open(path, "w") as f:
         for i in range(ROWS):
             cols = rng.integers(0, CARD, FIELDS) + CARD * np.arange(FIELDS)
+            if fmt == "criteo":   # 40 cells: the label, 13 + 26, most empty
+                cells = [f"{c}" for c in cols] + [""] * (13 - FIELDS) + \
+                    [f"{c:08x}" for c in cols] + [""] * (26 - FIELDS)
+                f.write("\t".join([f"{i % 2}"] + cells) + "\n")
+                continue
             f.write(f"{i % 2} " + " ".join(
                 f"{j}:{c}:1" if fmt == "libfm" else f"{c}:1"
                 for j, c in enumerate(cols)) + "\n")
+    return f"?hash_bits={HASH_BITS}" if fmt == "criteo" else ""
 
 
 @pytest.fixture(scope="module")
@@ -89,7 +98,7 @@ def program(tmp_path_factory):
         traffic = _json(BENCH, "traffic", cell["traffic"] + ".json")
         fmt = traffic["format"]
         uri = str(work / f"{cell['name']}.{fmt}")
-        _write_rows(uri, fmt)
+        uri += _write_rows(uri, fmt)
         if traffic["store"] == "crec":
             text, uri, fmt = uri, uri + ".crec", "crec"
             assert rows_to_csr_recordio(text, uri,
@@ -97,7 +106,8 @@ def program(tmp_path_factory):
         telemetry.reset()
         device_iter._reset_shape_census()
         mesh = data_mesh(cell["chips"])
-        learner = FMLearner(num_features=FIELDS * CARD, k=4, mesh=mesh)
+        learner = FMLearner(num_features=max(FIELDS * CARD, 1 << HASH_BITS),
+                            k=4, mesh=mesh)
         params = learner.init(0)
         with DeviceRowBlockIter(uri, mesh=mesh, batch_rows=BATCH,
                                 fmt=fmt) as it:
@@ -127,10 +137,12 @@ def _missing(how, runs):
         if not any(h["count"] for r in runs
                    for h in r["snapshot"]["histograms"] if h["name"] == name):
             out.append(f"histogram {name!r} was never observed")
-    if "counter" in how and not any(
-            c["value"] for r in runs for c in r["snapshot"]["counters"]
-            if c["name"] == how["counter"]):
-        out.append(f"counter {how['counter']!r} never rose")
+    # readers/hist_per_counter.py: the rise of `counter` less that of `less`
+    for key in ("counter", "less"):
+        if key in how and not any(
+                c["value"] for r in runs for c in r["snapshot"]["counters"]
+                if c["name"] == how[key]):
+            out.append(f"counter {how[key]!r} never rose")
     for name in how.get("spans", ()):
         # an opened span `x` is the annotation `dmlc.x` of the trace
         if not any(name.removeprefix("dmlc.") in r["spans"] for r in runs):

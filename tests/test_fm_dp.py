@@ -28,7 +28,8 @@ BENCH = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "benchmarks")
 sys.path.insert(0, BENCH)
 
-from harness import check, datagen  # noqa: E402
+from harness import check, datagen, datagen_criteo  # noqa: E402
+from reference import criteo as criteo_rule  # noqa: E402
 from reference import fm as ref  # noqa: E402
 
 F, K, LR, SCALE, SEED = 3000, 4, 0.1, 0.1, 11
@@ -207,25 +208,32 @@ def test_allreduce_bytes_counter_counts_what_the_psums_get(tmp_path, shards):
 
 
 # -- the cells' files and the ladder of the distinct-column list ----------------
-@pytest.mark.parametrize("config,traffic,chips,rung", [
-    ("kdd2012-fm", "libfm", 1, 106496),
-    ("kdd2010b-fm", "libsvm", 1, 262144),
-    ("kdd2012-fm-dp4", "libfm", 4, 106496),
+@pytest.mark.parametrize("config,traffic,chips,rung,nnz_rung", [
+    ("kdd2012-fm", "libfm", 1, 106496, 180224),
+    ("kdd2010b-fm", "libsvm", 1, 262144, 491520),
+    ("kdd2012-fm-dp4", "libfm", 4, 106496, 180224),
+    ("criteo1tb-fm", "tsv", 1, 212992, 589824),
 ])
 def test_an_epoch_of_the_cells_file_lands_on_one_distinct_rung(
-        config, traffic, chips, rung):
+        config, traffic, chips, rung, nnz_rung):
     """A second rung in a cell's epoch is a second compiled shape inside the
     benchmark's window (``compiles_in_window``, limit 0): the generator's
     own rows, shard by shard as the assemblers cut them, say before any
-    chip time whether a file straddles one."""
+    chip time whether a file straddles one, by its distinct columns or by
+    its entries. A hashed configuration's columns are its cells' ids by the
+    plain statement of the format."""
     with open(os.path.join(BENCH, "configs", config + ".json")) as f:
         cfg = json.load(f)
     with open(os.path.join(BENCH, "traffic", traffic + ".json")) as f:
         batches = int(json.load(f)["epoch_batches"])
     R = int(cfg["batch_rows"])
-    counts = []
+    counts, entries = [], []
     carry = None
     for block in datagen.iter_blocks(cfg["data"], 31, batches * chips * R):
+        if cfg.get("format") == "criteo":
+            c = datagen_criteo.cells(cfg["data"], block)
+            block.col = criteo_rule.cell_ids(c.column, c.text, c.lens,
+                                             cfg["hash_bits"])
         if carry is not None:
             block = datagen.concat_blocks([carry, block])
         whole = block.rows // R
@@ -233,6 +241,7 @@ def test_an_epoch_of_the_cells_file_lands_on_one_distinct_rung(
         for i in range(whole):
             lo, hi = ends[i * R], ends[(i + 1) * R]
             counts.append(np.unique(block.col[lo:hi]).size)
+            entries.append(hi - lo)
         carry = block.slice_rows(whole * R, block.rows) \
             if block.rows % R else None
     assert carry is None and len(counts) == batches * chips
@@ -242,3 +251,8 @@ def test_an_epoch_of_the_cells_file_lands_on_one_distinct_rung(
     assert rungs == {rung}, (
         f"{config}: distinct columns a shard {min(counts)} to "
         f"{max(counts)} land on rungs {sorted(rungs)}")
+    fullest = np.array(entries).reshape(batches, chips).max(axis=1)
+    rungs = {nnz_bucket(int(n), 4096) for n in fullest}
+    assert rungs == {nnz_rung}, (
+        f"{config}: entries a shard {min(entries)} to {max(entries)} land "
+        f"on rungs {sorted(rungs)}")

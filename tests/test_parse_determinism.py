@@ -5,7 +5,7 @@ deliver output BYTE-IDENTICAL to a synchronous single-threaded parse:
 reader-stage tiling is a pure function of chunk bytes, workers race only on
 who parses which slice, and the ordered-reassembly stage serves chunks in
 input order. These tests concatenate every per-row/per-feature array across
-blocks for all three text formats plus the binary rec lane and assert exact
+blocks for all four text formats plus the binary rec lane and assert exact
 equality between nthread=1 (threaded=False, the serial reference) and a
 4-worker pipeline with several chunks in flight. Chunks are shrunk via
 DCT_CHUNK_SIZE_KB so the fixtures span many chunks.
@@ -62,6 +62,19 @@ def _libfm_fixture(tmp_path):
     return str(path)
 
 
+def _criteo_fixture(tmp_path):
+    rng = np.random.default_rng(9)
+    path = tmp_path / "det.tsv"
+    with open(path, "w") as f:
+        for i in range(ROWS):
+            ints = ["" if rng.random() < 0.2 else str(rng.integers(0, 5000))
+                    for _ in range(13)]
+            cats = ["" if rng.random() < 0.1 else f"{rng.integers(2**32):08x}"
+                    for _ in range(26)]
+            f.write("\t".join([str(i % 2)] + ints + cats) + "\n")
+    return str(path) + "?format=criteo&hash_bits=25"
+
+
 def _rec_fixture(tmp_path):
     from dmlc_core_tpu.io.convert import rows_to_recordio
     src = _libsvm_fixture(tmp_path)
@@ -88,7 +101,8 @@ def _snapshot(uri, fmt="auto", **kw):
 
 
 FIXTURES = [("libsvm", _libsvm_fixture), ("csv", _csv_fixture),
-            ("libfm", _libfm_fixture), ("rec", _rec_fixture)]
+            ("libfm", _libfm_fixture), ("criteo", _criteo_fixture),
+            ("rec", _rec_fixture)]
 
 
 @pytest.mark.parametrize("name,make", FIXTURES, ids=[f[0] for f in FIXTURES])

@@ -46,16 +46,22 @@ def no_compile_cache():
     cc.reset_cache()
 
 
-# (configuration, chips, planes of the big pack, nnz rung, distinct rung):
-# the rungs tests/test_fm_dp.py finds for an epoch of each cell's file
+# (configuration, chips, planes of the big pack, nnz rung, distinct rung,
+# the temporaries' limit as a share of the tables): the rungs
+# tests/test_fm_dp.py finds for an epoch of each cell's file. criteo1tb-fm
+# has the most entries a batch beside the smallest tables (0.92 GB of
+# temporaries, 2.28 GB of tables: 0.40), so it has a limit of its own and
+# the others keep theirs
 @pytest.mark.slow
-@pytest.mark.parametrize("config,chips,planes,nnz,distinct", [
-    ("kdd2012-fm", 1, 4, 180224, 106496),
-    ("kdd2010b-fm", 1, 3, 491520, 262144),
-    ("kdd2012-fm-dp4", 4, 4, 180224, 106496),
+@pytest.mark.parametrize("config,chips,planes,nnz,distinct,temp_share", [
+    ("kdd2012-fm", 1, 4, 180224, 106496, 0.25),
+    ("kdd2010b-fm", 1, 3, 491520, 262144, 0.25),
+    ("kdd2012-fm-dp4", 4, 4, 180224, 106496, 0.25),
+    ("criteo1tb-fm", 1, 3, 589824, 212992, 0.45),
 ])
 def test_step_compiles_and_fits_beside_the_checks_table(
-        topo, no_compile_cache, config, chips, planes, nnz, distinct):
+        topo, no_compile_cache, config, chips, planes, nnz, distinct,
+        temp_share):
     with open(os.path.join(CONFIGS, config + ".json")) as f:
         cfg = json.load(f)
     mesh = Mesh(np.array(topo.devices[:chips]), ("data",))
@@ -85,8 +91,8 @@ def test_step_compiles_and_fits_beside_the_checks_table(
           f"alias {m.alias_size_in_bytes}")
     assert m.argument_size_in_bytes >= table
     # no third table: the temporaries are the batch's [NNZ, K] and [U, K]
-    # intermediates (0.28 GB and 0.77 GB in the one-chip cells)
-    assert m.temp_size_in_bytes < 0.25 * table
+    # intermediates (0.28, 0.77 and 0.92 GB in the one-chip cells)
+    assert m.temp_size_in_bytes < temp_share * table
     # a quarter of the chip at least, and room for the table the
     # benchmark's check regenerates beside the state
     assert 0.25 * 16e9 < peak < 16e9 - table
