@@ -7,24 +7,29 @@ value_and_grad of the shard loss, apply the update, and jit-cache per batch
 shape. That harness lives here once.
 
 The gradient has one of two forms, chosen when the step is built from what
-the batch and the mesh show (``_takes_row_form``):
+the batch and the model show (``_takes_row_form``):
 
-- table form (the default; every dense batch, every mesh of more than one
-  device): value_and_grad with respect to the parameters, so the gradient
-  is a pytree of the parameters' shapes; on a mesh the (loss, weight, grad)
+- table form (every dense batch; every model without the row hooks):
+  value_and_grad with respect to the parameters, so the gradient is a
+  pytree of the parameters' shapes; on a mesh the (loss, weight, grad)
   triple is psummed once over ICI (the Rabit allreduce equivalent, SURVEY
   §2.5) and ``_apply`` writes the new tables from the old ones and it.
-- row form (a CSR batch on one device, for a model that has the two row
-  hooks): the model gathers the rows its shard reads, value_and_grad is
-  taken of the same ``_shard_loss`` with respect to those rows, and
-  ``_apply_rows`` scatter-adds the gradient's rows into the tables at the
-  shard's distinct columns ``cols``: the gradient as (indices, rows), one
-  row a feature however often the shard names it. No table of the
-  parameters' shape is made beside the parameters in and out, and nothing
-  is reduced: there is one shard. (Across devices the row form would
-  all_gather every shard's rows and scatter all of them on every device;
-  cell kdd2012-fm-dp4.libfm times the all-reduce of the table it would
-  have to beat, PERF.md section 6, PR 28.)
+- row form (a CSR batch, for a model that has the two row hooks): the model
+  gathers the rows its shard reads, value_and_grad is taken of the same
+  ``_shard_loss`` with respect to those rows, and ``_apply_rows``
+  scatter-adds the gradient's rows into the tables at the shard's distinct
+  columns ``cols``: the gradient as (indices, rows), one row a feature
+  however often the shard names it. No table of the parameters' shape is
+  made beside the parameters in and out. On one device nothing is
+  exchanged: there is one shard. On a mesh of several every device does
+  the same for its own shard, then the shards' lists and the rows of their
+  gradients are all-gathered (``[D, U]``, ``[D, U, ...]``: what the shards
+  touched, not a table; loss, weight and what every shard reads whole are
+  psummed beside them) and every replica scatter-adds all of them, in the
+  mesh's order, so the replicas stay bit-identical. A column several
+  shards name is added to once a shard. (Cell kdd2012-fm-dp4.libfm runs
+  it; the all-reduce of the table it replaced took 65 of the step's
+  115 ms there, PERF.md section 6, PR 33.)
 
 The phases of the jitted step carry ``jax.named_scope``s (``dp.unpack``,
 ``dp.loss_grad``, ``dp.allreduce``, ``dp.apply``; the models add their own
@@ -37,8 +42,12 @@ Subclasses implement:
   _apply(params, grads, denom) -> new params
 and may implement, for CSR shards (both or neither):
   _gather_rows(params, shard) -> rows, a pytree that ``_shard_loss`` takes
-      in the place of ``params``
-  _apply_rows(params, cols, row_grads, denom) -> new params
+      in the place of ``params``: leaves ``[U, ...]``, a row a column of
+      the shard's list, and scalars the shard reads whole
+  _apply_rows(params, cols, row_grads, denom) -> new params; ``cols`` is
+      one shard's list ``[U]`` with ``row_grads`` as the rows were, or on a
+      mesh every shard's ``[D, U]`` with leaves ``[D, U, ...]`` and the
+      scalars summed
 """
 
 from __future__ import annotations
@@ -51,14 +60,16 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from dmlc_core_tpu import telemetry
-from dmlc_core_tpu.parallel.varying import mark_varying
+from dmlc_core_tpu.parallel.varying import (gather_unvarying,
+                                            mark_varying)
 from dmlc_core_tpu.tpu.device_iter import unpack_shard
 
 __all__ = ["DataParallelModel"]
 
 
 class DataParallelModel:
-    """Mixin: the shard_map+psum step over packed or named batch trees."""
+    """Mixin: the data-parallel step (``shard_map`` on a mesh, its exchange
+    under ``dp.allreduce``) over packed or named batch trees."""
 
     mesh: Optional[Mesh]
     axis_name: str
@@ -76,12 +87,10 @@ class DataParallelModel:
 
     def _takes_row_form(self, keys) -> bool:
         """Whether a batch tree of these leaves steps in the row form: the
-        model has the hooks, the batch is CSR (the distinct columns
-        ``cols`` travel with it, and no dense ``x``) and there is one
-        device."""
+        model has the hooks and the batch is CSR (the distinct columns
+        ``cols`` travel with it, and no dense ``x``), on any mesh."""
         return (self._gather_rows is not None
-                and "cols" in keys and "x" not in keys
-                and (self.mesh is None or self.mesh.devices.size == 1))
+                and "cols" in keys and "x" not in keys)
 
     def _build_step(self, rows_per_shard: int, keys: tuple):
         axis = self.axis_name
@@ -111,7 +120,8 @@ class DataParallelModel:
                 denom = jnp.maximum(wsum, 1.0)
                 return update(denom), loss_sum / denom
 
-        if self._takes_row_form(keys):
+        row_form = self._takes_row_form(keys)
+        if row_form and (self.mesh is None or self.mesh.devices.size == 1):
             # the benchmark finds the step's module by this name
             # (tests/test_benchmark_names.py holds it, here and below)
             def sharded_step(params, tree):
@@ -141,24 +151,61 @@ class DataParallelModel:
             # typed unvarying, autodiff's transpose psums their cotangent
             # by itself and the explicit psum below would count the
             # gradient once per device
-            loss_sum, wsum, grads = local_grads(
-                mark_varying(params, (axis,)), shard)
-            # ONE reduction per step over ICI — the Rabit allreduce
+            local = mark_varying(params, (axis,))
+            if row_form:
+                with jax.named_scope("dp.loss_grad"):
+                    local = self._gather_rows(local, shard)
+            loss_sum, wsum, grads = local_grads(local, shard)
+            # ONE exchange per step over ICI — the Rabit allreduce
             # equivalent (SURVEY §2.5)
             with jax.named_scope("dp.allreduce"):
                 loss_sum = jax.lax.psum(loss_sum, axis)
                 wsum = jax.lax.psum(wsum, axis)
-                grads = jax.tree.map(lambda g: jax.lax.psum(g, axis), grads)
-            return apply(lambda denom: self._apply(params, grads, denom),
-                         loss_sum, wsum)
+                if row_form:
+                    # every shard's list and the rows of its gradient, in
+                    # the mesh's order and the same on every device; what
+                    # the shards read whole (a scalar) is summed
+                    cols = gather_unvarying(shard["cols"], axis)
+                    grads = jax.tree.map(
+                        lambda g: gather_unvarying(g, axis) if g.ndim
+                        else jax.lax.psum(g, axis), grads)
+
+                    def update(denom):
+                        return self._apply_rows(params, cols, grads, denom)
+                else:
+                    grads = jax.tree.map(lambda g: jax.lax.psum(g, axis),
+                                         grads)
+
+                    def update(denom):
+                        return self._apply(params, grads, denom)
+            return apply(update, loss_sum, wsum)
 
         return jax.jit(sharded_step)
+
+    def _exchange_bytes(self, params, tree, n_dev: int) -> int:
+        """What a step on ``n_dev`` devices hands to its collectives, from
+        the shapes: loss sum and weight sum, and then a gradient of the
+        parameters' shapes (table form) or every shard's list with the
+        rows of its gradient, what the shards read whole counted once (row
+        form). Nothing on one device."""
+        if n_dev == 1:
+            return 0
+        if not self._takes_row_form(tree):
+            return 8 + sum(p.size * p.dtype.itemsize
+                           for p in jax.tree.leaves(params))
+        shard = jax.eval_shape(
+            lambda t: unpack_shard({k: v[0] for k, v in t.items()}), tree)
+        rows = jax.tree.leaves(jax.eval_shape(self._gather_rows, params,
+                                              shard))
+        return 8 + sum(
+            r.dtype.itemsize * (n_dev * r.size if r.ndim else 1)
+            for r in rows + [shard["cols"]])
 
     def step(self, params, batch):
         """One jitted training step on a device batch; returns
         (params, loss)."""
         if getattr(self, "_step_fn", None) is None:
-            self._step_fn = {}
+            self._step_fn, self._step_bytes = {}, {}
         tree = batch.tree()
         D = (tree["aux"].shape[0] if "aux" in tree
              else tree["label"].shape[0])
@@ -177,10 +224,7 @@ class DataParallelModel:
                 batch.rows_per_shard, tuple(sorted(tree.keys())))
             telemetry.counter("model_step_builds_total",
                               {"model": type(self).__name__}).inc()
-            # what a mesh step hands to its psums: loss sum, weight sum and
-            # a gradient of the parameters' shapes; nothing on one device
-            self._allreduce_bytes = 0 if n_dev == 1 else 8 + sum(
-                p.size * p.dtype.itemsize for p in jax.tree.leaves(params))
+            self._step_bytes[sig] = self._exchange_bytes(params, tree, n_dev)
         if not telemetry.enabled():
             return fn(params, tree)
         # model.step: the host's hand-over of one step to the runtime (the
@@ -195,8 +239,8 @@ class DataParallelModel:
             telemetry.counter("model_step_row_updates_total",
                               {"model": type(self).__name__}).inc()
         # with the device time under scope dp.allreduce, the exchange's rate
-        if self._allreduce_bytes:
+        if self._step_bytes[sig]:
             telemetry.counter("model_step_allreduce_bytes_total",
                               {"model": type(self).__name__}).inc(
-                                  self._allreduce_bytes)
+                                  self._step_bytes[sig])
         return out

@@ -6,10 +6,12 @@ wormhole/difacto lineage) on `label field:feature:value` rows; like the
 linear learner it ships no model itself. This module is that consumer,
 TPU-native: second-order FM over PaddedBatch CSR shards (or DenseBatch
 matrices, where the interaction term becomes two MXU matmuls),
-data-parallel under ``shard_map`` with one psum per step on a mesh of
-several devices. On one device a CSR step keeps the gradient in the rows
-the batch gathered and scatter-adds it straight into ``w`` and ``v``
-(the row form of models/_dp.py): no ``[F, K]`` gradient table is made.
+data-parallel under ``shard_map`` on a mesh of several devices. A CSR step
+keeps the gradient in the rows the batch gathered and scatter-adds it
+straight into ``w`` and ``v`` (the row form of models/_dp.py): no ``[F, K]``
+gradient table is made, and on a mesh the shards exchange those rows (an
+all-gather of every shard's distinct columns and the rows of its gradient)
+where a dense step all-reduces a gradient of the tables' shape.
 A feature is gathered and scattered once a batch: the rows are those at
 the shard's DISTINCT columns (``cols``, which every assembler sends:
 tpu/device_iter.py col_slots), expanded to the entries by ``slot``.
@@ -209,13 +211,22 @@ class FMLearner(DataParallelModel):
         tables and is dropped (it repeats one id, so the scatter is told
         its indices are sorted and no more; the hint of uniqueness bought
         nothing on the chip: PERF.md section 6, PR 31). Weight decay still
-        reaches every row."""
+        reaches every row.
+
+        On a mesh ``cols`` is ``[D, U]`` and the rows ``[D, U, ...]``:
+        every shard's list with its gradient's rows, shard after shard.
+        The lists go in as one: it ascends within a shard's stretch only,
+        so nothing is promised of its order, and a column that several
+        shards name is added to once a shard, with a rounding each."""
         lr, l2 = self.learning_rate, self.l2
 
         def update(table, grad):
             table = table if l2 == 0 else (1.0 - lr * l2) * table
-            return table.at[cols].add(-lr * (grad / denom),
-                                      indices_are_sorted=True)
+            if cols.ndim == 1:
+                return table.at[cols].add(-lr * (grad / denom),
+                                          indices_are_sorted=True)
+            return table.at[cols.reshape(-1)].add(
+                -lr * (grad.reshape((-1,) + table.shape[1:]) / denom))
         return FMParams(b=params.b - lr * g.b / denom,
                         w=update(params.w, g.w), v=update(params.v, g.v))
 

@@ -1116,12 +1116,13 @@ METRIC_HELP: Dict[str, str] = {
         "jitted step functions built, one per new batch signature, by "
         "model class",
     "model_step_row_updates_total":
-        "steps that took the row form (a CSR batch on one device): the "
-        "gradient stays in the batch's rows, by model class",
+        "steps that took the row form (a CSR batch, on one device or on a "
+        "mesh): the gradient stays in the batch's rows, by model class",
     "model_step_allreduce_bytes_total":
-        "bytes handed to the psums of the mesh step (loss sum, weight sum, "
-        "a gradient of the parameters' shapes), by model class; 0 on one "
-        "device",
+        "bytes handed to the collectives of the mesh step (loss sum, weight "
+        "sum, and every shard's distinct columns with the rows of its "
+        "gradient or, in the table form, a gradient of the parameters' "
+        "shapes), by model class; 0 on one device",
     "device_put_failures_total": "device_put calls that raised",
     "device_host_q_depth": "staged host batches queued for transfer",
     "device_ready_q_depth": "device batches queued for the consumer",

@@ -10,9 +10,10 @@ the metric files, never spelt out, and looked up in one real run on the CPU
 for each cell of ``BENCHMARK.json``: a small file of the cell's format (as
 text, or converted to ``.crec`` where the traffic file stores it so) through
 ``DeviceRowBlockIter`` for two epochs and ``FMLearner.step`` on as many
-devices as the cell has chips (one: the row form of the step; four: the table
-form). A metric that ``BENCHMARK.json`` promises a cell has to be found in
-that cell's run; a metric file it lists for no cell yet (the proposals under
+devices as the cell has chips (one: the row form of the step; four: the row
+form under ``shard_map``, with its exchange under ``dp.allreduce``). A metric
+that ``BENCHMARK.json`` promises a cell has to be found in that cell's run; a
+metric file it lists for no cell yet (the proposals under
 ``benchmarks/tests/``) in some cell's.
 """
 
@@ -37,6 +38,18 @@ NAME_KEYS = ("histograms", "counter", "less", "spans", "module", "any")
 ROWS, BATCH, FIELDS, CARD = 600, 256, 4, 200
 # the hashed format's feature space: its ids are below 2**HASH_BITS
 HASH_BITS = 10
+
+
+# proposed metric files (listed for no cell) whose names no cell's program
+# has any more; a PR that may not edit ``benchmarks/`` says why here, and the
+# case fails again, loudly, the day the name comes back
+NOTHING_TO_READ = {
+    "fm_step.grad_table_ms.json":
+        "reads transpose(jvp(fm.gather)), the scatter into a gradient of the "
+        "tables' shape, which only the table form of a CSR step lowers; "
+        "since ISSUE 33 every cell steps in the row form, on the mesh too. "
+        "The gate PR repoints or drops the file (PERF.md section 7)",
+}
 
 
 def _json(*path):
@@ -64,8 +77,11 @@ def _cases():
              if cell["name"] in m.get("workloads", [cell["name"]])
              and _names_something(m["name"] + ".json")]
     listed = {f for _, f in cases}
-    return cases + [(None, f) for f in sorted(os.listdir(
-        os.path.join(BENCH, "metrics")))
+    return cases + [
+        pytest.param(None, f, marks=pytest.mark.xfail(
+            strict=True, reason=NOTHING_TO_READ[f]))
+        if f in NOTHING_TO_READ else (None, f)
+        for f in sorted(os.listdir(os.path.join(BENCH, "metrics")))
         if f.endswith(".json") and f not in listed and _names_something(f)]
 
 

@@ -498,13 +498,15 @@ def test_jitted_step_carries_the_named_scopes(tmp_path, model, mesh_devices):
         assert "dp.allreduce" not in text
     # the backward of a model scope nests under dp.loss_grad
     inner = "fm.gather" if model == "fm" else "linear.margin"
-    if model == "fm" and mesh is None:
-        # row form (a CSR batch on one device): the gathers are not
-        # differentiated, the rest of the margin is, and the gradient's
-        # rows are scattered into the tables under dp.apply
+    if model == "fm":
+        # row form (a CSR batch, with or without a mesh): the gathers are
+        # not differentiated, the rest of the margin is, and the gradient's
+        # rows are scattered into the tables under dp.apply; on a mesh the
+        # shards' rows are gathered under dp.allreduce first
         assert f"transpose(jvp({inner}))" not in text
         assert "dp.loss_grad/transpose(jvp(fm.interaction))" in text
         assert "dp.apply/scatter-add" in text
+        assert ("dp.allreduce/all_gather" in text) == (mesh is not None)
     else:
         assert f"dp.loss_grad/transpose(jvp({inner}))" in text
     # predict carries its own scope

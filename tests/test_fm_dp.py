@@ -1,14 +1,19 @@
-"""The mesh step of the FM (models/_dp.py: table form, shard_map, one psum of
-loss, weight and gradient a step) against the benchmark's plain reference
-(benchmarks/reference/fm.py: FM by its definition on the global batch), as
-cell kdd2012-fm-dp4.libfm compares them on the chip, here at a small size on
-2, 4 and 8 host devices: losses, first gradient norms and change norms after
-three steps inside that cell's own limits, shards of unequal weight, a
-shard of padding rows alone and rows that all name one of three columns
-included; the replicas bit-identical after every step;
-``model_step_allreduce_bytes_total`` counting what the psums are handed; and
-the benchmark's three configurations landing every batch of an epoch on one
-rung of the distinct-column list (ISSUE 31)."""
+"""The mesh step of the FM (models/_dp.py: for CSR batches the row form under
+shard_map, an all-gather of every shard's distinct columns and of the rows of
+its gradient and one scatter-add of them all on every replica; the table
+form, with one psum of a gradient of the parameters' shapes, for dense
+batches and models without the row hooks) against the benchmark's plain
+reference (benchmarks/reference/fm.py: FM by its definition on the global
+batch), as cell kdd2012-fm-dp4.libfm compares them on the chip, here at a
+small size on 2, 4 and 8 host devices: losses, first gradient norms and
+change norms after three steps inside that cell's own limits, shards of
+unequal weight, a shard of padding rows alone and rows that all name one of
+three columns included; the replicas bit-identical after every step; a
+column that every shard names updated by each shard's gradient once; the
+lowered step free of collectives and intermediates of a table's shape; which
+steps count as row updates; ``model_step_allreduce_bytes_total`` counting
+what the collectives are handed; and the benchmark's configurations landing
+every batch of an epoch on one rung of the distinct-column list (ISSUE 31)."""
 
 import json
 import os
@@ -20,7 +25,7 @@ import pytest
 import jax
 
 from dmlc_core_tpu import telemetry
-from dmlc_core_tpu.models import FMLearner
+from dmlc_core_tpu.models import FMLearner, LinearLearner
 from dmlc_core_tpu.tpu.device_iter import DeviceRowBlockIter, nnz_bucket
 from dmlc_core_tpu.tpu.sharding import data_mesh
 
@@ -58,11 +63,11 @@ def limits():
         return json.load(f)["limits"]
 
 
-def write_rows(path, rows, nnz_of, few=0):
+def write_rows(path, rows, nnz_of, few=0, fmt="libfm"):
     """Seeded libfm rows with distinct features within a row and values a
     text round trip keeps; returns them as the reference takes them. With
     ``few``, a row's first feature is one of the first ``few`` and the
-    others lie above them."""
+    others lie above them. As ``libsvm`` the same rows without fields."""
     rng = np.random.default_rng(SEED)
     lens = np.array([nnz_of(r) for r in range(rows)])
     label = rng.integers(0, 2, size=rows).astype(np.float32)
@@ -79,8 +84,10 @@ def write_rows(path, rows, nnz_of, few=0):
     with open(path, "w") as f:
         at = 0
         for r in range(rows):
-            feats = " ".join(f"{i % 7}:{c}:{v}" for i, (c, v) in enumerate(
-                zip(col[at:at + lens[r]], val[at:at + lens[r]])))
+            feats = " ".join(
+                f"{i % 7}:{c}:{v}" if fmt == "libfm" else f"{c}:{v}"
+                for i, (c, v) in enumerate(
+                    zip(col[at:at + lens[r]], val[at:at + lens[r]])))
             f.write(f"{int(label[r])} {feats}\n")
             at += lens[r]
     return label, lens, col, val
@@ -191,18 +198,167 @@ def test_hot_shard_sets_the_bucket_and_padding_shard_is_empty(tmp_path):
     assert (np.asarray(batch.weight)[3] == 0).all()
 
 
+def one_batch(uri, shards, fmt="libfm", **kw):
+    mesh = data_mesh(shards) if shards else None
+    with DeviceRowBlockIter(uri, mesh=mesh, batch_rows=BATCH, fmt=fmt,
+                            min_nnz_bucket=64, **kw) as it:
+        return mesh, next(iter(it))
+
+
+@pytest.mark.parametrize("shards", [2, 4, 8])
+def test_a_column_every_shard_names_gets_each_shards_gradient_once(
+        tmp_path, shards):
+    """Every row names column 0: every shard's list holds it, so the
+    gathered list holds it once a shard, and its rows of ``w`` and ``v``
+    have to move by the sum of the shards' gradients, as they do when one
+    device steps the same rows as one batch."""
+    uri = str(tmp_path / "rows.libfm")
+    write_rows(uri, BATCH, lambda r: 4, few=1)
+    got = {}
+    for n in (0, shards):
+        mesh, batch = one_batch(uri, n)
+        cols = np.asarray(batch.tree()["cols"])
+        assert (cols[:, 0] == 0).all() and cols.shape[0] == max(n, 1)
+        learner = FMLearner(F, k=K, mesh=mesh, learning_rate=LR,
+                            init_scale=SCALE)
+        p0 = learner.init(SEED)
+        p1, loss = learner.step(p0, batch)
+        got[n] = jax.tree.map(np.asarray, p1), float(loss), \
+            jax.tree.map(np.asarray, p0)
+    (one, loss_one, p0), (many, loss_many, _) = got[0], got[shards]
+    assert loss_many == pytest.approx(loss_one, rel=1e-6)
+    for leaf in ("w", "v"):
+        a, b, start = (getattr(t, leaf) for t in (many, one, p0))
+        step0 = np.abs(b[0] - start[0]).max()
+        assert step0 > 0
+        # a shard's share of the step is about 1/shards of it: one left out
+        # or counted twice is a hundred thousand times this tolerance
+        assert np.abs(a[0] - b[0]).max() <= 1e-5 * step0, leaf
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-7)
+    np.testing.assert_allclose(many.b, one.b, rtol=1e-5, atol=1e-8)
+
+
+def lowered_ops(lowered):
+    """(op name, [(shape, element type) of each result], location) of every
+    operation of the lowered module."""
+    from jaxlib.mlir import ir
+    found = []
+
+    def visit(op):
+        found.append((op.name, [(tuple(r.type.shape), str(r.type.element_type))
+                                for r in op.results
+                                if isinstance(r.type, ir.RankedTensorType)],
+                      str(op.location)))
+        return ir.WalkResult.ADVANCE
+
+    lowered.compiler_ir(dialect="stablehlo").operation.walk(visit)
+    return found
+
+
+COLLECTIVES = ("stablehlo.all_reduce", "stablehlo.all_gather")
+
+
+@pytest.mark.parametrize("shards", [2, 4])
+def test_mesh_step_makes_and_exchanges_nothing_of_a_tables_shape(
+        tmp_path, shards):
+    uri = str(tmp_path / "rows.libfm")
+    write_rows(uri, BATCH, lambda r: 6)
+    mesh, batch = one_batch(uri, shards)
+    tree = batch.tree()
+    U = tree["cols"].shape[1]
+    learner = FMLearner(F, k=K, mesh=mesh)
+    step = learner._build_step(batch.rows_per_shard, tuple(sorted(tree)))
+    assert step.__name__ == "sharded_step"   # the benchmark's name for it
+    lowered = step.lower(learner.init(SEED), tree)
+    ops = lowered_ops(lowered)
+    # of a table's shape: the two scatter-adds under dp.apply and the
+    # shard_map's own results, the parameters out; no gradient table
+    made = [(name, loc) for name, results, loc in ops
+            if any(shape in {(F,), (F, K)} for shape, _ in results)]
+    assert sorted(name for name, _ in made) == \
+        ["sdy.manual_computation"] + ["stablehlo.scatter"] * 2, made
+    for name, loc in made:
+        if name == "stablehlo.scatter":
+            assert "dp.apply" in loc and "scatter-add" in loc, loc
+    assert "transpose(jvp(fm.gather))" not in lowered.as_text(debug_info=True)
+    # the collectives, all under dp.allreduce: three scalars summed, and
+    # the shards' lists and rows gathered, [U] a shard to [shards, U]
+    exchanged = [(name, results, loc) for name, results, loc in ops
+                 if name in COLLECTIVES]
+    assert all("dp.allreduce" in loc for _, _, loc in exchanged), exchanged
+    assert sorted((name, results[0]) for name, results, _ in exchanged) == \
+        sorted([("stablehlo.all_reduce", ((), "f32"))] * 3 + [
+            ("stablehlo.all_gather", ((shards, U), "i32")),
+            ("stablehlo.all_gather", ((shards, U), "f32")),
+            ("stablehlo.all_gather", ((shards, U, K), "f32"))]), exchanged
+
+
+def row_updates(model):
+    return telemetry.counter("model_step_row_updates_total",
+                             {"model": model}).value
+
+
+@pytest.mark.parametrize("what", ["fm-csr", "fm-dense", "linear-csr"])
+def test_which_mesh_steps_take_the_row_form(tmp_path, what):
+    """By what the step can see: the batch's leaves and the model's hooks.
+    A dense batch has no columns and the linear learner no row hooks: both
+    keep the table form on a mesh, with its all-reduce of a gradient of the
+    parameters' shapes, and count as no row update."""
+    uri = str(tmp_path / "rows.libsvm")
+    write_rows(uri, 2 * BATCH, lambda r: 6, fmt="libsvm")
+    dense = what == "fm-dense"
+    mesh, batch = one_batch(
+        uri, 4, "libsvm", **(dict(layout="dense", dense_dtype="float32")
+                             if dense else dict(layout="csr")))
+    tree = batch.tree()
+    assert ("x" in tree) == dense and ("cols" in tree) == (not dense)
+    learner = (LinearLearner(F, mesh=mesh) if what == "linear-csr"
+               else FMLearner(F, k=K, mesh=mesh))
+    name = type(learner).__name__
+    rows = what == "fm-csr"
+    assert learner._takes_row_form(tree) == rows
+    params = learner.init()
+    telemetry.enable(True)
+    before = row_updates(name)
+    for i in range(3):
+        params, loss = learner.step(params, batch)
+        assert row_updates(name) - before == (i + 1 if rows else 0)
+    assert np.isfinite(float(loss))
+    exchanged = [(name, results[0][0]) for name, results, _ in lowered_ops(
+        next(iter(learner._step_fn.values())).lower(params, tree))
+        if name in COLLECTIVES]
+    table = (F, K) if name == "FMLearner" else (F,)
+    assert (("stablehlo.all_reduce", table) in exchanged) == (not rows)
+    assert ("stablehlo.all_gather" in dict(exchanged)) == rows
+
+
+@pytest.mark.parametrize("form", ["rows", "table"])
 @pytest.mark.parametrize("shards", [1, 2, 4, 8])
-def test_allreduce_bytes_counter_counts_what_the_psums_get(tmp_path, shards):
+def test_allreduce_bytes_counter_counts_what_the_collectives_get(
+        tmp_path, shards, form):
+    """Row form (the FM's CSR batches): loss sum, weight sum and ``b``'s
+    gradient summed, and every shard's list ``[U]`` int32 gathered with the
+    rows of its gradient, ``[U]`` and ``[U, K]`` float32. Table form (the
+    linear learner's): loss sum and weight sum, and a gradient of the
+    parameters' shapes."""
     uri = str(tmp_path / "rows.libfm")
     write_rows(uri, STEPS * BATCH, lambda r: 6)
+    mesh, batch = one_batch(uri, shards, layout="csr")
+    U = batch.tree()["cols"].shape[1]
+    if form == "rows":
+        learner = FMLearner(F, k=K, mesh=mesh)
+        a_step = 12 + shards * U * (K + 2) * 4
+    else:
+        learner = LinearLearner(F, mesh=mesh)
+        a_step = 8 + 4 * (1 + F)
+    telemetry.enable(True)
     counter = telemetry.counter("model_step_allreduce_bytes_total",
-                                {"model": "FMLearner"})
-    # loss sum and weight sum, and a gradient of the parameters' shapes
-    a_step = 8 + 4 * (1 + F + F * K)
+                                {"model": type(learner).__name__})
+    params = learner.init()
     seen = [counter.value]
-    for _, loss in program_steps(uri, shards):
-        if loss is not None:
-            seen.append(counter.value)
+    for _ in range(STEPS):
+        params, _ = learner.step(params, batch)
+        seen.append(counter.value)
     rises = set(np.diff(seen))
     assert rises == ({0} if shards == 1 else {a_step}), rises
 

@@ -7,6 +7,7 @@ worker that gets this file loads the TPU's library."""
 
 import json
 import os
+import re
 
 import numpy as np
 import pytest
@@ -79,7 +80,7 @@ def test_step_compiles_and_fits_beside_the_checks_table(
                                         sharding=row),
             "cols": jax.ShapeDtypeStruct((chips, distinct), jnp.int32,
                                          sharding=row)}
-    assert learner._takes_row_form(tree) == (chips == 1)
+    assert learner._takes_row_form(tree)   # on one chip and on the mesh
     compiled = learner._build_step(R, tuple(sorted(tree))).lower(
         params, tree).compile()
     m = compiled.memory_analysis()
@@ -97,11 +98,37 @@ def test_step_compiles_and_fits_beside_the_checks_table(
     # benchmark's check regenerates beside the state
     assert 0.25 * 16e9 < peak < 16e9 - table
     text = compiled.as_text()
-    # the tables are updated at the distinct columns alone: every scatter
-    # into a table is told its indices ascend, as that list's do
+    # the tables are updated at the distinct columns alone, one scatter a
+    # table. One shard's list ascends and the scatter is told so; the four
+    # shards' lists go in as one, which ascends within a shard's stretch
+    # only, so nothing is promised of it: the compiler sorts the list
+    # itself for the scatter into w, and not for the one into v
     into_tables = [line for line in text.splitlines()
                    if " scatter(" in line and f"= f32[{F}" in line]
     assert len(into_tables) == 2, into_tables
+    sorts_of_the_list = [line for line in text.splitlines()
+                         if " sort(" in line and "dp.apply/scatter-add" in line]
     for line in into_tables:
-        assert "indices_are_sorted=true" in line, line
-    assert ("dp.allreduce/psum" in text) == (chips == 4)
+        assert "dp.apply/scatter-add" in line, line
+        # on the mesh a scatter reads as sorted only where the compiler
+        # sorted the list itself
+        assert ("indices_are_sorted=true" in line) == (
+            chips == 1 or (f"= f32[{F}]" in line and bool(sorts_of_the_list)))
+    assert not (chips == 1 and sorts_of_the_list), sorts_of_the_list
+    # the mesh step sums three scalars and gathers the shards' lists and
+    # rows: no collective, and nothing else but the parameters in and out
+    # and the scatters' copies of them, has a table's shape
+    collectives = [line for line in text.splitlines()
+                   if re.search(r" all-(reduce|gather)(-start)?\(", line)]
+    assert bool(collectives) == (chips == 4)
+    for line in collectives:
+        assert f"[{F}" not in line, line
+        assert "dp.allreduce" in line, line
+    if chips == 4:
+        # three gathers, of every shard's list, w rows and v rows (the
+        # compiler reshapes them: count elements)
+        sizes = sorted(
+            int(np.prod([int(n) for n in re.search(
+                r"= [a-z0-9]+\[([0-9,]+)\]", line).group(1).split(",")]))
+            for line in collectives if " all-gather" in line)
+        assert sizes == [chips * distinct] * 2 + [chips * distinct * K], sizes
