@@ -115,7 +115,10 @@ bool PaddedBatcher::NextMeta(uint64_t* take, uint64_t* bucket,
     max_shard = std::max(max_shard, shard_nnz);
     batch_nnz_ += shard_nnz;
   }
-  bucket_ = NnzBucket(max_shard, min_bucket_);
+  const uint64_t own = NnzBucket(max_shard, min_bucket_);
+  // a short last batch takes no rung below the batch before it
+  bucket_ = TailRung(own, prev_bucket_, take_, batch_rows_);
+  lifted_ = bucket_ != own;
   staged_ = true;
   *take = take_;
   *bucket = bucket_;
@@ -347,6 +350,10 @@ void PaddedBatcher::FillPacked(int32_t* big, int32_t kb, void* val,
   // entries' zeros already read slot 0
   slots_.Run(big + bucket_, static_cast<uint64_t>(kb) * bucket_,
              written.data(), num_shards_);
+  const uint64_t own = slots_.Capacity(min_bucket_);
+  cols_cap_ = TailRung(own, prev_cols_, take_, batch_rows_);
+  lifted_ = lifted_ || cols_cap_ != own;
+  prev_cols_ = cols_cap_;
   FillRowWisePacked(aux, ka, nrows);
   Consume();
 }
@@ -453,6 +460,7 @@ void PaddedBatcher::Consume() {
     }
   }
   avail_rows_ -= take_;
+  prev_bucket_ = bucket_;
   staged_ = false;
 }
 
@@ -463,6 +471,7 @@ void PaddedBatcher::BeforeFirst() {
   avail_rows_ = 0;
   done_ = false;
   staged_ = false;
+  prev_bucket_ = prev_cols_ = 0;  // the tail rule looks back within an epoch
   // max_index_ deliberately survives reset: the dense/csr layout choice must
   // stay sticky across epochs so device shapes remain static
 }

@@ -46,7 +46,8 @@ class PaddedBatcher {
 
   // Stage the next batch. Returns false at end of data. On success:
   //   *take      true (unpadded) row count, <= batch_rows
-  //   *bucket    per-shard nnz capacity (NnzBucket of the max shard nnz)
+  //   *bucket    per-shard nnz capacity (NnzBucket of the max shard nnz;
+  //              for a short last batch no lower than the batch before it)
   //   *max_index running max feature id (drives the dense/csr auto choice)
   //   *has_qid   1 when any parsed block carried query/group ids
   //   *has_field 1 when any parsed block carried per-nonzero field ids
@@ -91,9 +92,13 @@ class PaddedBatcher {
                   int32_t* aux, int32_t ka, int32_t* nrows);
   // The distinct-column lists of the batch FillPacked last wrote: their
   // capacity (the ladder rung of the fullest shard's count, same floor as
-  // the nnz bucket), the batch's count of distinct columns, and the
-  // [D, capacity] lists themselves, padded as col_slots.h says.
-  uint64_t ColsCapacity() const { return slots_.Capacity(min_bucket_); }
+  // the nnz bucket; a short last batch's no lower than the batch before
+  // it, nnz_bucket.h TailRung), the batch's count of distinct columns, and
+  // the [D, capacity] lists themselves, padded as col_slots.h says.
+  uint64_t ColsCapacity() const { return cols_cap_; }
+  // Whether that batch was a short one sent at the rungs of the batch
+  // before it, its own being lower (either capacity).
+  bool TailLifted() const { return lifted_; }
   uint64_t ColsDistinct() const { return slots_.Distinct(); }
   void FillCols(int32_t* cols, uint64_t cap) const {
     slots_.Write(cols, cap);
@@ -177,6 +182,11 @@ class PaddedBatcher {
   uint64_t batch_nnz_ = 0;
   bool staged_ = false;
   ColSlots slots_;  // of the batch FillPacked last wrote
+  uint64_t cols_cap_ = 0;     // its lists' capacity
+  bool lifted_ = false;       // the staged batch took the rungs before it
+  // rungs of the batch before, this epoch (0: none), for TailRung
+  uint64_t prev_bucket_ = 0;
+  uint64_t prev_cols_ = 0;
 };
 
 }  // namespace dct

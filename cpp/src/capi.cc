@@ -676,6 +676,13 @@ int dct_nnz_bucket(uint64_t n, uint64_t floor, uint64_t* out) {
   return Guard([&] { *out = dct::NnzBucket(n, floor); });
 }
 
+// The rule for a part's short last batch (nnz_bucket.h TailRung), exported
+// for the same test.
+int dct_tail_rung(uint64_t own, uint64_t before, uint64_t take,
+                  uint64_t batch_rows, uint64_t* out) {
+  return Guard([&] { *out = dct::TailRung(own, before, take, batch_rows); });
+}
+
 // The rule the `criteo` format hashes a cell by (criteo_hash.h), exported so
 // a test can hold the Python statements of it equal.
 int dct_criteo_id(uint32_t column, const char* cell, uint64_t len,
@@ -766,11 +773,12 @@ int dct_batcher_batch_nnz(dct_batcher_t h, uint64_t* out) {
 // The distinct-column lists of the batch fill_packed last wrote
 // (col_slots.h): capacity and count first, then the [D, cap] lists.
 int dct_batcher_cols_meta(dct_batcher_t h, uint64_t* cap,
-                          uint64_t* distinct) {
+                          uint64_t* distinct, int* tail_lifted) {
   return Guard([&] {
     auto* b = static_cast<dct::PaddedBatcher*>(h);
     *cap = b->ColsCapacity();
     *distinct = b->ColsDistinct();
+    *tail_lifted = b->TailLifted() ? 1 : 0;
   });
 }
 
@@ -926,11 +934,13 @@ int dct_csrrec_batch_nnz(dct_csrrec_t h, uint64_t* out) {
       [&] { *out = static_cast<dct::CsrRecBatcher*>(h)->BatchNnz(); });
 }
 
-int dct_csrrec_cols_meta(dct_csrrec_t h, uint64_t* cap, uint64_t* distinct) {
+int dct_csrrec_cols_meta(dct_csrrec_t h, uint64_t* cap, uint64_t* distinct,
+                         int* tail_lifted) {
   return Guard([&] {
     auto* b = static_cast<dct::CsrRecBatcher*>(h);
     *cap = b->ColsCapacity();
     *distinct = b->ColsDistinct();
+    *tail_lifted = b->TailLifted() ? 1 : 0;
   });
 }
 

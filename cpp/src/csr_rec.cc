@@ -197,6 +197,12 @@ uint64_t CsrRecBatcher::FillPacked(int32_t* big, int32_t kb, int32_t* aux,
   // the col planes become the slot planes (col_slots.h); the padded
   // entries' zeros already read slot 0
   slots_.Run(t.col, t.nnz_stride, shard_nnz_.data(), num_shards_);
+  // the nnz bucket is the file's; a short last batch's list takes no rung
+  // below the batch before it (nnz_bucket.h TailRung)
+  const uint64_t own = slots_.Capacity(min_bucket_);
+  cols_cap_ = TailRung(own, prev_cols_, filled, batch_rows_);
+  lifted_ = cols_cap_ != own;
+  prev_cols_ = cols_cap_;
   return filled;
 }
 
@@ -345,6 +351,7 @@ void CsrRecBatcher::BeforeFirst() {
   nnz_in_rec_ = 0;
   rec_rows_ = 0;
   rec_nnz_ = 0;
+  prev_cols_ = 0;  // the tail rule looks back within an epoch
   // flags/bucket deliberately survive: device shapes stay static across
   // epochs (dense_rec.cc rule)
 }

@@ -72,7 +72,8 @@ class CsrRecBatcher {
   // The distinct-column lists of the batch FillPacked last wrote, as
   // PaddedBatcher has them: capacity, count, the [num_shards, capacity]
   // lists.
-  uint64_t ColsCapacity() const { return slots_.Capacity(min_bucket_); }
+  uint64_t ColsCapacity() const { return cols_cap_; }
+  bool TailLifted() const { return lifted_; }
   uint64_t ColsDistinct() const { return slots_.Distinct(); }
   void FillCols(int32_t* cols, uint64_t cap) const {
     slots_.Write(cols, cap);
@@ -136,6 +137,9 @@ class CsrRecBatcher {
   uint64_t batch_nnz_ = 0;
   std::vector<uint64_t> shard_nnz_;  // real entries of each shard, last fill
   ColSlots slots_;                   // of the batch FillPacked last wrote
+  uint64_t cols_cap_ = 0;            // its lists' capacity (TailRung)
+  uint64_t prev_cols_ = 0;           // of the batch before, this epoch
+  bool lifted_ = false;
 
   bool have_record_ = false;
   bool eof_ = false;

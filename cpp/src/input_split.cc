@@ -15,6 +15,7 @@
 
 #include "numparse.h"
 #include "recordio.h"
+#include "telemetry.h"
 
 namespace dct {
 
@@ -215,14 +216,25 @@ size_t ByteSplit::ReadSpan(char* buf, size_t want) {
       prev_byte_ = '\n';
       continue;
     }
-    if (cur_stream_ == nullptr) {
-      cur_stream_.reset(FileSystem::GetInstance(files_[file_idx_].path)
-                            ->OpenForRead(files_[file_idx_].path));
+    // The split over objects (doc/observability.md): an open that the
+    // reader waits out. Nothing is in flight when the part's first object
+    // is opened after BeforeFirst, nor when the next one is after the
+    // current one is drained; split_open_us is that wait, from here to the
+    // first byte read from the new stream.
+    uint64_t open_start = 0;
+    const bool opening = cur_stream_ == nullptr;
+    if (opening) {
+      if (telemetry::Enabled()) open_start = telemetry::NowUs();
+      const URI& path = files_[file_idx_].path;
+      cur_stream_.reset(FileSystem::GetInstance(path)->OpenForRead(path));
       cur_stream_->Seek(local_pos_);
       // this partition never reads past end_ in this file: a readahead
       // stream must not prefetch a window past the partition edge
       cur_stream_->HintReadBound(std::min(
           files_[file_idx_].size, end_ - file_start_[file_idx_]));
+      bytes_read_ = telemetry::GetCounter(
+          "split_bytes_read_total",
+          {{"scheme", path.scheme.empty() ? "file" : path.scheme}});
     }
     size_t to_read = std::min(
         {want - got, files_[file_idx_].size - local_pos_, end_ - global});
@@ -230,6 +242,18 @@ size_t ByteSplit::ReadSpan(char* buf, size_t want) {
     DCT_CHECK_GT(n, size_t(0))
         << "file " << files_[file_idx_].path.Str()
         << " shorter than listed size";
+    if (opening) {
+      static telemetry::Counter* opened =
+          telemetry::GetCounter("split_objects_opened_total");
+      opened->Add(1);
+      if (open_start != 0) {
+        static telemetry::Hist* open_us = telemetry::GetHist("split_open_us");
+        const uint64_t dur = telemetry::NowUs() - open_start;
+        open_us->Observe(dur);
+        telemetry::EmitSpan("split.open", open_start, dur, file_idx_);
+      }
+    }
+    bytes_read_->Add(n);
     local_pos_ += n;
     got += n;
     prev_byte_ = buf[got - 1];

@@ -24,6 +24,7 @@
 #include "filesys.h"
 #include "pipeline.h"
 #include "stream.h"
+#include "telemetry.h"
 
 namespace dct {
 
@@ -148,6 +149,8 @@ class ByteSplit : public InputSplit, public RecordChunkSource {
   size_t file_idx_ = 0;
   size_t local_pos_ = 0;  // position within current file
   std::unique_ptr<SeekStream> cur_stream_;
+  // split_bytes_read_total{scheme=} of the open stream's scheme
+  telemetry::Counter* bytes_read_ = nullptr;
   char prev_byte_ = '\n';  // last byte read from current file
   bool pending_newline_ = false;
 

@@ -11,9 +11,19 @@
 // under min(floor, 128) entries, so small shapes and 128-entry lane rows
 // stay whole.
 //
-// Stated twice, here and in dmlc_core_tpu/tpu/device_iter.py (nnz_bucket,
-// whose docstring has the trade); tests/test_nnz_bucket.py holds the two
-// equal.
+// A part's short last batch. A byte-range part of a data set never holds a
+// whole number of batches, so the last batch of every epoch has fewer real
+// rows than batch_rows. Its rows are padded, but its own counts would land
+// on lower rungs: a second compiled shape for one batch an epoch. So a
+// batch with fewer real rows than batch_rows takes no rung below that of
+// the batch before it in the same epoch (TailRung, for the nnz capacity and
+// for the distinct-column list alike); the fill is the padding the ladder
+// already uses. A full batch keeps its own rung, and so does a short batch
+// with none before it.
+//
+// Both stated twice, here and in dmlc_core_tpu/tpu/device_iter.py
+// (nnz_bucket, whose docstring has the trade, and tail_rung);
+// tests/test_nnz_bucket.py holds the two equal.
 #ifndef DCT_NNZ_BUCKET_H_
 #define DCT_NNZ_BUCKET_H_
 
@@ -30,6 +40,13 @@ inline uint64_t NnzBucket(uint64_t n, uint64_t floor) {
   const uint64_t g =
       std::max<uint64_t>(p >> 4, std::min<uint64_t>(floor, 128));
   return (n + g - 1) / g * g;
+}
+
+// `own` is the batch's own rung, `before` the rung the batch before it in
+// the epoch was sent at (0: there was none), `take` its count of real rows.
+inline uint64_t TailRung(uint64_t own, uint64_t before, uint64_t take,
+                         uint64_t batch_rows) {
+  return take < batch_rows && before > own ? before : own;
 }
 
 }  // namespace dct

@@ -201,6 +201,8 @@ def _declare_signatures(cdll: ctypes.CDLL) -> None:
                                    c.POINTER(vp)]),
         "dct_nnz_bucket": (i, [c.c_uint64, c.c_uint64,
                                c.POINTER(c.c_uint64)]),
+        "dct_tail_rung": (i, [c.c_uint64, c.c_uint64, c.c_uint64,
+                              c.c_uint64, c.POINTER(c.c_uint64)]),
         "dct_batcher_next_meta": (i, [vp, c.POINTER(c.c_uint64),
                                       c.POINTER(c.c_uint64),
                                       c.POINTER(c.c_uint64), c.POINTER(i),
@@ -218,7 +220,7 @@ def _declare_signatures(cdll: ctypes.CDLL) -> None:
         "dct_batcher_bytes_read": (i, [vp, c.POINTER(sz)]),
         "dct_batcher_batch_nnz": (i, [vp, c.POINTER(c.c_uint64)]),
         "dct_batcher_cols_meta": (i, [vp, c.POINTER(c.c_uint64),
-                                      c.POINTER(c.c_uint64)]),
+                                      c.POINTER(c.c_uint64), c.POINTER(i)]),
         "dct_batcher_fill_cols": (i, [vp, vp, c.c_uint64]),
         "dct_col_slots": (i, [vp, vp, c.c_uint32, c.c_uint64, c.c_uint64,
                               vp, c.POINTER(c.c_uint64),
@@ -252,7 +254,7 @@ def _declare_signatures(cdll: ctypes.CDLL) -> None:
         "dct_csrrec_bytes_read": (i, [vp, c.POINTER(sz)]),
         "dct_csrrec_batch_nnz": (i, [vp, c.POINTER(c.c_uint64)]),
         "dct_csrrec_cols_meta": (i, [vp, c.POINTER(c.c_uint64),
-                                     c.POINTER(c.c_uint64)]),
+                                     c.POINTER(c.c_uint64), c.POINTER(i)]),
         "dct_csrrec_fill_cols": (i, [vp, vp, c.c_uint64]),
         "dct_csrrec_free": (i, [vp]),
         "dct_bf16_convert": (i, [vp, vp, c.c_uint64]),
@@ -1038,6 +1040,17 @@ def native_nnz_bucket(n: int, floor: int) -> int:
     return out.value
 
 
+def native_tail_rung(own: int, before: int, take: int,
+                     batch_rows: int) -> int:
+    """The native statement of the rule for a part's short last batch
+    (cpp/src/nnz_bucket.h TailRung; the Python one is
+    dmlc_core_tpu.tpu.device_iter.tail_rung)."""
+    out = ctypes.c_uint64()
+    _check(lib().dct_tail_rung(own, before, take, batch_rows,
+                               ctypes.byref(out)))
+    return out.value
+
+
 def native_col_slots(col: np.ndarray, n, floor: int):
     """The native statement of the dedupe (cpp/src/col_slots.h; the Python
     one is dmlc_core_tpu.tpu.device_iter.col_slots): ``col`` [D, NNZ] int32
@@ -1062,8 +1075,10 @@ def native_col_slots(col: np.ndarray, n, floor: int):
 def _cols_meta(fn, handle):
     cap = ctypes.c_uint64()
     distinct = ctypes.c_uint64()
-    _check(fn(handle, ctypes.byref(cap), ctypes.byref(distinct)))
-    return cap.value, distinct.value
+    lifted = ctypes.c_int()
+    _check(fn(handle, ctypes.byref(cap), ctypes.byref(distinct),
+              ctypes.byref(lifted)))
+    return cap.value, distinct.value, bool(lifted.value)
 
 
 def _fill_cols(fn, handle, cols: np.ndarray, num_shards: int) -> None:
@@ -1168,8 +1183,10 @@ class NativeBatcher:
             self._ptr(nrows, np.int32, D)))
 
     def cols_meta(self):
-        """(capacity U, distinct count) of the distinct-column lists of the
-        batch fill_packed last wrote (cpp/src/col_slots.h)."""
+        """(capacity U, distinct count, tail lifted) of the distinct-column
+        lists of the batch fill_packed last wrote (cpp/src/col_slots.h);
+        the last says that the batch was a short one sent at the rungs of
+        the batch before it (cpp/src/nnz_bucket.h TailRung)."""
         return _cols_meta(lib().dct_batcher_cols_meta, self._h)
 
     def fill_cols(self, cols: np.ndarray) -> None:
@@ -1362,8 +1379,9 @@ class NativeCsrRecBatcher:
         return out.value
 
     def cols_meta(self):
-        """(capacity U, distinct count) of the distinct-column lists of the
-        batch fill_packed last wrote (cpp/src/col_slots.h)."""
+        """(capacity U, distinct count, tail lifted) of the distinct-column
+        lists of the batch fill_packed last wrote (cpp/src/col_slots.h,
+        cpp/src/nnz_bucket.h TailRung)."""
         return _cols_meta(lib().dct_csrrec_cols_meta, self._h)
 
     def fill_cols(self, cols: np.ndarray) -> None:

@@ -1064,6 +1064,13 @@ METRIC_HELP: Dict[str, str] = {
     "parse_cells_total":
         "feature cells of the lines a cell-counting text format parsed",
     "parse_cells_missing_total": "of those cells, the empty ones (skipped)",
+    "split_open_us":
+        "one open the split's reader waited out: no stream open, to the "
+        "first byte read from the new one (us)",
+    "split_objects_opened_total":
+        "streams a split opened: a part's first object after BeforeFirst "
+        "and every next one after the one before it was drained",
+    "split_bytes_read_total": "bytes a split read from its objects",
     "parse_stage_fill_us": "one ReadChunk, source to owned bytes (us)",
     "parse_stage_scan_us": "one TileCuts slice pre-tiling (us)",
     "parse_stage_parse_us": "one worker slice decode (us)",
@@ -1098,6 +1105,9 @@ METRIC_HELP: Dict[str, str] = {
         "distinct columns of the CSR batches dispatched, summed over their "
         "shards, as the batcher's dedupe counted them: the rows a step "
         "gathers and scatters",
+    "device_tail_batches_total":
+        "short last batches sent at the rungs of the batch before them, "
+        "their own being lower (the padding is in device_nnz_sent_total)",
     "device_stage_us":
         "one host batch assembly (parse+pad+bucket+pack) on the staging "
         "thread (us)",

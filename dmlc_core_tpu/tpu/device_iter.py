@@ -79,6 +79,7 @@ def _get_lane_metrics():
             "nnz_sent": telemetry.counter("device_nnz_sent_total"),
             "nnz_real": telemetry.counter("device_nnz_real_total"),
             "cols_distinct": telemetry.counter("device_cols_distinct_total"),
+            "tail_batches": telemetry.counter("device_tail_batches_total"),
             "bytes": telemetry.counter("device_transfer_bytes_total"),
             "failures": telemetry.counter("device_put_failures_total"),
             "host_q": telemetry.gauge("device_host_q_depth"),
@@ -179,7 +180,7 @@ def _dense_dtype_of(d) -> np.dtype:
 __all__ = ["PaddedBatch", "DenseBatch", "DeviceRowBlockIter", "HostBatcher",
            "NativeHostBatcher", "DenseRecHostBatcher", "CsrRecHostBatcher",
            "unpack_tree", "unpack_shard", "match_placement_rules",
-           "jax_profiler_capture", "nnz_bucket", "col_slots"]
+           "jax_profiler_capture", "nnz_bucket", "tail_rung", "col_slots"]
 
 
 @dataclass
@@ -241,6 +242,9 @@ class PaddedBatch:
     # dedupe counted it: against total_nnz, the share of the gathers and
     # scatters that is left
     total_distinct: int = 0
+    # host-side: a short last batch that was sent at the rungs of the batch
+    # before it, its own being lower (``tail_rung``)
+    tail_lifted: bool = False
     qid: Any = None
     field: Any = None
     big: Any = None  # [D, Kb, NNZ] packed row/slot[/val][/field]
@@ -482,10 +486,10 @@ def nnz_bucket(n: int, floor: int) -> int:
     The step's gathers and scatters cost per entry sent, padding included
     (PERF.md section 5), which is why the ladder is fine. The trade: a
     corpus whose batch nnz wanders across rungs compiles up to eight shapes
-    an octave where it compiled one, and an epoch's short last batch lands
-    on a rung of its own; ``device_distinct_shapes`` and
+    an octave where it compiled one; ``device_distinct_shapes`` and
     ``model_step_builds_total`` show it. At thousands of rows a batch the
-    count is steady to a fraction of a percent."""
+    count is steady to a fraction of a percent. An epoch's short last batch
+    does not land on a rung of its own: ``tail_rung``."""
     floor = max(int(floor), 1)
     n = int(n)
     if n <= floor:
@@ -493,6 +497,26 @@ def nnz_bucket(n: int, floor: int) -> int:
     p = 1 << (n - 1).bit_length()
     g = max(p >> 4, min(floor, 128))
     return -(-n // g) * g
+
+
+def tail_rung(own: int, before: int, take: int, batch_rows: int) -> int:
+    """The rung a batch of ``take`` real rows is sent at, ``own`` being the
+    rung of its own count and ``before`` the rung the batch before it in the
+    same epoch was sent at (0: there was none). Stated once more natively
+    (cpp/src/nnz_bucket.h TailRung; tests/test_nnz_bucket.py holds the two
+    equal), and applied by every assembler to the nnz capacity and to the
+    distinct-column list alike.
+
+    A byte-range part of a data set (``part``/``npart``) never holds a whole
+    number of batches, so every epoch ends in a batch with fewer real rows
+    than ``batch_rows``. Its rows are padded, but its own counts would land
+    on lower rungs: a second compiled shape, for one batch an epoch. So a
+    short batch takes no rung below the batch before it; the fill is the
+    padding the ladder already uses (entries on the sacrificial row, ``cols``
+    padded by 2**31 - 1), counted in ``device_nnz_sent_total`` and, a batch,
+    in ``device_tail_batches_total``. A full batch keeps its own rung, and
+    so does a short batch with none before it (a part of under one batch)."""
+    return before if take < batch_rows and before > own else own
 
 
 def col_slots(col: np.ndarray, n, floor: int):
@@ -652,6 +676,9 @@ class HostBatcher:
         self._emit_field: Optional[bool] = None
         # recycled big/aux packs (see _HostBufferPool contract)
         self._pool = _HostBufferPool()
+        # (nnz rung, distinct rung) of the CSR batch before, this epoch,
+        # for tail_rung
+        self._rungs_before = (0, 0)
 
     def recycle(self, batch) -> None:
         """Return a consumed host batch's packed buffers for reuse (same
@@ -801,8 +828,11 @@ class HostBatcher:
         shard_starts = np.concatenate(
             [[0], np.cumsum(lens.reshape(D, R).sum(axis=1))]).astype(np.int64)
         shard_nnz = np.diff(shard_starts)
-        bucket = nnz_bucket(int(shard_nnz.max()) if take else 1,
-                            self.min_nnz_bucket)
+        own = nnz_bucket(int(shard_nnz.max()) if take else 1,
+                         self.min_nnz_bucket)
+        # a short last batch takes no rung below the batch before it
+        nnz_before, cols_before = self._rungs_before
+        bucket = tail_rung(own, nnz_before, take, self.batch_rows)
 
         # assemble straight into the packed layout (the same big/aux
         # contract the native batchers emit, so index64 batches cross
@@ -832,6 +862,14 @@ class HostBatcher:
                 fldp[d, :n] = fld[lo:hi]
         # the columns become the distinct lists, the plane their slots
         cols, distinct = col_slots(slotp, shard_nnz, self.min_nnz_bucket)
+        U = tail_rung(cols.shape[1], cols_before, take, self.batch_rows)
+        lifted = bucket != own or U != cols.shape[1]
+        if U != cols.shape[1]:  # the list's own padding, to the rung before
+            wide = _aligned_empty((D, U), np.int32)
+            wide[:] = np.iinfo(np.int32).max
+            wide[:, :cols.shape[1]] = cols
+            cols = wide
+        self._rungs_before = (bucket, cols.shape[1])
 
         nrows = np.minimum(
             np.maximum(take - np.arange(D) * R, 0), R).astype(np.int32)
@@ -842,6 +880,7 @@ class HostBatcher:
             label=label_v, weight=weight_v,
             nrows=nrows, total_rows=int(take),
             total_nnz=int(shard_starts[-1]), total_distinct=distinct,
+            tail_lifted=lifted,
             qid=qid_v, field=fldp, big=big, aux=aux)
 
     def _emit_dense(self, take, label, weight, lens, col, val, qid):
@@ -881,6 +920,7 @@ class HostBatcher:
         self._pending.clear()
         self._pending_rows = 0
         self._done = False
+        self._rungs_before = (0, 0)
 
     def set_epoch(self, epoch: int) -> bool:
         """Pin the shuffle permutation the next reset() samples (mid-epoch
@@ -891,13 +931,14 @@ class HostBatcher:
 def _native_cols(native, pool: _HostBufferPool, D: int):
     """The distinct-column lists of the batch a native ``fill_packed`` just
     wrote (its col plane now holds the slots), in a pooled [D, U] buffer;
-    returns (cols, distinct count)."""
-    U, distinct = native.cols_meta()
+    returns (cols, distinct count, whether a short batch was lifted to the
+    rungs of the batch before it)."""
+    U, distinct, lifted = native.cols_meta()
     cols = pool.pop(("cols", U))
     if cols is None:
         cols = _aligned_empty((D, U), np.int32)
     native.fill_cols(cols)
-    return cols, distinct
+    return cols, distinct, lifted
 
 
 class NativeHostBatcher:
@@ -1013,7 +1054,7 @@ class NativeHostBatcher:
             aux = _aligned_empty((D, 4 if has_qid else 3, R), np.int32)
         # one fused native pass assembles the whole shard-major batch
         self._b.fill_packed(big, aux, nrows, val=val16 if sep_val else None)
-        cols, distinct = _native_cols(self._b, self._pool, D)
+        cols, distinct, lifted = _native_cols(self._b, self._pool, D)
         row, slot, val, field = _view_big(big, has_val=not sep_val)
         _, label, weight, qid = _view_aux(aux)
         return PaddedBatch(row=row, slot=slot, cols=cols,
@@ -1021,7 +1062,7 @@ class NativeHostBatcher:
                            label=label, weight=weight,
                            nrows=nrows, total_rows=int(take),
                            total_nnz=self._b.batch_nnz(),
-                           total_distinct=distinct,
+                           total_distinct=distinct, tail_lifted=lifted,
                            qid=qid, field=field, big=big, aux=aux,
                            val16=val16)
 
@@ -1129,14 +1170,14 @@ class CsrRecHostBatcher:
         take = self._b.fill_packed(big, aux, nrows)
         if take == 0:
             return None
-        cols, distinct = _native_cols(self._b, self._pool, D)
+        cols, distinct, lifted = _native_cols(self._b, self._pool, D)
         row, slot, val, field = _view_big(big)
         _, label, weight, qid = _view_aux(aux)
         return PaddedBatch(row=row, slot=slot, cols=cols, val=val,
                            label=label, weight=weight,
                            nrows=nrows, total_rows=int(take),
                            total_nnz=self._b.batch_nnz(),
-                           total_distinct=distinct,
+                           total_distinct=distinct, tail_lifted=lifted,
                            qid=qid, field=field, big=big, aux=aux)
 
     def reset(self) -> None:
@@ -1589,8 +1630,11 @@ class DeviceRowBlockIter:
             m["nnz_sent"].inc(plane.size)
             m["nnz_real"].inc(batch.total_nnz)
             m["cols_distinct"].inc(batch.total_distinct)
+            if batch.tail_lifted:
+                m["tail_batches"].inc()
             kwargs["total_nnz"] = batch.total_nnz
             kwargs["total_distinct"] = batch.total_distinct
+            kwargs["tail_lifted"] = batch.tail_lifted
         if "val" in kwargs and "aux" in kwargs:
             # the packed tree's separate bf16 value leaf rides the val16
             # field so the device batch's tree() re-emits it

@@ -69,6 +69,7 @@ def run_origin(args) -> int:
 
     config = mock_origin.OriginConfig(
         latency_ms=args.latency_ms, latency_block=args.latency_block,
+        first_byte_ms=args.first_byte_ms,
         stall_every=args.stall_every, stall_seconds=args.stall_seconds,
         reset_every=args.reset_every, get_500_every=args.get_500_every,
         get_truncate_every=args.get_truncate_every,
@@ -122,6 +123,8 @@ def run_origin(args) -> int:
           f"pids={','.join(str(p) for p in pids)}", flush=True)
     try:
         while pids and time.monotonic() < deadline:
+            if args.parent_pid and os.getppid() != args.parent_pid:
+                break  # the launcher's caller is gone: so is its run
             try:
                 done, _ = os.waitpid(-1, os.WNOHANG)
             except ChildProcessError:
@@ -196,9 +199,16 @@ class OriginProcess:
 
 
 def spawn_origin(backend: str, corpus_specs, config=None,
-                 timeout_s: float = 30.0) -> OriginProcess:
+                 timeout_s: float = 30.0,
+                 ttl_s: "float | None" = None,
+                 port: int = 0) -> OriginProcess:
     """Launch ``loadrig.py origin`` as a subprocess and wait for
-    ``RIG_READY``.
+    ``RIG_READY``. The launcher stops when the caller is gone (it watches
+    its parent's pid; its workers die with it by PR_SET_PDEATHSIG), and
+    with ``ttl_s`` after that many seconds whatever happens: an origin
+    never outlives its run. ``port`` 0 is an ephemeral one; a process
+    whose native core has already read an origin's address (it does once)
+    asks for that port again.
 
     ``corpus_specs`` is a list of ``key=@path`` / ``key=size:seed``
     strings (tests/mock_origin.build_corpus); ``config`` an
@@ -212,6 +222,11 @@ def spawn_origin(backend: str, corpus_specs, config=None,
     for spec in corpus_specs:
         cmd.extend(["--corpus", spec])
     cmd.extend(config.cli_args())
+    if ttl_s is not None:
+        cmd.extend(["--ttl", str(ttl_s)])
+    if port:
+        cmd.extend(["--port", str(port)])
+    cmd.extend(["--parent-pid", str(os.getpid())])
     proc = subprocess.Popen(cmd, stdout=subprocess.PIPE, text=True)
     deadline = time.monotonic() + timeout_s
     line = ""
@@ -624,7 +639,7 @@ def run_loadgen(args) -> int:
 
 
 # ---------------------------------------------------------------------------
-def main(argv=None) -> int:
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     sub = ap.add_subparsers(dest="cmd", required=True)
 
@@ -639,6 +654,9 @@ def main(argv=None) -> int:
     o.add_argument("--backlog", type=int, default=128)
     o.add_argument("--latency-ms", type=int, default=0)
     o.add_argument("--latency-block", type=int, default=256 * 1024)
+    o.add_argument("--first-byte-ms", type=int, default=None,
+                   help="sleep before the response head (unset: "
+                        "--latency-ms, which then also paces the body)")
     o.add_argument("--stall-every", type=int, default=0)
     o.add_argument("--stall-seconds", type=float, default=3.0)
     o.add_argument("--reset-every", type=int, default=0)
@@ -648,6 +666,9 @@ def main(argv=None) -> int:
     o.add_argument("--slow-ms", type=int, default=0)
     o.add_argument("--ignore-range", action="store_true")
     o.add_argument("--bad-content-range-every", type=int, default=0)
+    o.add_argument("--parent-pid", type=int, default=0,
+                   help="stop once this process is no longer the parent "
+                        "(spawn_origin passes its own pid)")
     o.add_argument("--ttl", type=float, default=600.0,
                    help="self-destruct after this many seconds — an "
                         "orphaned rig must never outlive its run")
@@ -686,8 +707,11 @@ def main(argv=None) -> int:
                     help="generate POST payloads from a corpus spec, "
                          "e.g. libsvm:rows=4,features=64,nnz=8,seed=3")
     lg.set_defaults(fn=run_loadgen)
+    return ap
 
-    args = ap.parse_args(argv)
+
+def main(argv=None) -> int:
+    args = build_parser().parse_args(argv)
     return args.fn(args)
 
 

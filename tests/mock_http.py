@@ -38,6 +38,7 @@ class MockHttpState(FaultCounterMixin):
         # latency/bandwidth shaping (mock_s3 parity)
         self.latency_ms = 0
         self.latency_block = 256 * 1024
+        self.first_byte_ms = None   # head delay when not latency_ms
         # served stall: every Nth GET is delayed slow_ms then completes
         self.slow_every = 0
         self.slow_ms = 0
@@ -100,7 +101,7 @@ class MockHttpHandler(BaseHTTPRequestHandler):
         if st._tick("slow", st.slow_every):
             time.sleep(st.slow_ms / 1000.0)
         send_with_latency(self, status, body, headers, st.latency_ms,
-                          st.latency_block)
+                          st.latency_block, st.first_byte_ms)
 
 
 def serve(ssl_context=None, config=None):

@@ -55,6 +55,12 @@ class OriginConfig:
     # capped connection — one connection tops out at block/latency)
     latency_ms: int = 0
     latency_block: int = 256 * 1024
+    # the sleep before the response head where it is not latency_ms: an
+    # object store's time to the first byte, with latency_ms left to pace
+    # the body (first_byte_ms=100, latency_ms=3, latency_block=262144:
+    # 100 ms, then 256 KiB every 3 ms, 87 MB/s a connection). Unset, the
+    # head waits latency_ms as it always did.
+    first_byte_ms: "int | None" = None
     # fault plan (every-Nth scheduling via FaultCounterMixin)
     stall_every: int = 0          # accept, sleep past client deadline
     stall_seconds: float = 3.0
@@ -102,7 +108,8 @@ class OriginConfig:
 
 
 # knobs applied onto a state object (only those the state declares)
-_KNOBS = ("latency_ms", "latency_block", "stall_every", "stall_seconds",
+_KNOBS = ("latency_ms", "latency_block", "first_byte_ms", "stall_every",
+          "stall_seconds",
           "reset_every", "get_500_every", "get_truncate_every",
           "slow_every", "slow_ms", "ignore_range",
           "bad_content_range_every")
@@ -226,16 +233,22 @@ def build_corpus(specs) -> dict:
     """``key=<size>:<seed>`` or ``key=@<path>`` spec strings -> bytes.
 
     The same spec list handed to ``loadrig.py origin`` and to an
-    in-process :func:`serve_backend` produces the same objects."""
+    in-process :func:`serve_backend` produces the same objects. A path is
+    read once: keys that name the same path share one buffer (24 day
+    objects of one 266 MB file are 266 MB, in the launcher and, the
+    corpus being built before the pre-fork, in every worker)."""
     corpus = {}
+    files = {}
     for spec in specs or ():
         key, _, rhs = spec.partition("=")
         if not key or not rhs:
             raise ValueError(f"corpus spec {spec!r}: want key=@path or "
                              f"key=size:seed")
         if rhs.startswith("@"):
-            with open(rhs[1:], "rb") as f:
-                corpus[key] = f.read()
+            if rhs not in files:
+                with open(rhs[1:], "rb") as f:
+                    files[rhs] = f.read()
+            corpus[key] = files[rhs]
         else:
             size, _, seed = rhs.partition(":")
             corpus[key] = pseudo_bytes(int(size), int(seed or "0"))

@@ -83,6 +83,9 @@ class MockS3State(FaultCounterMixin):
         # -- ranged-read knobs (cpp/src/range_reader.h lane) --
         self.latency_ms = 0        # per-request + per-block delay
         self.latency_block = LATENCY_BLOCK  # bytes per latency "burst"
+        # the delay before the response head when it is not latency_ms:
+        # an object store's time to the first byte (None: latency_ms)
+        self.first_byte_ms = None
         self.ignore_range = False  # answer 200 full-body (Range ignored)
         # every Nth ranged GET: 206 whose Content-Range window (header AND
         # body, consistent with each other) is shifted +64 bytes from the
@@ -100,16 +103,20 @@ LATENCY_BLOCK = 256 * 1024
 
 
 def send_with_latency(handler, status, data, headers=None, latency_ms=0,
-                      block=LATENCY_BLOCK):
+                      block=LATENCY_BLOCK, first_byte_ms=None):
     """Send a response; with ``latency_ms`` the mock sleeps once before the
     response head and once per ``block`` bytes of body, emulating a remote
     origin whose per-connection throughput is capped by its
     latency-bandwidth product (block/latency per connection). This is what
     makes parallel ranged reads (cpp/src/range_reader.h) observable and
     benchable on localhost: one connection is capped, N concurrent ranges
-    get ~N times the bandwidth."""
-    if latency_ms:
-        time.sleep(latency_ms / 1000.0)
+    get ~N times the bandwidth. With ``first_byte_ms`` the sleep before
+    the head is that, and ``latency_ms`` paces the body alone: 100 ms to
+    the first byte, then 256 KiB every 3 ms, is an object store that
+    answers late and then streams at 87 MB/s a connection."""
+    head = latency_ms if first_byte_ms is None else first_byte_ms
+    if head:
+        time.sleep(head / 1000.0)
     handler.send_response(status)
     for k, v in (headers or {}).items():
         handler.send_header(k, v)
@@ -270,7 +277,7 @@ class MockS3Handler(BaseHTTPRequestHandler):
             self.close_connection = True
             return
         send_with_latency(self, status, data, headers, st.latency_ms,
-                          st.latency_block)
+                          st.latency_block, st.first_byte_ms)
 
     def _list(self, bucket, q):
         st = self.state

@@ -8,8 +8,10 @@ edit ``benchmarks/``: a rename that no test here sees would pass tier-1 and
 be refused on the chip as a malformed result line. So the names are read from
 the metric files, never spelt out, and looked up in one real run on the CPU
 for each cell of ``BENCHMARK.json``: a small file of the cell's format (as
-text, or converted to ``.crec`` where the traffic file stores it so) through
-``DeviceRowBlockIter`` for two epochs and ``FMLearner.step`` on as many
+text, or converted to ``.crec`` where the traffic file stores it so, or put
+under 24 keys of the test process's mock S3 and read as part 1 of 16 where it
+stores it there: ISSUE 34) through ``DeviceRowBlockIter`` for two epochs and
+``FMLearner.step`` on as many
 devices as the cell has chips (one: the row form of the step; four: the row
 form under ``shard_map``, with its exchange under ``dp.allreduce``). A metric
 that ``BENCHMARK.json`` promises a cell has to be found in that cell's run; a
@@ -23,6 +25,8 @@ import re
 
 import numpy as np
 import pytest
+
+from tests.s3_shared import STATE as S3
 
 from dmlc_core_tpu import telemetry
 from dmlc_core_tpu.io.convert import rows_to_csr_recordio
@@ -115,10 +119,24 @@ def program(tmp_path_factory):
         fmt = traffic["format"]
         uri = str(work / f"{cell['name']}.{fmt}")
         uri += _write_rows(uri, fmt)
+        part = {}
         if traffic["store"] == "crec":
             text, uri, fmt = uri, uri + ".crec", "crec"
             assert rows_to_csr_recordio(text, uri,
                                         fmt=traffic["format"]) == ROWS
+        elif traffic["store"] == "s3":
+            # one worker's part of a directory of objects, as the cell's
+            # runner reads it (benchmarks/runners/fm_s3.py)
+            text, args = uri.partition("?")[::2]
+            with open(text, "rb") as f:
+                body = f.read()
+            # an object the ranged reader splits (doc/io-ranged.md: twice
+            # its least range, 512 KiB, and up)
+            body *= -(-(600 << 10) // len(body))
+            for day in range(24):
+                S3.objects[("names", f"day_{day:02d}")] = body
+            uri = f"s3://names/?format={fmt}" + (args and "&" + args)
+            fmt, part = "auto", {"part": 1, "npart": 16}
         telemetry.reset()
         device_iter._reset_shape_census()
         mesh = data_mesh(cell["chips"])
@@ -126,7 +144,7 @@ def program(tmp_path_factory):
                             k=4, mesh=mesh)
         params = learner.init(0)
         with DeviceRowBlockIter(uri, mesh=mesh, batch_rows=BATCH,
-                                fmt=fmt) as it:
+                                fmt=fmt, **part) as it:
             for _ in range(2):
                 for batch in it:
                     params, loss = learner.step(params, batch)

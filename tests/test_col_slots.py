@@ -166,26 +166,37 @@ def _epoch(batcher):
 def test_batchers_send_the_oracles_lists(skewed_file, kind, shards):
     """Every batch of the file, its short last one (whose tail shards are
     empty) included: the list, the slots and the counts are the oracle's on
-    the batch's own columns, which the python batcher states by numpy."""
+    the batch's own columns, which the python batcher states by numpy. The
+    short one's list is no narrower than the one before it (ISSUE 34,
+    ``device_iter.tail_rung``)."""
     got = _epoch(_open(kind, skewed_file, 512, shards, 64))
     ref = _epoch(_open("python", skewed_file, 512, shards, 64))
     assert len(got) == len(ref) == 6
+    before = 0
     for b, r in zip(got, ref):
         R = b.rows_per_shard
         n = (b.row < R).sum(axis=1)
         assert n.sum() == b.total_nnz
         r_col = _expand_cols(r.cols, r.slot)
         col = np.array(r_col)
+        short = b.total_rows < 512
         want_cols, want_distinct = col_slots(col, n, 64)   # col -> slots
+        if short and before > want_cols.shape[1]:
+            want_cols = np.pad(want_cols,
+                               ((0, 0), (0, before - want_cols.shape[1])),
+                               constant_values=np.iinfo(np.int32).max)
         assert np.array_equal(b.cols, want_cols)
         # (the .crec lane's nnz capacity is the file's, not the batch's)
         width = min(b.nnz_bucket, r.nnz_bucket)
         assert np.array_equal(b.slot[:, :width], col[:, :width])
         assert not b.slot[:, width:].any() and not col[:, width:].any()
         assert b.total_distinct == want_distinct
-        assert b.cols.shape == (shards, nnz_bucket(
-            max(np.unique(r_col[d, :n[d]]).size for d in range(shards)),
-            64))
+        own = nnz_bucket(
+            max(np.unique(r_col[d, :n[d]]).size for d in range(shards)), 64)
+        assert b.cols.shape == (shards, max(own, before) if short else own)
+        assert b.tail_lifted == (short and (
+            own < before or b.nnz_bucket > nnz_bucket(int(n.max()), 64)))
+        before = b.cols.shape[1]
         # col is not kept: the unpack reads it back from the two
         assert b.col is None
         assert b.tree().keys() == {"big", "cols", "aux"}

@@ -426,13 +426,15 @@ def test_model_step_counts_builds_and_times_every_dispatch(tmp_path):
                 params, _ = learner.step(params, batch)
                 seen.append(builds.value)
             it.before_first()
-    # 256, 256 rows pad to one signature and the epoch's short last batch
-    # (188 rows, 1,504 entries) to the ladder's rung below it: each built
-    # on its first step only, never on a repeat or in the second epoch
-    assert seen == [1, 1, 2, 2, 2, 2]
+    # 256, 256 rows pad to one signature, and the epoch's short last batch
+    # (188 rows, 1,504 entries: its own rung is the one below) is sent at
+    # the rungs of the batch before it (device_iter.tail_rung): one build,
+    # on the first step, never at an epoch's end or in the second epoch
+    assert seen == [1, 1, 1, 1, 1, 1]
     assert _hist("model_step_dispatch_us") == 6
+    assert telemetry.counter("device_tail_batches_total").value == 2
     steps = [s for s in telemetry.spans() if s["name"] == "model.step"]
-    assert [s["args"]["built"] for s in steps] == [1, 0, 1, 0, 0, 0]
+    assert [s["args"]["built"] for s in steps] == [1, 0, 0, 0, 0, 0]
     # each new batch signature builds once more, a repeat never
     sigs = set()
     with DeviceRowBlockIter(path, batch_rows=128, layout="csr",
@@ -441,7 +443,7 @@ def test_model_step_counts_builds_and_times_every_dispatch(tmp_path):
             sigs.add(tuple(sorted((k, v.shape)
                                   for k, v in batch.tree().items())))
             params, _ = learner.step(params, batch)
-            assert builds.value == 2 + len(sigs)
+            assert builds.value == 1 + len(sigs)
     assert len(sigs) >= 1
     assert telemetry.counter("model_step_builds_total",
                              {"model": "FMLearner"}).value == 0

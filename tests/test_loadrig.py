@@ -127,6 +127,127 @@ def test_one_config_surface():
             state, mock_origin.OriginConfig(extra={"no_such_knob": 1}))
 
 
+# -- an object store's shape (ISSUE 34) ---------------------------------------
+@pytest.mark.parametrize("nbytes,latency_ms,block,first,want", [
+    # unset, the head waits latency_ms as it always did
+    (4 << 20, 3, 262144, None, [3] + [3] * 15),
+    (0, 7, 262144, None, [7]),
+    (1, 0, 262144, None, []),
+    # 100 ms to the first byte, then 256 KiB every 3 ms
+    (4 << 20, 3, 262144, 100, [100] + [3] * 15),
+    ((4 << 20) + 1, 3, 262144, 100, [100] + [3] * 16),
+    (262144, 3, 262144, 100, [100]),
+    # the first byte alone: no pacing of the body
+    (4 << 20, 0, 262144, 100, [100]),
+    (4 << 20, 3, 262144, 0, [3] * 15),
+])
+def test_first_byte_ms_sleeps_before_the_head_and_latency_paces_the_body(
+        monkeypatch, nbytes, latency_ms, block, first, want):
+    """The sleeps a response of n bytes incurs (``time.sleep`` recorded,
+    no wall clock)."""
+    from tests import mock_s3
+    slept, wrote = [], []
+
+    class Handler:
+        wfile = type("W", (), {"write": staticmethod(
+            lambda b: wrote.append(len(b)))})
+
+        def send_response(self, status):
+            wrote.append("head")
+
+        def send_header(self, k, v):
+            pass
+
+        def end_headers(self):
+            pass
+
+    monkeypatch.setattr(mock_s3.time, "sleep",
+                        lambda s: slept.append(round(s * 1e3)))
+    mock_s3.send_with_latency(Handler(), 206, bytes(nbytes), None,
+                              latency_ms, block, first)
+    assert slept == want
+    assert wrote[0] == "head" and sum(wrote[1:]) == nbytes
+
+
+def test_first_byte_ms_round_trips_through_the_cli_and_the_state():
+    cfg = mock_origin.OriginConfig(first_byte_ms=100, latency_ms=3,
+                                   workers=4)
+    args = cfg.cli_args()
+    assert args[args.index("--first-byte-ms") + 1] == "100"
+    assert args[args.index("--latency-ms") + 1] == "3"
+    assert "--first-byte-ms" not in mock_origin.OriginConfig(
+        latency_ms=3).cli_args()
+    # the CLI's parser gives the knob back as it was
+    sys_argv = ["origin", "--backend", "s3"] + args
+    parsed = loadrig.build_parser().parse_args(sys_argv)
+    assert (parsed.first_byte_ms, parsed.latency_ms, parsed.workers) \
+        == (100, 3, 4)
+    assert loadrig.build_parser().parse_args(
+        ["origin", "--backend", "s3"]).first_byte_ms is None
+    for backend in ("s3", "http"):
+        state, _, shutdown = mock_origin.serve_backend(backend, cfg)
+        try:
+            assert (state.first_byte_ms, state.latency_ms) == (100, 3)
+            mock_origin.reset_state(state)
+            assert (state.first_byte_ms, state.latency_ms) == (None, 0)
+        finally:
+            shutdown()
+
+
+def test_keys_of_one_path_share_one_buffer(tmp_path):
+    day = tmp_path / "day"
+    day.write_bytes(b"0\t1\n" * 1000)
+    other = tmp_path / "other"
+    other.write_bytes(b"x")
+    corpus = mock_origin.build_corpus(
+        [f"criteo/day_{i:02d}=@{day}" for i in range(24)]
+        + [f"criteo/other=@{other}", "criteo/p=16:3"])
+    days = [corpus[f"criteo/day_{i:02d}"] for i in range(24)]
+    assert all(d is days[0] for d in days) and len(days[0]) == 4000
+    assert corpus["criteo/other"] == b"x" and len(corpus["criteo/p"]) == 16
+    state, _, shutdown = mock_origin.serve_backend("s3")
+    try:
+        mock_origin.load_corpus("s3", state, corpus)
+        assert all(state.objects[("criteo", f"day_{i:02d}")] is days[0]
+                   for i in range(24))
+    finally:
+        shutdown()
+
+
+def _pid_alive(pid: int) -> bool:
+    try:
+        os.kill(pid, 0)
+    except OSError:
+        return False
+    with open(f"/proc/{pid}/stat") as f:  # a zombie awaiting its reaper
+        return f.read().rsplit(")", 1)[1].split()[0] != "Z"
+
+
+def test_an_origin_stops_when_its_caller_is_gone(tmp_path):
+    """``spawn_origin``'s launcher watches its parent: a caller that is
+    killed leaves no origin behind (and ``ttl_s`` bounds it besides)."""
+    script = tmp_path / "caller.py"
+    script.write_text(
+        "import sys, time\n"
+        f"sys.path.insert(0, {REPO!r})\n"
+        "from scripts import loadrig\n"
+        "o = loadrig.spawn_origin('s3', ['b/k=64:1'], ttl_s=120)\n"
+        "print(o.proc.pid, *o.pids, flush=True)\n"
+        "time.sleep(60)\n")
+    caller = subprocess.Popen([sys.executable, str(script)],
+                              stdout=subprocess.PIPE, text=True)
+    pids = [int(p) for p in caller.stdout.readline().split()]
+    assert len(pids) >= 2
+    caller.kill()
+    caller.wait(10)
+    deadline = time.time() + 10
+    live = pids
+    while live and time.time() < deadline:
+        live = [p for p in live if _pid_alive(p)]
+        time.sleep(0.1)
+    assert not live, f"the origin outlived its caller: {live}"
+
+
 # ---------------------------------------------------------------------------
 # open-loop load generator
 # ---------------------------------------------------------------------------

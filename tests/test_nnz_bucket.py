@@ -13,7 +13,11 @@ once in Python (device_iter.nnz_bucket), called by ``PaddedBatcher``,
 - the shard cache replays at the capacity the text epoch had;
 - padding is inert: the learners' step on the same rows at the next power
   of two and at the ladder's capacity gives the same loss and parameters;
-- the device lane's fill counters (doc/observability.md "Device lane").
+- the device lane's fill counters (doc/observability.md "Device lane");
+- a part's short last batch (ISSUE 34): ``tail_rung`` natively and in
+  Python on every point of a sweep, all three batchers send an epoch's
+  short last batch at the rungs of the batch before it and count it, full
+  batches are the bytes they were, and two epochs build the step once.
 """
 
 from __future__ import annotations
@@ -28,14 +32,14 @@ import jax
 from dmlc_core_tpu import telemetry
 from dmlc_core_tpu.io.convert import rows_to_csr_recordio
 from dmlc_core_tpu.io.native import (NativeParser, native_col_slots,
-                                     native_nnz_bucket,
+                                     native_nnz_bucket, native_tail_rung,
                                      native_telemetry_snapshot)
 from dmlc_core_tpu.models import FMLearner, LinearLearner
 from dmlc_core_tpu.tpu import device_iter
 from dmlc_core_tpu.tpu.device_iter import (CsrRecHostBatcher,
                                            DeviceRowBlockIter, HostBatcher,
                                            NativeHostBatcher, PaddedBatch,
-                                           col_slots, nnz_bucket)
+                                           col_slots, nnz_bucket, tail_rung)
 from dmlc_core_tpu.tpu.sharding import data_mesh
 
 
@@ -328,3 +332,106 @@ def test_fill_counters_read_the_padded_share(tmp_path, kind, _counters):
     real = _counters("device_nnz_real_total")
     assert (sent, real) == (4 * 1152, 4 * 1025)
     assert real / sent == pytest.approx(1025 / 1152)
+
+
+# -- a part's short last batch (ISSUE 34) ------------------------------------------
+def test_tail_rung_native_equals_python_and_lifts_only_a_short_batch():
+    rng = np.random.default_rng(34)
+    rungs = sorted({nnz_bucket(int(n), 128)
+                    for n in np.exp2(rng.uniform(0, 24, 200))}) + [0]
+    for own in rungs[:-1]:
+        for before in rungs:
+            for take, batch_rows in ((0, 1), (1, 512), (511, 512),
+                                     (512, 512), (16384, 16384)):
+                got = tail_rung(own, before, take, batch_rows)
+                assert got == native_tail_rung(own, before, take,
+                                               batch_rows)
+                if take == batch_rows or before == 0:
+                    assert got == own      # full, or first of its epoch
+                else:
+                    assert got == max(own, before)
+
+
+@pytest.fixture(scope="module")
+def part_like(tmp_path_factory):
+    """Four batches of 256 rows of 11 tokens and a fifth of 20 rows: a
+    byte-range part's epoch."""
+    d = tmp_path_factory.mktemp("part")
+    return _write_rows(d / "t.libsvm", np.full(4 * 256 + 20, 11), seed=34)
+
+
+@pytest.mark.parametrize("shards", [1, 4])
+@pytest.mark.parametrize("kind", ["native", "crec", "python"])
+def test_short_last_batch_takes_the_shape_before_it(part_like, kind, shards):
+    b = _open_batcher(kind, part_like, 256, shards, floor=128)
+    whole = _open_batcher(kind, part_like, 256, shards, floor=128)
+    for _ in range(2):  # the rule looks back within an epoch: both alike
+        batches = []
+        while (x := b.next_batch()) is not None:
+            batches.append(x)
+        assert [x.total_rows for x in batches] == [256] * 4 + [20]
+        assert {x.big.shape for x in batches} == {batches[0].big.shape}
+        assert {x.cols.shape for x in batches} == {batches[0].cols.shape}
+        assert [x.tail_lifted for x in batches] == [False] * 4 + [True]
+        last = batches[-1]
+        assert last.total_nnz == 20 * 11
+        # the fill is the ladder's own padding: entries on the sacrificial
+        # row with slot 0 and value 0, the list's tail beyond any table
+        R = last.rows_per_shard
+        real = int((last.row < R).sum())
+        assert real == 20 * 11
+        pad = last.row == R
+        assert not last.val[pad].any() and not last.slot[pad].any()
+        own = max(1, max(np.unique(np.take_along_axis(
+            last.cols, last.slot, 1)[d][~pad[d]]).size
+            for d in range(shards)))
+        assert nnz_bucket(own, 128) < last.cols.shape[1]
+        assert (last.cols[:, own:] == np.iinfo(np.int32).max).all()
+        # a full batch keeps its own rung (and its bytes, below)
+        for x in batches[:-1]:
+            assert x.nnz_bucket == nnz_bucket(R * 11, 128)
+        b.reset()
+    # full batches are bit for bit what they were without the rule: the
+    # rule's only input besides the batch is the rung before it
+    first = whole.next_batch()
+    again = _open_batcher(kind, part_like, 256, shards, floor=128)
+    assert np.array_equal(first.big, again.next_batch().big)
+
+
+@pytest.mark.parametrize("kind", ["native", "crec", "python"])
+def test_a_part_of_under_one_batch_keeps_its_own_rung(tmp_path, kind):
+    path = _write_rows(tmp_path / "u.libsvm", np.full(60, 11), seed=35)
+    b = _open_batcher(kind, path, 256, 1, floor=128)
+    for _ in range(2):
+        x = b.next_batch()
+        assert x.total_rows == 60 and not x.tail_lifted
+        if kind != "crec":  # the .crec lane's capacity is the file's bound
+            assert x.nnz_bucket == nnz_bucket(60 * 11, 128)
+        assert x.cols.shape[1] == nnz_bucket(x.total_distinct, 128)
+        assert b.next_batch() is None
+        b.reset()
+
+
+@pytest.mark.parametrize("kind", ["native", "crec", "python"])
+def test_two_epochs_of_a_part_are_one_shape_and_one_build(part_like, kind,
+                                                          _counters):
+    learner = FMLearner(num_features=100000, k=4, mesh=data_mesh(1),
+                        learning_rate=0.1)
+    params = learner.init(0)
+    builds = telemetry.counter("model_step_builds_total",
+                               {"model": "FMLearner"})
+    with _lane(kind, part_like, 256, 128) as it:
+        for _ in range(2):
+            for batch in it:
+                params, loss = learner.step(params, batch)
+            assert np.isfinite(float(loss))
+            it.before_first()
+    assert builds.value == 1
+    gauges = {g["name"]: g["value"] for g in telemetry.snapshot()["gauges"]}
+    assert gauges["device_distinct_shapes"] == 1
+    assert _counters("device_tail_batches_total") == 2
+    assert _counters("device_batches_total") == 10
+    # the lifted entries are inside the fill counters' difference
+    sent = _counters("device_nnz_sent_total")
+    assert sent == 10 * nnz_bucket(256 * 11, 128)
+    assert _counters("device_nnz_real_total") == 2 * (4 * 256 + 20) * 11

@@ -10,14 +10,9 @@ import os
 
 import pytest
 
-import tests.mock_s3 as mock_s3
-
-# env must be set before the native S3 singleton initializes
-_STATE, _PORT, _SHUTDOWN = mock_s3.serve()
-os.environ["S3_ENDPOINT"] = f"http://127.0.0.1:{_PORT}"
-os.environ["S3_ACCESS_KEY_ID"] = mock_s3.ACCESS_KEY
-os.environ["S3_SECRET_ACCESS_KEY"] = mock_s3.SECRET_KEY
-os.environ["S3_REGION"] = mock_s3.REGION
+# one mock S3 a test process: the native S3 singleton reads its environment
+# once, so every test file that reads s3:// shares tests/s3_shared.py's
+from tests.s3_shared import PORT as _PORT, STATE as _STATE  # noqa: E402
 
 from dmlc_core_tpu.base import DMLCError  # noqa: E402
 from dmlc_core_tpu.io.native import (NativeInputSplit, NativeParser,  # noqa: E402
