@@ -156,6 +156,15 @@ class DataParallelModel:
                 with jax.named_scope("dp.loss_grad"):
                     local = self._gather_rows(local, shard)
             loss_sum, wsum, grads = local_grads(local, shard)
+            if row_form:
+                # the rows go into the exchange as the leaves they are:
+                # where a model's backward makes one as a slice of a wider
+                # array (the FM's w out of its merged [U, K+1] gradient),
+                # the compiler would sink the reshape behind the all-gather
+                # and move [D*U, 1] padded to 128 lanes (PERF.md section 6,
+                # PR 35). Outside dp.allreduce: the copy it may cost is no
+                # part of the exchange
+                grads = jax.lax.optimization_barrier(grads)
             # ONE exchange per step over ICI — the Rabit allreduce
             # equivalent (SURVEY §2.5)
             with jax.named_scope("dp.allreduce"):

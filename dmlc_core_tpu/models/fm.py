@@ -20,16 +20,21 @@ Margin (Rendle's O(NNZ·K) identity):
 
     y(x) = b + Σ_i w_i x_i + ½ Σ_f [ (Σ_i V_{i,f} x_i)² − Σ_i V_{i,f}² x_i² ]
 
-CSR shards compute the two inner sums with one gather ``V[cols]``, its
-expansion by ``slot`` and two segment-sums over the row ids — the same
-segment-op layout the sparse ops use (ops/sparse.py); padding nonzeros
-(val 0, sacrificial row id) vanish.
+CSR shards index every entry four times a step. The rows ``w[cols]``
+(scope ``fm.linear``: that gather alone) and ``V[cols]`` (``fm.gather``)
+are expanded to the entries by ``slot`` as one ``[U, K+1]`` array in one
+gather (``fm.expand``), and the linear sum and the two inner sums ride in
+one segment sum over the row ids as the ``2K+1`` lanes of one array
+(``fm.interaction``); autodiff's transposes are one gather back by row and
+one scatter-add by ``slot``, the merge of a feature's repeats. On the chip
+a pass over the entries costs by its indices, one lane what K lanes cost
+(PERF.md section 6, PR 35). Padding nonzeros (val 0, sacrificial row id)
+vanish.
 Dense batches compute them as ``(x @ V)² − x² @ V²`` — pure MXU work.
 """
 
 from __future__ import annotations
 
-import functools
 from typing import NamedTuple, Optional, Tuple
 
 import jax
@@ -78,29 +83,35 @@ def _fm_gather(params: FMParams, cols) -> FMRows:
 
 def _fm_margin_entries(b, w, v, row, val, num_rows: int) -> jnp.ndarray:
     """The margin from each entry's own row of the parameters ([NNZ],
-    [NNZ, K])."""
-    seg = functools.partial(jax.ops.segment_sum,
-                            num_segments=num_rows + 1,
-                            indices_are_sorted=True)
-    with jax.named_scope("fm.linear"):
-        linear = seg(val * w, row)[:num_rows]
+    [NNZ, K]). The three sums over ``row`` ride in one segment sum as lanes
+    of one ``[NNZ, 2K+1]`` array (Σ V x, Σ V²x², and last Σ w x), each lane
+    summing the terms it would sum alone; the transpose is one gather back
+    by ``row``."""
+    k = v.shape[1]
     with jax.named_scope("fm.interaction"):
         vx = v * val[:, None]                      # [NNZ, K]
-        s1 = seg(vx, row)[:num_rows]               # Σ V x   per row  [R, K]
-        s2 = seg(vx * vx, row)[:num_rows]          # Σ V²x²  per row  [R, K]
+        sums = jax.ops.segment_sum(
+            jnp.concatenate([vx, vx * vx, (val * w)[:, None]], axis=1), row,
+            num_segments=num_rows + 1, indices_are_sorted=True)[:num_rows]
+        s1, s2, linear = sums[:, :k], sums[:, k:2 * k], sums[:, 2 * k]
         inter = 0.5 * jnp.sum(s1 * s1 - s2, axis=-1)
     return b + linear + inter
 
 
 def _fm_margin_rows(rows: FMRows, slot, row, val, num_rows: int
                     ) -> jnp.ndarray:
-    # the expansion reads a [U, K] intermediate, not the tables; its
-    # transpose sums an entry's gradient into its column's row, at the
-    # gradient's magnitude: the merge of a feature's repeats
+    # the expansion reads a [U, K+1] intermediate (v's lanes, then w's),
+    # not the tables, in one gather; its transpose sums an entry's gradient
+    # into its column's row, at the gradient's magnitude: the merge of a
+    # feature's repeats, w's and v's in one scatter-add. The concatenation
+    # is inside the differentiated function, so the gradient comes back as
+    # FMRows
+    k = rows.v.shape[1]
     with jax.named_scope("fm.expand"):
-        w = rows.w.at[slot].get(mode="promise_in_bounds")
-        v = rows.v.at[slot].get(mode="promise_in_bounds")
-    return _fm_margin_entries(rows.b, w, v, row, val, num_rows)
+        wv = jnp.concatenate([rows.v, rows.w[:, None]], axis=1).at[slot].get(
+            mode="promise_in_bounds")
+    return _fm_margin_entries(rows.b, wv[:, k], wv[:, :k], row, val,
+                              num_rows)
 
 
 def _fm_margin_csr(params: FMParams, row, col, val, num_rows: int

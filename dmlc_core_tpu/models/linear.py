@@ -42,9 +42,13 @@ def objective_loss(margin: jnp.ndarray, shard: Dict[str, jnp.ndarray],
     y = shard["label"]
     wgt = shard["weight"]  # 0 on padding rows
     if objective == "logistic":
-        # y in {0,1}; stable log-sigmoid cross-entropy
+        # y in {0,1}; stable log-sigmoid cross-entropy. -|margin| is
+        # written as a minimum, whose derivative at a tie is the mean of
+        # the two sides: abs' reads 1 at 0 (jax 0.9), and the derivative
+        # would step from sigmoid(0) - y to -y at a margin of exactly 0,
+        # which a zero start and an FM row of one entry both give
         per_row = jnp.maximum(margin, 0) - margin * y + \
-            jnp.log1p(jnp.exp(-jnp.abs(margin)))
+            jnp.log1p(jnp.exp(jnp.minimum(margin, -margin)))
     elif objective == "squared":
         per_row = 0.5 * (margin - y) ** 2
     elif objective == "pairwise":

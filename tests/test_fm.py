@@ -132,21 +132,27 @@ def _batches(uri, mesh=None):
         return list(it)
 
 
+def _lowered_ops(lowered):
+    """Every operation of the lowered module, in program order."""
+    from jaxlib.mlir import ir
+    ops = []
+
+    def visit(op):
+        ops.append(op)
+        return ir.WalkResult.ADVANCE
+
+    lowered.compiler_ir(dialect="stablehlo").operation.walk(visit)
+    return ops
+
+
 def _table_ops(lowered, shapes):
     """(op name, location) of every operation in the lowered module with a
     result of one of ``shapes``."""
     from jaxlib.mlir import ir
-    found = []
-
-    def visit(op):
-        for r in op.results:
-            if isinstance(r.type, ir.RankedTensorType) and \
-                    tuple(r.type.shape) in shapes:
-                found.append((op.name, str(op.location)))
-        return ir.WalkResult.ADVANCE
-
-    lowered.compiler_ir(dialect="stablehlo").operation.walk(visit)
-    return found
+    return [(op.name, str(op.location)) for op in _lowered_ops(lowered)
+            for r in op.results
+            if isinstance(r.type, ir.RankedTensorType)
+            and tuple(r.type.shape) in shapes]
 
 
 @pytest.mark.parametrize("objective", ["logistic", "squared"])
@@ -343,14 +349,26 @@ def test_fm_names_the_list_a_csr_batch_came_without(tmp_path, call):
     LinearLearner(F_ROWS).step(LinearLearner(F_ROWS).init(), named)
 
 
+@pytest.mark.parametrize("start", ["init", "nonzero"])
 @pytest.mark.parametrize("devices", [2, 8])
-def test_fm_one_device_and_mesh_steps_agree(tmp_path, devices):
+def test_fm_one_device_and_mesh_steps_agree(tmp_path, devices, start):
+    """From ``init()`` a row of one entry has a margin of exactly 0 or of a
+    rounding's residue, by how each program's compiler contracts
+    ``s1*s1 - s2`` (the file has 55 such rows in its first batch): the
+    logistic loss's derivative may not step there (ISSUE 35's review;
+    test_logistic_derivative_has_no_step_at_margin_zero). The second start
+    keeps every margin off 0."""
     uri = write_recurring_libsvm(tmp_path / "m.libsvm")
 
     def epoch(mesh):
         learner = FMLearner(F_ROWS, k=K_ROWS, mesh=mesh, learning_rate=0.3,
                             l2=0.01, init_scale=0.2)
         params = learner.init(seed=7)
+        if start == "nonzero":
+            rng = np.random.default_rng(1)
+            params = params._replace(
+                b=jax.numpy.float32(0.25), w=jax.numpy.asarray(
+                    0.05 * rng.normal(size=F_ROWS).astype(np.float32)))
         for batch in _batches(uri, mesh):
             params, _ = learner.step(params, batch)
         return jax.tree.map(np.asarray, params)
@@ -391,6 +409,151 @@ def test_fm_row_step_makes_no_table_but_the_parameters(tmp_path, l2,
         assert "dp.apply" in loc, (name, loc)
         if name == "stablehlo.scatter":
             assert "scatter-add" in loc
+
+
+def _indexed_ops(lowered):
+    """(op, scope path) of every gather and scatter in the lowered module,
+    in program order; the path without the jitted function's own name."""
+    import re
+    return [(op.name.split(".")[1],
+             re.match(r'loc\("([^"]*)"', str(op.location)).group(1)
+             .split("sharded_step)/")[-1])
+            for op in _lowered_ops(lowered)
+            if op.name in ("stablehlo.gather", "stablehlo.scatter")]
+
+
+@pytest.mark.parametrize("mesh_devices", [0, 4], ids=["nomesh", "mesh4"])
+def test_fm_csr_step_indexes_an_entry_four_times(tmp_path, mesh_devices):
+    """The passes over a batch's entries that share their indices are lanes
+    of one pass: one expansion by slot ([U, K+1]), one segment sum by row
+    ([NNZ, 2K+1]), its transpose one gather back by row, the expansion's
+    one merge by slot; beside them the two gathers from the tables and the
+    two scatter-adds into them. An edit that splits a pass again shows
+    here and not on the chip (PERF.md section 6, PR 35: a pass costs by its
+    indices, one lane what K lanes cost)."""
+    mesh = data_mesh(mesh_devices) if mesh_devices else None
+    learner = FMLearner(F_ROWS, k=K_ROWS, mesh=mesh)
+    params = learner.init()
+    batch = _batches(write_recurring_libsvm(tmp_path / "i.libsvm"), mesh)[0]
+    tree = batch.tree()
+    lowered = learner._build_step(
+        batch.rows_per_shard, tuple(sorted(tree.keys()))).lower(params, tree)
+    assert _indexed_ops(lowered) == [
+        ("gather", "dp.loss_grad/fm.linear/gather"),            # w[cols]
+        ("gather", "dp.loss_grad/fm.gather/gather"),            # v[cols]
+        ("gather", "dp.loss_grad/jvp(fm.expand)/gather"),       # by slot
+        ("scatter", "dp.loss_grad/jvp(fm.interaction)/scatter-add"),  # row
+        ("gather", "dp.loss_grad/transpose(jvp(fm.interaction))/gather"),
+        ("scatter", "dp.loss_grad/transpose(jvp(fm.expand))/scatter-add"),
+        ("scatter", "dp.apply/scatter-add"),                    # into w
+        ("scatter", "dp.apply/scatter-add"),                    # into v
+    ]
+
+
+def test_fm_mesh_step_exchanges_the_rows_as_their_own_leaves(tmp_path):
+    """The mesh step settles the row gradient's leaves before the exchange
+    (an optimization barrier before ``dp.allreduce``, under no scope): w's rows go into the
+    all-gather as ``[U]`` and come out ``[D, U]``, not as the ``[U, 1]``
+    slice of the merged ``[U, K+1]`` gradient that the chip's compiler
+    pads to 128 lanes (1.84 ms a step for 0.02 in cell
+    kdd2012-fm-dp4.libfm: PERF.md section 6, PR 35;
+    tests/test_step_compiles_for_v5e.py holds the compiled layout)."""
+    mesh = data_mesh(4)
+    learner = FMLearner(F_ROWS, k=K_ROWS, mesh=mesh)
+    batch = _batches(write_recurring_libsvm(tmp_path / "x.libsvm"), mesh)[0]
+    tree = batch.tree()
+    U = tree["cols"].shape[1]
+    lowered = learner._build_step(
+        batch.rows_per_shard, tuple(sorted(tree.keys()))).lower(
+            learner.init(), tree)
+    gathers = [op for op in _lowered_ops(lowered)
+               if op.name == "stablehlo.all_gather"
+               and "f32" in str(op.results[0].type)]
+    assert sorted(tuple(op.results[0].type.shape) for op in gathers) == [
+        (4, U), (4, U, K_ROWS)]
+    for op in gathers:
+        src = op.operands[0].owner
+        while src.name != "stablehlo.optimization_barrier":
+            assert src.name == "stablehlo.broadcast_in_dim", src.name
+            src = src.operands[0].owner
+        assert [tuple(r.type.shape) for r in src.results] == [
+            (), (U,), (U, K_ROWS)]
+
+
+@pytest.mark.parametrize("y", [0.0, 1.0])
+def test_logistic_derivative_has_no_step_at_margin_zero(y):
+    """The loss is composed of a maximum and a minimum whose kinks cancel:
+    at a margin of exactly 0, which a zero start gives every row and
+    ``FMLearner.init()`` every row of one entry, the derivative is
+    sigmoid(0) - y as on either side (with ``-abs`` for the minimum it
+    read -y there: abs' is 1 at 0)."""
+    import jax.numpy as jnp
+    from dmlc_core_tpu.models.linear import objective_loss
+    margin = jnp.asarray([0.0, -0.0, 1e-30, -1e-30, 1e-9, -1e-9, 0.7, -3.0],
+                         jnp.float32)
+    shard = {"label": jnp.full(margin.shape, y, jnp.float32),
+             "weight": jnp.ones(margin.shape, jnp.float32)}
+    got = jax.grad(lambda m: objective_loss(m, shard, margin.size,
+                                            "logistic")[0])(margin)
+    np.testing.assert_allclose(got, jax.nn.sigmoid(margin) - y, atol=1e-7)
+    assert np.all(np.asarray(got)[:6] == 0.5 - y)
+
+
+@pytest.mark.parametrize("k", [8, 16, 32])
+def test_fm_merged_passes_equal_the_lane_by_lane_sums(k):
+    """The margin and its row gradients against the same sums stated a
+    lane at a time (two expansions, three segment sums), on entries that
+    repeat their columns, with padding entries on the sacrificial row id,
+    rows that hold no entry and rows of the list that no entry names."""
+    import jax.numpy as jnp
+    from dmlc_core_tpu.models.fm import FMRows, _fm_margin_rows
+    R, U, NNZ, real = 64, 96, 1024, 700
+    rng = np.random.default_rng(k)
+    # rows 54.. hold no entry; the padding entries name row R, value 0
+    row = np.concatenate([np.sort(rng.integers(0, R - 10, real)),
+                          np.full(NNZ - real, R)]).astype(np.int32)
+    slot = np.concatenate([(80 * rng.random(real) ** 3).astype(np.int32),
+                           np.full(NNZ - real, U - 1, np.int32)])
+    assert len(set(slot[:real])) < real / 4            # repeats
+    val = np.concatenate([rng.normal(size=real),
+                          np.zeros(NNZ - real)]).astype(np.float32)
+    rows = FMRows(jnp.float32(0.3),
+                  jnp.asarray(rng.normal(size=U).astype(np.float32)),
+                  jnp.asarray(0.3 * rng.normal(size=(U, k))
+                              .astype(np.float32)))
+    coef = jnp.asarray(rng.normal(size=R).astype(np.float32))
+
+    def lane_by_lane(rows):
+        w, v = rows.w[slot], rows.v[slot]
+
+        def seg(x):
+            return jax.ops.segment_sum(x, row, num_segments=R + 1)[:R]
+        vx = v * val[:, None]
+        s1, s2 = seg(vx), seg(vx * vx)
+        return rows.b + seg(val * w) + 0.5 * jnp.sum(s1 * s1 - s2, axis=-1)
+
+    def merged(rows):
+        return _fm_margin_rows(rows, slot, row, val, R)
+
+    def readings(margin):
+        def scalar(rows):
+            m = margin(rows)
+            return jnp.sum(coef * m + m * m), m
+        (_, m), grads = jax.jit(jax.value_and_grad(scalar, has_aux=True))(
+            rows)
+        return {"margin": m, **grads._asdict()}
+
+    got, want = readings(merged), readings(lane_by_lane)
+    assert np.all(np.asarray(want["margin"])[R - 10:] == 0.3)
+    for name, b in want.items():
+        a, b = np.asarray(got[name]), np.asarray(b)
+        assert np.linalg.norm(a - b) <= 1e-6 * np.linalg.norm(b), name
+    # rows of the list that no entry names take no gradient; the padding's
+    # slot takes none either (its entries have value 0, and row R is cut)
+    unnamed = np.setdiff1d(np.arange(U), slot[:real])
+    assert unnamed.size and U - 1 in unnamed
+    assert not np.asarray(got["v"])[unnamed].any()
+    assert not np.asarray(got["w"])[unnamed].any()
 
 
 @pytest.mark.parametrize("mesh_devices", [0, 2], ids=["nomesh", "mesh2"])

@@ -49,20 +49,28 @@ def no_compile_cache():
 
 # (configuration, chips, planes of the big pack, nnz rung, distinct rung,
 # the temporaries' limit as a share of the tables): the rungs
-# tests/test_fm_dp.py finds for an epoch of each cell's file. criteo1tb-fm
-# has the most entries a batch beside the smallest tables (0.92 GB of
-# temporaries, 2.28 GB of tables: 0.40), so it has a limit of its own and
-# the others keep theirs. One shape a configuration is all there is to
+# tests/test_fm_dp.py finds for an epoch of each cell's file. Since ISSUE 35
+# the entries' passes carry all their lanes at once, and the compiler keeps
+# two [NNZ, .] intermediates more alive, each padded to 128 lanes whatever
+# its width (302 MB at 589,824 entries, a one-lane [NNZ, 1] column too):
+# 0.391 / 1.289 / 1.542 GB of temporaries where 0.281 / 0.773 / 0.924
+# were, and criteo1tb-fm's step holds 6.11 GB beside tables it does not
+# donate where it held 5.50. kdd2012-fm (0.105 of its 3.72 GB of tables)
+# keeps its limit; kdd2010b-fm, with the widest rows (1.289 beside 3.95:
+# 0.327), and criteo1tb-fm, with the most entries a batch beside the
+# smallest tables (1.542 beside 2.28: 0.676), passed theirs and are held
+# to their readings, so one array more (0.13 of criteo1tb-fm's tables)
+# fails here. One shape a configuration is all there is to
 # compile: since ISSUE 34 an epoch's short last batch is sent at the rungs of
 # the batch before it (device_iter.tail_rung), so criteo1tb-fm-s3, whose
 # part of 24 objects ends every epoch in one, steps at criteo1tb-fm's shape
 @pytest.mark.slow
 @pytest.mark.parametrize("config,chips,planes,nnz,distinct,temp_share", [
     ("kdd2012-fm", 1, 4, 180224, 106496, 0.25),
-    ("kdd2010b-fm", 1, 3, 491520, 262144, 0.25),
+    ("kdd2010b-fm", 1, 3, 491520, 262144, 0.33),
     ("kdd2012-fm-dp4", 4, 4, 180224, 106496, 0.25),
-    ("criteo1tb-fm", 1, 3, 589824, 212992, 0.45),
-    ("criteo1tb-fm-s3", 1, 3, 589824, 212992, 0.45),
+    ("criteo1tb-fm", 1, 3, 589824, 212992, 0.68),
+    ("criteo1tb-fm-s3", 1, 3, 589824, 212992, 0.68),
 ])
 def test_step_compiles_and_fits_beside_the_checks_table(
         topo, no_compile_cache, config, chips, planes, nnz, distinct,
@@ -96,7 +104,7 @@ def test_step_compiles_and_fits_beside_the_checks_table(
           f"alias {m.alias_size_in_bytes}")
     assert m.argument_size_in_bytes >= table
     # no third table: the temporaries are the batch's [NNZ, K] and [U, K]
-    # intermediates (0.28, 0.77 and 0.92 GB in the one-chip cells)
+    # intermediates (0.39, 1.29 and 1.54 GB in the one-chip cells)
     assert m.temp_size_in_bytes < temp_share * table
     # a quarter of the chip at least, and room for the table the
     # benchmark's check regenerates beside the state
@@ -107,6 +115,18 @@ def test_step_compiles_and_fits_beside_the_checks_table(
     # shards' lists go in as one, which ascends within a shard's stretch
     # only, so nothing is promised of it: the compiler sorts the list
     # itself for the scatter into w, and not for the one into v
+    if chips > 1:
+        # the shards' rows are exchanged at their own widths: w's as [D*U]
+        # and not as a [D*U, 1] slice of the merged gradient, which the
+        # compiler pads to 128 lanes (ISSUE 35: 1.84 ms a step for 0.02;
+        # models/_dp.py settles the leaves before the exchange, and
+        # tests/test_fm.py holds that in the lowered step)
+        gathered = [line for line in text.splitlines()
+                    if " all-gather(" in line and "= f32[" in line]
+        assert any(f"= f32[{chips * distinct}]{{" in line
+                   for line in gathered), gathered
+        assert not any(f"= f32[{chips * distinct},1]" in line
+                       for line in gathered), gathered
     into_tables = [line for line in text.splitlines()
                    if " scatter(" in line and f"= f32[{F}" in line]
     assert len(into_tables) == 2, into_tables
