@@ -206,6 +206,28 @@ int dct_flight_dump(const char* reason, int* written) {
   });
 }
 
+// The native pulse (telemetry.h "pulse"): a thread that naps 20 ms at a
+// time and records how late it woke, with no interpreter lock to wait for.
+// Start and stop are idempotent; the Python half starts it beside its own
+// pulse (dmlc_core_tpu.telemetry.pulse_start).
+int dct_pulse_start() {
+  return Guard([&] { dct::telemetry::PulseStart(); });
+}
+
+int dct_pulse_stop() {
+  return Guard([&] { dct::telemetry::PulseStop(); });
+}
+
+// The largest lateness of the native pulse between `since_us_ago` and
+// `until_us_ago` microseconds before now, and how many ticks lay there.
+int dct_pulse_max_late_us(uint64_t since_us_ago, uint64_t until_us_ago,
+                          uint64_t* max_late_us, uint64_t* ticks) {
+  return Guard([&] {
+    *max_late_us =
+        dct::telemetry::PulseMaxLateUs(since_us_ago, until_us_ago, ticks);
+  });
+}
+
 // ----------------------------------------------------------- io resilience --
 // Mirror of dct::io::IoStats (retry.h) — process-global remote-I/O
 // resilience counters, surfaced in Python as io_stats() (alongside the

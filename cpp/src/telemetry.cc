@@ -5,12 +5,14 @@
 
 #include <unistd.h>
 
+#include <condition_variable>
 #include <cstdio>
 #include <cstdlib>
 #include <cstring>
 #include <deque>
 #include <mutex>
 #include <sstream>
+#include <thread>
 #include <vector>
 
 namespace dct {
@@ -120,6 +122,7 @@ bool Enabled() {
 
 void SetEnabled(bool on) {
   g_enabled.store(on ? 1 : 0, std::memory_order_relaxed);
+  if (!on) PulseStop();
 }
 
 Counter* GetCounter(const std::string& name) {
@@ -488,6 +491,100 @@ bool FlightDump(const char* reason) {
   const bool ok = std::fwrite(doc.data(), 1, doc.size(), f) == doc.size();
   std::fclose(f);
   return ok;
+}
+
+// ------------------------------------------------------------------ pulse --
+namespace {
+
+// The ticks change fifty times a second and are read once a long hold:
+// one mutex, held by the thread whenever it is not napping.
+struct Pulse {
+  std::mutex mu;
+  std::condition_variable cv;
+  std::thread napper DMLC_GUARDED_BY(mu);  // joinable while the pulse runs
+  bool stop DMLC_GUARDED_BY(mu) = false;
+  uint64_t due_us DMLC_GUARDED_BY(mu) = 0;  // when the running nap ends
+  uint64_t nticks DMLC_GUARDED_BY(mu) = 0;  // ticks ever recorded
+  uint64_t wake_us[kPulseTicks] DMLC_GUARDED_BY(mu) = {};
+  uint64_t late_us[kPulseTicks] DMLC_GUARDED_BY(mu) = {};
+};
+
+Pulse& ThePulse() {
+  static Pulse* p = new Pulse();  // leaked: a running thread outlives exit
+  return *p;
+}
+
+void PulseLoop(Pulse* p) {
+  Hist* late_hist = GetHist("pulse_native_late_us");
+  std::unique_lock<std::mutex> lk(p->mu);
+  uint64_t due = NowUs();  // the first tick is due at once
+  while (!p->stop) {
+    p->due_us = due;
+    const auto until = std::chrono::steady_clock::time_point(
+        std::chrono::microseconds(due));
+    if (p->cv.wait_until(lk, until, [p] { return p->stop; })) break;
+    const uint64_t woke = NowUs();
+    const uint64_t late = woke > due ? woke - due : 0;
+    late_hist->Observe(late);
+    const size_t slot = p->nticks++ % kPulseTicks;
+    p->wake_us[slot] = woke;
+    p->late_us[slot] = late;
+    due = woke + kPulsePeriodUs;
+  }
+}
+
+}  // namespace
+
+void PulseStart() {
+  if (!Enabled()) return;
+  Pulse& p = ThePulse();
+  std::lock_guard<std::mutex> lk(p.mu);
+  if (p.napper.joinable()) return;
+  p.stop = false;
+  p.napper = std::thread(PulseLoop, &p);
+}
+
+void PulseStop() {
+  Pulse& p = ThePulse();
+  std::thread stopped;
+  {
+    std::lock_guard<std::mutex> lk(p.mu);
+    if (!p.napper.joinable()) return;
+    p.stop = true;
+    stopped = std::move(p.napper);
+  }
+  p.cv.notify_all();
+  stopped.join();
+}
+
+bool PulseRunning() {
+  Pulse& p = ThePulse();
+  std::lock_guard<std::mutex> lk(p.mu);
+  return p.napper.joinable();
+}
+
+uint64_t PulseMaxLateUs(uint64_t since_us_ago, uint64_t until_us_ago,
+                        uint64_t* ticks) {
+  Pulse& p = ThePulse();
+  const uint64_t now = NowUs();
+  const uint64_t from = now > since_us_ago ? now - since_us_ago : 0;
+  const uint64_t to = now > until_us_ago ? now - until_us_ago : 0;
+  uint64_t worst = 0, found = 0;
+  std::lock_guard<std::mutex> lk(p.mu);
+  const uint64_t kept = p.nticks < kPulseTicks ? p.nticks : kPulseTicks;
+  for (uint64_t i = 0; i < kept; ++i) {
+    if (p.wake_us[i] < from || p.wake_us[i] > to) continue;
+    ++found;
+    if (p.late_us[i] > worst) worst = p.late_us[i];
+  }
+  // a nap that should have ended inside the window and has not: the thread
+  // that asks may have got the processor back before this one
+  if (p.napper.joinable() && p.due_us >= from && p.due_us <= to &&
+      now > p.due_us && now - p.due_us > worst) {
+    worst = now - p.due_us;
+  }
+  if (ticks != nullptr) *ticks = found;
+  return worst;
 }
 
 }  // namespace telemetry

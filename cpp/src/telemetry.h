@@ -202,6 +202,29 @@ void TraceReset();
 // the Python half mirrors it for abort paths.
 bool FlightDump(const char* reason);
 
+// ------------------------------------------------------------------ pulse --
+// The native side of the pulse (doc/observability.md "The hold and the
+// pulse"): one thread that asks for a nap of kPulsePeriodUs and records how
+// late it woke into pulse_native_late_us, keeping the last kPulseTicks
+// (wake time, lateness) pairs, about twenty seconds of them. It needs no
+// interpreter lock to wake, so it stops only when the whole process does:
+// beside the Python pulse, which needs the lock, it tells a frozen host from
+// a held lock. One thread a process; the first tick comes at once.
+constexpr uint64_t kPulsePeriodUs = 20000;
+constexpr size_t kPulseTicks = 1024;
+
+// Start the thread unless it runs (or telemetry is disabled); stop and join
+// it. Both idempotent; SetEnabled(false) stops it too.
+void PulseStart();
+void PulseStop();
+bool PulseRunning();
+
+// The largest lateness among the ticks that woke between `since_us_ago` and
+// `until_us_ago` microseconds before now, the running nap counted by how far
+// it is overdue; `*ticks` (may be null) gets the number of ticks found.
+uint64_t PulseMaxLateUs(uint64_t since_us_ago, uint64_t until_us_ago,
+                        uint64_t* ticks);
+
 // -------------------------------------------------------------- io spans --
 // Per-backend remote-I/O latency histograms (connect / time-to-first-
 // header-byte / per-ReadBody recv), labeled {backend="s3"|...}. Resolved

@@ -2040,12 +2040,70 @@ void TestTelemetryConcurrentWritersAndSnapshot() {
   tl::Reset();
 }
 
+void TestPulseTicksBesideWritersAndSnapshot() {
+  // the native pulse (telemetry.h "pulse"): it ticks at once when it
+  // starts, then every 20 ms; one thread however often it is started; a
+  // disabled plane starts none. Under TSan the thread's observations race
+  // writers of other metrics, snapshot walkers and a reader of its ticks.
+  namespace tl = dct::telemetry;
+  tl::Hist* late = tl::GetHist("pulse_native_late_us");
+  tl::PulseStop();
+  late->Zero();
+  tl::SetEnabled(false);
+  tl::PulseStart();
+  EXPECT(!tl::PulseRunning());
+  tl::SetEnabled(true);
+  tl::PulseStart();
+  tl::PulseStart();  // idempotent: still one thread
+  EXPECT(tl::PulseRunning());
+  tl::Counter* c = tl::GetCounter("test_pulse_total");
+  std::atomic<bool> stop{false};
+  std::thread writer([&] {
+    while (!stop.load(std::memory_order_relaxed)) c->Add(1);
+  });
+  std::thread reader([&] {
+    while (!stop.load(std::memory_order_relaxed)) {
+      EXPECT(!tl::SnapshotJson().empty());
+      uint64_t ticks = 0;
+      tl::PulseMaxLateUs(1000000, 0, &ticks);
+    }
+  });
+  std::this_thread::sleep_for(std::chrono::milliseconds(120));
+  stop.store(true);
+  writer.join();
+  reader.join();
+  uint64_t ticks = 0;
+  const uint64_t worst = tl::PulseMaxLateUs(1000000, 0, &ticks);
+  // 120 ms of 20 ms naps: the first at once, then six; a loaded machine
+  // wakes late, never early, so at least a few and at most 7
+  EXPECT(ticks >= 3 && ticks <= 7);
+  EXPECT(late->count() == ticks);
+  EXPECT(worst < 1000000);
+  // a window that ended before the pulse began holds no tick
+  uint64_t none = 7;
+  EXPECT(tl::PulseMaxLateUs(5000000, 4000000, &none) == 0 && none == 0);
+  tl::PulseStop();
+  tl::PulseStop();  // idempotent
+  EXPECT(!tl::PulseRunning());
+  const uint64_t after = late->count();
+  std::this_thread::sleep_for(std::chrono::milliseconds(30));
+  EXPECT(late->count() == after);  // stopped: nothing observes
+  // SetEnabled(false) stops a running pulse
+  tl::PulseStart();
+  EXPECT(tl::PulseRunning());
+  tl::SetEnabled(false);
+  EXPECT(!tl::PulseRunning());
+  tl::SetEnabled(true);
+  tl::Reset();
+}
+
 void RunTelemetrySuite() {
   TestHistBucketBoundaries();
   TestTelemetryRegistryAndSnapshot();
   TestTelemetryEnabledGate();
   TestIoHistsPerBackend();
   TestTelemetryConcurrentWritersAndSnapshot();
+  TestPulseTicksBesideWritersAndSnapshot();
 }
 
 // ---- span ring / distributed tracing (telemetry.h) -- `--trace` suite ----

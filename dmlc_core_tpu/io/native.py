@@ -187,6 +187,11 @@ def _declare_signatures(cdll: ctypes.CDLL) -> None:
         "dct_trace_snapshot": (i, [c.POINTER(c.c_char_p)]),
         "dct_trace_reset": (i, []),
         "dct_flight_dump": (i, [c.c_char_p, c.POINTER(i)]),
+        "dct_pulse_start": (i, []),
+        "dct_pulse_stop": (i, []),
+        "dct_pulse_max_late_us": (i, [c.c_uint64, c.c_uint64,
+                                      c.POINTER(c.c_uint64),
+                                      c.POINTER(c.c_uint64)]),
         "dct_io_retry_stats": (i, [c.POINTER(IoRetryStatsC)]),
         "dct_io_stats_reset": (i, []),
         "dct_io_set_fault_plan": (i, [c.c_char_p]),

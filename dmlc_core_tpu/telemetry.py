@@ -27,9 +27,14 @@ both halves.
 
 from __future__ import annotations
 
+import atexit
+import collections
 import json
+import logging
 import math
 import os
+import resource
+import statistics
 import sys
 import threading
 import time
@@ -45,6 +50,7 @@ __all__ = ["Counter", "Gauge", "Histogram", "HIST_BUCKETS",
            "cluster_prometheus_text", "cluster_trace_json",
            "stall_attribution", "straggler_attribution", "VERDICT_CODES",
            "flight_dump", "device_overlap_ratio", "quantile_from_buckets",
+           "HoldWatch", "pulse_start", "pulse_stop",
            "WindowedView", "SloMonitor", "start_windowed_view",
            "stop_windowed_view", "windowed_view", "slo_page_active"]
 
@@ -294,6 +300,8 @@ def enable(on: bool) -> None:
     (``dct_telemetry_enable``)."""
     global _enabled
     _enabled = bool(on)
+    if not on:
+        pulse_stop()
     lib = _native_lib_if_loaded()
     if lib is not None:
         lib.dct_telemetry_enable(1 if on else 0)
@@ -304,10 +312,14 @@ def reset(native: bool = True) -> None:
     ``native=True`` (default) also zero the native registry when its
     library is loaded (``dct_telemetry_reset``). Also force-stops the
     process :class:`WindowedView` (test isolation: a leaked ticker thread
-    from one test must not publish windows into the next)."""
-    global _spans_dropped
+    from one test must not publish windows into the next), and for the
+    same reason the pulse (the next iterator starts it again) and the count
+    of long-hold records made."""
+    global _spans_dropped, _hold_records, _hold_record_at
     stop_windowed_view(force=True)
+    pulse_stop()
     with _lock:
+        _hold_records, _hold_record_at = 0, 0.0
         for c in _counters.values():
             c.zero()
         for g in _gauges.values():
@@ -797,6 +809,374 @@ def flight_dump(reason: str, rank: Optional[int] = None) -> Optional[str]:
         return None
 
 
+# -- the hold and the pulse (doc/observability.md "The hold and the pulse") ---
+# A consumer of batches spends its time waiting for one (device.wait) or
+# holding one (device.hold: its step and whatever else it does before it asks
+# for the next). A hold far over the running median is a stray stall, and
+# what tells its causes apart exists only at that moment, inside the process:
+# whether the host ran at all, whether the interpreter lock was held, whether
+# the consumer computed or waited, and whether puts to the device went on.
+# The constants are fixed here and stated in the doc; none is a knob.
+PULSE_PERIOD_S = 0.020       # the nap both pulses ask for
+PULSE_TICKS = 1024           # ticks kept, about twenty seconds of them
+HOLD_RING = 64               # holds an iterator keeps for its median
+HOLD_MIN_HOLDS = 8           # no excess and no record before this many
+HOLD_LONG_FLOOR_US = 50_000  # long: this far over the median, or half of it
+HOLD_RECORD_GAP_S = 1.0      # at most one record in this long ...
+HOLD_RECORDS_MAX = 64        # ... and this many a process
+PUT_SLOW_FACTOR = 3          # a put this many times its usual is blocked
+_STACK_FRAMES = 5
+_USUAL_PUTS = 16             # puts before the hold that give the usual time
+
+_pulse_lock = threading.Lock()
+_pulse_thread: Optional[threading.Thread] = None
+_pulse_halt: Optional[threading.Event] = None
+_pulse_native = False        # the native pulse was started from here
+_pulse_at_exit = False
+_pulse_ticks: "collections.deque" = collections.deque(maxlen=PULSE_TICKS)
+_pulse_due_us = 0.0          # when the running nap of the Python pulse ends
+_running_holds: Dict[int, "_RunningHold"] = {}
+_hold_records = 0
+_hold_record_at = 0.0
+_marks_cache: Tuple[int, list, list] = (-1, [], [])
+
+
+def _stack_of(ident: int) -> List[str]:
+    """The innermost frames of thread ``ident`` as ``file:line function``."""
+    frame = sys._current_frames().get(ident)
+    out = []
+    while frame is not None and len(out) < _STACK_FRAMES:
+        code = frame.f_code
+        out.append(f"{os.path.basename(code.co_filename)}:{frame.f_lineno} "
+                   f"{code.co_name}")
+        frame = frame.f_back
+    return out
+
+
+def _pulse_loop(halt: threading.Event) -> None:
+    """The Python pulse: nap, wake (which needs the interpreter lock), and
+    record how late. Each tick is an opened span, so a profiler trace has a
+    ``dmlc.pulse`` line whose holes are where no Python thread could run.
+    A hold that has run past its limit gets the consumer's stack, once."""
+    global _pulse_due_us
+    late_hist = histogram("pulse_py_late_us")
+    due = _perf_us()  # the first tick is due at once
+    while True:
+        _pulse_due_us = due
+        if halt.wait(max(0.0, (due - _perf_us()) / 1e6)):
+            return
+        woke = _perf_us()
+        with span("pulse") as sp:
+            late = max(0.0, woke - due)
+            late_hist.observe(late)
+            _pulse_ticks.append((woke, late))
+            sp.set_arg("late_us", int(late))
+            for run in list(_running_holds.values()):
+                if (run.stack is None and run.limit_us is not None
+                        and woke - run.t0_us > run.limit_us):
+                    run.stack = _stack_of(run.ident)
+        due = _perf_us() + PULSE_PERIOD_S * 1e6
+
+
+def pulse_start() -> None:
+    """Start the two pulses unless they run: a Python daemon thread here
+    and, where the native library is loaded, its thread
+    (``dct_pulse_start``). One pair a process however many iterators call
+    this; nothing starts while telemetry is disabled. They stop at exit, by
+    :func:`pulse_stop` and by ``enable(False)``."""
+    global _pulse_thread, _pulse_halt, _pulse_native, _pulse_at_exit
+    th = _pulse_thread
+    if th is not None and th.is_alive() and _pulse_native:
+        return
+    if not enabled():
+        return
+    with _pulse_lock:
+        if _pulse_thread is None or not _pulse_thread.is_alive():
+            _pulse_halt = threading.Event()
+            _pulse_thread = threading.Thread(
+                target=_pulse_loop, args=(_pulse_halt,), name="dmlc-pulse",
+                daemon=True)
+            _pulse_thread.start()
+            if not _pulse_at_exit:
+                _pulse_at_exit = True
+                atexit.register(pulse_stop)
+        lib = _native_lib_if_loaded()
+        if lib is not None and not _pulse_native:
+            lib.dct_pulse_start()
+            _pulse_native = True
+
+
+def pulse_stop() -> None:
+    """Stop both pulses and wait for their threads (idempotent)."""
+    global _pulse_thread, _pulse_halt, _pulse_native
+    with _pulse_lock:
+        th, halt = _pulse_thread, _pulse_halt
+        _pulse_thread = _pulse_halt = None
+        native, _pulse_native = _pulse_native, False
+    if halt is not None:
+        halt.set()
+    if th is not None and th is not threading.current_thread():
+        th.join(timeout=2.0)
+    lib = _native_lib_if_loaded()
+    if native and lib is not None:
+        lib.dct_pulse_stop()
+
+
+def _pulse_late_us(t0_us: float, t1_us: float
+                   ) -> Tuple[float, Optional[float]]:
+    """The largest lateness of each pulse between two perf-counter times:
+    (python, native); native is None where that pulse does not run. A nap
+    that should have ended in the interval and has not counts by how far it
+    is overdue: the thread that asks may have got the processor, or the
+    interpreter lock, back before the pulse."""
+    py = max((late for woke, late in list(_pulse_ticks)
+              if t0_us <= woke <= t1_us), default=0.0)
+    now = _perf_us()
+    th = _pulse_thread
+    if th is not None and th.is_alive() and t0_us <= _pulse_due_us <= t1_us:
+        py = max(py, now - _pulse_due_us)
+    lib = _native_lib_if_loaded()
+    if lib is None or not _pulse_native:
+        return py, None
+    import ctypes
+    late = ctypes.c_uint64(0)
+    lib.dct_pulse_max_late_us(int(max(0.0, now - t0_us)),
+                              int(max(0.0, now - t1_us)),
+                              ctypes.byref(late), None)
+    return py, float(late.value)
+
+
+def _compile_marks() -> Tuple[float, int]:
+    """(``device_compile_us``' sum, ``model_step_builds_total`` over its
+    labels) now: their rise inside a hold says the step compiled there. The
+    matching metric objects are looked up again only when the registry has
+    grown."""
+    global _marks_cache
+    size = len(_hists) + len(_counters)
+    if _marks_cache[0] != size:
+        with _lock:
+            _marks_cache = (
+                size,
+                [h for (n, _), h in _hists.items()
+                 if n == "device_compile_us"],
+                [c for (n, _), c in _counters.items()
+                 if n == "model_step_builds_total"])
+    _, hists, counters = _marks_cache
+    return sum(h.sum for h in hists), sum(c.value for c in counters)
+
+
+class _RunningHold:
+    """A hold that has begun: what its end, and the pulse, need of it."""
+
+    __slots__ = ("ident", "t0_us", "cpu_s", "median_us", "limit_us", "marks",
+                 "rusage", "stack")
+
+    def __init__(self, median_us: Optional[float]):
+        self.ident = threading.get_ident()
+        self.median_us = median_us
+        # a hold is long when hold - median >= max(50 ms, median / 2)
+        self.limit_us = None if median_us is None else median_us + max(
+            HOLD_LONG_FLOOR_US, median_us / 2)
+        self.stack: Optional[List[str]] = None
+        self.marks = _compile_marks()
+        self.rusage = resource.getrusage(resource.RUSAGE_SELF)
+        self.cpu_s = time.thread_time()
+        self.t0_us = _perf_us()
+
+
+class HoldWatch:
+    """One iterator's account of its consumer's holds: :meth:`begin` as a
+    batch is handed over (just before the ``yield``), :meth:`end` when the
+    consumer asks for the next, :meth:`abandon` from the generator's
+    ``finally``. Every hold is a post hoc span ``device.hold`` (with the
+    consumer thread's CPU time as ``cpu_us``), an observation of
+    ``device_hold_us`` and one of ``device_hold_excess_us``, the hold less
+    the median of the iterator's last :data:`HOLD_RING` holds (0 until
+    :data:`HOLD_MIN_HOLDS` are in). A hold that ends far over the median
+    is counted in ``device_long_holds_total`` and recorded with a verdict
+    (:func:`_long_hold`)."""
+
+    __slots__ = ("_holds", "_run", "_hold_us", "_excess_us", "_long")
+
+    def __init__(self):
+        self._holds: "collections.deque" = collections.deque(maxlen=HOLD_RING)
+        self._run: Optional[_RunningHold] = None
+        self._hold_us = histogram("device_hold_us")
+        self._excess_us = histogram("device_hold_excess_us")
+        self._long = counter("device_long_holds_total")
+
+    def begin(self) -> None:
+        """The consumer is about to be handed a batch (a no-op while
+        telemetry is disabled)."""
+        if not enabled():
+            return
+        holds = self._holds
+        self._run = run = _RunningHold(
+            statistics.median(holds) if len(holds) >= HOLD_MIN_HOLDS
+            else None)
+        _running_holds[id(self)] = run
+
+    def end(self, put_since_us: Optional[float] = None) -> None:
+        """``put_since_us``: when the put now in flight began, if one is."""
+        run = self._run
+        if run is None:
+            return
+        t1_us = _perf_us()
+        cpu_us = (time.thread_time() - run.cpu_s) * 1e6
+        self.abandon()
+        hold_us = t1_us - run.t0_us
+        self._hold_us.observe(hold_us)
+        self._excess_us.observe(  # a hold under the median observes 0
+            0 if run.median_us is None else hold_us - run.median_us)
+        self._holds.append(hold_us)
+        if run.limit_us is not None and hold_us >= run.limit_us:
+            self._long.inc()
+            _long_hold(run, t1_us, hold_us, cpu_us, put_since_us)
+        emit_span("device.hold", run.t0_us, hold_us, cpu_us=int(cpu_us))
+
+    def abandon(self) -> None:
+        """Forget the running hold, if any (the consumer dropped the
+        generator: no hold is recorded)."""
+        self._run = None
+        _running_holds.pop(id(self), None)
+
+
+def _spans_over(t0_us: float, t1_us: float
+                ) -> Tuple[dict, dict, Optional[float]]:
+    """What both span rings hold of the interval (perf-counter times): for
+    each lane the milliseconds its spans cover of it, by span name, and the
+    ``device.put`` spans apart, with the shortest of those that completed
+    inside it. The Python ring is on the interval's clock;
+    the native ring's steady clock is reached through the two anchor pairs,
+    by way of the wall clock. A ring is in completion order, so the walk
+    goes back from its newest span and stops once it is before the interval
+    and has the usual puts."""
+    lanes: Dict[str, Dict[str, float]] = {}
+    inside = {"device.put": [], "device.put.block": []}
+    usual: List[float] = []
+
+    def walk(records, cat, tid_base, lo, hi):
+        for s in reversed(records):
+            ts, end = s["ts"], s["ts"] + s["dur"]
+            name = s["name"]
+            if end < lo:
+                if cat == "native" or len(usual) >= _USUAL_PUTS:
+                    break
+                if name == "device.put":
+                    usual.append(s["dur"])
+                continue
+            if name in inside and end <= hi:
+                inside[name].append(s["dur"])
+            covered = min(end, hi) - max(ts, lo)
+            if covered > 0:
+                lane = lanes.setdefault(f"{cat}:{tid_base + int(s['tid'])}",
+                                        {})
+                lane[name] = lane.get(name, 0.0) + covered / 1e3
+
+    walk(spans(), "python", 0, t0_us, t1_us)
+    nat = _native_trace_doc()
+    if nat:
+        a, na = clock_anchor(), nat.get("anchor") or {}
+        shift = (a["wall_us"] - a["perf_us"]) - (
+            float(na.get("wall_us", 0)) - float(na.get("steady_us", 0)))
+        # native lanes keep the tid namespace _wall_spans gives them
+        walk(nat.get("spans", ()), "native", 1000, t0_us + shift,
+             t1_us + shift)
+    top = {lane: {n: round(ms, 1) for n, ms in sorted(
+        by_name.items(), key=lambda kv: -kv[1])[:4]}
+        for lane, by_name in sorted(lanes.items())}
+    puts = {"n": len(inside["device.put"]),
+            "median_us": (statistics.median(inside["device.put"])
+                          if inside["device.put"] else None),
+            "block_n": len(inside["device.put.block"]),
+            "block_median_us": (statistics.median(inside["device.put.block"])
+                                if inside["device.put.block"] else None),
+            "usual_us": statistics.median(usual) if usual else None}
+    return top, puts, min(inside["device.put"], default=None)
+
+
+def _puts_state(fastest_us: Optional[float], usual_us: Optional[float],
+                in_flight_us: Optional[float]) -> str:
+    """What the path to the device did during a hold the consumer waited
+    through: ``puts_flowing`` (a put completed inside it in under
+    :data:`PUT_SLOW_FACTOR` times the usual), ``puts_blocked`` (one
+    completed that slowly, or is in flight that long) or ``puts_idle`` (no
+    put ran inside it)."""
+    slow = None if usual_us is None else PUT_SLOW_FACTOR * usual_us
+    if fastest_us is not None:
+        return ("puts_flowing" if slow is None or fastest_us < slow
+                else "puts_blocked")
+    if in_flight_us is not None and in_flight_us >= (slow or 0.0):
+        return "puts_blocked"
+    return "puts_idle"
+
+
+def _long_hold(run: _RunningHold, t1_us: float, hold_us: float,
+               cpu_us: float, put_since_us: Optional[float]) -> None:
+    """Build the record of one long hold on the consumer's thread and send
+    it out three ways: the event ``long_hold``, one WARNING line on the
+    package's logger, and a flight dump (a file only where
+    ``DMLC_TRACE_DUMP`` is set). At most one record in
+    :data:`HOLD_RECORD_GAP_S` and :data:`HOLD_RECORDS_MAX` a process; the
+    counter has counted the hold already. The verdict, in this order:
+    ``compile`` (compile time inside the hold is half the excess or more),
+    ``host_frozen`` (both pulses that late), ``gil_held`` (the Python pulse
+    alone), ``consumer_busy`` (the consumer's CPU time is half the hold or
+    more), else ``consumer_waiting`` with what the puts did beside it."""
+    global _hold_records, _hold_record_at
+    with _lock:
+        now = time.monotonic()
+        if (_hold_records >= HOLD_RECORDS_MAX
+                or now - _hold_record_at < HOLD_RECORD_GAP_S):
+            return
+        _hold_records += 1
+        _hold_record_at = now
+    try:
+        ru0, ru1 = run.rusage, resource.getrusage(resource.RUSAGE_SELF)
+        marks = _compile_marks()
+        compile_us = marks[0] - run.marks[0]
+        py_late, native_late = _pulse_late_us(run.t0_us, t1_us)
+        lanes, puts, fastest = _spans_over(run.t0_us, t1_us)
+        half = (hold_us - run.median_us) / 2
+        if compile_us >= half:
+            verdict = "compile"
+        elif py_late >= half and (native_late or 0.0) >= half:
+            verdict = "host_frozen"
+        elif py_late >= half:
+            verdict = "gil_held"
+        elif cpu_us >= hold_us / 2:
+            verdict = "consumer_busy"
+        else:
+            verdict = "consumer_waiting"
+        rec = {"verdict": verdict, "hold_us": int(hold_us),
+               "median_us": int(run.median_us), "cpu_us": int(cpu_us),
+               "pulse_py_late_us": int(py_late),
+               "pulse_native_late_us":
+                   None if native_late is None else int(native_late),
+               "compile_us": int(compile_us),
+               "step_builds": int(marks[1] - run.marks[1]),
+               "utime_us": int((ru1.ru_utime - ru0.ru_utime) * 1e6),
+               "stime_us": int((ru1.ru_stime - ru0.ru_stime) * 1e6),
+               "nvcsw": ru1.ru_nvcsw - ru0.ru_nvcsw,
+               "nivcsw": ru1.ru_nivcsw - ru0.ru_nivcsw,
+               "majflt": ru1.ru_majflt - ru0.ru_majflt,
+               "put_spans": puts, "lanes": lanes,
+               "stack": run.stack or []}
+        if verdict == "consumer_waiting":
+            rec["puts"] = _puts_state(
+                fastest, puts["usual_us"],
+                None if put_since_us is None else t1_us - put_since_us)
+        emit_event("long_hold", **rec)
+        logging.getLogger("dmlc_core_tpu").warning(
+            "long hold: %.1f ms for a median of %.1f: %s %s",
+            hold_us / 1e3, run.median_us / 1e3,
+            verdict + ("/" + rec["puts"] if "puts" in rec else ""),
+            json.dumps(rec, separators=(",", ":")))
+        flight_dump("long-hold: " + verdict)
+    except Exception:  # a record that fails must not stop the loop it watches
+        logging.getLogger("dmlc_core_tpu").exception("long hold: no record")
+
+
 # -- cluster aggregation (the tracker's /metrics + /trace) -------------------
 def rank_export(max_spans: int = 2048) -> dict:
     """The per-rank telemetry document a worker ships to the tracker in
@@ -1119,6 +1499,25 @@ METRIC_HELP: Dict[str, str] = {
     "device_turnover_us":
         "one before_first(): staging threads joined and the batcher "
         "reset (us)",
+    "device_hold_us":
+        "the consumer's hold of one batch: handed over, to its asking for "
+        "the next (its step and loss read); with device_wait_us the "
+        "consumer's whole time (us)",
+    "device_hold_excess_us":
+        "a hold less the median of the iterator's last 64, 0 where under "
+        "it or before eight holds: observed every hold, so its sum is the "
+        "time lost to stray stalls (us)",
+    "device_long_holds_total":
+        "holds that ended max(50 ms, median / 2) or more over the median; "
+        "each is the event long_hold with a verdict, at most one record a "
+        "second and 64 a process",
+    "pulse_py_late_us":
+        "how late a Python thread woke from a nap of 20 ms: it needs the "
+        "interpreter lock, so a held lock or a frozen host shows at its "
+        "length (us)",
+    "pulse_native_late_us":
+        "how late a native thread woke from a nap of 20 ms: it needs no "
+        "interpreter lock, so only a frozen host or process shows (us)",
     "model_step_dispatch_us":
         "one learner step handed to the runtime, host side; the call that "
         "built the step function traces and compiles inside it (us)",

@@ -1394,6 +1394,11 @@ class DeviceRowBlockIter:
         # the next batch is the first after a (re)start: its wait is the
         # one device_first_batch_wait_us keeps
         self._first_wait = True
+        # the consumer's holds between batches (telemetry.HoldWatch), and
+        # when the put now in flight began: a put that hangs with a long
+        # hold says the path to the device stood still
+        self._hold = telemetry.HoldWatch()
+        self._put_since_us: Optional[float] = None
         # epoch ordinal: selects the shuffle permutation for shuffled URIs
         # (?shuffle_parts= / ?index=&shuffle=1). The split samples epoch 0's
         # permutation at construction; before_first() advances it. state()
@@ -1551,6 +1556,7 @@ class DeviceRowBlockIter:
         self._deferred = keep
 
     def _ensure_started(self) -> None:
+        telemetry.pulse_start()
         if self._thread is None:
             self._stop.clear()
             self._thread = threading.Thread(target=self._parse_loop,
@@ -1579,6 +1585,8 @@ class DeviceRowBlockIter:
         # the block itself is unconditional (semantics must not depend
         # on DMLC_TELEMETRY).
         tel = telemetry.enabled()
+        if tel:
+            self._put_since_us = time.perf_counter() * 1e6
         try:
             # the parent span is OPENED (telemetry.span), not emitted
             # post-hoc, so the submit/block children below genuinely
@@ -1619,6 +1627,8 @@ class DeviceRowBlockIter:
             m["failures"].inc()
             telemetry.flight_dump("device-put-failure")
             raise
+        finally:
+            self._put_since_us = None
         m["batches"].inc()
         m["bytes"].inc(nbytes)
         cls = type(batch)
@@ -1740,29 +1750,39 @@ class DeviceRowBlockIter:
                     f"data stream disagree")
             if hasattr(self.batcher, "recycle"):
                 self.batcher.recycle(batch)
-        while True:
-            # inline, every batch is waited for in full; only the first
-            # after a (re)start is recorded as a device.wait, for
-            # device_first_batch_wait_us
-            first, self._first_wait = self._first_wait, False
-            with (telemetry.span("device.wait", first=1)
-                  if first and telemetry.enabled() else _NO_SPAN) as sp:
-                host = self._stage_next(m)
-                if host is None:
-                    return
-                _note_shape(host)
-                item = self._device_put(host)
-                if sp is not None:
-                    m["first_wait_us"].observe(sp.elapsed_us)
-            self.batches_consumed += 1
-            if recycle_ok and item is not host:
-                # same alias-probed direct-or-deferred recycling as the
-                # transfer thread: _device_put blocked until the DMA
-                # landed, so the host buffers are refillable unless the
-                # device arrays alias them — in which case they are
-                # parked and reclaimed once the consumer drops `item`
-                self._recycle_or_defer(host, item, m)
-            yield item
+        telemetry.pulse_start()
+        hold = self._hold
+        try:
+            while True:
+                # inline, every batch is waited for in full; only the first
+                # after a (re)start is recorded as a device.wait, for
+                # device_first_batch_wait_us
+                first, self._first_wait = self._first_wait, False
+                with (telemetry.span("device.wait", first=1)
+                      if first and telemetry.enabled() else _NO_SPAN) as sp:
+                    host = self._stage_next(m)
+                    if host is None:
+                        return
+                    _note_shape(host)
+                    item = self._device_put(host)
+                    if sp is not None:
+                        m["first_wait_us"].observe(sp.elapsed_us)
+                self.batches_consumed += 1
+                if recycle_ok and item is not host:
+                    # same alias-probed direct-or-deferred recycling as the
+                    # transfer thread: _device_put blocked until the DMA
+                    # landed, so the host buffers are refillable unless the
+                    # device arrays alias them — in which case they are
+                    # parked and reclaimed once the consumer drops `item`
+                    self._recycle_or_defer(host, item, m)
+                # device.hold: from handing the batch over to being asked
+                # for the next (recorded post hoc: an opened span may not
+                # straddle a yield)
+                hold.begin()
+                yield item
+                hold.end()
+        finally:
+            hold.abandon()  # a dropped generator records no hold
 
     def __iter__(self) -> Iterator[PaddedBatch]:
         if self._prefetch == 0:
@@ -1770,38 +1790,50 @@ class DeviceRowBlockIter:
             return
         self._ensure_started()
         m = _get_lane_metrics()
-        while True:
-            # device.wait: consumer head-of-line — the time this thread
-            # stood idle because staging/transfer had not delivered the
-            # next READY batch. The complement of these intervals is the
-            # consumer's compute time, which is what the overlap ratio
-            # (telemetry.device_overlap_ratio) intersects device.put
-            # spans against. An OPENED span (a profiler annotation too);
-            # the first wait after a (re)start carries first=1 and also
-            # feeds device_first_batch_wait_us: how late an epoch's first
-            # batch comes.
-            if telemetry.enabled():
-                first, self._first_wait = self._first_wait, False
-                with telemetry.span("device.wait",
-                                    **({"first": 1} if first else {})) as sp:
+        hold = self._hold
+        try:
+            while True:
+                # device.wait: consumer head-of-line — the time this thread
+                # stood idle because staging/transfer had not delivered the
+                # next READY batch. The complement of these intervals is
+                # the consumer's holds (device.hold below), which is what
+                # the overlap ratio (telemetry.device_overlap_ratio)
+                # intersects device.put spans against. An OPENED span (a
+                # profiler annotation too); the first wait after a
+                # (re)start carries first=1 and also feeds
+                # device_first_batch_wait_us: how late an epoch's first
+                # batch comes.
+                if telemetry.enabled():
+                    first, self._first_wait = self._first_wait, False
+                    with telemetry.span(
+                            "device.wait",
+                            **({"first": 1} if first else {})) as sp:
+                        item = self._queue.get()
+                        dur_us = sp.elapsed_us
+                    m["wait_us"].observe(dur_us)
+                    if first:
+                        m["first_wait_us"].observe(dur_us)
+                else:
                     item = self._queue.get()
-                    dur_us = sp.elapsed_us
-                m["wait_us"].observe(dur_us)
-                if first:
-                    m["first_wait_us"].observe(dur_us)
-            else:
-                item = self._queue.get()
-            m["ready_q"].set(self._queue.qsize())
-            if item is None:
-                self._thread = None
-                self._xfer_thread = None
-                return
-            if isinstance(item, BaseException):
-                self._thread = None
-                self._xfer_thread = None
-                raise item
-            self.batches_consumed += 1
-            yield item
+                m["ready_q"].set(self._queue.qsize())
+                if item is None:
+                    self._thread = None
+                    self._xfer_thread = None
+                    return
+                if isinstance(item, BaseException):
+                    self._thread = None
+                    self._xfer_thread = None
+                    raise item
+                self.batches_consumed += 1
+                # device.hold: from handing the batch over to being asked
+                # for the next: the consumer's step and whatever else it
+                # does (recorded post hoc: an opened span may not straddle
+                # a yield). With device.wait it is the consumer's whole time
+                hold.begin()
+                yield item
+                hold.end(self._put_since_us)
+        finally:
+            hold.abandon()  # a dropped generator records no hold
 
     # -- mid-epoch checkpoint/resume ----------------------------------------
     def state(self) -> Dict[str, Any]:

@@ -18,6 +18,12 @@ Covers the ISSUE 15 acceptance surface on the deterministic CPU backend:
   counts between them land on an eighth-of-an-octave rung); replaying the
   same corpus reports zero new shapes.
 - `_device_put` failures: counted and flight-dumped like host aborts.
+- The hold (ISSUE 36): the time between handing a batch over and being
+  asked for the next is `device.hold` / `device_hold_us` on both consumer
+  loops, with `device_wait_us` the consumer's whole time; a hold far over
+  the running median is counted once and recorded with a verdict, each
+  verdict produced by its made cause (a sleep, a spin, a held interpreter
+  lock, a stopped process, a compile; puts that flow, hang or do not run).
 - One clock for host and device (ISSUE 26): under `jax.profiler` the
   program's opened spans are `dmlc.*` events of the trace's host plane;
   the learner's step counts its builds and times its dispatch; turnover
@@ -28,10 +34,16 @@ Covers the ISSUE 15 acceptance surface on the deterministic CPU backend:
 
 from __future__ import annotations
 
+import ctypes
 import json
+import logging
 import os
 import random
 import re
+import signal
+import subprocess
+import sys
+import threading
 import time
 
 import numpy as np
@@ -579,3 +591,296 @@ def test_jax_profiler_capture_noop_without_env(monkeypatch):
     monkeypatch.delenv("DMLC_JAX_PROFILE", raising=False)
     with jax_profiler_capture() as started:
         assert started is False
+
+
+# -- the hold between batches (ISSUE 36) ---------------------------------------
+HOLD_BATCH = 64
+
+
+def _consume(path, act, **kw):
+    """Every batch of the file through the iterator, ``act(i)`` as the
+    consumer's hold of batch ``i``; returns the loop's wall seconds and the
+    seconds ``act`` took by the test's own clock."""
+    kw.setdefault("batch_rows", HOLD_BATCH)
+    kw.setdefault("min_nnz_bucket", 128)
+    kw.setdefault("layout", "csr")
+    held = 0.0
+    with DeviceRowBlockIter(path, **kw) as it:
+        t0 = time.perf_counter()
+        for i, _batch in enumerate(it):
+            t = time.perf_counter()
+            act(i)
+            held += time.perf_counter() - t
+        return time.perf_counter() - t0, held
+
+
+def _hist_sum(name):
+    return sum(h["sum"] for h in telemetry.snapshot()["histograms"]
+               if h["name"] == name)
+
+
+def _long_holds():
+    return [e for e in telemetry.events() if e["event"] == "long_hold"]
+
+
+def _one_long(i, long_act, at=20, short_s=0.01):
+    """Twenty short holds, then one made long by ``long_act``."""
+    if i == at:
+        long_act()
+    else:
+        time.sleep(short_s)
+
+
+@pytest.mark.parametrize("prefetch", [0, 2])
+def test_hold_and_excess_are_observed_every_batch(tmp_path, prefetch):
+    batches = 20
+    path = write_libsvm(tmp_path / "h.libsvm", rows=HOLD_BATCH * batches)
+    _, held = _consume(path, lambda i: time.sleep(0.03), prefetch=prefetch)
+    assert _hist("device_hold_us") == batches
+    assert _hist("device_hold_excess_us") == batches
+    spans = telemetry.spans()
+    holds = [s for s in spans if s["name"] == "device.hold"]
+    assert len(holds) == batches
+    assert all(0 <= s["args"]["cpu_us"] <= s["dur"] + 1000 for s in holds)
+    # the hold is what the consumer did between two batches, no more
+    hold_s = _hist_sum("device_hold_us") / 1e6
+    assert held <= hold_s <= held * 1.02, (held, hold_s)
+    # no hold ran long: the excess is the jitter of a 30 ms sleep
+    assert _hist_sum("device_hold_excess_us") < 0.1 * hold_s * 1e6
+    assert _long_holds() == []
+    if prefetch:
+        # wait + hold is the consumer's whole time, from its first asking
+        # for a batch to its being told there is none left
+        waits = [s for s in spans if s["name"] == "device.wait"]
+        assert len(waits) == _hist("device_wait_us") == batches + 1
+        wall_us = waits[-1]["ts"] + waits[-1]["dur"] - waits[0]["ts"]
+        covered = _hist_sum("device_wait_us") + hold_s * 1e6
+        assert wall_us * 0.98 <= covered <= wall_us, (wall_us, covered)
+
+
+def test_an_abandoned_generator_records_no_hold(tmp_path):
+    path = write_libsvm(tmp_path / "g.libsvm", rows=HOLD_BATCH * 8)
+    with DeviceRowBlockIter(path, batch_rows=HOLD_BATCH, layout="csr",
+                            min_nnz_bucket=128) as it:
+        gen = iter(it)
+        next(gen)
+        next(gen)           # ends the first hold, begins the second
+        assert telemetry._running_holds
+        gen.close()         # the consumer walks away holding a batch
+        assert not telemetry._running_holds
+    assert _hist("device_hold_us") == 1
+
+
+def test_one_long_hold_is_counted_once_recorded_and_logged(tmp_path, caplog):
+    path = write_libsvm(tmp_path / "l.libsvm", rows=HOLD_BATCH * 30)
+    with caplog.at_level(logging.WARNING, logger="dmlc_core_tpu"):
+        _consume(path, lambda i: _one_long(i, lambda: time.sleep(0.3)))
+    snap = telemetry.snapshot()
+    assert [c["value"] for c in snap["counters"]
+            if c["name"] == "device_long_holds_total"] == [1]
+    recs = _long_holds()
+    assert len(recs) == 1
+    rec = recs[0]
+    assert {"verdict", "hold_us", "median_us", "cpu_us", "pulse_py_late_us",
+            "pulse_native_late_us", "compile_us", "step_builds", "utime_us",
+            "stime_us", "nvcsw", "nivcsw", "majflt", "put_spans", "lanes",
+            "stack", "puts"} <= set(rec)
+    assert 290_000 <= rec["hold_us"] <= 600_000
+    assert 9_000 <= rec["median_us"] <= 30_000
+    assert rec["verdict"] == "consumer_waiting"
+    assert rec["compile_us"] == 0 and rec["step_builds"] == 0
+    assert {"n", "median_us", "block_n", "block_median_us",
+            "usual_us"} == set(rec["put_spans"])
+    # the pulse saw the hold run past its limit and took the consumer's
+    # stack: the test's own sleep, innermost first
+    assert rec["stack"] and "test_device_observability.py" in rec["stack"][0]
+    assert len(rec["stack"]) <= 5
+    # the record is an event of every snapshot, and of the jsonl export
+    assert any(e["event"] == "long_hold" for e in snap["events"])
+    assert '"long_hold"' in telemetry.events_jsonl()
+    # and one WARNING line on the package's logger
+    lines = [r for r in caplog.records if r.name == "dmlc_core_tpu"
+             and r.levelno == logging.WARNING
+             and r.getMessage().startswith("long hold:")]
+    assert len(lines) == 1
+    assert "consumer_waiting" in lines[0].getMessage()
+    # the excess histogram holds the stall's weight, the count the holds'
+    assert _hist("device_hold_excess_us") == 30
+    assert 250_000 <= _hist_sum("device_hold_excess_us") <= 700_000
+
+
+def test_long_hold_dumps_the_flight_recorder(tmp_path, monkeypatch):
+    monkeypatch.setenv("DMLC_TRACE_DUMP", str(tmp_path / "dump"))
+    path = write_libsvm(tmp_path / "d.libsvm", rows=HOLD_BATCH * 30)
+    _consume(path, lambda i: _one_long(i, lambda: time.sleep(0.2)))
+    dumps = sorted((tmp_path / "dump").glob("flight_*.json"))
+    assert len(dumps) == 1
+    doc = json.loads(dumps[0].read_text())
+    assert doc["reason"] == "long-hold: consumer_waiting"
+    assert any(e["event"] == "long_hold" for e in doc["metrics"]["events"])
+
+
+def _spin(seconds):
+    end = time.perf_counter() + seconds
+    while time.perf_counter() < end:
+        pass
+
+
+def _hold_the_gil(seconds):
+    """A helper thread that keeps the interpreter lock through a C call
+    (``ctypes.PyDLL`` does not release it) while the consumer sleeps."""
+    usleep = ctypes.PyDLL(None).usleep
+    th = threading.Thread(target=usleep, args=(int(seconds * 1e6),))
+    th.start()
+    time.sleep(0.02)   # gives the lock up; wants it back after 20 ms
+    th.join()
+
+
+def _compile_something():
+    """A step whose shape is new: a jitted function never seen before (the
+    constant keeps it out of any compile cache)."""
+    salt = random.random()
+
+    @jax.jit
+    def f(x):
+        for _ in range(120):
+            x = jax.numpy.sin(x) * salt + x[::-1]
+        return x
+
+    jax.block_until_ready(f(np.arange(17, dtype=np.float32)))
+
+
+@pytest.mark.parametrize("cause,verdict", [
+    (lambda: time.sleep(0.3), "consumer_waiting"),
+    (lambda: _spin(0.3), "consumer_busy"),
+    (lambda: _hold_the_gil(0.3), "gil_held"),
+    (_compile_something, "compile"),
+], ids=["sleep", "spin", "gil", "compile"])
+def test_long_hold_verdict_follows_the_made_cause(tmp_path, cause, verdict):
+    path = write_libsvm(tmp_path / "v.libsvm", rows=HOLD_BATCH * 30)
+    _consume(path, lambda i: _one_long(i, cause))
+    recs = _long_holds()
+    assert [r["verdict"] for r in recs] == [verdict], recs
+    rec = recs[0]
+    excess = rec["hold_us"] - rec["median_us"]
+    if verdict == "gil_held":
+        # the Python pulse could not wake, the native one could
+        assert rec["pulse_py_late_us"] >= excess / 2
+        assert rec["pulse_native_late_us"] < excess / 2
+        assert rec["cpu_us"] < rec["hold_us"] / 2
+    elif verdict == "compile":
+        assert rec["compile_us"] >= excess / 2
+    else:
+        assert rec["pulse_py_late_us"] < excess / 2
+        assert rec["compile_us"] == 0
+        busy = rec["cpu_us"] >= rec["hold_us"] / 2
+        assert busy == (verdict == "consumer_busy")
+    assert ("puts" in rec) == (verdict == "consumer_waiting")
+
+
+def test_long_hold_of_a_stopped_process_reads_host_frozen(tmp_path):
+    path = write_libsvm(tmp_path / "f.libsvm", rows=HOLD_BATCH * 45)
+    worker = os.path.join(REPO, "tests", "hold_worker.py")
+    proc = subprocess.Popen([sys.executable, worker, REPO, path],
+                            stdout=subprocess.PIPE, text=True,
+                            env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    try:
+        assert proc.stdout.readline().strip() == "READY"
+        time.sleep(0.05)
+        proc.send_signal(signal.SIGSTOP)   # every thread stops, mid-hold
+        time.sleep(0.3)
+        proc.send_signal(signal.SIGCONT)
+        out, _ = proc.communicate(timeout=60)
+    finally:
+        proc.kill()
+    assert proc.returncode == 0
+    recs = json.loads(out.strip().splitlines()[-1])
+    assert [r["verdict"] for r in recs] == ["host_frozen"], recs
+    rec = recs[0]
+    assert rec["hold_us"] >= 300_000
+    half = (rec["hold_us"] - rec["median_us"]) / 2
+    assert rec["pulse_py_late_us"] >= half
+    assert rec["pulse_native_late_us"] >= half
+
+
+@pytest.mark.parametrize("prefetch,hang,want", [
+    (2, False, "puts_flowing"), (2, True, "puts_blocked"),
+    (0, False, "puts_idle")], ids=["flowing", "blocked", "idle"])
+def test_waiting_consumer_says_what_the_puts_did(tmp_path, monkeypatch,
+                                                 prefetch, hang, want):
+    path = write_libsvm(tmp_path / "p.libsvm", rows=HOLD_BATCH * 30)
+    puts = []
+    real = DeviceRowBlockIter._copy_put
+
+    def slow_put(self, tree):
+        # a put takes its usual 3 ms; from the long hold on, where the
+        # test asks for it, the path to the device stands still
+        puts.append(1)
+        time.sleep(0.5 if hang and len(puts) > 22 else 0.003)
+        return real(self, tree)
+
+    monkeypatch.setattr(DeviceRowBlockIter, "_copy_put", slow_put)
+    monkeypatch.setenv("DMLC_DEVICE_ZERO_COPY", "0")
+    _consume(path, lambda i: _one_long(i, lambda: time.sleep(0.3)),
+             prefetch=prefetch)
+    recs = _long_holds()
+    assert recs and recs[0]["verdict"] == "consumer_waiting"
+    assert recs[0]["puts"] == want, recs[0]
+    if want == "puts_flowing":
+        assert recs[0]["put_spans"]["n"] >= 1
+        assert any("device.put" in names
+                   for names in recs[0]["lanes"].values())
+
+
+def test_long_hold_records_are_capped_at_one_a_second(tmp_path):
+    path = write_libsvm(tmp_path / "c.libsvm", rows=HOLD_BATCH * 30)
+    _consume(path, lambda i: time.sleep(0.12 if i in (20, 22, 24)
+                                        else 0.005))
+    count = [c["value"] for c in telemetry.snapshot()["counters"]
+             if c["name"] == "device_long_holds_total"]
+    assert count == [3]              # every long hold is counted
+    assert len(_long_holds()) == 1   # one record in a second
+
+
+def test_telemetry_off_holds_and_pulses_nothing(tmp_path):
+    path = write_libsvm(tmp_path / "z.libsvm", rows=HOLD_BATCH * 30)
+    telemetry.enable(False)
+    for prefetch in (0, 2):
+        _consume(path, lambda i: _one_long(i, lambda: time.sleep(0.12),
+                                           short_s=0.002),
+                 prefetch=prefetch)
+    assert not [t for t in threading.enumerate() if t.name == "dmlc-pulse"]
+    observed = {h["name"]: h["count"]
+                for h in telemetry.snapshot(native=True)["histograms"]}
+    for name in ("device_hold_us", "device_hold_excess_us",
+                 "pulse_py_late_us", "pulse_native_late_us"):
+        assert observed.get(name, 0) == 0, name
+    assert telemetry.spans() == [] and _long_holds() == []
+    assert not telemetry._running_holds
+
+
+def test_two_iterators_share_one_pair_of_pulses(tmp_path):
+    path = write_libsvm(tmp_path / "t.libsvm", rows=HOLD_BATCH * 12)
+    kw = dict(batch_rows=HOLD_BATCH, layout="csr", min_nnz_bucket=128)
+
+    def native_ticks():
+        return sum(h["count"]
+                   for h in telemetry.snapshot(native=True)["histograms"]
+                   if h["name"] == "pulse_native_late_us")
+
+    with DeviceRowBlockIter(path, **kw) as a, \
+            DeviceRowBlockIter(path, prefetch=0, **kw) as b:
+        ga, gb = iter(a), iter(b)
+        next(ga), next(gb)
+        assert len([t for t in threading.enumerate()
+                    if t.name == "dmlc-pulse"]) == 1
+        n0 = native_ticks()
+        time.sleep(0.3)
+        assert 3 <= native_ticks() - n0 <= 17   # one native thread, not two
+        telemetry.enable(False)
+        assert not [t for t in threading.enumerate()
+                    if t.name == "dmlc-pulse"]
+        n1 = native_ticks()
+        time.sleep(0.05)
+        assert native_ticks() == n1
+        ga.close(), gb.close()

@@ -20,6 +20,10 @@ Covers the ISSUE 11 acceptance surface:
   timeline with sane per-lane ordering, and ``/metrics`` job-level
   ``job:`` sums equal the per-rank series counter-for-counter. Plus
   ``/healthz``.
+- The pulse (ISSUE 36): one Python and one native thread that nap 20 ms
+  and record how late they woke; each Python tick an opened span, so a
+  line of the profiler's trace; one pair a process, none while telemetry
+  is disabled, gone after ``enable(False)``.
 """
 
 from __future__ import annotations
@@ -684,3 +688,67 @@ def test_step_timeline_straggler_e2e(tmp_path):
     # ... and the gauge on /metrics
     samples = _parse_exposition(scrape)
     assert samples[("tracker_straggler_rank", "")] == slow_rank
+
+
+# -- the pulse (doc/observability.md "The hold and the pulse") ----------------
+def _pulse_threads():
+    return [t for t in threading.enumerate() if t.name == "dmlc-pulse"]
+
+
+def _hist_count(name):
+    return sum(h["count"]
+               for h in telemetry.snapshot(native=True)["histograms"]
+               if h["name"] == name)
+
+
+def test_pulse_ticks_at_once_and_each_tick_is_an_opened_span(
+        fake_annotation):
+    native_telemetry_snapshot()   # the native library is loaded
+    telemetry.pulse_start()
+    deadline = time.monotonic() + 5.0
+    while time.monotonic() < deadline and not (
+            _hist_count("pulse_py_late_us")
+            and _hist_count("pulse_native_late_us")):
+        time.sleep(0.001)
+    # both tick at once when they start, before any nap has ended
+    assert _hist_count("pulse_py_late_us") >= 1
+    assert _hist_count("pulse_native_late_us") >= 1
+    time.sleep(0.05)
+    telemetry.pulse_stop()
+    ticks = [s for s in telemetry.spans() if s["name"] == "pulse"]
+    assert len(ticks) == _hist_count("pulse_py_late_us") >= 2
+    assert all(s["args"]["late_us"] >= 0 for s in ticks)
+    # an opened span: the profiler's trace has a dmlc.pulse line
+    assert ("enter", "dmlc.pulse") in fake_annotation
+    assert sum(e == ("enter", "dmlc.pulse") for e in fake_annotation) == \
+        sum(e == ("exit", "dmlc.pulse") for e in fake_annotation) == \
+        len(ticks)
+    # the lateness of every tick is kept for the record of a long hold
+    py, native = telemetry._pulse_late_us(0.0, time.perf_counter() * 1e6)
+    assert py >= max(s["args"]["late_us"] for s in ticks) - 1
+    assert native is None   # stopped: no native pulse to ask
+
+
+def test_pulse_is_one_pair_a_process_and_stops_with_telemetry():
+    native_telemetry_snapshot()
+    for _ in range(3):
+        telemetry.pulse_start()
+    assert len(_pulse_threads()) == 1
+    n0 = _hist_count("pulse_native_late_us")
+    time.sleep(0.3)
+    rose = _hist_count("pulse_native_late_us") - n0
+    # one native thread ticks 15 times in 0.3 s (late, never early); two
+    # would tick 30
+    assert 3 <= rose <= 17, rose
+    telemetry.enable(False)
+    assert _pulse_threads() == []
+    n1 = _hist_count("pulse_native_late_us")
+    p1 = _hist_count("pulse_py_late_us")
+    time.sleep(0.05)
+    assert _hist_count("pulse_native_late_us") == n1
+    assert _hist_count("pulse_py_late_us") == p1
+    # while telemetry is disabled nothing starts
+    telemetry.pulse_start()
+    assert _pulse_threads() == []
+    time.sleep(0.03)
+    assert _hist_count("pulse_native_late_us") == n1
