@@ -25,11 +25,15 @@ CSR shards index every entry four times a step. The rows ``w[cols]``
 are expanded to the entries by ``slot`` as one ``[U, K+1]`` array in one
 gather (``fm.expand``), and the linear sum and the two inner sums ride in
 one segment sum over the row ids as the ``2K+1`` lanes of one array
-(``fm.interaction``); autodiff's transposes are one gather back by row and
-one scatter-add by ``slot``, the merge of a feature's repeats. On the chip
-a pass over the entries costs by its indices, one lane what K lanes cost
-(PERF.md section 6, PR 35). Padding nonzeros (val 0, sacrificial row id)
-vanish.
+(``fm.interaction``); the backward is one gather back by row and one
+scatter-add by ``slot``, the merge of a feature's repeats. On the chip a
+pass over the entries costs by its indices, one lane what K lanes cost
+(PERF.md section 6, PR 35), and every [NNZ, n] array between the passes is
+padded to 128 lanes and costs its bytes, so the row form's backward is
+written by hand (``_fm_margin_rows_bwd``: one pass writes the entries'
+cotangent; autodiff's slices, pads and one-lane columns touched such an
+array 24 times a step where this touches one 13, PERF.md section 6, PR 37).
+Padding nonzeros (val 0, sacrificial row id) vanish.
 Dense batches compute them as ``(x @ V)² − x² @ V²`` — pure MXU work.
 """
 
@@ -100,18 +104,73 @@ def _fm_margin_entries(b, w, v, row, val, num_rows: int) -> jnp.ndarray:
 
 def _fm_margin_rows(rows: FMRows, slot, row, val, num_rows: int
                     ) -> jnp.ndarray:
-    # the expansion reads a [U, K+1] intermediate (v's lanes, then w's),
-    # not the tables, in one gather; its transpose sums an entry's gradient
-    # into its column's row, at the gradient's magnitude: the merge of a
-    # feature's repeats, w's and v's in one scatter-add. The concatenation
-    # is inside the differentiated function, so the gradient comes back as
-    # FMRows
+    """The margin from the rows a shard gathered, with its backward written
+    by hand (``_fm_margin_rows_bwd``): called outside a gradient (``predict``)
+    this is the plain composition, an expansion and ``_fm_margin_entries``."""
     k = rows.v.shape[1]
-    with jax.named_scope("fm.expand"):
-        wv = jnp.concatenate([rows.v, rows.w[:, None]], axis=1).at[slot].get(
-            mode="promise_in_bounds")
+    wv = _fm_expand(rows, slot)
     return _fm_margin_entries(rows.b, wv[:, k], wv[:, :k], row, val,
                               num_rows)
+
+
+def _fm_expand(rows: FMRows, slot) -> jnp.ndarray:
+    # the expansion reads a [U, K+1] intermediate (v's lanes, then w's),
+    # not the tables, in one gather
+    with jax.named_scope("fm.expand"):
+        return jnp.concatenate([rows.v, rows.w[:, None]], axis=1).at[
+            slot].get(mode="promise_in_bounds")
+
+
+def _fm_margin_rows_fwd(rows: FMRows, slot, row, val, num_rows: int):
+    """``_fm_margin_rows`` with the products made over the ``K+1`` lanes at
+    once and the segment sum's lanes in the order (V x, w x, V²x²): each
+    lane sums what it sums in ``_fm_margin_entries``. Kept for the backward:
+    the expanded rows and Σ V x, no [NNZ, n] array that the forward did not
+    have to make."""
+    k = rows.v.shape[1]
+    wv = _fm_expand(rows, slot)
+    with jax.named_scope("fm.interaction"):
+        t = wv * val[:, None]                      # [NNZ, K+1]: V x, then w x
+        sums = jax.ops.segment_sum(
+            jnp.concatenate([t, t[:, :k] * t[:, :k]], axis=1), row,
+            num_segments=num_rows + 1, indices_are_sorted=True)[:num_rows]
+        s1, linear, s2 = sums[:, :k], sums[:, k], sums[:, k + 1:]
+        inter = 0.5 * jnp.sum(s1 * s1 - s2, axis=-1)
+    # rows.w: for the count of rows the merge lands in
+    return rows.b + linear + inter, (wv, s1, slot, row, val, rows.w)
+
+
+def _fm_margin_rows_bwd(num_rows: int, kept, dm):
+    """From the margin's cotangent ``dm`` [R]: one gather back by ``row`` of
+    the ``K+1`` lanes (dm·Σ V x, dm); one pass that writes the entries'
+    cotangent once, d(V) = x·dm·(Σ V x − V x) and d(w) = x·dm; one
+    scatter-add by ``slot`` into [U, K+1], the merge of a feature's
+    repeats, w's and v's at once, at the gradient's magnitude. The padding
+    entries name row ``num_rows``, a row of zeros (a gather that fills
+    costs a pass over its result for the mask)."""
+    wv, s1, slot, row, val, w_rows = kept
+    k = s1.shape[1]
+    with jax.named_scope("fm.interaction"):
+        back = jnp.pad(jnp.concatenate([dm[:, None] * s1, dm[:, None]],
+                                       axis=1), ((0, 1), (0, 0)))
+        g = back.at[row].get(mode="clip", indices_are_sorted=True)
+        # every lane at once, dm read from its lane as [NNZ] (a [NNZ, 1]
+        # column is padded to 128 lanes like any [NNZ, n] array): V x
+        # under v's lanes, 0 under w's
+        x = val[:, None]
+        v_lanes = jax.lax.broadcasted_iota(jnp.int32, (1, k + 1), 1) < k
+        inner = g - g[:, k][:, None] * jnp.where(v_lanes, wv * x, 0)
+        d = x * inner                              # [NNZ, K+1]
+    with jax.named_scope("fm.expand"):
+        merged = jnp.zeros((w_rows.shape[0], k + 1), d.dtype).at[slot].add(
+            d, mode="promise_in_bounds")
+    # val's cotangent is dead code in a step, which asks for the rows' alone
+    return (FMRows(jnp.sum(dm), merged[:, k], merged[:, :k]), None, None,
+            jnp.sum(wv * inner, axis=1))
+
+
+_fm_margin_rows = jax.custom_vjp(_fm_margin_rows, nondiff_argnums=(4,))
+_fm_margin_rows.defvjp(_fm_margin_rows_fwd, _fm_margin_rows_bwd)
 
 
 def _fm_margin_csr(params: FMParams, row, col, val, num_rows: int

@@ -518,7 +518,11 @@ def test_jitted_step_carries_the_named_scopes(tmp_path, model, mesh_devices):
         # rows are scattered into the tables under dp.apply; on a mesh the
         # shards' rows are gathered under dp.allreduce first
         assert f"transpose(jvp({inner}))" not in text
-        assert "dp.loss_grad/transpose(jvp(fm.interaction))" in text
+        # (the margin's backward is hand-written: its operations carry
+        # the forward's scopes under the transposed dp.loss_grad)
+        assert ("dp.loss_grad/transpose(dp.loss_grad)/jvp(fm.interaction)"
+                in text)
+        assert "dp.loss_grad/transpose(dp.loss_grad)/jvp(fm.expand)" in text
         assert "dp.apply/scatter-add" in text
         assert ("dp.allreduce/all_gather" in text) == (mesh is not None)
     else:

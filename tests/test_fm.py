@@ -395,10 +395,10 @@ def test_fm_row_step_makes_no_table_but_the_parameters(tmp_path, l2,
     text = lowered.as_text(debug_info=True)
     assert "transpose(jvp(fm.gather))" not in text
     assert "dp.allreduce" not in text
-    # the expansion by slot and its transpose, the merge of a feature's
-    # repeats, carry a scope of their own
+    # the expansion by slot and, in the hand-written backward, the merge
+    # of a feature's repeats carry a scope of their own
     assert "dp.loss_grad/jvp(fm.expand)/gather" in text
-    assert "dp.loss_grad/transpose(jvp(fm.expand))" in text
+    assert BACKWARD + "jvp(fm.expand)/scatter-add" in text
     made = _table_ops(lowered, {(F_ROWS, K_ROWS), (F_ROWS,)})
     # one scatter-add into each table, under dp.apply; with l2 the decayed
     # operand of each (a scalar's broadcast and a multiply) and nothing else
@@ -409,6 +409,13 @@ def test_fm_row_step_makes_no_table_but_the_parameters(tmp_path, l2,
         assert "dp.apply" in loc, (name, loc)
         if name == "stablehlo.scatter":
             assert "scatter-add" in loc
+
+
+# where an operation of the margin's hand-written backward stands: jax
+# names what a custom VJP's backward traces by the scopes open around the
+# gradient (dp.loss_grad, transposed) and then the backward's own, where
+# autodiff's transposes read dp.loss_grad/transpose(jvp(<scope>))
+BACKWARD = "dp.loss_grad/transpose(dp.loss_grad)/"
 
 
 def _indexed_ops(lowered):
@@ -426,11 +433,12 @@ def _indexed_ops(lowered):
 def test_fm_csr_step_indexes_an_entry_four_times(tmp_path, mesh_devices):
     """The passes over a batch's entries that share their indices are lanes
     of one pass: one expansion by slot ([U, K+1]), one segment sum by row
-    ([NNZ, 2K+1]), its transpose one gather back by row, the expansion's
+    ([NNZ, 2K+1]), in the backward one gather back by row ([R+1, K+1]) and
     one merge by slot; beside them the two gathers from the tables and the
     two scatter-adds into them. An edit that splits a pass again shows
     here and not on the chip (PERF.md section 6, PR 35: a pass costs by its
-    indices, one lane what K lanes cost)."""
+    indices, one lane what K lanes cost). Since ISSUE 37 the two backward
+    ones are the hand-written backward's, under the forward's scopes."""
     mesh = data_mesh(mesh_devices) if mesh_devices else None
     learner = FMLearner(F_ROWS, k=K_ROWS, mesh=mesh)
     params = learner.init()
@@ -443,8 +451,8 @@ def test_fm_csr_step_indexes_an_entry_four_times(tmp_path, mesh_devices):
         ("gather", "dp.loss_grad/fm.gather/gather"),            # v[cols]
         ("gather", "dp.loss_grad/jvp(fm.expand)/gather"),       # by slot
         ("scatter", "dp.loss_grad/jvp(fm.interaction)/scatter-add"),  # row
-        ("gather", "dp.loss_grad/transpose(jvp(fm.interaction))/gather"),
-        ("scatter", "dp.loss_grad/transpose(jvp(fm.expand))/scatter-add"),
+        ("gather", BACKWARD + "jvp(fm.interaction)/gather"),    # by row
+        ("scatter", BACKWARD + "jvp(fm.expand)/scatter-add"),   # by slot
         ("scatter", "dp.apply/scatter-add"),                    # into w
         ("scatter", "dp.apply/scatter-add"),                    # into v
     ]
@@ -554,6 +562,237 @@ def test_fm_merged_passes_equal_the_lane_by_lane_sums(k):
     assert unnamed.size and U - 1 in unnamed
     assert not np.asarray(got["v"])[unnamed].any()
     assert not np.asarray(got["w"])[unnamed].any()
+
+
+# -- the row form's hand-written backward (models/fm.py, ISSUE 37) ------------
+def _shard_by_hand(k, seed=0, R=64, F=200, U=96, NNZ=1024, real=700):
+    """A shard's arrays and tables to read them from: 80 distinct columns
+    that recur (a cubed draw), the list padded beyond the tables, padding
+    entries (value 0) on the sacrificial row ``R`` and the list's last
+    padding slot, row 0 of one entry, rows 54.. of none, some rows of
+    weight 0."""
+    import jax.numpy as jnp
+    from dmlc_core_tpu.models.fm import FMParams
+    rng = np.random.default_rng(100 * k + seed)
+    cols = np.concatenate([np.sort(rng.choice(F, 80, replace=False)),
+                           np.full(U - 80, F)]).astype(np.int32)
+    row = np.concatenate([[0], np.sort(rng.integers(1, R - 10, real - 1)),
+                          np.full(NNZ - real, R)]).astype(np.int32)
+    slot = np.concatenate([(80 * rng.random(real) ** 3).astype(np.int32),
+                           np.full(NNZ - real, U - 1, np.int32)])
+    assert len(set(slot[:real])) < real / 4 and (row == 0).sum() == 1
+    val = np.concatenate([rng.normal(size=real),
+                          np.zeros(NNZ - real)]).astype(np.float32)
+    shard = {"cols": jnp.asarray(cols), "slot": jnp.asarray(slot),
+             "row": jnp.asarray(row), "val": jnp.asarray(val),
+             "label": jnp.asarray(rng.integers(0, 2, R).astype(np.float32)),
+             "weight": jnp.asarray((rng.random(R) < 0.9).astype(np.float32))}
+    params = FMParams(
+        jnp.float32(0.3), jnp.asarray(rng.normal(size=F).astype(np.float32)),
+        jnp.asarray(0.3 * rng.normal(size=(F, k)).astype(np.float32)))
+    return params, shard
+
+
+def _plain_margin(params, shard, R, val=None):
+    """The margin by its plain composition, which autodiff differentiates:
+    the gathered rows, each expanded by itself, into the entries' sums."""
+    from dmlc_core_tpu.models.fm import _fm_gather, _fm_margin_entries
+    rows = _fm_gather(params, shard["cols"])
+    return _fm_margin_entries(
+        rows.b, rows.w[shard["slot"]], rows.v[shard["slot"]], shard["row"],
+        shard["val"] if val is None else val, R)
+
+
+def _hand_margin(params, shard, R, val=None):
+    from dmlc_core_tpu.models.fm import _fm_gather, _fm_margin_rows
+    return _fm_margin_rows(
+        _fm_gather(params, shard["cols"]), shard["slot"], shard["row"],
+        shard["val"] if val is None else val, R)
+
+
+def _close(got, want, rel=1e-6):
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        a, b = np.asarray(a), np.asarray(b)
+        assert np.linalg.norm(a - b) <= rel * np.linalg.norm(b), (a, b)
+
+
+@pytest.mark.parametrize("objective", ["logistic", "squared"])
+@pytest.mark.parametrize("k", [1, 8, 16, 32])
+def test_fm_hand_written_backward_equals_autodiff(k, objective):
+    """The loss's gradient through ``_fm_margin_rows`` and its hand-written
+    backward against ``jax.grad`` of the plain composition, down to the
+    tables and to the entries' values; the margins agree to the bit (the
+    forward sums the same lanes)."""
+    from dmlc_core_tpu.models.linear import objective_loss
+    R = 64
+    params, shard = _shard_by_hand(k)
+
+    def readings(margin):
+        def loss(params, val):
+            m = margin(params, shard, R, val)
+            return objective_loss(m, shard, R, objective)[0], m
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1),
+                                          has_aux=True))(params, shard["val"])
+
+    (loss, m), grads = readings(_hand_margin)
+    (want_loss, want_m), want = readings(_plain_margin)
+    assert np.asarray(m).tobytes() == np.asarray(want_m).tobytes()
+    assert float(loss) == float(want_loss)
+    assert all(np.asarray(g).any() for g in jax.tree.leaves(want))
+    _close(grads, want)
+    # what no entry names takes no gradient: the list's padding, the
+    # tables' other rows
+    named = np.asarray(shard["cols"])[np.unique(np.asarray(shard["slot"])[
+        np.asarray(shard["val"]) != 0])]
+    rest = np.setdiff1d(np.arange(params.w.shape[0]), named)
+    assert not np.asarray(grads[0].v)[rest].any()
+    assert not np.asarray(grads[0].w)[rest].any()
+
+
+@pytest.mark.parametrize("objective", ["logistic", "squared"])
+@pytest.mark.parametrize("mesh_devices", [0, 4], ids=["nomesh", "mesh4"])
+def test_fm_step_equals_autodiff_of_the_plain_margin(tmp_path, mesh_devices,
+                                                     objective):
+    """A whole step, on one device and on a mesh of 4, against the update
+    from ``jax.grad`` of the plain composition over every shard: the file
+    has rows of one entry, recurring columns, padded entries and a last
+    batch with padding rows."""
+    from dmlc_core_tpu.models.linear import objective_loss
+    from dmlc_core_tpu.tpu.device_iter import unpack_shard
+    mesh = data_mesh(mesh_devices) if mesh_devices else None
+    learner = FMLearner(F_ROWS, k=K_ROWS, mesh=mesh, objective=objective,
+                        learning_rate=0.3, init_scale=0.2)
+    rng = np.random.default_rng(1)
+    params = learner.init(seed=7)._replace(
+        b=jax.numpy.float32(0.25),
+        w=jax.numpy.asarray(rng.normal(size=F_ROWS).astype(np.float32)))
+    plain = FMLearner(F_ROWS, k=K_ROWS, learning_rate=0.3)
+    for batch in _batches(write_recurring_libsvm(tmp_path / "a.libsvm"),
+                          mesh):
+        tree = batch.tree()
+        R = batch.rows_per_shard
+        shards = [unpack_shard({k: v[d] for k, v in tree.items()})
+                  for d in range(tree["aux"].shape[0])]
+        assert any((np.bincount(np.asarray(s["row"]), minlength=R + 1)[:R]
+                    == 1).any() for s in shards)        # one-entry rows
+        assert all((np.asarray(s["val"]) == 0).any() for s in shards)
+        host = jax.tree.map(np.asarray, params)
+
+        def loss(p):
+            sums = [objective_loss(_plain_margin(p, s, R), s, R, objective)
+                    for s in shards]
+            return sum(x[0] for x in sums), sum(x[1] for x in sums)
+        (loss_sum, wsum), grads = jax.value_and_grad(loss, has_aux=True)(
+            type(params)(*host))
+        denom = jax.numpy.maximum(wsum, 1.0)
+        want = plain._apply(type(params)(*host), grads, denom)
+        params, got_loss = learner.step(params, batch)
+        np.testing.assert_allclose(float(got_loss), float(loss_sum / denom),
+                                   rtol=1e-6)
+        for leaf in ("b", "w", "v"):
+            a, b = np.asarray(getattr(params, leaf)), \
+                np.asarray(getattr(want, leaf))
+            assert np.abs(a - b).max() <= 1e-6 * np.abs(b).max(), leaf
+
+
+@pytest.mark.parametrize("what", ["margin", "gradient"])
+def test_fm_hand_written_backward_under_vmap(what):
+    """``predict`` maps the margin over a batch's shards: mapped, the
+    margin is the plain composition's to the bit and its gradient the
+    plain one's, shard by shard."""
+    import jax.numpy as jnp
+    R, k = 64, 8
+    params, _ = _shard_by_hand(k)
+    shards = [_shard_by_hand(k, seed)[1] for seed in (1, 2, 3)]
+    stacked = {key: jnp.stack([s[key] for s in shards]) for key in shards[0]}
+    coef = jnp.asarray(np.random.default_rng(0).normal(size=R)
+                       .astype(np.float32))
+
+    def mapped(margin):
+        def f(params):
+            m = jax.vmap(lambda s: margin(params, s, R))(stacked)
+            return jnp.sum(coef * m + m * m), m
+        return jax.jit(jax.value_and_grad(f, has_aux=True))(params)
+
+    (_, m), grads = mapped(_hand_margin)
+    if what == "margin":
+        for i, s in enumerate(shards):
+            want = jax.jit(lambda p, s=s: _plain_margin(p, s, R))(params)
+            assert np.asarray(m[i]).tobytes() == np.asarray(want).tobytes()
+    else:
+        _close(grads, mapped(_plain_margin)[1])
+
+
+@pytest.mark.parametrize("what", ["second-order", "keywords", "unjitted"])
+def test_fm_hand_written_backward_takes_what_autodiff_took(what):
+    """A gradient of a gradient goes through the backward as through any
+    jax code; the arguments may be named; nothing needs a jit around it
+    (the entries' values have a cotangent too, dead code in a step:
+    test_fm_hand_written_backward_equals_autodiff holds it)."""
+    import jax.numpy as jnp
+    from dmlc_core_tpu.models.fm import _fm_gather, _fm_margin_rows
+    R = 64
+    params, shard = _shard_by_hand(8)
+
+    def hand(params, val):
+        if what != "keywords":
+            return _hand_margin(params, shard, R, val)
+        return _fm_margin_rows(
+            rows=_fm_gather(params, shard["cols"]), slot=shard["slot"],
+            row=shard["row"], val=val, num_rows=R)
+
+    def scalar(margin):
+        return lambda params, val: jnp.sum(jnp.sin(margin(params, val)))
+
+    def grad_norm(margin):
+        return lambda params, val: sum(
+            jnp.sum(g * g) for g in jax.tree.leaves(
+                jax.grad(scalar(margin))(params, val)))
+
+    def readings(margin):
+        f = grad_norm(margin) if what == "second-order" else scalar(margin)
+        wrap = (lambda f: f) if what == "unjitted" else jax.jit
+        return wrap(jax.grad(f, argnums=(0, 1)))(params, shard["val"])
+
+    want = readings(lambda params, val: _plain_margin(params, shard, R, val))
+    assert all(np.asarray(g).any() for g in jax.tree.leaves(want))
+    _close(readings(hand), want, rel=1e-5 if what == "second-order" else 1e-6)
+
+
+@pytest.mark.parametrize("mesh_devices", [0, 4], ids=["nomesh", "mesh4"])
+def test_fm_csr_step_makes_few_arrays_of_the_entries(tmp_path, mesh_devices):
+    """The operations of the lowered step that produce a float ``[NNZ, n]``
+    tensor, a scalar's or a column's broadcast aside: 14, where autodiff's
+    backward left 19 (ISSUE 37; on the chip every such array is padded to
+    128 lanes and costs its bytes whatever ``n``: compiled for the v5e the
+    step reads and writes one 13 times for 24,
+    tests/test_step_compiles_for_v5e.py). A slice, a pad or a
+    concatenation more around the indexed passes shows here."""
+    from jaxlib.mlir import ir
+    mesh = data_mesh(mesh_devices) if mesh_devices else None
+    learner = FMLearner(F_ROWS, k=K_ROWS, mesh=mesh)
+    with DeviceRowBlockIter(
+            write_recurring_libsvm(tmp_path / "n.libsvm"), batch_rows=256,
+            layout="csr", mesh=mesh, min_nnz_bucket=128) as it:
+        batch = next(iter(it))
+    tree = batch.tree()
+    nnz = tree["big"].shape[-1]
+    # a shape of the entries' own: not the list's, not the rows'
+    assert nnz not in (tree["cols"].shape[-1], batch.rows_per_shard,
+                       batch.rows_per_shard + 1)
+    lowered = learner._build_step(
+        batch.rows_per_shard, tuple(sorted(tree.keys()))).lower(
+            learner.init(), tree)
+    made = [(op.name, tuple(r.type.shape)) for op in _lowered_ops(lowered)
+            for r in op.results
+            if isinstance(r.type, ir.RankedTensorType)
+            and len(r.type.shape) == 2 and r.type.shape[0] == nnz
+            and str(r.type.element_type) == "f32"
+            and op.name != "stablehlo.broadcast_in_dim"]
+    assert len(made) == 14, sorted(made)
+    # none wider than the segment sum's 2K+1 lanes, and that one once
+    assert sorted(n for _, (_, n) in made)[-2:] == [K_ROWS + 1,
+                                                    2 * K_ROWS + 1]
 
 
 @pytest.mark.parametrize("mesh_devices", [0, 2], ids=["nomesh", "mesh2"])
