@@ -354,6 +354,8 @@ void PaddedBatcher::FillPacked(int32_t* big, int32_t kb, void* val,
   cols_cap_ = TailRung(own, prev_cols_, take_, batch_rows_);
   lifted_ = lifted_ || cols_cap_ != own;
   prev_cols_ = cols_cap_;
+  slots_.Lay(big + bucket_, static_cast<uint64_t>(kb) * bucket_,
+             written.data(), cols_cap_);
   FillRowWisePacked(aux, ka, nrows);
   Consume();
 }

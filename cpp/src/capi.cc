@@ -804,6 +804,20 @@ int dct_batcher_cols_meta(dct_batcher_t h, uint64_t* cap,
   });
 }
 
+// Key-range owners of the columns (col_slots.h "Owners"), before the first
+// batch; and the fullest owner's count of the last batch's distinct columns.
+int dct_batcher_set_col_owners(dct_batcher_t h, uint32_t owners,
+                               uint64_t range) {
+  return Guard([&] {
+    static_cast<dct::PaddedBatcher*>(h)->SetColOwners(owners, range);
+  });
+}
+
+int dct_batcher_cols_owner_max(dct_batcher_t h, uint64_t* out) {
+  return Guard(
+      [&] { *out = static_cast<dct::PaddedBatcher*>(h)->ColsOwnerMax(); });
+}
+
 int dct_batcher_fill_cols(dct_batcher_t h, int32_t* cols, uint64_t cap) {
   return Guard(
       [&] { static_cast<dct::PaddedBatcher*>(h)->FillCols(cols, cap); });
@@ -812,15 +826,18 @@ int dct_batcher_fill_cols(dct_batcher_t h, int32_t* cols, uint64_t cap) {
 // The dedupe both batchers run (col_slots.h), exported so a test can hold
 // the Python statement of it equal: col is [D, stride] with n[d] real
 // entries in shard d and becomes the slot plane; cols takes the [D, *cap]
-// lists and must hold D * NnzBucket(max n, floor) entries.
+// lists and must hold D * owners * NnzBucket(max n, floor) entries.
 int dct_col_slots(int32_t* col, const uint64_t* n, uint32_t num_shards,
-                  uint64_t stride, uint64_t floor, int32_t* cols,
-                  uint64_t* cap, uint64_t* distinct) {
+                        uint64_t stride, uint64_t floor, uint32_t owners,
+                        uint64_t range, int32_t* cols, uint64_t* cap,
+                        uint64_t* distinct) {
   return Guard([&] {
     dct::ColSlots slots;
+    slots.SetOwners(owners, range);
     slots.Run(col, stride, n, num_shards);
     *cap = slots.Capacity(floor);
     *distinct = slots.Distinct();
+    slots.Lay(col, stride, n, *cap);
     slots.Write(cols, *cap);
   });
 }
@@ -964,6 +981,18 @@ int dct_csrrec_cols_meta(dct_csrrec_t h, uint64_t* cap, uint64_t* distinct,
     *distinct = b->ColsDistinct();
     *tail_lifted = b->TailLifted() ? 1 : 0;
   });
+}
+
+int dct_csrrec_set_col_owners(dct_csrrec_t h, uint32_t owners,
+                              uint64_t range) {
+  return Guard([&] {
+    static_cast<dct::CsrRecBatcher*>(h)->SetColOwners(owners, range);
+  });
+}
+
+int dct_csrrec_cols_owner_max(dct_csrrec_t h, uint64_t* out) {
+  return Guard(
+      [&] { *out = static_cast<dct::CsrRecBatcher*>(h)->ColsOwnerMax(); });
 }
 
 int dct_csrrec_fill_cols(dct_csrrec_t h, int32_t* cols, uint64_t cap) {

@@ -20,6 +20,17 @@
 // consumer; the scatter then adds a zero to it, as the padded entries'
 // column 0 always did.
 //
+// Owners. Where the tables are sharded by key range (models/_dp.py, the
+// range-sharded form), the id space is cut into `owners` contiguous ranges
+// of `range` ids and a column's row lives with the owner of its range. The
+// list is ascending, so an owner's columns are one contiguous stretch of
+// it; only the stretch's length is dynamic. The list is then laid out
+// owner-major, [owners, C] flattened: stretch o holds the shard's columns
+// in [o * range, (o + 1) * range), padded to C as the list's tail is, C the
+// ladder rung of the fullest (shard, owner) stretch of the batch, and slot
+// names positions in the flattened list. With one owner that is the list
+// above to the byte.
+//
 // Stated twice, here and in dmlc_core_tpu/tpu/device_iter.py (col_slots:
 // np.unique, the oracle); tests/test_col_slots.py holds the two equal.
 #ifndef DCT_COL_SLOTS_H_
@@ -38,6 +49,15 @@ namespace dct {
 
 class ColSlots {
  public:
+  // The key ranges the lists are laid out by (the header's "Owners"): ids
+  // at or beyond owners * range have no owner and are refused.
+  void SetOwners(uint32_t owners, uint64_t range) {
+    DCT_CHECK(owners >= 1 && (owners == 1 || range >= 1))
+        << owners << " owners of " << range << " ids";
+    owners_ = owners;
+    range_ = range;
+  }
+
   // A batch of num_shards shards, shard d's col plane at col + d * stride
   // holding n[d] real entries (non-negative ids): replace each entry by
   // its slot and keep every shard's distinct list. The shards share
@@ -62,31 +82,85 @@ class ColSlots {
     for (uint32_t d = 0; d < num_shards; ++d) {
       if (n[d] != 0) real_ += shards_[d].list.size();
     }
+    owner_max_ = real_;
+    if (owners_ == 1) return;
+    // where each owner's stretch of a shard's list starts
+    std::vector<uint64_t> got(owners_, 0);
+    for (uint32_t d = 0; d < num_shards; ++d) {
+      Shard& s = shards_[d];
+      DCT_CHECK(static_cast<uint64_t>(s.list.back()) < owners_ * range_)
+          << "column " << s.list.back() << " lies beyond the " << owners_
+          << " owners' ranges of " << range_ << " ids";
+      s.first.assign(owners_ + 1, s.list.size());
+      for (uint32_t o = 0; o < owners_; ++o) {
+        s.first[o] = std::lower_bound(s.list.begin(), s.list.end(),
+                                      static_cast<int64_t>(o * range_)) -
+                     s.list.begin();
+      }
+      for (uint32_t o = 0; o < owners_ && n[d] != 0; ++o) {
+        got[o] += s.first[o + 1] - s.first[o];
+      }
+    }
+    owner_max_ = *std::max_element(got.begin(), got.end());
   }
 
   // Distinct columns of the batch, summed over its shards (a shard's
   // stand-in column 0 not counted).
   uint64_t Distinct() const { return real_; }
 
-  // The list's capacity: the ladder rung of the fullest shard's count.
+  // Of those, the columns of the owner that got the most, all shards'
+  // stretches together (one owner: all of them).
+  uint64_t OwnerMax() const { return owner_max_; }
+
+  // The list's capacity: owners times the ladder rung of the fullest
+  // (shard, owner) stretch's count.
   uint64_t Capacity(uint64_t floor) const {
     uint64_t fullest = 1;
     for (const Shard& s : shards_) {
-      fullest = std::max<uint64_t>(fullest, s.list.size());
+      for (uint32_t o = 0; o < owners_; ++o) {
+        fullest = std::max<uint64_t>(fullest, s.Stretch(o, owners_));
+      }
     }
-    return NnzBucket(fullest, floor);
+    return owners_ * NnzBucket(fullest, floor);
   }
 
-  // Write the [D, cap] lists, each padded to its end as the header says.
+  // Move every slot of the planes Run wrote to its place in lists of
+  // capacity `cap` (Capacity's, or a rung above it: TailRung). Nothing to
+  // do for one owner, where a slot is the column's place in the list.
+  void Lay(int32_t* col, uint64_t stride, const uint64_t* n, uint64_t cap) {
+    if (owners_ == 1) return;
+    const uint64_t c = cap / owners_;
+    auto lay = [this, col, stride, n, c](size_t d) {
+      const Shard& s = shards_[d];
+      int32_t* plane = col + d * stride;
+      for (uint64_t i = 0; i < n[d]; ++i) {
+        const uint64_t at = static_cast<uint64_t>(plane[i]);
+        uint32_t o = 0;
+        while (at >= s.first[o + 1]) ++o;
+        plane[i] = static_cast<int32_t>(o * c + (at - s.first[o]));
+      }
+    };
+    std::vector<std::thread> others;
+    for (size_t d = 1; d < shards_.size(); ++d) others.emplace_back(lay, d);
+    if (!shards_.empty()) lay(0);
+    for (std::thread& t : others) t.join();
+  }
+
+  // Write the [D, cap] lists, each stretch padded to its end as the header
+  // says.
   void Write(int32_t* cols, uint64_t cap) const {
+    const uint64_t c = cap / owners_;
     for (size_t d = 0; d < shards_.size(); ++d) {
-      const std::vector<int32_t>& list = shards_[d].list;
-      const uint64_t n = list.size();
-      DCT_CHECK(n >= 1 && n <= cap)
-          << "distinct-column list of " << n << " does not fit " << cap;
-      int32_t* out = cols + d * cap;
-      std::memcpy(out, list.data(), n * sizeof(int32_t));
-      std::fill(out + n, out + cap, INT32_MAX);
+      const Shard& s = shards_[d];
+      for (uint32_t o = 0; o < owners_; ++o) {
+        const uint64_t n = s.Stretch(o, owners_);
+        DCT_CHECK((n >= 1 || owners_ > 1) && n <= c)
+            << "distinct-column list of " << n << " does not fit " << c;
+        int32_t* out = cols + d * cap + o * c;
+        std::memcpy(out, s.list.data() + (owners_ == 1 ? 0 : s.first[o]),
+                    n * sizeof(int32_t));
+        std::fill(out + n, out + c, INT32_MAX);
+      }
     }
   }
 
@@ -95,6 +169,12 @@ class ColSlots {
 
   struct Shard {
     std::vector<int32_t> list;          // distinct columns, ascending
+    std::vector<uint64_t> first;        // several owners: where each
+                                        // one's stretch of the list starts
+
+    uint64_t Stretch(uint32_t o, uint32_t owners) const {
+      return owners == 1 ? list.size() : first[o + 1] - first[o];
+    }
     std::vector<uint64_t> keys, tmp;    // sort scratch, kept
     std::vector<uint32_t> count;        // digit counts, every pass
 
@@ -159,6 +239,9 @@ class ColSlots {
 
   std::vector<Shard> shards_;
   uint64_t real_ = 0;
+  uint64_t owner_max_ = 0;
+  uint32_t owners_ = 1;
+  uint64_t range_ = 0;
 };
 
 }  // namespace dct

@@ -203,6 +203,7 @@ uint64_t CsrRecBatcher::FillPacked(int32_t* big, int32_t kb, int32_t* aux,
   cols_cap_ = TailRung(own, prev_cols_, filled, batch_rows_);
   lifted_ = cols_cap_ != own;
   prev_cols_ = cols_cap_;
+  slots_.Lay(t.col, t.nnz_stride, shard_nnz_.data(), cols_cap_);
   return filled;
 }
 

@@ -75,6 +75,14 @@ class CsrRecBatcher {
   uint64_t ColsCapacity() const { return cols_cap_; }
   bool TailLifted() const { return lifted_; }
   uint64_t ColsDistinct() const { return slots_.Distinct(); }
+  // Key-range owners of the columns (col_slots.h "Owners"): set before
+  // the first batch; the lists are then owner-major, ColsCapacity() all the
+  // owners' stretches together, and ColsOwnerMax() the fullest owner's
+  // count of the batch's distinct columns.
+  void SetColOwners(uint32_t owners, uint64_t range) {
+    slots_.SetOwners(owners, range);
+  }
+  uint64_t ColsOwnerMax() const { return slots_.OwnerMax(); }
   void FillCols(int32_t* cols, uint64_t cap) const {
     slots_.Write(cols, cap);
   }

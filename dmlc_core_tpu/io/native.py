@@ -227,9 +227,12 @@ def _declare_signatures(cdll: ctypes.CDLL) -> None:
         "dct_batcher_cols_meta": (i, [vp, c.POINTER(c.c_uint64),
                                       c.POINTER(c.c_uint64), c.POINTER(i)]),
         "dct_batcher_fill_cols": (i, [vp, vp, c.c_uint64]),
-        "dct_col_slots": (i, [vp, vp, c.c_uint32, c.c_uint64, c.c_uint64,
-                              vp, c.POINTER(c.c_uint64),
-                              c.POINTER(c.c_uint64)]),
+        "dct_batcher_set_col_owners": (i, [vp, c.c_uint32, c.c_uint64]),
+        "dct_batcher_cols_owner_max": (i, [vp, c.POINTER(c.c_uint64)]),
+        "dct_col_slots": (i, [vp, vp, c.c_uint32, c.c_uint64,
+                                    c.c_uint64, c.c_uint32, c.c_uint64, vp,
+                                    c.POINTER(c.c_uint64),
+                                    c.POINTER(c.c_uint64)]),
         "dct_batcher_free": (i, [vp]),
         "dct_denserec_create": (i, [c.c_char_p, u, u, c.c_uint64,
                                     c.c_uint32, c.POINTER(vp)]),
@@ -261,6 +264,8 @@ def _declare_signatures(cdll: ctypes.CDLL) -> None:
         "dct_csrrec_cols_meta": (i, [vp, c.POINTER(c.c_uint64),
                                      c.POINTER(c.c_uint64), c.POINTER(i)]),
         "dct_csrrec_fill_cols": (i, [vp, vp, c.c_uint64]),
+        "dct_csrrec_set_col_owners": (i, [vp, c.c_uint32, c.c_uint64]),
+        "dct_csrrec_cols_owner_max": (i, [vp, c.POINTER(c.c_uint64)]),
         "dct_csrrec_free": (i, [vp]),
         "dct_bf16_convert": (i, [vp, vp, c.c_uint64]),
         "dct_bf16_upcast": (i, [vp, vp, c.c_uint64]),
@@ -1056,20 +1061,23 @@ def native_tail_rung(own: int, before: int, take: int,
     return out.value
 
 
-def native_col_slots(col: np.ndarray, n, floor: int):
+def native_col_slots(col: np.ndarray, n, floor: int, owners: int = 1,
+                     owner_rows: int = 0):
     """The native statement of the dedupe (cpp/src/col_slots.h; the Python
     one is dmlc_core_tpu.tpu.device_iter.col_slots): ``col`` [D, NNZ] int32
     with ``n[d]`` real entries in shard d becomes the slot plane in place;
-    returns (cols [D, U], distinct count)."""
+    returns (cols [D, U], distinct count). With several ``owners`` of
+    ``owner_rows`` ids each the lists are owner-major, ``U`` all the
+    stretches together."""
     D, stride = col.shape
     n = np.ascontiguousarray(n, np.uint64)
-    worst = native_nnz_bucket(int(n.max(initial=0)), floor)
+    worst = owners * native_nnz_bucket(int(n.max(initial=0)), floor)
     cols = np.empty(D * worst, np.int32)
     cap = ctypes.c_uint64()
     distinct = ctypes.c_uint64()
     _check(lib().dct_col_slots(
         NativeBatcher._ptr(col, np.int32, D * stride),
-        ctypes.c_void_p(n.ctypes.data), D, stride, floor,
+        ctypes.c_void_p(n.ctypes.data), D, stride, floor, owners, owner_rows,
         ctypes.c_void_p(cols.ctypes.data), ctypes.byref(cap),
         ctypes.byref(distinct)))
     return cols[:D * cap.value].reshape(D, cap.value), distinct.value
@@ -1089,6 +1097,12 @@ def _cols_meta(fn, handle):
 def _fill_cols(fn, handle, cols: np.ndarray, num_shards: int) -> None:
     U = cols.shape[1]
     _check(fn(handle, NativeBatcher._ptr(cols, np.int32, num_shards * U), U))
+
+
+def _cols_owner_max(fn, handle) -> int:
+    out = ctypes.c_uint64()
+    _check(fn(handle, ctypes.byref(out)))
+    return out.value
 
 
 class NativeBatcher:
@@ -1198,6 +1212,16 @@ class NativeBatcher:
         """Write those lists into ``cols`` [D, U] int32."""
         _fill_cols(lib().dct_batcher_fill_cols, self._h, cols,
                    self._num_shards)
+
+    def set_col_owners(self, owners: int, owner_rows: int) -> None:
+        """Lay the lists out owner-major for ``owners`` key ranges of
+        ``owner_rows`` ids each (col_slots.h "Owners"); before the first
+        batch."""
+        _check(lib().dct_batcher_set_col_owners(self._h, owners, owner_rows))
+
+    def cols_owner_max(self) -> int:
+        """The fullest owner's count of that batch's distinct columns."""
+        return _cols_owner_max(lib().dct_batcher_cols_owner_max, self._h)
 
     def fill_dense_packed(self, x: np.ndarray, aux: np.ndarray,
                           nrows: np.ndarray) -> None:
@@ -1393,6 +1417,14 @@ class NativeCsrRecBatcher:
         """Write those lists into ``cols`` [D, U] int32."""
         _fill_cols(lib().dct_csrrec_fill_cols, self._h, cols,
                    self._num_shards)
+
+    def set_col_owners(self, owners: int, owner_rows: int) -> None:
+        """As NativeBatcher.set_col_owners."""
+        _check(lib().dct_csrrec_set_col_owners(self._h, owners, owner_rows))
+
+    def cols_owner_max(self) -> int:
+        """As NativeBatcher.cols_owner_max."""
+        return _cols_owner_max(lib().dct_csrrec_cols_owner_max, self._h)
 
     def batch_nnz(self) -> int:
         """Real nonzeros of the batch the last fill wrote, all shards

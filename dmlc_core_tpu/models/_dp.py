@@ -6,8 +6,9 @@ batch is identical — unpack the packed batch per shard, take
 value_and_grad of the shard loss, apply the update, and jit-cache per batch
 shape. That harness lives here once.
 
-The gradient has one of two forms, chosen when the step is built from what
-the batch and the model show (``_takes_row_form``):
+The gradient has one of three forms. The first two are chosen when the step
+is built from what the batch and the model show (``_takes_row_form``); the
+third by how the model was told to keep its tables (``table_layout``):
 
 - table form (every dense batch; every model without the row hooks):
   value_and_grad with respect to the parameters, so the gradient is a
@@ -30,6 +31,25 @@ the batch and the model show (``_takes_row_form``):
   shards name is added to once a shard. (Cell kdd2012-fm-dp4.libfm runs
   it; the all-reduce of the table it replaced took 65 of the step's
   115 ms there, PERF.md section 6, PR 33.)
+- row form on range-sharded tables (``table_layout="range_sharded"``: a
+  CSR batch whose ``cols`` is owner-major, tpu/device_iter.py col_slots):
+  no device holds a whole table. The leaves ``_table_specs`` names are
+  sharded over the mesh by contiguous ranges of rows, a row's owner the
+  device of its range: what ps-lite's servers are to wormhole's workers,
+  server d and worker d on device d. A shard's list is ascending, so the
+  columns one owner holds are one stretch of it, at a static capacity
+  ``C``. The step pulls (scope ``dp.pull``: an all-to-all of the id
+  stretches ``[D, C]``, every owner gathers the ``D * C`` rows asked of
+  its range, ids less the range's first row, padding reads zeros, and an
+  all-to-all takes them back), differentiates exactly as the row form
+  does, pushes (``dp.push``: an all-to-all of the gradient's rows to their
+  owners; loss, weight and what every shard reads whole are psummed) and
+  every owner scatter-adds the ``D * C`` rows it got into its own range,
+  in the mesh's order (``dp.apply``). A column several shards name is
+  added to once a shard, as on replicated tables; what every shard reads
+  whole (a scalar) stays replicated and bit-identical. (Cell
+  criteo1tb-fm-ps4.tsv runs it: 2^27 rows of 17 floats, which one chip
+  cannot hold twice; PERF.md section 6, PR 39.)
 
 The phases of the jitted step carry ``jax.named_scope``s (``dp.unpack``,
 ``dp.loss_grad``, ``dp.allreduce``, ``dp.apply``; the models add their own
@@ -48,6 +68,12 @@ and may implement, for CSR shards (both or neither):
       one shard's list ``[U]`` with ``row_grads`` as the rows were, or on a
       mesh every shard's ``[D, U]`` with leaves ``[D, U, ...]`` and the
       scalars summed
+and for range-sharded tables, with the row hooks (``_gather_rows`` then
+also takes lists ``[D, C]``, stretch after stretch, and gives leaves
+``[D, C, ...]``):
+  table_layout = "range_sharded", col_owners = (owners, rows an owner)
+  _table_specs() -> the parameters' PartitionSpecs, a leaf each: ``P(axis)``
+      on the row axis of a table, ``P()`` for what every shard reads whole
 """
 
 from __future__ import annotations
@@ -60,6 +86,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from dmlc_core_tpu import telemetry
+from dmlc_core_tpu.base import DMLCError
 from dmlc_core_tpu.parallel.varying import (gather_unvarying,
                                             mark_varying)
 from dmlc_core_tpu.tpu.device_iter import unpack_shard
@@ -73,6 +100,7 @@ class DataParallelModel:
 
     mesh: Optional[Mesh]
     axis_name: str
+    table_layout: str = "replicated"
 
     def _shard_loss(self, params, shard, rows_per_shard: int
                     ) -> Tuple[jnp.ndarray, jnp.ndarray]:
@@ -121,6 +149,9 @@ class DataParallelModel:
                 return update(denom), loss_sum / denom
 
         row_form = self._takes_row_form(keys)
+        if self.table_layout == "range_sharded":
+            return self._build_owner_step(tree_keys, shard_view, local_grads,
+                                          apply)
         if row_form and (self.mesh is None or self.mesh.devices.size == 1):
             # the benchmark finds the step's module by this name
             # (tests/test_benchmark_names.py holds it, here and below)
@@ -191,12 +222,64 @@ class DataParallelModel:
 
         return jax.jit(sharded_step)
 
+    def _build_owner_step(self, tree_keys, shard_view, local_grads, apply):
+        """The row form on range-sharded tables (the module docstring's
+        third form): pull, the row form's gradient, push, and the update by
+        each row's owner alone."""
+        axis = self.axis_name
+        n_dev = int(self.mesh.devices.size)
+        owner_rows = self.col_owners[1]
+        specs = self._table_specs()
+
+        def exchange(leaf):
+            """``[D, C, ...]``, stretch d for device d, against the
+            stretches the devices hold for this one, in the mesh's order."""
+            return jax.lax.all_to_all(leaf, axis, 0, 0, tiled=True)
+
+        # the benchmark finds the step's module by this name
+        # (tests/test_benchmark_names.py)
+        @functools.partial(jax.shard_map, mesh=self.mesh,
+                           in_specs=(specs, dict(tree_keys)),
+                           out_specs=(specs, P()))
+        def sharded_step(params, tree):
+            shard = shard_view(tree)
+            with jax.named_scope("dp.pull"):
+                # row d: the columns shard d reads of this owner's range,
+                # as rows of the range (the padding stays beyond it)
+                asked = exchange(shard["cols"].reshape(n_dev, -1)) \
+                    - jax.lax.axis_index(axis) * owner_rows
+                # what every shard reads whole is differentiated as
+                # device-varying, as the replicated step's parameters are
+                local = type(params)(*(
+                    mark_varying(p, (axis,)) if spec == P() else p
+                    for p, spec in zip(params, specs)))
+                rows = jax.tree.map(
+                    lambda r: exchange(r).reshape((-1,) + r.shape[2:])
+                    if r.ndim else r,
+                    self._gather_rows(local, {"cols": asked}))
+            loss_sum, wsum, grads = local_grads(rows, shard)
+            # the rows go into the exchange as the leaves they are (the
+            # replicated row form's reason, PERF.md section 6, PR 35)
+            grads = jax.lax.optimization_barrier(grads)
+            with jax.named_scope("dp.push"):
+                loss_sum = jax.lax.psum(loss_sum, axis)
+                wsum = jax.lax.psum(wsum, axis)
+                grads = jax.tree.map(
+                    lambda g: exchange(g.reshape(asked.shape + g.shape[1:]))
+                    if g.ndim else jax.lax.psum(g, axis), grads)
+            return apply(lambda denom: self._apply_rows(params, asked, grads,
+                                                        denom),
+                         loss_sum, wsum)
+
+        return jax.jit(sharded_step)
+
     def _exchange_bytes(self, params, tree, n_dev: int) -> int:
         """What a step on ``n_dev`` devices hands to its collectives, from
         the shapes: loss sum and weight sum, and then a gradient of the
         parameters' shapes (table form) or every shard's list with the
         rows of its gradient, what the shards read whole counted once (row
-        form). Nothing on one device."""
+        form; on range-sharded tables the rows twice, pulled and pushed).
+        Nothing on one device."""
         if n_dev == 1:
             return 0
         if not self._takes_row_form(tree):
@@ -206,9 +289,11 @@ class DataParallelModel:
             lambda t: unpack_shard({k: v[0] for k, v in t.items()}), tree)
         rows = jax.tree.leaves(jax.eval_shape(self._gather_rows, params,
                                               shard))
-        return 8 + sum(
-            r.dtype.itemsize * (n_dev * r.size if r.ndim else 1)
-            for r in rows + [shard["cols"]])
+        trips = 2 if self.table_layout == "range_sharded" else 1
+        cols = shard["cols"]
+        return 8 + n_dev * cols.size * cols.dtype.itemsize + sum(
+            r.dtype.itemsize * (trips * n_dev * r.size if r.ndim else 1)
+            for r in rows)
 
     def step(self, params, batch):
         """One jitted training step on a device batch; returns
@@ -225,6 +310,15 @@ class DataParallelModel:
             raise ValueError(
                 f"batch device axis D={D} != mesh size {n_dev}; "
                 f"build the batch with num_shards={n_dev}")
+        owners = getattr(batch, "owners", 1)
+        if self.table_layout == "range_sharded" and owners != n_dev:
+            # a plain list cut in equal parts would send ids to owners that
+            # do not hold them
+            raise DMLCError(
+                f"range-sharded tables read a CSR batch whose distinct "
+                f"columns are laid out by their {n_dev} owners; this one "
+                f"has {owners}: build the iterator with "
+                f"col_owners=learner.col_owners")
         sig = tuple((k, tuple(v.shape)) for k, v in sorted(tree.items()))
         fn = self._step_fn.get(sig)
         built = fn is None

@@ -74,10 +74,12 @@ class FMRows(NamedTuple):
 def _fm_gather(params: FMParams, cols) -> FMRows:
     """The rows at ``cols``: ascending to the list's end, its padding
     beyond the tables (col_slots), where the gather reads zeros and its
-    transpose, the table form's scatter, drops."""
+    transpose, the table form's scatter, drops. ``[D, C]`` is stretch
+    after stretch (what an owner of a range is asked for, models/_dp.py),
+    ascending within a stretch only, and gives ``[D, C, ...]``."""
     def at(table):
         return table.at[cols].get(mode="fill", fill_value=0,
-                                  indices_are_sorted=True)
+                                  indices_are_sorted=cols.ndim == 1)
     with jax.named_scope("fm.linear"):
         w_rows = at(params.w)
     with jax.named_scope("fm.gather"):
@@ -231,9 +233,20 @@ class FMLearner(DataParallelModel):
     def __init__(self, num_features: int, k: int = 8,
                  mesh: Optional[Mesh] = None, objective: str = "logistic",
                  learning_rate: float = 0.05, l2: float = 0.0,
-                 init_scale: float = 0.01, axis_name: str = "data"):
+                 init_scale: float = 0.01, axis_name: str = "data",
+                 table_layout: str = "replicated"):
+        """``table_layout``: how a mesh keeps ``w`` and ``v``.
+        ``"replicated"``: whole on every device. ``"range_sharded"``: cut
+        over the mesh's devices by contiguous ranges of
+        ``num_features / devices`` rows, a row on its owner alone (``b``
+        stays on every device); the batches then come from an iterator
+        built with ``col_owners=learner.col_owners`` (models/_dp.py, the
+        third form)."""
         if k <= 0:
             raise ValueError(f"factor rank k must be positive, got {k}")
+        if table_layout not in ("replicated", "range_sharded"):
+            raise ValueError(f"unknown table_layout {table_layout!r} "
+                             f"(replicated, range_sharded)")
         self.num_features = num_features
         self.k = k
         self.mesh = mesh
@@ -242,21 +255,49 @@ class FMLearner(DataParallelModel):
         self.l2 = l2
         self.init_scale = init_scale
         self.axis_name = axis_name
+        self.table_layout = table_layout
+        self.col_owners = (1, 0)
+        if table_layout == "range_sharded":
+            owners = 0 if mesh is None else int(mesh.devices.size)
+            if owners < 2 or num_features % owners:
+                raise ValueError(
+                    f"range-sharded tables are cut in equal ranges over a "
+                    f"mesh of several devices: {num_features} rows over "
+                    f"{owners} (pad num_features to a multiple)")
+            self.col_owners = (owners, num_features // owners)
         self._step_fn = None
 
     def init(self, seed: int = 0) -> FMParams:
-        """Fresh parameters (replicated): zero linear part, small random
-        factors — an all-zero V has zero interaction gradient."""
-        v = self.init_scale * jax.random.normal(
-            jax.random.PRNGKey(seed), (self.num_features, self.k),
-            jnp.float32)
+        """Fresh parameters: zero linear part, small random factors (an
+        all-zero V has zero interaction gradient). On a mesh replicated, or
+        with range-sharded tables every range made on its owner, no device
+        and not the host holding a whole table: the same draw row for row
+        (a row's bits depend on its place in the table, not on who makes
+        it; the draw alone is jitted, cut by rows, and scaled outside the
+        jit as the replicated one is, so that no compiler folds the two
+        factors into one rounding)."""
+        key = jax.random.PRNGKey(seed)
+        shape = (self.num_features, self.k)
+        if self.table_layout == "range_sharded":
+            at = FMParams(*(NamedSharding(self.mesh, spec)
+                            for spec in self._table_specs()))
+            draw = jax.jit(
+                lambda k: jax.random.normal(k, shape, jnp.float32),
+                out_shardings=at.v)(key)
+            return FMParams(b=jnp.zeros((), jnp.float32, device=at.b),
+                            w=jnp.zeros(shape[:1], jnp.float32, device=at.w),
+                            v=self.init_scale * draw)
+        v = self.init_scale * jax.random.normal(key, shape, jnp.float32)
         params = FMParams(b=jnp.zeros((), jnp.float32),
-                          w=jnp.zeros((self.num_features,), jnp.float32),
-                          v=v)
+                          w=jnp.zeros(shape[:1], jnp.float32), v=v)
         if self.mesh is not None:
             params = jax.device_put(params,
                                     NamedSharding(self.mesh, P()))
         return params
+
+    def _table_specs(self) -> FMParams:
+        rows = P(self.axis_name)
+        return FMParams(b=P(), w=rows, v=rows)
 
     # -- DataParallelModel hooks (the step harness lives in models/_dp.py) --
     def _shard_loss(self, params, shard, rows_per_shard):

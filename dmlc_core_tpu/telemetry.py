@@ -1485,6 +1485,14 @@ METRIC_HELP: Dict[str, str] = {
         "distinct columns of the CSR batches dispatched, summed over their "
         "shards, as the batcher's dedupe counted them: the rows a step "
         "gathers and scatters",
+    "device_cols_owner_max_total":
+        "where the columns have several key-range owners: of a batch's "
+        "distinct columns, those of the owner that got the most",
+    "device_stretch_sent_total":
+        "where the columns have several key-range owners: positions of the "
+        "owner-major lists dispatched (shards x owners x stretch capacity)",
+    "device_stretch_real_total":
+        "of the stretch positions sent, the distinct columns in them",
     "device_tail_batches_total":
         "short last batches sent at the rungs of the batch before them, "
         "their own being lower (the padding is in device_nnz_sent_total)",
@@ -1530,8 +1538,9 @@ METRIC_HELP: Dict[str, str] = {
     "model_step_allreduce_bytes_total":
         "bytes handed to the collectives of the mesh step (loss sum, weight "
         "sum, and every shard's distinct columns with the rows of its "
-        "gradient or, in the table form, a gradient of the parameters' "
-        "shapes), by model class; 0 on one device",
+        "gradient, pulled and pushed on range-sharded tables, or, in the "
+        "table form, a gradient of the parameters' shapes), by model class; "
+        "0 on one device",
     "device_put_failures_total": "device_put calls that raised",
     "device_host_q_depth": "staged host batches queued for transfer",
     "device_ready_q_depth": "device batches queued for the consumer",

@@ -39,7 +39,7 @@ import threading
 import time
 import weakref
 from dataclasses import dataclass
-from typing import Any, Dict, Iterator, Optional
+from typing import Any, Dict, Iterator, Optional, Tuple
 
 import numpy as np
 
@@ -79,6 +79,9 @@ def _get_lane_metrics():
             "nnz_sent": telemetry.counter("device_nnz_sent_total"),
             "nnz_real": telemetry.counter("device_nnz_real_total"),
             "cols_distinct": telemetry.counter("device_cols_distinct_total"),
+            "owner_max": telemetry.counter("device_cols_owner_max_total"),
+            "stretch_sent": telemetry.counter("device_stretch_sent_total"),
+            "stretch_real": telemetry.counter("device_stretch_real_total"),
             "tail_batches": telemetry.counter("device_tail_batches_total"),
             "bytes": telemetry.counter("device_transfer_bytes_total"),
             "failures": telemetry.counter("device_put_failures_total"),
@@ -180,7 +183,8 @@ def _dense_dtype_of(d) -> np.dtype:
 __all__ = ["PaddedBatch", "DenseBatch", "DeviceRowBlockIter", "HostBatcher",
            "NativeHostBatcher", "DenseRecHostBatcher", "CsrRecHostBatcher",
            "unpack_tree", "unpack_shard", "match_placement_rules",
-           "jax_profiler_capture", "nnz_bucket", "tail_rung", "col_slots"]
+           "jax_profiler_capture", "nnz_bucket", "tail_rung", "col_slots",
+           "owner_counts"]
 
 
 @dataclass
@@ -190,7 +194,9 @@ class PaddedBatch:
     row/col/val: [D, NNZ]  per-nonzero segment id (local), column, value
     cols: [D, U] int32     each shard's DISTINCT columns, ascending, padded
                            to the ladder rung U of the fullest shard's count
-                           by an id beyond any table (``col_slots``)
+                           by an id beyond any table (``col_slots``); where
+                           the columns have key-range owners, owner-major:
+                           ``[D, owners * C]``, a padded stretch an owner
     slot: [D, NNZ] int32   per-nonzero position of its column in ``cols``:
                            ``col == cols[slot]``; 0 on padding nonzeros
     label/weight: [D, R]   weight 0 marks padding rows
@@ -245,6 +251,11 @@ class PaddedBatch:
     # host-side: a short last batch that was sent at the rungs of the batch
     # before it, its own being lower (``tail_rung``)
     tail_lifted: bool = False
+    # host-side: the key-range owners ``cols`` is laid out by (1: the plain
+    # list) and, with several, the fullest owner's count of the batch's
+    # distinct columns, all shards' stretches together
+    owners: int = 1
+    owner_max: int = 0
     qid: Any = None
     field: Any = None
     big: Any = None  # [D, Kb, NNZ] packed row/slot[/val][/field]
@@ -519,7 +530,8 @@ def tail_rung(own: int, before: int, take: int, batch_rows: int) -> int:
     return before if take < batch_rows and before > own else own
 
 
-def col_slots(col: np.ndarray, n, floor: int):
+def col_slots(col: np.ndarray, n, floor: int, owners: int = 1,
+              owner_rows: int = 0):
     """The distinct columns of each CSR shard and every entry's slot among
     them: the dedupe every assembler runs, stated here by ``np.unique`` as
     the oracle and once natively (cpp/src/col_slots.h, whose header has the
@@ -533,19 +545,50 @@ def col_slots(col: np.ndarray, n, floor: int):
     gather reads zeros there, a scatter drops them, no slot names them,
     and the list stays sorted to its end); a shard without entries lists
     column 0 once, so slot 0 always names a real row. ``distinct`` is the
-    batch's count of distinct columns, summed over the shards."""
+    batch's count of distinct columns, summed over the shards.
+
+    With several ``owners`` of ``owner_rows`` ids each (tables sharded by
+    key range: models/_dp.py) the list is owner-major, ``[owners, C]``
+    flattened: stretch ``o`` holds the shard's columns in
+    ``[o * owner_rows, (o + 1) * owner_rows)``, one contiguous stretch of
+    the ascending list, padded to ``C`` as the tail is, ``C =
+    nnz_bucket(fullest (shard, owner) stretch, floor)``, ``U = owners * C``,
+    and a slot names a position in the flattened list. One owner: the list
+    above to the byte."""
     lists = []
     for d, nd in enumerate(n):
         uniq, inv = np.unique(col[d, :nd], return_inverse=True)
         col[d, :nd] = inv
         col[d, nd:] = 0
         lists.append(uniq if nd else np.zeros(1, np.int32))
-    U = nnz_bucket(max(len(u) for u in lists), floor)
-    cols = _aligned_empty((len(lists), U), np.int32)
+    top = max(int(u[-1]) for u in lists)
+    if owners > 1 and top >= owners * owner_rows:
+        raise DMLCError(f"column {top} lies beyond the {owners} owners' "
+                        f"ranges of {owner_rows} ids")
+    # where each owner's stretch of a shard's list starts, and its end
+    edges = np.arange(owners) * owner_rows
+    first = [np.append(np.searchsorted(u, edges), len(u)) for u in lists]
+    C = nnz_bucket(max(int(np.diff(f).max()) for f in first), floor)
+    cols = _aligned_empty((len(lists), owners * C), np.int32)
     cols[:] = np.iinfo(np.int32).max
-    for d, uniq in enumerate(lists):
-        cols[d, :len(uniq)] = uniq
+    for d, (uniq, f) in enumerate(zip(lists, first)):
+        for o in range(owners):
+            cols[d, o * C:o * C + f[o + 1] - f[o]] = uniq[f[o]:f[o + 1]]
+        if owners > 1:  # a slot moves with its column's stretch
+            nd = n[d]
+            o = np.searchsorted(f[1:], col[d, :nd], side="right")
+            col[d, :nd] += (o * C - f[o]).astype(np.int32)
     return cols, sum(len(u) for u, nd in zip(lists, n) if nd)
+
+
+def owner_counts(cols: np.ndarray, n, owners: int) -> np.ndarray:
+    """``[owners]``: how many distinct columns of a batch each key-range
+    owner is sent, all shards' stretches of the owner-major ``cols``
+    together (a shard without entries sends its stand-in and counts
+    nothing). What ``device_cols_owner_max_total`` takes the largest of."""
+    D = cols.shape[0]
+    real = cols.reshape(D, owners, -1) != np.iinfo(np.int32).max
+    return (real & (np.asarray(n) > 0)[:, None, None]).sum(axis=(0, 2))
 
 
 # -- spec-driven placement ---------------------------------------------------
@@ -650,13 +693,15 @@ class HostBatcher:
     def __init__(self, parser: NativeParser, batch_rows: int,
                  num_shards: int, min_nnz_bucket: int = 4096,
                  index64: bool = False, layout: str = "auto",
-                 dense_max_features: int = 512, dense_dtype=np.float32):
+                 dense_max_features: int = 512, dense_dtype=np.float32,
+                 col_owners: Tuple[int, int] = (1, 0)):
         if batch_rows % num_shards != 0:
             raise DMLCError(
                 f"batch_rows={batch_rows} must divide by shards={num_shards}")
         if layout not in ("auto", "csr", "dense"):
             raise DMLCError(f"unknown layout {layout!r}")
         self.parser = parser
+        self.col_owners = col_owners
         self.batch_rows = batch_rows
         self.num_shards = num_shards
         self.min_nnz_bucket = min_nnz_bucket
@@ -861,15 +906,23 @@ class HostBatcher:
             if fldp is not None:
                 fldp[d, :n] = fld[lo:hi]
         # the columns become the distinct lists, the plane their slots
-        cols, distinct = col_slots(slotp, shard_nnz, self.min_nnz_bucket)
+        owners = self.col_owners[0]
+        cols, distinct = col_slots(slotp, shard_nnz, self.min_nnz_bucket,
+                                   *self.col_owners)
         U = tail_rung(cols.shape[1], cols_before, take, self.batch_rows)
         lifted = bucket != own or U != cols.shape[1]
         if U != cols.shape[1]:  # the list's own padding, to the rung before
+            c0, c1 = cols.shape[1] // owners, U // owners
             wide = _aligned_empty((D, U), np.int32)
             wide[:] = np.iinfo(np.int32).max
-            wide[:, :cols.shape[1]] = cols
+            wide.reshape(D, owners, c1)[:, :, :c0] = cols.reshape(D, owners,
+                                                                  c0)
+            if owners > 1:  # a slot moves with its stretch
+                slotp[:] = slotp // c0 * c1 + slotp % c0
             cols = wide
         self._rungs_before = (bucket, cols.shape[1])
+        owner_max = int(owner_counts(cols, shard_nnz, owners).max()) \
+            if owners > 1 else 0
 
         nrows = np.minimum(
             np.maximum(take - np.arange(D) * R, 0), R).astype(np.int32)
@@ -880,7 +933,7 @@ class HostBatcher:
             label=label_v, weight=weight_v,
             nrows=nrows, total_rows=int(take),
             total_nnz=int(shard_starts[-1]), total_distinct=distinct,
-            tail_lifted=lifted,
+            tail_lifted=lifted, owners=owners, owner_max=owner_max,
             qid=qid_v, field=fldp, big=big, aux=aux)
 
     def _emit_dense(self, take, label, weight, lens, col, val, qid):
@@ -928,17 +981,19 @@ class HostBatcher:
         return self.parser.set_epoch(epoch)
 
 
-def _native_cols(native, pool: _HostBufferPool, D: int):
+def _native_cols(native, pool: _HostBufferPool, D: int, owners: int = 1):
     """The distinct-column lists of the batch a native ``fill_packed`` just
     wrote (its col plane now holds the slots), in a pooled [D, U] buffer;
     returns (cols, distinct count, whether a short batch was lifted to the
-    rungs of the batch before it)."""
+    rungs of the batch before it, the fullest owner's count where the
+    columns have several owners and 0 otherwise)."""
     U, distinct, lifted = native.cols_meta()
     cols = pool.pop(("cols", U))
     if cols is None:
         cols = _aligned_empty((D, U), np.int32)
     native.fill_cols(cols)
-    return cols, distinct, lifted
+    return (cols, distinct, lifted,
+            native.cols_owner_max() if owners > 1 else 0)
 
 
 class NativeHostBatcher:
@@ -955,7 +1010,8 @@ class NativeHostBatcher:
                  batch_rows: int = 65536, num_shards: int = 1,
                  min_nnz_bucket: int = 4096, layout: str = "auto",
                  dense_max_features: int = 512, dense_dtype=np.float32,
-                 csr_val_dtype: str = "f32"):
+                 csr_val_dtype: str = "f32",
+                 col_owners: Tuple[int, int] = (1, 0)):
         if batch_rows % num_shards != 0:
             raise DMLCError(
                 f"batch_rows={batch_rows} must divide by shards={num_shards}")
@@ -968,6 +1024,9 @@ class NativeHostBatcher:
                                 nthread=nthread, batch_rows=batch_rows,
                                 num_shards=num_shards,
                                 min_nnz_bucket=min_nnz_bucket)
+        self._owners = col_owners[0]
+        if self._owners > 1:
+            self._b.set_col_owners(*col_owners)
         self.batch_rows = batch_rows
         self.num_shards = num_shards
         self.layout = layout
@@ -1054,7 +1113,8 @@ class NativeHostBatcher:
             aux = _aligned_empty((D, 4 if has_qid else 3, R), np.int32)
         # one fused native pass assembles the whole shard-major batch
         self._b.fill_packed(big, aux, nrows, val=val16 if sep_val else None)
-        cols, distinct, lifted = _native_cols(self._b, self._pool, D)
+        cols, distinct, lifted, owner_max = _native_cols(
+            self._b, self._pool, D, self._owners)
         row, slot, val, field = _view_big(big, has_val=not sep_val)
         _, label, weight, qid = _view_aux(aux)
         return PaddedBatch(row=row, slot=slot, cols=cols,
@@ -1063,6 +1123,7 @@ class NativeHostBatcher:
                            nrows=nrows, total_rows=int(take),
                            total_nnz=self._b.batch_nnz(),
                            total_distinct=distinct, tail_lifted=lifted,
+                           owners=self._owners, owner_max=owner_max,
                            qid=qid, field=field, big=big, aux=aux,
                            val16=val16)
 
@@ -1126,7 +1187,8 @@ class CsrRecHostBatcher:
 
     def __init__(self, uri: str, part: int = 0, npart: int = 1,
                  batch_rows: int = 65536, num_shards: int = 1,
-                 min_nnz_bucket: int = 4096):
+                 min_nnz_bucket: int = 4096,
+                 col_owners: Tuple[int, int] = (1, 0)):
         if batch_rows % num_shards != 0:
             raise DMLCError(
                 f"batch_rows={batch_rows} must divide by shards="
@@ -1135,6 +1197,9 @@ class CsrRecHostBatcher:
                                       batch_rows=batch_rows,
                                       num_shards=num_shards,
                                       min_nnz_bucket=min_nnz_bucket)
+        self._owners = col_owners[0]
+        if self._owners > 1:
+            self._b.set_col_owners(*col_owners)
         self.batch_rows = batch_rows
         self.num_shards = num_shards
         self._meta = None
@@ -1170,7 +1235,8 @@ class CsrRecHostBatcher:
         take = self._b.fill_packed(big, aux, nrows)
         if take == 0:
             return None
-        cols, distinct, lifted = _native_cols(self._b, self._pool, D)
+        cols, distinct, lifted, owner_max = _native_cols(
+            self._b, self._pool, D, self._owners)
         row, slot, val, field = _view_big(big)
         _, label, weight, qid = _view_aux(aux)
         return PaddedBatch(row=row, slot=slot, cols=cols, val=val,
@@ -1178,6 +1244,7 @@ class CsrRecHostBatcher:
                            nrows=nrows, total_rows=int(take),
                            total_nnz=self._b.batch_nnz(),
                            total_distinct=distinct, tail_lifted=lifted,
+                           owners=self._owners, owner_max=owner_max,
                            qid=qid, field=field, big=big, aux=aux)
 
     def reset(self) -> None:
@@ -1298,7 +1365,12 @@ class DeviceRowBlockIter:
                  index64: bool = False, nthread: int = 0,
                  prefetch: int = 2, to_device: bool = True,
                  layout: str = "auto", dense_max_features: int = 512,
-                 dense_dtype=np.float32, csr_val_dtype: str = "f32"):
+                 dense_dtype=np.float32, csr_val_dtype: str = "f32",
+                 col_owners: Tuple[int, int] = (1, 0)):
+        """``col_owners``: ``(owners, ids an owner)`` where the consumer
+        keeps its tables sharded by key range (``FMLearner.col_owners``
+        gives it): every CSR batch's ``cols`` is then owner-major
+        (``col_slots``)."""
         self.mesh = mesh
         self.to_device = to_device
         self.batch_rows = batch_rows
@@ -1334,7 +1406,8 @@ class DeviceRowBlockIter:
             self.parser = None
             self.batcher = CsrRecHostBatcher(
                 uri, part=part, npart=npart, batch_rows=batch_rows,
-                num_shards=num_shards, min_nnz_bucket=min_nnz_bucket)
+                num_shards=num_shards, min_nnz_bucket=min_nnz_bucket,
+                col_owners=col_owners)
         elif index64:
             # 64-bit parse width; the int32 device layout is still the hard
             # contract — the numpy batcher raises on any id >= 2^31
@@ -1344,7 +1417,8 @@ class DeviceRowBlockIter:
             self.batcher = HostBatcher(self.parser, batch_rows, num_shards,
                                        min_nnz_bucket, index64, layout=layout,
                                        dense_max_features=dense_max_features,
-                                       dense_dtype=dense_dtype)
+                                       dense_dtype=dense_dtype,
+                                       col_owners=col_owners)
         else:
             self.parser = None
             self.batcher = NativeHostBatcher(
@@ -1352,7 +1426,8 @@ class DeviceRowBlockIter:
                 batch_rows=batch_rows, num_shards=num_shards,
                 min_nnz_bucket=min_nnz_bucket, layout=layout,
                 dense_max_features=dense_max_features,
-                dense_dtype=dense_dtype, csr_val_dtype=csr_val_dtype)
+                dense_dtype=dense_dtype, csr_val_dtype=csr_val_dtype,
+                col_owners=col_owners)
         # per-leaf sharding derived from _PLACEMENT_RULES (every leaf is
         # shard-major, so all take the leading device axis); materialized
         # lazily from the first batch's tree structure
@@ -1642,6 +1717,14 @@ class DeviceRowBlockIter:
             m["cols_distinct"].inc(batch.total_distinct)
             if batch.tail_lifted:
                 m["tail_batches"].inc()
+            if batch.owners > 1:
+                # several key-range owners: the fullest one's columns, and
+                # the stretches' fill (positions sent against columns real)
+                m["owner_max"].inc(batch.owner_max)
+                m["stretch_sent"].inc(batch.cols.size)
+                m["stretch_real"].inc(batch.total_distinct)
+                kwargs["owners"] = batch.owners
+                kwargs["owner_max"] = batch.owner_max
             kwargs["total_nnz"] = batch.total_nnz
             kwargs["total_distinct"] = batch.total_distinct
             kwargs["tail_lifted"] = batch.tail_lifted

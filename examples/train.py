@@ -9,6 +9,8 @@ Walks the full TPU-native pipeline surface in ~60 lines of user code:
   python examples/train.py s3://bucket/train.drec --batch-rows 8192
   python examples/train.py data.rec --resume ckpt.bin   # after preemption
   python examples/train.py "day_0.tsv?hash_bits=25" --format criteo --model fm
+  python examples/train.py "day_0.tsv?hash_bits=27" --format criteo --model fm \
+      --table-layout range_sharded   # w, v cut by key range over the chips
 
 Under dmlc-submit the same script runs per-host with its own partition:
 
@@ -63,6 +65,13 @@ def main() -> int:
                          "machine (the libfm lane's canonical consumer)")
     ap.add_argument("--fm-rank", type=int, default=8,
                     help="FM interaction-factor rank k")
+    ap.add_argument("--table-layout", default="replicated",
+                    choices=("replicated", "range_sharded"),
+                    help="fm on several devices: every device holds the "
+                         "whole tables, or each a contiguous range of their "
+                         "rows (a batch's rows pulled from and pushed to "
+                         "their owners; --num-features is rounded up to a "
+                         "multiple of the devices)")
     ap.add_argument("--objective", default="logistic",
                     choices=("logistic", "squared", "pairwise"))
     ap.add_argument("--epochs", type=int, default=2)
@@ -93,9 +102,13 @@ def main() -> int:
         args.num_features = mx + 1
 
     if args.model == "fm":
+        if args.table_layout == "range_sharded":
+            n_dev = int(mesh.devices.size)  # equal ranges: pad the last
+            args.num_features = -(-args.num_features // n_dev) * n_dev
         learner = FMLearner(num_features=args.num_features, mesh=mesh,
                             k=args.fm_rank, objective=args.objective,
-                            learning_rate=args.learning_rate)
+                            learning_rate=args.learning_rate,
+                            table_layout=args.table_layout)
     else:
         learner = LinearLearner(num_features=args.num_features, mesh=mesh,
                                 objective=args.objective,
@@ -121,7 +134,8 @@ def main() -> int:
 
     it = DeviceRowBlockIter(args.uri, part=part, npart=npart, mesh=mesh,
                             fmt=args.format, batch_rows=args.batch_rows,
-                            dense_dtype="bf16")
+                            dense_dtype="bf16",
+                            col_owners=getattr(learner, "col_owners", (1, 0)))
     epochs = []
     first_batch_devices = None
     shapes = telemetry.gauge("device_distinct_shapes")

@@ -190,7 +190,10 @@ def gen_api() -> str:
 
 
 def gen_parameters() -> str:
+    import inspect
+
     from dmlc_core_tpu.io.native import parser_formats_doc
+    from dmlc_core_tpu.models import FMLearner
     from dmlc_core_tpu.params import Parameter, field
 
     class _Example(Parameter):
@@ -246,6 +249,13 @@ def gen_parameters() -> str:
         "input_split_shuffle.h).",
         "",
         parser_formats_doc().rstrip(),
+        "",
+        "# Model table layouts",
+        "",
+        "`FMLearner(..., table_layout=)`, from the entry point "
+        "`examples/train.py --model fm --table-layout "
+        "{replicated,range_sharded}`. " + " ".join(
+            inspect.getdoc(FMLearner.__init__).split()),
         "",
         "# Environment knobs",
         "",

@@ -13,7 +13,9 @@ under 24 keys of the test process's mock S3 and read as part 1 of 16 where it
 stores it there: ISSUE 34) through ``DeviceRowBlockIter`` for two epochs and
 ``FMLearner.step`` on as many
 devices as the cell has chips (one: the row form of the step; four: the row
-form under ``shard_map``, with its exchange under ``dp.allreduce``). A metric
+form under ``shard_map``, with its exchange under ``dp.allreduce`` or, where
+the configuration's ``deployment`` says ``table_layout``, on range-sharded
+tables with ``dp.pull`` and ``dp.push``: ISSUE 39). A metric
 that ``BENCHMARK.json`` promises a cell has to be found in that cell's run; a
 metric file it lists for no cell yet (the proposals under
 ``benchmarks/tests/``) in some cell's.
@@ -36,7 +38,8 @@ from dmlc_core_tpu.tpu import DeviceRowBlockIter, data_mesh, device_iter
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH = os.path.join(REPO, "benchmarks")
 # the keys of a metric file that name something of the program
-NAME_KEYS = ("histograms", "counter", "less", "spans", "module", "any")
+NAME_KEYS = ("histograms", "counter", "less", "counters", "spans", "module",
+             "any")
 # more features than `dense_max_features`: the iterator's layout="auto" then
 # makes CSR batches, as it does of every cell's data
 ROWS, BATCH, FIELDS, CARD = 600, 256, 4, 200
@@ -140,11 +143,13 @@ def program(tmp_path_factory):
         telemetry.reset()
         device_iter._reset_shape_census()
         mesh = data_mesh(cell["chips"])
+        layout = _json(BENCH, "configs", cell["config"] + ".json").get(
+            "deployment", {}).get("table_layout", "replicated")
         learner = FMLearner(num_features=max(FIELDS * CARD, 1 << HASH_BITS),
-                            k=4, mesh=mesh)
+                            k=4, mesh=mesh, table_layout=layout)
         params = learner.init(0)
-        with DeviceRowBlockIter(uri, mesh=mesh, batch_rows=BATCH,
-                                fmt=fmt, **part) as it:
+        with DeviceRowBlockIter(uri, mesh=mesh, batch_rows=BATCH, fmt=fmt,
+                                col_owners=learner.col_owners, **part) as it:
             for _ in range(2):
                 for batch in it:
                     params, loss = learner.step(params, batch)
@@ -172,11 +177,13 @@ def _missing(how, runs):
                    for h in r["snapshot"]["histograms"] if h["name"] == name):
             out.append(f"histogram {name!r} was never observed")
     # readers/hist_per_counter.py: the rise of `counter` less that of `less`
-    for key in ("counter", "less"):
-        if key in how and not any(
-                c["value"] for r in runs for c in r["snapshot"]["counters"]
-                if c["name"] == how[key]):
-            out.append(f"counter {how[key]!r} never rose")
+    # and readers/counter_ratio.py: the rise of one of `counters` over the
+    # other's
+    for name in [how[k] for k in ("counter", "less") if k in how] + \
+            how.get("counters", []):
+        if not any(c["value"] for r in runs
+                   for c in r["snapshot"]["counters"] if c["name"] == name):
+            out.append(f"counter {name!r} never rose")
     for name in how.get("spans", ()):
         # an opened span `x` is the annotation `dmlc.x` of the trace
         if not any(name.removeprefix("dmlc.") in r["spans"] for r in runs):
