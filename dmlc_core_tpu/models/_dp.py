@@ -57,6 +57,14 @@ inside ``dp.loss_grad``), which change the operations' ``op_name`` metadata
 only: a device trace then names its time by phase whatever the compiler
 numbers its fusions (doc/observability.md "Device lane").
 
+Every form is compiled with its state donated (``donate_argnums=(0,)``):
+the new tables are written into the buffers of the old, so a step holds one
+table where it held two and no pass copies a table before the scatter (that
+copy was 11 to 33% of the step, PERF.md section 6, PR 40). ``step`` hands the
+compiled step a state as it is only when it is the one the learner's own
+last step returned; any other is the caller's and is copied first
+(``_own_state``).
+
 Subclasses implement:
   _shard_loss(params, shard, rows_per_shard) -> (loss_sum, weight_sum)
   _apply(params, grads, denom) -> new params
@@ -79,6 +87,7 @@ also takes lists ``[D, C]``, stretch after stretch, and gives leaves
 from __future__ import annotations
 
 import functools
+import weakref
 from typing import Optional, Tuple
 
 import jax
@@ -162,14 +171,14 @@ class DataParallelModel:
                 loss_sum, wsum, row_grads = local_grads(rows, shard)
                 return apply(lambda denom: self._apply_rows(
                     params, shard["cols"], row_grads, denom), loss_sum, wsum)
-            return jax.jit(sharded_step)
+            return jax.jit(sharded_step, donate_argnums=(0,))
 
         if self.mesh is None:
             def step(params, tree):
                 loss_sum, wsum, grads = local_grads(params, shard_view(tree))
                 return apply(lambda denom: self._apply(params, grads, denom),
                              loss_sum, wsum)
-            return jax.jit(step)
+            return jax.jit(step, donate_argnums=(0,))
 
         # the benchmark finds the step's module by this name
         # (tests/test_benchmark_names.py)
@@ -220,7 +229,7 @@ class DataParallelModel:
                         return self._apply(params, grads, denom)
             return apply(update, loss_sum, wsum)
 
-        return jax.jit(sharded_step)
+        return jax.jit(sharded_step, donate_argnums=(0,))
 
     def _build_owner_step(self, tree_keys, shard_view, local_grads, apply):
         """The row form on range-sharded tables (the module docstring's
@@ -271,7 +280,7 @@ class DataParallelModel:
                                                         denom),
                          loss_sum, wsum)
 
-        return jax.jit(sharded_step)
+        return jax.jit(sharded_step, donate_argnums=(0,))
 
     def _exchange_bytes(self, params, tree, n_dev: int) -> int:
         """What a step on ``n_dev`` devices hands to its collectives, from
@@ -295,9 +304,47 @@ class DataParallelModel:
             r.dtype.itemsize * (trips * n_dev * r.size if r.ndim else 1)
             for r in rows)
 
+    def _own_state(self, params):
+        """``params`` as the donating step may consume it: itself where
+        every leaf *is* (the object, not an equal one) the leaf this
+        learner's last step returned; else (the first call's, one from
+        ``init``, a restored checkpoint, another learner's, a tree with a
+        leaf replaced) a copy made on the device, leaf by leaf with its
+        sharding, so that nothing of the caller's is deleted."""
+        leaves = jax.tree.leaves(params)
+        # weak references (``_run``): they keep nothing on the device alive
+        last = getattr(self, "_returned", ())
+        if len(last) == len(leaves) and all(
+                ref() is leaf for ref, leaf in zip(last, leaves)):
+            return params
+        # beside model_step_dispatch_us' count: the share of steps that ran
+        # in place (doc/observability.md "Device lane")
+        telemetry.counter("model_step_state_copies_total",
+                          {"model": type(self).__name__}).inc()
+        return jax.tree.map(jnp.copy, params)
+
+    def _run(self, fn, params, tree):
+        """The compiled step on a state it may consume; what it returns is
+        remembered as the learner's own."""
+        out = fn(self._own_state(params), tree)
+        self._returned = tuple(weakref.ref(leaf)
+                               for leaf in jax.tree.leaves(out[0]))
+        return out
+
     def step(self, params, batch):
         """One jitted training step on a device batch; returns
-        (params, loss)."""
+        (params, loss). The state is updated where it lies: one this
+        learner's last ``step`` returned is consumed (its arrays are
+        deleted), any other is copied first and survives.
+
+        So ``params, loss = learner.step(params, batch)`` holds one table
+        where a step that kept its input held two, and reading a consumed
+        state, or handing it in again, raises jax's error for a deleted
+        array: copy a returned state you mean to keep (``jnp.copy`` a
+        leaf). A state the learner did not itself return (``init()``, a
+        restored checkpoint, arrays of your own) is copied once on the
+        device and the copy is consumed (``model_step_state_copies_total``
+        counts those)."""
         if getattr(self, "_step_fn", None) is None:
             self._step_fn, self._step_bytes = {}, {}
         tree = batch.tree()
@@ -329,11 +376,12 @@ class DataParallelModel:
                               {"model": type(self).__name__}).inc()
             self._step_bytes[sig] = self._exchange_bytes(params, tree, n_dev)
         if not telemetry.enabled():
-            return fn(params, tree)
+            return self._run(fn, params, tree)
         # model.step: the host's hand-over of one step to the runtime (the
-        # call that built the function also traces and compiles inside it)
+        # call that built the function also traces and compiles inside it,
+        # and a state that is not the learner's own is copied inside it)
         with telemetry.span("model.step", built=int(built)) as sp:
-            out = fn(params, tree)
+            out = self._run(fn, params, tree)
             telemetry.histogram("model_step_dispatch_us").observe(
                 sp.elapsed_us)
         # counted beside the histogram: the two counts give the share of

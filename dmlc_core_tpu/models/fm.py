@@ -228,6 +228,15 @@ class FMLearner(DataParallelModel):
         state = learner.init()
         for batch in device_iter:          # libfm/libsvm/crec/... lanes
             state, loss = learner.step(state, batch)
+
+    The state you pass back is consumed: ``step`` updates the tables where
+    they lie, and the arrays of a state that ``step`` itself returned are
+    deleted once it is handed in again (a loop as above holds one table,
+    not two). Copy a state you mean to keep beside the training
+    (``jax.tree.map(jnp.copy, state)``). A state you made (``init()``, a
+    restored checkpoint, your own arrays) is never invalidated: the step
+    copies it once on the device, in every layout
+    (``DataParallelModel.step``, models/_dp.py).
     """
 
     def __init__(self, num_features: int, k: int = 8,

@@ -112,6 +112,9 @@ class LinearLearner(DataParallelModel):
         state = learner.init()
         for batch in device_iter:
             state, loss = learner.step(state, batch)
+
+    ``step`` consumes a state it returned itself and copies one you made
+    (``DataParallelModel.step``, models/_dp.py).
     """
 
     def __init__(self, num_features: int, mesh: Optional[Mesh] = None,

@@ -1535,6 +1535,11 @@ METRIC_HELP: Dict[str, str] = {
     "model_step_row_updates_total":
         "steps that took the row form (a CSR batch, on one device or on a "
         "mesh): the gradient stays in the batch's rows, by model class",
+    "model_step_state_copies_total":
+        "states copied on the device before a step because they were not "
+        "what the learner's last step returned (init's, a restored "
+        "checkpoint's, a caller's own); every other step updates its tables "
+        "where they lie, by model class",
     "model_step_allreduce_bytes_total":
         "bytes handed to the collectives of the mesh step (loss sum, weight "
         "sum, and every shard's distinct columns with the rows of its "

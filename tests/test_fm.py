@@ -828,3 +828,145 @@ def test_fm_dense_steps_keep_the_table_form(tmp_path, mesh_devices):
     before = rows.value
     learner.step(params, batch)
     assert rows.value == before
+
+
+# -- the step donates its state (models/_dp.py _own_state, ISSUE 40) ----------
+def _state_copies(model="FMLearner"):
+    from dmlc_core_tpu import telemetry
+    return telemetry.counter("model_step_state_copies_total",
+                             {"model": model})
+
+
+def _deleted(params):
+    return [leaf.is_deleted() for leaf in jax.tree.leaves(params)]
+
+
+def test_a_state_the_caller_made_survives_a_step(tmp_path):
+    """``init()``'s state is the caller's: copied in once, never deleted,
+    and as readable after the step as before it."""
+    batches = _batches(write_recurring_libsvm(tmp_path / "r.libsvm"))
+    learner = FMLearner(F_ROWS, k=K_ROWS)
+    p0 = learner.init(3)
+    before = jax.tree.map(np.asarray, p0)
+    copies = _state_copies()
+    at = copies.value
+    p1, _ = learner.step(p0, batches[0])
+    assert copies.value == at + 1
+    assert _deleted(p0) == [False] * 3
+    for a, b in zip(before, p0):
+        assert np.array_equal(a, np.asarray(b))
+    assert not np.array_equal(np.asarray(p1.v), before.v)
+    # and again: the same foreign state steps to the same place, bit for bit
+    again, _ = learner.step(p0, batches[0])
+    assert copies.value == at + 2
+    for a, b in zip(p1, again):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_the_state_a_step_returned_is_consumed_when_handed_back(tmp_path):
+    batches = _batches(write_recurring_libsvm(tmp_path / "r.libsvm"))
+    learner = FMLearner(F_ROWS, k=K_ROWS)
+    copies = _state_copies()
+    p1, _ = learner.step(learner.init(3), batches[0])
+    at = copies.value
+    assert _deleted(p1) == [False] * 3
+    p2, _ = learner.step(p1, batches[1])
+    assert copies.value == at            # the learner's own: no copy
+    assert _deleted(p1) == [True] * 3
+    assert _deleted(p2) == [False] * 3
+    # the same leaves in a tree built anew are still the learner's own
+    p3, _ = learner.step(type(p2)(*p2), batches[0])
+    assert copies.value == at and _deleted(p2) == [True] * 3
+    # a caller that kept the old state is told by the runtime
+    with pytest.raises(RuntimeError, match="deleted"):
+        np.asarray(p1.v)
+    with pytest.raises((RuntimeError, ValueError), match="deleted"):
+        learner.step(p1, batches[0])
+    assert _deleted(p3) == [False] * 3   # the refused call consumed nothing
+
+
+@pytest.mark.parametrize("leaf", ["b", "w", "v"])
+def test_a_tree_with_one_leaf_replaced_is_foreign(tmp_path, leaf):
+    batches = _batches(write_recurring_libsvm(tmp_path / "r.libsvm"))
+    learner = FMLearner(F_ROWS, k=K_ROWS)
+    copies = _state_copies()
+    p1, _ = learner.step(learner.init(3), batches[0])
+    mixed = p1._replace(**{leaf: jax.numpy.copy(getattr(p1, leaf))})
+    at = copies.value
+    p2, _ = learner.step(mixed, batches[1])
+    assert copies.value == at + 1
+    assert _deleted(p1) == [False] * 3 and _deleted(mixed) == [False] * 3
+    # an equal state is not the same state: identity decides, not equality
+    own, _ = learner.step(p2, batches[0])
+    twin = jax.tree.map(jax.numpy.copy, own)
+    learner.step(twin, batches[0])
+    assert copies.value == at + 2 and _deleted(own) == [False] * 3
+
+
+def test_another_learners_state_is_foreign(tmp_path):
+    batches = _batches(write_recurring_libsvm(tmp_path / "r.libsvm"))
+    one, other = FMLearner(F_ROWS, k=K_ROWS), FMLearner(F_ROWS, k=K_ROWS)
+    p1, _ = one.step(one.init(3), batches[0])
+    at = _state_copies().value
+    other.step(p1, batches[1])
+    assert _state_copies().value == at + 1
+    assert _deleted(p1) == [False] * 3
+    one.step(p1, batches[1])             # still its own learner's to consume
+    assert _state_copies().value == at + 1
+    assert _deleted(p1) == [True] * 3
+
+
+@pytest.mark.parametrize("model", ["fm-dense", "linear-csr"])
+def test_table_steps_donated_equal_steps_each_from_a_fresh_copy(tmp_path,
+                                                                model):
+    """Six steps through the states the learner returned (updated where
+    they lie) and six steps each from a copy the caller made (copied in):
+    the same parameters and losses, bit for bit; one copy against six. The
+    table form here; the row form in every layout: tests/test_fm_ps.py."""
+    uri = write_recurring_libsvm(tmp_path / "r.libsvm", rows=768)
+    with DeviceRowBlockIter(uri, batch_rows=256, min_nnz_bucket=2048,
+                            layout="dense" if model == "fm-dense" else "csr",
+                            dense_dtype="float32") as it:
+        batches = list(it) * 2
+
+    def run(fresh):
+        learner = (LinearLearner(F_ROWS) if model == "linear-csr"
+                   else FMLearner(F_ROWS, k=K_ROWS))
+        copies = _state_copies(type(learner).__name__)
+        at = copies.value
+        params, losses = learner.init(), []
+        for batch in batches:
+            if fresh:
+                params = jax.tree.map(jax.numpy.copy, params)
+            params, loss = learner.step(params, batch)
+            losses.append(float(loss))
+        return jax.tree.map(np.asarray, params), losses, copies.value - at
+
+    own, own_losses, own_copies = run(fresh=False)
+    each, each_losses, each_copies = run(fresh=True)
+    assert (own_copies, each_copies) == (1, len(batches))
+    assert own_losses == each_losses and own_losses[0] != own_losses[-1]
+    for a, b in zip(jax.tree.leaves(own), jax.tree.leaves(each)):
+        assert a.tobytes() == b.tobytes()
+
+
+@pytest.mark.parametrize("model", ["fm-csr", "fm-dense", "linear-csr"])
+@pytest.mark.parametrize("mesh_devices", [0, 2], ids=["nomesh", "mesh2"])
+def test_the_lowered_step_marks_its_parameters_as_donated(tmp_path, model,
+                                                          mesh_devices):
+    """Every form of the step (row and table, with and without a mesh)
+    donates its state and nothing of the batch."""
+    mesh = data_mesh(mesh_devices) if mesh_devices else None
+    learner = (LinearLearner(F_ROWS, mesh=mesh) if model == "linear-csr"
+               else FMLearner(F_ROWS, k=K_ROWS, mesh=mesh))
+    uri = write_recurring_libsvm(tmp_path / "r.libsvm")
+    with DeviceRowBlockIter(uri, batch_rows=256, min_nnz_bucket=2048,
+                            layout="dense" if model == "fm-dense" else "csr",
+                            dense_dtype="float32", mesh=mesh) as it:
+        batch = next(iter(it))
+    tree = batch.tree()
+    lowered = learner._build_step(
+        batch.rows_per_shard, tuple(sorted(tree))).lower(learner.init(), tree)
+    (params, batch_info), _ = lowered.args_info
+    assert all(a.donated for a in jax.tree.leaves(params))
+    assert not any(a.donated for a in jax.tree.leaves(batch_info))

@@ -154,10 +154,17 @@ def test_mesh_step_agrees_with_the_plain_reference(tmp_path, case):
     uri = str(tmp_path / "rows.libfm")
     reference = reference_readings(*write_rows(uri, rows, nnz_of,
                                                FEW.get(case, 0)))
-    states = list(program_steps(uri, shards))
-    p0, p1, p3 = states[0][0], states[1][0], states[-1][0]
-    program = check.Readings([loss for _, loss in states[1:]],
-                             [n / LR for n in norms(p0, p1)], norms(p3, p0))
+    # read as the states are yielded: the state after step 1 is consumed
+    # by step 2 (the step donates what it returned)
+    losses, grad_norms = [], None
+    for params, loss in program_steps(uri, shards):
+        if loss is None:
+            p0 = params
+            continue
+        if not losses:
+            grad_norms = [n / LR for n in norms(p0, params)]
+        losses.append(loss)
+    program = check.Readings(losses, grad_norms, norms(params, p0))
     gaps = check.gaps(program, reference)
     lim = limits()
     for name in ("loss_gap", "grad_norm_gap", "change_norm_gap"):

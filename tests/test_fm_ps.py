@@ -35,6 +35,7 @@ from dmlc_core_tpu.tpu.device_iter import (DeviceRowBlockIter, col_slots,
 from dmlc_core_tpu.tpu.sharding import data_mesh
 from dmlc_core_tpu.utils import restore_checkpoint, save_checkpoint
 
+from tests.test_fm import _state_copies as state_copies
 from tests.test_fm_dp import (BATCH, BENCH, CASES, F, FEW, LR, SCALE, SEED,
                               STEPS, limits, lowered_ops, norms,
                               reference_readings, write_rows)
@@ -403,6 +404,129 @@ def test_a_range_sharded_state_is_saved_and_restored_onto_its_owners(
         assert np.array_equal(np.asarray(a), np.asarray(b))
         assert b.sharding.is_equivalent_to(template.sharding, b.ndim)
     assert {s.data.shape for s in back.v.addressable_shards} == {(RANGE, 4)}
+
+
+# -- the step donates its state (models/_dp.py _own_state, ISSUE 40) ----------
+
+def stepper(uri, layout, shards=OWNERS, k=4):
+    """A learner of ``layout`` (``None``: one device, no mesh), its first
+    state and two batches of ``uri``."""
+    mesh = data_mesh(shards) if shards else None
+    learner = FMLearner(F, k=k, mesh=mesh, learning_rate=LR,
+                        init_scale=SCALE,
+                        **({"table_layout": layout} if shards else {}))
+    with DeviceRowBlockIter(uri, mesh=mesh, batch_rows=BATCH, fmt="libsvm",
+                            min_nnz_bucket=64,
+                            col_owners=learner.col_owners) as it:
+        batches = list(it)[:2]
+    return learner, learner.init(SEED), batches
+
+
+LAYOUTS = {"one-device": (None, 0), "replicated": ("replicated", OWNERS),
+           "range-sharded": ("range_sharded", OWNERS)}
+
+
+@pytest.mark.parametrize("layout", sorted(LAYOUTS))
+def test_steps_donated_equal_steps_each_from_a_fresh_copy(tmp_path, layout):
+    """Six steps through the states the learner returned and six steps each
+    from a copy the caller made: the same tables and losses, bit for bit,
+    on every device; one state copied in against six."""
+    uri = str(tmp_path / "rows.libsvm")
+    write_cols(uri, BATCHES["shared-column"][1], 2 * BATCH)
+
+    def run(fresh):
+        learner, params, batches = stepper(uri, *LAYOUTS[layout])
+        at, losses = state_copies().value, []
+        for batch in batches * 3:
+            if fresh:
+                params = jax.tree.map(jax.numpy.copy, params)
+            params, loss = learner.step(params, batch)
+            losses.append(float(loss))
+        shards = [[np.asarray(s.data).tobytes()
+                   for s in leaf.addressable_shards] for leaf in params]
+        return shards, losses, state_copies().value - at
+
+    own, own_losses, own_copies = run(fresh=False)
+    each, each_losses, each_copies = run(fresh=True)
+    assert (own_copies, each_copies) == (1, 6)
+    assert own_losses == each_losses and own_losses[0] != own_losses[-1]
+    assert own == each
+
+
+@pytest.mark.parametrize("layout", sorted(LAYOUTS))
+def test_a_foreign_state_is_copied_where_it_lies_and_survives(tmp_path,
+                                                              layout):
+    """``init()``'s state on each layout: alive after the step with every
+    shard where it was, and the state the step returns laid out as it (a
+    range-sharded table stays a range on its owner); handed back, that one
+    is consumed and nothing is copied."""
+    uri = str(tmp_path / "rows.libsvm")
+    write_cols(uri, anywhere, 2 * BATCH)
+    learner, p0, batches = stepper(uri, *LAYOUTS[layout])
+    before = jax.tree.map(np.asarray, p0)
+    at = state_copies().value
+    # the copy itself: new buffers, each shard on the device of its twin
+    for kept, twin in zip(p0, learner._own_state(p0)):
+        assert twin.sharding == kept.sharding
+        for s, t in zip(kept.addressable_shards, twin.addressable_shards):
+            assert (s.device, s.index) == (t.device, t.index)
+            assert (s.data.unsafe_buffer_pointer()
+                    != t.data.unsafe_buffer_pointer())
+    assert state_copies().value == at + 1
+    at += 1
+    p1, _ = learner.step(p0, batches[0])
+    assert state_copies().value == at + 1
+    for kept, was, new in zip(p0, before, p1):
+        assert not kept.is_deleted()
+        assert np.array_equal(np.asarray(kept), was)
+        assert new.sharding.is_equivalent_to(kept.sharding, new.ndim)
+        assert ([s.data.shape for s in new.addressable_shards]
+                == [s.data.shape for s in kept.addressable_shards])
+    if layout == "range-sharded":
+        assert {s.data.shape for s in p1.v.addressable_shards} == {(RANGE, 4)}
+    p2, _ = learner.step(p1, batches[1])
+    assert state_copies().value == at + 1
+    assert all(leaf.is_deleted() for leaf in p1)
+    assert not any(leaf.is_deleted() for leaf in tuple(p0) + tuple(p2))
+
+
+@pytest.mark.parametrize("layout", ["range-sharded", "replicated"])
+def test_the_lowered_mesh_step_marks_its_parameters_as_donated(tmp_path,
+                                                               layout):
+    uri = str(tmp_path / "rows.libsvm")
+    write_cols(uri, anywhere, BATCH)
+    learner, p0, batches = stepper(uri, *LAYOUTS[layout])
+    tree = batches[0].tree()
+    lowered = learner._build_step(
+        batches[0].rows_per_shard, tuple(sorted(tree))).lower(p0, tree)
+    (params, batch_info), _ = lowered.args_info
+    assert [a.donated for a in jax.tree.leaves(params)] == [True] * 3
+    assert not any(a.donated for a in jax.tree.leaves(batch_info))
+    # and the compiler takes the offer: every leaf of the state is written
+    # into the buffer it came in
+    assert lowered.compile().memory_analysis().alias_size_in_bytes >= sum(
+        s.data.nbytes for leaf in p0 for s in leaf.addressable_shards[:1])
+
+
+def test_a_restored_checkpoint_is_foreign_once(tmp_path):
+    """The state ``restore_checkpoint`` hands back is the caller's: copied
+    in by the step it resumes with, and by no step after it."""
+    uri = str(tmp_path / "rows.libsvm")
+    write_cols(uri, anywhere, 2 * BATCH)
+    learner, p0, batches = stepper(uri, "range_sharded")
+    p1, _ = learner.step(p0, batches[0])
+    ckpt = str(tmp_path / "ps.ckpt")
+    save_checkpoint(ckpt, p1, step=1)
+    want, _ = learner.step(p1, batches[1])
+    back, _, _ = restore_checkpoint(ckpt, like=p0)
+    at = state_copies().value
+    got, _ = learner.step(back, batches[1])
+    assert state_copies().value == at + 1
+    assert not any(leaf.is_deleted() for leaf in back)
+    for a, b in zip(want, got):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+    learner.step(got, batches[0])
+    assert state_copies().value == at + 1
 
 
 # -- the cell's file -----------------------------------------------------------
