@@ -2,7 +2,8 @@
 machine (the libfm lane's canonical model), and the DPxSP transformer
 (ring attention)."""
 
-from dmlc_core_tpu.models.fm import FMLearner, FMParams  # noqa: F401
+from dmlc_core_tpu.models.fm import (FMLearner, FMParams, FMSlots,  # noqa: F401
+                                     FMState)
 from dmlc_core_tpu.models.linear import LinearLearner  # noqa: F401
 from dmlc_core_tpu.models.tp_transformer import (  # noqa: F401
     TPTransformerConfig, TPTransformerLM)

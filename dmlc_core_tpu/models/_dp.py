@@ -389,6 +389,12 @@ class DataParallelModel:
         if self._takes_row_form(tree):
             telemetry.counter("model_step_row_updates_total",
                               {"model": type(self).__name__}).inc()
+        # beside it, the steps of a rule with a state of its own
+        rule = getattr(self, "updater", "sgd")
+        if rule != "sgd":
+            telemetry.counter("model_step_rule_updates_total",
+                              {"model": type(self).__name__,
+                               "rule": rule}).inc()
         # with the device time under scope dp.allreduce, the exchange's rate
         if self._step_bytes[sig]:
             telemetry.counter("model_step_allreduce_bytes_total",

@@ -35,6 +35,14 @@ cotangent; autodiff's slices, pads and one-lane columns touched such an
 array 24 times a step where this touches one 13, PERF.md section 6, PR 37).
 Padding nonzeros (val 0, sacrificial row id) vanish.
 Dense batches compute them as ``(x @ V)² − x² @ V²`` — pure MXU work.
+
+Two update rules. ``updater="sgd"`` (the default): ``p − lr·g``, a
+scatter-add of the gradient's rows. ``updater="ftrl"``: what the click
+logs' trainers run (wormhole's ``linear`` and ``difacto``), FTRL-proximal
+on ``b`` and ``w`` (McMahan et al., KDD 2013, Algorithm 1) and AdaGrad on
+``v`` (DiFacto, Li et al., WSDM 2016), with a state for every row
+(``FMState``: the parameters and ``FMSlots``) that is read and written only
+at the batch's distinct columns; on one device only.
 """
 
 from __future__ import annotations
@@ -45,18 +53,63 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from dmlc_core_tpu import telemetry
 from dmlc_core_tpu.base import DMLCError
 from dmlc_core_tpu.models._dp import DataParallelModel
 from dmlc_core_tpu.models.linear import objective_loss
 from dmlc_core_tpu.tpu.device_iter import unpack_tree
 
-__all__ = ["FMParams", "FMLearner"]
+__all__ = ["FMParams", "FMSlots", "FMState", "FMLearner"]
 
 
 class FMParams(NamedTuple):
     b: jnp.ndarray   # []
     w: jnp.ndarray   # [F]
     v: jnp.ndarray   # [F, K] interaction factors
+
+
+class FMSlots(NamedTuple):
+    """What ``updater="ftrl"`` keeps beside the parameters, row for row:
+    FTRL-proximal's ``z`` and ``n`` of ``b`` and of ``w``, AdaGrad's sum of
+    squared gradients ``g`` (the equations' ``G``) of ``v``. Zeros at
+    ``init``."""
+    zb: jnp.ndarray  # []
+    nb: jnp.ndarray  # []
+    z: jnp.ndarray   # [F]
+    n: jnp.ndarray   # [F]
+    g: jnp.ndarray   # [F, K]
+
+
+class FMState(NamedTuple):
+    """The state of an FM trained by a rule with slots: one pytree, donated
+    to the step as the parameters alone are under SGD."""
+    params: FMParams
+    slots: FMSlots
+
+
+def _params_of(state) -> FMParams:
+    return state.params if isinstance(state, FMState) else state
+
+
+def _ftrl(w, z, n, g, alpha, beta, l1, l2):
+    """FTRL-proximal per coordinate on the batch-mean gradient ``g`` (McMahan
+    et al. 2013, Algorithm 1): the new ``(w, z, n)``. Where ``g`` is 0
+    nothing moves (``σ`` is 0 and ``w`` is what it was: the closed form of
+    the same ``z``, ``n``)."""
+    sigma = (jnp.sqrt(n + g * g) - jnp.sqrt(n)) / alpha
+    z = z + g - sigma * w
+    n = n + g * g
+    w = jnp.where(jnp.abs(z) <= l1, 0.0,
+                  -(z - jnp.sign(z) * l1) / ((beta + jnp.sqrt(n)) / alpha
+                                             + l2))
+    return w, z, n
+
+
+def _adagrad_step(big_g, g, lr, beta):
+    """AdaGrad per element: the new sum of squares and the step to add to
+    ``v``."""
+    big_g = big_g + g * g
+    return big_g, -lr * g / (beta + jnp.sqrt(big_g))
 
 
 class FMRows(NamedTuple):
@@ -220,7 +273,10 @@ def _fm_shard_loss(params, shard, num_rows: int, objective: str
 
 
 class FMLearner(DataParallelModel):
-    """Distributed second-order factorization machine.
+    """Distributed second-order factorization machine, trained by plain SGD
+    or (``updater="ftrl"``) by FTRL-proximal on ``b`` and ``w`` with AdaGrad
+    on ``v``, whose equations follow below (doc/parameters.md "Model update
+    rules").
 
     Usage::
 
@@ -237,25 +293,72 @@ class FMLearner(DataParallelModel):
     restored checkpoint, your own arrays) is never invalidated: the step
     copies it once on the device, in every layout
     (``DataParallelModel.step``, models/_dp.py).
+
+    The update rule (``updater``). ``"sgd"``: ``p − learning_rate·(g +
+    l2·p)`` with ``g`` the batch-mean gradient; the state is ``FMParams``.
+    ``"ftrl"``: the state is ``FMState`` (``FMParams`` and ``FMSlots``,
+    35 floats a row at rank 16 where SGD keeps 17), and per coordinate::
+
+        w (and b, with l1 = l2 = 0):  σ = (sqrt(n + g²) − sqrt(n)) / alpha
+                                      z ← z + g − σ·w
+                                      n ← n + g²
+                                      w ← 0 if |z| ≤ l1, else
+                                          −(z − sign(z)·l1)
+                                          / ((beta + sqrt(n)) / alpha + l2)
+        v, per element:               G ← G + g_v²
+                                      v ← v − v_lr·g_v / (v_beta + sqrt(G))
+
+    ``learning_rate`` is then unused and ``l2`` is FTRL's λ2 on ``w``.
+    Only the rows at a CSR batch's distinct columns are read and written
+    (scope ``opt.update``: the slots' rows gathered and the new rows
+    computed; their write-back under ``dp.apply``); an untouched row does
+    not change under either rule. FTRL runs on one device: a mesh of
+    several hands the update one gradient row a shard for a column that
+    several shards name, and the rule needs their sum first.
     """
 
     def __init__(self, num_features: int, k: int = 8,
                  mesh: Optional[Mesh] = None, objective: str = "logistic",
                  learning_rate: float = 0.05, l2: float = 0.0,
                  init_scale: float = 0.01, axis_name: str = "data",
-                 table_layout: str = "replicated"):
+                 table_layout: str = "replicated", updater: str = "sgd",
+                 alpha: float = 0.1, beta: float = 1.0, l1: float = 0.0,
+                 v_lr: float = 0.1, v_beta: float = 1.0):
         """``table_layout``: how a mesh keeps ``w`` and ``v``.
         ``"replicated"``: whole on every device. ``"range_sharded"``: cut
         over the mesh's devices by contiguous ranges of
         ``num_features / devices`` rows, a row on its owner alone (``b``
         stays on every device); the batches then come from an iterator
         built with ``col_owners=learner.col_owners`` (models/_dp.py, the
-        third form)."""
+        third form).
+
+        ``updater``: ``"sgd"`` (``learning_rate``, ``l2`` as weight decay)
+        or ``"ftrl"``: FTRL-proximal on ``b`` and ``w`` (``alpha``,
+        ``beta``, ``l1``, ``l2``; ``b`` without ``l1``, ``l2``) and AdaGrad
+        on ``v`` (``v_lr``, ``v_beta``), on one device (the class's
+        docstring has the equations)."""
         if k <= 0:
             raise ValueError(f"factor rank k must be positive, got {k}")
         if table_layout not in ("replicated", "range_sharded"):
             raise ValueError(f"unknown table_layout {table_layout!r} "
                              f"(replicated, range_sharded)")
+        if updater not in ("sgd", "ftrl"):
+            raise ValueError(f"unknown updater {updater!r} (sgd, ftrl)")
+        if updater == "ftrl":
+            devices = 1 if mesh is None else int(mesh.devices.size)
+            if devices > 1 or table_layout != "replicated":
+                raise ValueError(
+                    f"updater='ftrl' runs on one device: on a mesh of "
+                    f"{devices} ({table_layout} tables) a column several "
+                    f"shards name reaches the update as one gradient row a "
+                    f"shard, and FTRL's z, n and AdaGrad's G need the "
+                    f"column's summed gradient, which the mesh step does "
+                    f"not make yet")
+            if min(alpha, beta, v_lr, v_beta) <= 0 or min(l1, l2) < 0:
+                raise ValueError(
+                    f"ftrl wants alpha, beta, v_lr, v_beta > 0 and l1, l2 "
+                    f">= 0, got alpha={alpha} beta={beta} l1={l1} l2={l2} "
+                    f"v_lr={v_lr} v_beta={v_beta}")
         self.num_features = num_features
         self.k = k
         self.mesh = mesh
@@ -265,6 +368,9 @@ class FMLearner(DataParallelModel):
         self.init_scale = init_scale
         self.axis_name = axis_name
         self.table_layout = table_layout
+        self.updater = updater
+        self.alpha, self.beta, self.l1 = alpha, beta, l1
+        self.v_lr, self.v_beta = v_lr, v_beta
         self.col_owners = (1, 0)
         if table_layout == "range_sharded":
             owners = 0 if mesh is None else int(mesh.devices.size)
@@ -276,7 +382,7 @@ class FMLearner(DataParallelModel):
             self.col_owners = (owners, num_features // owners)
         self._step_fn = None
 
-    def init(self, seed: int = 0) -> FMParams:
+    def init(self, seed: int = 0):
         """Fresh parameters: zero linear part, small random factors (an
         all-zero V has zero interaction gradient). On a mesh replicated, or
         with range-sharded tables every range made on its owner, no device
@@ -284,7 +390,9 @@ class FMLearner(DataParallelModel):
         (a row's bits depend on its place in the table, not on who makes
         it; the draw alone is jitted, cut by rows, and scaled outside the
         jit as the replicated one is, so that no compiler folds the two
-        factors into one rounding)."""
+        factors into one rounding). Under ``updater="ftrl"`` an ``FMState``:
+        the same parameters and zero slots, made where they will live.
+        Sets the gauge ``model_state_bytes{part=params|slots}``."""
         key = jax.random.PRNGKey(seed)
         shape = (self.num_features, self.k)
         if self.table_layout == "range_sharded":
@@ -293,16 +401,36 @@ class FMLearner(DataParallelModel):
             draw = jax.jit(
                 lambda k: jax.random.normal(k, shape, jnp.float32),
                 out_shardings=at.v)(key)
-            return FMParams(b=jnp.zeros((), jnp.float32, device=at.b),
-                            w=jnp.zeros(shape[:1], jnp.float32, device=at.w),
-                            v=self.init_scale * draw)
+            return self._counted(FMParams(
+                b=jnp.zeros((), jnp.float32, device=at.b),
+                w=jnp.zeros(shape[:1], jnp.float32, device=at.w),
+                v=self.init_scale * draw))
         v = self.init_scale * jax.random.normal(key, shape, jnp.float32)
         params = FMParams(b=jnp.zeros((), jnp.float32),
                           w=jnp.zeros(shape[:1], jnp.float32), v=v)
+        at = None
         if self.mesh is not None:
-            params = jax.device_put(params,
-                                    NamedSharding(self.mesh, P()))
-        return params
+            at = NamedSharding(self.mesh, P())
+            params = jax.device_put(params, at)
+        if self.updater == "ftrl":
+            def zeros(s):
+                return jnp.zeros(s, jnp.float32, device=at)
+            params = FMState(params, FMSlots(
+                zb=zeros(()), nb=zeros(()), z=zeros(shape[:1]),
+                n=zeros(shape[:1]), g=zeros(shape)))
+        return self._counted(params)
+
+    def _counted(self, state):
+        """``state``, its bytes set on ``model_state_bytes`` by part (one
+        copy: a replicated mesh holds one on every device)."""
+        parts = {"params": _params_of(state)}
+        if isinstance(state, FMState):
+            parts["slots"] = state.slots
+        for part, tree in parts.items():
+            telemetry.gauge("model_state_bytes",
+                            {"model": type(self).__name__, "part": part}).set(
+                sum(leaf.nbytes for leaf in jax.tree.leaves(tree)))
+        return state
 
     def _table_specs(self) -> FMParams:
         rows = P(self.axis_name)
@@ -310,9 +438,15 @@ class FMLearner(DataParallelModel):
 
     # -- DataParallelModel hooks (the step harness lives in models/_dp.py) --
     def _shard_loss(self, params, shard, rows_per_shard):
-        return _fm_shard_loss(params, shard, rows_per_shard, self.objective)
+        return _fm_shard_loss(_params_of(params), shard, rows_per_shard,
+                              self.objective)
 
     def _apply(self, params, grads, denom):
+        if self.updater == "ftrl":
+            # the table form: every row, an untouched one with g = 0
+            return self._ftrl_update(params, _params_of(grads), denom,
+                                     lambda table: table,
+                                     lambda table, rows: rows)
         lr, l2 = self.learning_rate, self.l2
         return FMParams(
             b=params.b - lr * grads.b / denom,
@@ -320,7 +454,25 @@ class FMLearner(DataParallelModel):
             v=params.v - lr * (grads.v / denom + l2 * params.v))
 
     def _gather_rows(self, params, shard):
-        return _fm_gather(params, shard["cols"])
+        return _fm_gather(_params_of(params), shard["cols"])
+
+    def _ftrl_update(self, state: FMState, g, denom, rows_of, put):
+        """The FTRL / AdaGrad step on the rows ``rows_of`` reads out of a
+        table (``g``'s leaves are those rows' gradients), written back by
+        ``put(table, new_rows)``."""
+        p, s = state
+        with jax.named_scope("opt.update"):
+            b, zb, nb = _ftrl(p.b, s.zb, s.nb, g.b / denom, self.alpha,
+                              self.beta, 0.0, 0.0)
+            w, z, n = _ftrl(rows_of(p.w), rows_of(s.z), rows_of(s.n),
+                            g.w / denom, self.alpha, self.beta, self.l1,
+                            self.l2)
+            big_g, dv = _adagrad_step(rows_of(s.g), g.v / denom, self.v_lr,
+                                      self.v_beta)
+            v = rows_of(p.v) + dv
+        return FMState(FMParams(b, put(p.w, w), put(p.v, v)),
+                       FMSlots(zb, nb, put(s.z, z), put(s.n, n),
+                               put(s.g, big_g)))
 
     def _apply_rows(self, params, cols, g, denom):
         """``_apply`` with the gradient as FMRows at ``cols``: a row's
@@ -337,7 +489,20 @@ class FMLearner(DataParallelModel):
         every shard's list with its gradient's rows, shard after shard.
         The lists go in as one: it ascends within a shard's stretch only,
         so nothing is promised of its order, and a column that several
-        shards name is added to once a shard, with a rounding each."""
+        shards name is added to once a shard, with a rounding each.
+
+        Under ``updater="ftrl"`` (one device) the rows of every leaf at
+        ``cols`` are read, the rule gives their new values and these are
+        written back; the padding's reads fill zeros and its writes are
+        dropped, as the SGD scatter's are."""
+        if self.updater == "ftrl":
+            def rows_of(table):
+                return table.at[cols].get(mode="fill", fill_value=0,
+                                          indices_are_sorted=True)
+
+            def put(table, rows):
+                return table.at[cols].set(rows, indices_are_sorted=True)
+            return self._ftrl_update(params, g, denom, rows_of, put)
         lr, l2 = self.learning_rate, self.l2
 
         def update(table, grad):
@@ -350,8 +515,10 @@ class FMLearner(DataParallelModel):
         return FMParams(b=params.b - lr * g.b / denom,
                         w=update(params.w, g.w), v=update(params.v, g.v))
 
-    def predict(self, params: FMParams, batch) -> jnp.ndarray:
-        """Margins [D, R] (apply sigmoid for probabilities)."""
+    def predict(self, params, batch) -> jnp.ndarray:
+        """Margins [D, R] (apply sigmoid for probabilities); ``params``:
+        ``FMParams`` or an ``FMState``, whose parameters it reads."""
+        params = _params_of(params)
         R = batch.rows_per_shard
         # one jitted fwd per rows-per-shard, cached on the learner — a
         # fresh @jax.jit closure per call would retrace every predict

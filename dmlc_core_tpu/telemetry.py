@@ -1535,6 +1535,14 @@ METRIC_HELP: Dict[str, str] = {
     "model_step_row_updates_total":
         "steps that took the row form (a CSR batch, on one device or on a "
         "mesh): the gradient stays in the batch's rows, by model class",
+    "model_step_rule_updates_total":
+        "steps that ran an update rule other than plain SGD (FMLearner "
+        "updater='ftrl': FTRL-proximal on w, AdaGrad on v, with a state "
+        "for every row), by model class and rule",
+    "model_state_bytes":
+        "bytes of the state a learner's init made, one copy, by model class "
+        "and part: params (the model's tables) or slots (what its update "
+        "rule keeps beside them)",
     "model_step_state_copies_total":
         "states copied on the device before a step because they were not "
         "what the learner's last step returned (init's, a restored "

@@ -11,6 +11,8 @@ Walks the full TPU-native pipeline surface in ~60 lines of user code:
   python examples/train.py "day_0.tsv?hash_bits=25" --format criteo --model fm
   python examples/train.py "day_0.tsv?hash_bits=27" --format criteo --model fm \
       --table-layout range_sharded   # w, v cut by key range over the chips
+  python examples/train.py "day_0.tsv?hash_bits=25" --format criteo --model fm \
+      --updater ftrl --l1 4e-5       # FTRL-proximal on w, AdaGrad on v
 
 Under dmlc-submit the same script runs per-host with its own partition:
 
@@ -72,6 +74,24 @@ def main() -> int:
                          "rows (a batch's rows pulled from and pushed to "
                          "their owners; --num-features is rounded up to a "
                          "multiple of the devices)")
+    ap.add_argument("--updater", default="sgd", choices=("sgd", "ftrl"),
+                    help="fm update rule: plain SGD (--learning-rate), or "
+                         "FTRL-proximal on b and w (--alpha --beta --l1 "
+                         "--l2) with AdaGrad on v (--v-lr --v-beta), a "
+                         "state for every row; ftrl runs on one device")
+    ap.add_argument("--alpha", type=float, default=0.1,
+                    help="ftrl: per-coordinate rate of w")
+    ap.add_argument("--beta", type=float, default=1.0,
+                    help="ftrl: beta of w's rate")
+    ap.add_argument("--l1", type=float, default=0.0,
+                    help="ftrl: L1 on w (a weight whose |z| is at most l1 "
+                         "is exactly 0)")
+    ap.add_argument("--l2", type=float, default=0.0,
+                    help="ftrl: L2 on w; sgd: weight decay")
+    ap.add_argument("--v-lr", type=float, default=0.1,
+                    help="ftrl: AdaGrad's rate of the factors v")
+    ap.add_argument("--v-beta", type=float, default=1.0,
+                    help="ftrl: AdaGrad's beta of the factors v")
     ap.add_argument("--objective", default="logistic",
                     choices=("logistic", "squared", "pairwise"))
     ap.add_argument("--epochs", type=int, default=2)
@@ -107,8 +127,11 @@ def main() -> int:
             args.num_features = -(-args.num_features // n_dev) * n_dev
         learner = FMLearner(num_features=args.num_features, mesh=mesh,
                             k=args.fm_rank, objective=args.objective,
-                            learning_rate=args.learning_rate,
-                            table_layout=args.table_layout)
+                            learning_rate=args.learning_rate, l2=args.l2,
+                            table_layout=args.table_layout,
+                            updater=args.updater, alpha=args.alpha,
+                            beta=args.beta, l1=args.l1, v_lr=args.v_lr,
+                            v_beta=args.v_beta)
     else:
         learner = LinearLearner(num_features=args.num_features, mesh=mesh,
                                 objective=args.objective,

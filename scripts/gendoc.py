@@ -189,6 +189,25 @@ def gen_api() -> str:
     return "\n".join(parts)
 
 
+def rule_doc(cls) -> str:
+    """The part of ``cls``'s docstring from "The update rule" on, as
+    markdown: its literal block (after ``::``) fenced, prose joined."""
+    import inspect
+    doc = inspect.getdoc(cls)
+    doc = doc[doc.index("The update rule"):]
+    head, _, rest = doc.partition("::\n\n")
+    lines = rest.splitlines()
+    n = 0
+    while n < len(lines) and (lines[n].startswith("    ") or not lines[n]):
+        n += 1
+    block = [line[4:] for line in lines[:n]]
+    while block and not block[-1]:
+        block.pop()
+    return "\n".join([" ".join(head.split()) + ":", "", "```",
+                      *block, "```", "", " ".join(
+                          " ".join(lines[n:]).split())])
+
+
 def gen_parameters() -> str:
     import inspect
 
@@ -256,6 +275,14 @@ def gen_parameters() -> str:
         "`examples/train.py --model fm --table-layout "
         "{replicated,range_sharded}`. " + " ".join(
             inspect.getdoc(FMLearner.__init__).split()),
+        "",
+        "# Model update rules",
+        "",
+        "`FMLearner(..., updater=)`, from the entry point "
+        "`examples/train.py --model fm --updater {sgd,ftrl}` (`--alpha "
+        "--beta --l1 --l2 --v-lr --v-beta`; `--learning-rate` for sgd).",
+        "",
+        rule_doc(FMLearner),
         "",
         "# Environment knobs",
         "",

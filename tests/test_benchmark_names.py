@@ -45,6 +45,8 @@ NAME_KEYS = ("histograms", "counter", "less", "counters", "spans", "module",
 ROWS, BATCH, FIELDS, CARD = 600, 256, 4, 200
 # the hashed format's feature space: its ids are below 2**HASH_BITS
 HASH_BITS = 10
+# what a configuration with an update rule of its own hands FMLearner
+RULE_KEYS = ("updater", "alpha", "beta", "l1", "l2", "v_lr", "v_beta")
 
 
 # proposed metric files (listed for no cell) whose names no cell's program
@@ -143,10 +145,13 @@ def program(tmp_path_factory):
         telemetry.reset()
         device_iter._reset_shape_census()
         mesh = data_mesh(cell["chips"])
-        layout = _json(BENCH, "configs", cell["config"] + ".json").get(
-            "deployment", {}).get("table_layout", "replicated")
+        cfg = _json(BENCH, "configs", cell["config"] + ".json")
+        layout = cfg.get("deployment", {}).get("table_layout", "replicated")
+        # the configuration's update rule and its settings, where it has one
+        rule = ({k: cfg[k] for k in RULE_KEYS if k in cfg}
+                if "updater" in cfg else {})
         learner = FMLearner(num_features=max(FIELDS * CARD, 1 << HASH_BITS),
-                            k=4, mesh=mesh, table_layout=layout)
+                            k=4, mesh=mesh, table_layout=layout, **rule)
         params = learner.init(0)
         with DeviceRowBlockIter(uri, mesh=mesh, batch_rows=BATCH, fmt=fmt,
                                 col_owners=learner.col_owners, **part) as it:
@@ -210,3 +215,34 @@ def test_names_a_metric_file_reads_exist_in_the_program(cell, fname, program):
     assert not missing, (f"benchmarks/metrics/{fname}, "
                          f"{cell or 'listed for no cell, sought in all'}: "
                          + "; ".join(missing))
+
+
+def _ruled_cells():
+    return [c["name"] for c in SPEC["workloads"]
+            if _json(BENCH, "configs", c["config"] + ".json").get(
+                "updater", "sgd") != "sgd"]
+
+
+@pytest.mark.parametrize("cell", _ruled_cells())
+def test_a_cell_with_an_update_rule_shows_its_scope_counter_and_gauge(
+        cell, program):
+    """What ``PERF.md`` names for a rule with slots and no metric file
+    reads: the scope ``opt.update`` in the step, the counter
+    ``model_step_rule_updates_total`` risen once a step, the gauge
+    ``model_state_bytes`` by part; and none of them in an SGD cell's run."""
+    def counted(run, name):
+        return sum(c["value"] for c in run["snapshot"]["counters"]
+                   if c["name"] == name)
+
+    run = program[cell]
+    assert any("opt.update" in op for op in run["ops"])
+    steps = counted(run, "model_step_row_updates_total")
+    assert steps > 0 and counted(run, "model_step_rule_updates_total") == steps
+    parts = {g["labels"]["part"]: g["value"]
+             for g in run["snapshot"]["gauges"]
+             if g["name"] == "model_state_bytes"}
+    assert parts["slots"] > parts["params"] > 0
+    for other, sgd in program.items():
+        if other not in _ruled_cells():
+            assert not any("opt.update" in op for op in sgd["ops"]), other
+            assert counted(sgd, "model_step_rule_updates_total") == 0, other

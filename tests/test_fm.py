@@ -4,6 +4,8 @@ reduces loss on data with a planted multiplicative interaction (which a
 linear model cannot fit), and the DP step runs sharded on the 8-device
 mesh over packed batches."""
 
+import os
+
 import numpy as np
 import pytest
 
@@ -970,3 +972,239 @@ def test_the_lowered_step_marks_its_parameters_as_donated(tmp_path, model,
     (params, batch_info), _ = lowered.args_info
     assert all(a.donated for a in jax.tree.leaves(params))
     assert not any(a.donated for a in jax.tree.leaves(batch_info))
+
+
+# -- FTRL-proximal on w, AdaGrad on v (FMLearner(updater="ftrl")) -----------
+F_FTRL, K_FTRL, B_FTRL = 40, 3, 128
+RULE = dict(alpha=0.3, beta=1.0, l1=0.02, l2=0.1, v_lr=0.2, v_beta=0.5)
+
+
+def _ftrl_reference():
+    import sys
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    from reference import fm as ref_fm, ftrl as ref_ftrl
+    return ref_fm, ref_ftrl
+
+
+def write_ftrl_libsvm(path, rows=300, seed=13, twice=True):
+    """Rows of 2 to 6 features from a cubed draw over the first 36 of 40
+    (so they recur across a batch and across batches; never one alone: the
+    plain reference's loss has no derivative at a margin of exactly 0,
+    which a lone feature's row has at init, ROADMAP D16), every third
+    row naming one column twice (with ``twice``: a dense row holds a column
+    once), the last row feature 39 too (a dense
+    batch is as wide as its largest id), so that 36..38 are never named;
+    values a text round trip keeps. 300 rows in batches of 128: the last
+    batch has padding rows. Returns (label, lens, col, val)."""
+    rng = np.random.default_rng(seed)
+    label, lens, col, val = [], [], [], []
+    with open(path, "w") as f:
+        for r in range(rows):
+            ids = (36 * rng.random(rng.integers(2, 7)) ** 3).astype(int)
+            ids = sorted(set(ids.tolist()) | {35})[-6:]
+            if twice and r % 3 == 0:
+                ids.append(ids[0])                 # a column twice in a row
+            if r == rows - 1:
+                ids.append(F_FTRL - 1)
+            vals = rng.choice([0.5, 1.0, 1.5, 2.0], len(ids))
+            y = int(rng.integers(0, 2))
+            f.write(f"{y} " + " ".join(f"{c}:{v}" for c, v in zip(ids, vals))
+                    + "\n")
+            label.append(y)
+            lens.append(len(ids))
+            col += ids
+            val += vals.tolist()
+    return (np.array(label, np.float32), np.array(lens), np.array(col),
+            np.array(val, np.float32))
+
+
+def _ftrl_expected(rows, seed, epochs):
+    """The plain reference (benchmarks/reference/ftrl.py) batch by batch
+    over the file's rows, every batch of 128 rows as the iterator cuts
+    them, on the tables' touched rows: the losses and the compact state."""
+    import jax.numpy as jnp
+    ref_fm, ref_ftrl = _ftrl_reference()
+    label, lens, col, val = rows
+    uniq, inv = np.unique(col, return_inverse=True)
+    v0 = ref_fm.initial_factors(seed, F_FTRL, K_FTRL, 0.3, uniq)
+    s = ref_ftrl.initial_state(v0, jnp.float32)
+    rule = ref_ftrl.Rule(**RULE)
+    off = np.concatenate([[0], np.cumsum(lens)])
+    losses = []
+    for _ in range(epochs):
+        for r0 in range(0, label.size, B_FTRL):
+            r1 = min(r0 + B_FTRL, label.size)
+            c, x = ref_fm.pad_rows(lens[r0:r1], inv[off[r0]:off[r1]],
+                                   val[off[r0]:off[r1]], int(lens.max()))
+            batch = ref_fm.Batch(jnp.asarray(label[r0:r1]), jnp.asarray(c),
+                                 jnp.asarray(x))
+            value, grad = jax.value_and_grad(ref_fm.loss)(
+                ref_fm.Params(s.b, s.w, s.v), batch)
+            losses.append(float(value))
+            s = ref_ftrl.update(s, grad, rule)
+    return losses, uniq, s
+
+
+def _ftrl_program(uri, layout, epochs, seed):
+    learner = FMLearner(F_FTRL, k=K_FTRL, init_scale=0.3, updater="ftrl",
+                        **RULE)
+    state = learner.init(seed)
+    start = jax.tree.map(np.asarray, state)
+    losses = []
+    with DeviceRowBlockIter(uri, batch_rows=B_FTRL, layout=layout,
+                            min_nnz_bucket=1024,
+                            dense_dtype="float32") as it:
+        for _ in range(epochs):
+            for batch in it:
+                state, loss = learner.step(state, batch)
+                losses.append(float(loss))
+            it.before_first()
+    return learner, start, state, losses
+
+
+@pytest.mark.parametrize("layout", ["csr", "dense"])
+def test_fm_ftrl_steps_equal_the_plain_reference(tmp_path, layout):
+    """Six steps (two epochs of three batches, the last short) of the row
+    form (csr) and of the table form (dense) against the reference's
+    equations: the losses and every leaf of the state, ``b`` under its own
+    ``z`` and ``n``; both branches of the L1 closed form taken; rows no
+    batch names, and the list's padding beyond the tables, left bit for
+    bit as ``init`` made them."""
+    uri = str(tmp_path / "f.libsvm")
+    rows = write_ftrl_libsvm(uri, twice=layout == "csr")
+    want_losses, uniq, want = _ftrl_expected(rows, seed=5, epochs=2)
+    learner, start, got, losses = _ftrl_program(uri, layout, 2, seed=5)
+    np.testing.assert_allclose(losses, want_losses, rtol=2e-6)
+    assert type(got).__name__ == "FMState"
+    p, s = got
+    for name, a, b in (("b", p.b, want.b), ("zb", s.zb, want.zb),
+                       ("nb", s.nb, want.nb)):
+        np.testing.assert_allclose(float(a), float(b), rtol=2e-5,
+                                   err_msg=name)
+    assert float(s.nb) > 0 and float(p.b) != 0     # b has its own z, n
+    for name, table, ref in (("w", p.w, want.w), ("v", p.v, want.v),
+                             ("z", s.z, want.z), ("n", s.n, want.n),
+                             ("g", s.g, want.g)):
+        a, b = np.asarray(table)[uniq], np.asarray(ref)
+        assert np.abs(a - b).max() <= 2e-5 * np.abs(b).max(), name
+    w = np.asarray(p.w)[uniq]
+    z = np.asarray(s.z)[uniq]
+    # the L1 term holds some weights at exactly 0 and lets others through
+    assert (w == 0).any() and (w != 0).any()
+    assert (np.abs(z[w == 0]) <= RULE["l1"]).all()
+    assert (np.abs(z[w != 0]) > RULE["l1"]).all()
+    # features 36..38 are never named: every leaf's row as init made it
+    rest = np.setdiff1d(np.arange(F_FTRL), uniq)
+    assert rest.size == 3
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(start)):
+        if np.ndim(b):
+            assert np.asarray(a)[rest].tobytes() == b[rest].tobytes()
+            assert np.shape(a) == np.shape(b)
+
+
+def test_fm_ftrl_padding_beyond_the_tables_writes_nothing(tmp_path):
+    """The CSR step's list is padded past the tables' last row (col_slots):
+    its reads fill zeros and its writes are dropped, in every leaf; what
+    the batch names moved."""
+    uri = str(tmp_path / "f.libsvm")
+    write_ftrl_libsvm(uri, rows=B_FTRL)
+    learner = FMLearner(F_FTRL, k=K_FTRL, init_scale=0.3, updater="ftrl",
+                        **RULE)
+    with DeviceRowBlockIter(uri, batch_rows=B_FTRL, layout="csr",
+                            min_nnz_bucket=1024) as it:
+        batch = next(iter(it))
+    cols = np.asarray(batch.tree()["cols"])[0]
+    named = cols[cols < F_FTRL]
+    assert (cols >= F_FTRL).sum() > 0              # padding past the tables
+    state = learner.init(2)
+    before = jax.tree.map(np.asarray, state)
+    after, _ = learner.step(state, batch)
+    for a, b in zip(jax.tree.leaves(after), jax.tree.leaves(before)):
+        a = np.asarray(a)
+        assert a.shape == b.shape
+        if a.ndim:
+            untouched = np.setdiff1d(np.arange(F_FTRL), named)
+            assert a[untouched].tobytes() == b[untouched].tobytes()
+    assert (np.asarray(after.slots.n)[named] > 0).all()
+
+
+def test_fm_ftrl_state_is_donated_and_counted(tmp_path):
+    """One pytree of eight leaves, donated as SGD's three are: init's state
+    copied in once (model_step_state_copies_total 1 over a loop), every
+    later state consumed; each step counted under its rule, the state's
+    bytes by part."""
+    from dmlc_core_tpu import telemetry
+    uri = str(tmp_path / "f.libsvm")
+    write_ftrl_libsvm(uri)
+    copies = _state_copies()
+    rule = telemetry.counter("model_step_rule_updates_total",
+                             {"model": "FMLearner", "rule": "ftrl"})
+    at, rules_at = copies.value, rule.value
+    learner, start, state, losses = _ftrl_program(uri, "csr", 2, seed=1)
+    assert copies.value == at + 1
+    assert rule.value == rules_at + len(losses) == rules_at + 6
+    assert len(jax.tree.leaves(state)) == 8
+    assert telemetry.gauge("model_state_bytes", {
+        "model": "FMLearner", "part": "params"}).value == 4 * (
+            1 + F_FTRL * (K_FTRL + 1))
+    assert telemetry.gauge("model_state_bytes", {
+        "model": "FMLearner", "part": "slots"}).value == 4 * (
+            2 + F_FTRL * (K_FTRL + 2))
+    batches = _batches(write_recurring_libsvm(tmp_path / "r.libsvm"))
+    learner = FMLearner(F_ROWS, k=K_ROWS, updater="ftrl", l1=0.01)
+    p1, _ = learner.step(learner.init(3), batches[0])
+    p2, _ = learner.step(p1, batches[1])
+    assert _deleted(p1) == [True] * 8 and _deleted(p2) == [False] * 8
+    tree = batches[0].tree()
+    lowered = learner._build_step(batches[0].rows_per_shard,
+                                  tuple(sorted(tree))).lower(p2, tree)
+    (params, _), _ = lowered.args_info
+    assert [a.donated for a in jax.tree.leaves(params)] == [True] * 8
+    assert "opt.update" in lowered.as_text(debug_info=True)
+
+
+@pytest.mark.parametrize("layout", ["csr", "dense"])
+def test_fm_sgd_state_and_step_carry_no_slot(tmp_path, layout):
+    """The default rule keeps today's state and program: three leaves, and
+    no operation of the lowered step (row form for CSR, table form for
+    dense) under ``opt.update``; the rule counter does not rise."""
+    from dmlc_core_tpu import telemetry
+    learner = FMLearner(F_ROWS, k=K_ROWS)
+    params = learner.init()
+    assert type(params).__name__ == "FMParams"
+    assert len(jax.tree.leaves(params)) == 3
+    uri = write_recurring_libsvm(tmp_path / "r.libsvm")
+    with DeviceRowBlockIter(uri, batch_rows=256, layout=layout,
+                            min_nnz_bucket=2048,
+                            dense_dtype="float32") as it:
+        batch = next(iter(it))
+    tree = batch.tree()
+    assert learner._takes_row_form(tree) == (layout == "csr")
+    text = learner._build_step(batch.rows_per_shard, tuple(sorted(tree))
+                               ).lower(params, tree).as_text(debug_info=True)
+    assert "dp.apply" in text and "opt.update" not in text
+    rule = telemetry.counter("model_step_rule_updates_total",
+                             {"model": "FMLearner", "rule": "sgd"})
+    learner.step(params, batch)
+    assert rule.value == 0
+
+
+@pytest.mark.parametrize("devices,layout", [
+    (2, "replicated"), (8, "replicated"), (4, "range_sharded")])
+def test_fm_mesh_forms_refuse_ftrl(devices, layout):
+    with pytest.raises(ValueError, match="one device.*summed gradient"):
+        FMLearner(64, k=2, mesh=data_mesh(devices), table_layout=layout,
+                  updater="ftrl")
+    # one device, with or without a mesh, is where the rule runs
+    FMLearner(64, k=2, mesh=data_mesh(1), updater="ftrl")
+
+
+@pytest.mark.parametrize("kw", [dict(updater="adam"), dict(updater="ftrl",
+                                                            alpha=0.0),
+                                dict(updater="ftrl", l1=-1.0)])
+def test_fm_rejects_an_unknown_rule_or_its_bad_settings(kw):
+    with pytest.raises(ValueError, match="updater|ftrl wants"):
+        FMLearner(16, k=2, **kw)

@@ -209,3 +209,59 @@ def test_step_compiles_and_fits_beside_the_checks_table(
                 r"= [a-z0-9]+\[([0-9,]+)\]", line).group(1).split(",")]))
             for line in collectives if " all-gather" in line)
         assert sizes == [chips * distinct] * 2 + [chips * distinct * K], sizes
+
+
+# criteo1tb-fm-ftrl: the row form under FTRL-proximal on w and
+# AdaGrad on v, a state of 8 leaves (b, w, v; zb, nb, z, n, G): 35 floats a
+# row, 4.70 GB at 2^25 ids. Donated like SGD's three: every leaf written
+# where it lies, no whole-table copy, and what a step holds beyond its
+# state is the SGD step's temporaries and the slots' gathered rows
+@pytest.mark.slow
+def test_ftrl_step_compiles_in_place(topo, no_compile_cache):
+    from dmlc_core_tpu.models.fm import FMSlots, FMState
+    with open(os.path.join(CONFIGS, "criteo1tb-fm-ftrl.json")) as f:
+        cfg = json.load(f)
+    mesh = Mesh(np.array(topo.devices[:1]), ("data",))
+    learner = FMLearner(
+        num_features=cfg["num_features"], k=cfg["fm_rank"], mesh=mesh,
+        objective=cfg["objective"], init_scale=cfg["init_scale"],
+        updater=cfg["updater"], **{k: cfg[k] for k in (
+            "alpha", "beta", "l1", "l2", "v_lr", "v_beta")})
+    rep, row = NamedSharding(mesh, P()), NamedSharding(mesh, P("data"))
+    F, K, R = cfg["num_features"], cfg["fm_rank"], cfg["batch_rows"]
+
+    def shape(*s):
+        return jax.ShapeDtypeStruct(s, jnp.float32, sharding=rep)
+    state = FMState(FMParams(shape(), shape(F), shape(F, K)),
+                    FMSlots(shape(), shape(), shape(F), shape(F),
+                            shape(F, K)))
+    nnz, distinct = 589824, 212992
+    tree = {"aux": jax.ShapeDtypeStruct((1, 3, R), jnp.int32, sharding=row),
+            "big": jax.ShapeDtypeStruct((1, 3, nnz), jnp.int32, sharding=row),
+            "cols": jax.ShapeDtypeStruct((1, distinct), jnp.int32,
+                                         sharding=row)}
+    compiled = learner._build_step(R, tuple(sorted(tree))).lower(
+        state, tree).compile()
+    m = compiled.memory_analysis()
+    size = F * (2 * K + 3) * 4 + 12
+    print(f"criteo1tb-fm-ftrl: arguments {m.argument_size_in_bytes} outputs "
+          f"{m.output_size_in_bytes} temp {m.temp_size_in_bytes} alias "
+          f"{m.alias_size_in_bytes}")
+    # all eight leaves written where they lie
+    assert m.alias_size_in_bytes >= size
+    text = compiled.as_text()
+    copies = [line for line in text.splitlines()
+              if re.search(r"= f32\[%d(,%d)?\]\{[^}]*\} copy\(" % (F, K),
+                           line)]
+    assert not copies, copies
+    # one write-back a table leaf (w, v; z, n, G), under dp.apply
+    into_tables = [line for line in text.splitlines()
+                   if " scatter(" in line and f"= f32[{F}" in line]
+    assert len(into_tables) == 5, into_tables
+    assert all("dp.apply" in line for line in into_tables)
+    assert "opt.update" in text
+    # the step beside the check's spare state (init()'s, held through the
+    # first step): a quarter of the chip at least, and it fits
+    peak = (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    assert 0.25 * 16e9 < peak + size < 16e9
