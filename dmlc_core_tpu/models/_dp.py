@@ -122,6 +122,20 @@ class DataParallelModel:
     _gather_rows = None
     _apply_rows = None
 
+    def _count_step(self) -> None:
+        """Hook: the counters a model raises on every step beside the shared
+        ones (none here)."""
+
+    def saved(self, state):
+        """``state`` as a checkpoint holds it (itself here; a model that
+        lays a table out for the chip gives it back in its plain shape)."""
+        return state
+
+    def from_saved(self, state):
+        """The inverse of ``saved``: a restored state as this model holds
+        it."""
+        return state
+
     def _takes_row_form(self, keys) -> bool:
         """Whether a batch tree of these leaves steps in the row form: the
         model has the hooks and the batch is CSR (the distinct columns
@@ -389,12 +403,7 @@ class DataParallelModel:
         if self._takes_row_form(tree):
             telemetry.counter("model_step_row_updates_total",
                               {"model": type(self).__name__}).inc()
-        # beside it, the steps of a rule with a state of its own
-        rule = getattr(self, "updater", "sgd")
-        if rule != "sgd":
-            telemetry.counter("model_step_rule_updates_total",
-                              {"model": type(self).__name__,
-                               "rule": rule}).inc()
+        self._count_step()
         # with the device time under scope dp.allreduce, the exchange's rate
         if self._step_bytes[sig]:
             telemetry.counter("model_step_allreduce_bytes_total",

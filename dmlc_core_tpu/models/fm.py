@@ -36,6 +36,14 @@ array 24 times a step where this touches one 13, PERF.md section 6, PR 37).
 Padding nonzeros (val 0, sacrificial row id) vanish.
 Dense batches compute them as ``(x @ V)² − x² @ V²`` — pure MXU work.
 
+The factor tables are held lane-dense where the rank divides the chip's 128
+lanes (``_Lanes``): the bytes of ``[F, K]`` row-major as ``[ceil(F/p), 128]``,
+``p = 128 / K`` features a row, so a feature's K floats are one run of one
+row. The compiler lays a plain ``[F, K]`` out feature-minor (row-major would
+pad K lanes to 128), where a feature's row is K single elements over K/8
+tiles and the scatter of a batch's rows writes each of them alone (PERF.md
+section 6). ``FMLearner.factors`` gives the ``[F, K]`` view.
+
 Two update rules. ``updater="sgd"`` (the default): ``p − lr·g``, a
 scatter-add of the gradient's rows. ``updater="ftrl"``: what the click
 logs' trainers run (wormhole's ``linear`` and ``difacto``), FTRL-proximal
@@ -65,7 +73,7 @@ __all__ = ["FMParams", "FMSlots", "FMState", "FMLearner"]
 class FMParams(NamedTuple):
     b: jnp.ndarray   # []
     w: jnp.ndarray   # [F]
-    v: jnp.ndarray   # [F, K] interaction factors
+    v: jnp.ndarray   # [F, K] interaction factors, or lane-dense (_Lanes)
 
 
 class FMSlots(NamedTuple):
@@ -77,7 +85,7 @@ class FMSlots(NamedTuple):
     nb: jnp.ndarray  # []
     z: jnp.ndarray   # [F]
     n: jnp.ndarray   # [F]
-    g: jnp.ndarray   # [F, K]
+    g: jnp.ndarray   # [F, K], laid out as v is
 
 
 class FMState(NamedTuple):
@@ -89,6 +97,15 @@ class FMState(NamedTuple):
 
 def _params_of(state) -> FMParams:
     return state.params if isinstance(state, FMState) else state
+
+
+def _relaid(state, relay):
+    """``state`` with its factor tables (``v``; ``G`` of an ``FMState``)
+    passed through ``relay``."""
+    if isinstance(state, FMState):
+        return FMState(state.params._replace(v=relay(state.params.v)),
+                       state.slots._replace(g=relay(state.slots.g)))
+    return state._replace(v=relay(state.v))
 
 
 def _ftrl(w, z, n, g, alpha, beta, l1, l2):
@@ -106,10 +123,84 @@ def _ftrl(w, z, n, g, alpha, beta, l1, l2):
 
 
 def _adagrad_step(big_g, g, lr, beta):
-    """AdaGrad per element: the new sum of squares and the step to add to
-    ``v``."""
-    big_g = big_g + g * g
-    return big_g, -lr * g / (beta + jnp.sqrt(big_g))
+    """AdaGrad per element: the square to add to the sum ``big_g`` and the
+    step to add to ``v``."""
+    sq = g * g
+    return sq, -lr * g / (beta + jnp.sqrt(big_g + sq))
+
+
+LANES = 128   # a row of the chip's (8, 128) tiles
+# XLA's scatter into [rows, 128], told that its indices ascend, passes over
+# the whole table (on v5e 1.57 ns a row of it) and then costs 9.7 ns an
+# update; told nothing, it leaves the other rows alone and costs about 71 ns
+# an update (PERF.md section 5). Told where the table holds fewer rows than
+# this many an update.
+STREAM_ROWS_PER_UPDATE = 39
+
+
+class _Lanes(NamedTuple):
+    """A lane-dense factor table: ``[F, K]`` row-major held as ``[rows,
+    128]``, feature ``i`` in row ``i // p``, lanes ``(i % p)·K`` on, ``p =
+    128 / K``; ``rows = ceil(F / p)``, and the pad features of the last row
+    are never named by a column. A batch's rows are read as whole 128-lane
+    rows and their K lanes picked out; an update is placed at its lanes of
+    a ``[U, 128]`` row of ``-0.0`` (the identity of addition, bit for bit)
+    and scatter-added, so a neighbour that shares the row is left as it
+    was, and two columns of one row are two adds to it."""
+    k: int
+    num_features: int
+
+    @property
+    def pack(self) -> int:
+        return LANES // self.k
+
+    @property
+    def shape(self) -> Tuple[int, int]:
+        return (-(-self.num_features // self.pack), LANES)
+
+    def _at(self, cols):
+        """The row of each column; the list's padding (an id beyond any
+        table, col_slots) a row beyond this table's last, even where that
+        last row holds pad features."""
+        return jnp.where(cols < self.num_features, cols // self.pack,
+                         self.shape[0])
+
+    def rows(self, table, cols):
+        """``[U, K]``: the rows at ``cols`` (sorted), zeros at the padding.
+        The padding reads the last row and is zeroed on its K lanes alone,
+        not filled over whole 128-lane rows (PERF.md section 5: 1–14% less
+        a gather on the chip)."""
+        packed = table.at[jnp.minimum(cols // self.pack, self.shape[0] - 1)
+                          ].get(mode="promise_in_bounds",
+                                indices_are_sorted=True)
+        lane, k = (cols % self.pack)[:, None], self.k
+        out = packed[:, :k]
+        for j in range(1, self.pack):
+            out = jnp.where(lane == j, packed[:, j * k:(j + 1) * k], out)
+        return jnp.where((cols < self.num_features)[:, None], out, 0)
+
+    def add(self, table, cols, delta, sorted_cols: bool = True):
+        """``table`` with ``delta`` ``[U, K]`` added at ``cols``; the
+        padding's rows are dropped. The scatter is told that ``cols``
+        ascend only where that is cheaper (``STREAM_ROWS_PER_UPDATE``)."""
+        group = jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1) // self.k
+        placed = jnp.where(group == (cols % self.pack)[:, None],
+                           jnp.tile(delta, (1, self.pack)),
+                           jnp.float32(-0.0))
+        streams = sorted_cols and (
+            table.shape[0] < STREAM_ROWS_PER_UPDATE * cols.shape[0])
+        return table.at[self._at(cols)].add(placed,
+                                            indices_are_sorted=streams)
+
+    def matrix(self, table):
+        """The ``[F, K]`` view (a reshape and a slice: on the chip a pass
+        over the table)."""
+        return table.reshape(-1, self.k)[:self.num_features]
+
+    def packed(self, matrix):
+        """``[F, K]`` as this table holds it, the pad features zero."""
+        pad = self.shape[0] * self.pack - self.num_features
+        return jnp.pad(matrix, ((0, pad), (0, 0))).reshape(self.shape)
 
 
 class FMRows(NamedTuple):
@@ -124,19 +215,22 @@ class FMRows(NamedTuple):
 # read .../transpose(jvp(fm.gather))/..., which is how a trace tells the
 # scatter into the dense gradient from the forward gather; in the row form
 # the gathers are outside the differentiated function and have no backward
-def _fm_gather(params: FMParams, cols) -> FMRows:
+def _fm_gather(params: FMParams, cols, lanes: Optional[_Lanes] = None
+               ) -> FMRows:
     """The rows at ``cols``: ascending to the list's end, its padding
     beyond the tables (col_slots), where the gather reads zeros and its
     transpose, the table form's scatter, drops. ``[D, C]`` is stretch
     after stretch (what an owner of a range is asked for, models/_dp.py),
-    ascending within a stretch only, and gives ``[D, C, ...]``."""
+    ascending within a stretch only, and gives ``[D, C, ...]``. ``lanes``:
+    ``v`` is lane-dense (one list ``[U]``)."""
     def at(table):
         return table.at[cols].get(mode="fill", fill_value=0,
                                   indices_are_sorted=cols.ndim == 1)
     with jax.named_scope("fm.linear"):
         w_rows = at(params.w)
     with jax.named_scope("fm.gather"):
-        v_rows = at(params.v)
+        v_rows = at(params.v) if lanes is None else lanes.rows(params.v,
+                                                               cols)
     return FMRows(params.b, w_rows, v_rows)
 
 
@@ -249,9 +343,13 @@ def _fm_margin_dense(params: FMParams, x) -> jnp.ndarray:
         return params.b + linear + inter
 
 
-def _margin(params, shard, num_rows: int) -> jnp.ndarray:
-    """``params``: FMParams, or the FMRows a CSR shard gathered from them."""
+def _margin(params, shard, num_rows: int,
+            lanes: Optional[_Lanes] = None) -> jnp.ndarray:
+    """``params``: FMParams (``v`` lane-dense where ``lanes`` is given),
+    or the FMRows a CSR shard gathered from them."""
     if "x" in shard:
+        if lanes is not None:
+            params = params._replace(v=lanes.matrix(params.v))
         return _fm_margin_dense(params, shard["x"])
     if "cols" not in shard:
         raise DMLCError(
@@ -259,16 +357,17 @@ def _margin(params, shard, num_rows: int) -> jnp.ndarray:
             f"every assembler sends; this one holds {sorted(shard)} and no "
             "'cols' / 'slot' (tpu/device_iter.py col_slots makes them)")
     rows = params if isinstance(params, FMRows) else \
-        _fm_gather(params, shard["cols"])
+        _fm_gather(params, shard["cols"], lanes)
     return _fm_margin_rows(rows, shard["slot"], shard["row"], shard["val"],
                            num_rows)
 
 
-def _fm_shard_loss(params, shard, num_rows: int, objective: str
+def _fm_shard_loss(params, shard, num_rows: int, objective: str,
+                   lanes: Optional[_Lanes] = None
                    ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """(weighted loss sum, weight sum) — the shared objective zoo
     (models/linear.py objective_loss) over the FM margin."""
-    margin = _margin(params, shard, num_rows)
+    margin = _margin(params, shard, num_rows, lanes)
     return objective_loss(margin, shard, num_rows, objective)
 
 
@@ -315,6 +414,12 @@ class FMLearner(DataParallelModel):
     not change under either rule. FTRL runs on one device: a mesh of
     several hands the update one gradient row a shard for a column that
     several shards name, and the rule needs their sum first.
+
+    Where K divides 128 (and is less) on replicated tables, ``v`` (and
+    ``G``) is held lane-dense, ``[ceil(F / (128/K)), 128]`` (the module
+    docstring; ``lane_dense``): read it as ``[F, K]`` through
+    ``factors``, and save and restore a state through ``saved`` /
+    ``from_saved``, whose tables are ``[F, K]`` whatever the layout.
     """
 
     def __init__(self, num_features: int, k: int = 8,
@@ -380,6 +485,11 @@ class FMLearner(DataParallelModel):
                     f"mesh of several devices: {num_features} rows over "
                     f"{owners} (pad num_features to a multiple)")
             self.col_owners = (owners, num_features // owners)
+        # range-sharded tables keep [F, K]: an owner's range is rows of v
+        self._lanes = _Lanes(k, num_features) if (
+            k < LANES and LANES % k == 0
+            and table_layout == "replicated") else None
+        self.lane_dense = self._lanes is not None
         self._step_fn = None
 
     def init(self, seed: int = 0):
@@ -391,7 +501,10 @@ class FMLearner(DataParallelModel):
         it; the draw alone is jitted, cut by rows, and scaled outside the
         jit as the replicated one is, so that no compiler folds the two
         factors into one rounding). Under ``updater="ftrl"`` an ``FMState``:
-        the same parameters and zero slots, made where they will live.
+        the same parameters and zero slots, made where they will live. A
+        lane-dense ``v`` is drawn at its own shape: the draw's bits follow
+        the flat index, so ``factors`` of it is the ``[F, K]`` draw bit for
+        bit, and no ``[F, K]`` table is made beside it.
         Sets the gauge ``model_state_bytes{part=params|slots}``."""
         key = jax.random.PRNGKey(seed)
         shape = (self.num_features, self.k)
@@ -405,7 +518,8 @@ class FMLearner(DataParallelModel):
                 b=jnp.zeros((), jnp.float32, device=at.b),
                 w=jnp.zeros(shape[:1], jnp.float32, device=at.w),
                 v=self.init_scale * draw))
-        v = self.init_scale * jax.random.normal(key, shape, jnp.float32)
+        factors = shape if self._lanes is None else self._lanes.shape
+        v = self.init_scale * jax.random.normal(key, factors, jnp.float32)
         params = FMParams(b=jnp.zeros((), jnp.float32),
                           w=jnp.zeros(shape[:1], jnp.float32), v=v)
         at = None
@@ -417,8 +531,27 @@ class FMLearner(DataParallelModel):
                 return jnp.zeros(s, jnp.float32, device=at)
             params = FMState(params, FMSlots(
                 zb=zeros(()), nb=zeros(()), z=zeros(shape[:1]),
-                n=zeros(shape[:1]), g=zeros(shape)))
+                n=zeros(shape[:1]), g=zeros(factors)))
         return self._counted(params)
+
+    def factors(self, params) -> jnp.ndarray:
+        """``v`` as ``[F, K]``, whatever its layout; ``params``:
+        ``FMParams`` or an ``FMState``."""
+        v = _params_of(params).v
+        return v if self._lanes is None else self._lanes.matrix(v)
+
+    def saved(self, state):
+        """``state`` as a checkpoint holds it: every factor table ``[F,
+        K]`` (so a checkpoint reads the same under either layout, and the
+        scoring server loads it)."""
+        return state if self._lanes is None else \
+            _relaid(state, self._lanes.matrix)
+
+    def from_saved(self, state):
+        """The inverse of ``saved``: a state with ``[F, K]`` tables as this
+        learner holds it."""
+        return state if self._lanes is None else \
+            _relaid(state, self._lanes.packed)
 
     def _counted(self, state):
         """``state``, its bytes set on ``model_state_bytes`` by part (one
@@ -437,16 +570,30 @@ class FMLearner(DataParallelModel):
         return FMParams(b=P(), w=rows, v=rows)
 
     # -- DataParallelModel hooks (the step harness lives in models/_dp.py) --
+    def _count_step(self):
+        # beside model_step_row_updates_total: the steps whose factor tables
+        # are lane-dense, and the steps of a rule with a state of its own
+        model = {"model": type(self).__name__}
+        if self.lane_dense:
+            telemetry.counter("model_step_lane_dense_updates_total",
+                              model).inc()
+        if self.updater != "sgd":
+            telemetry.counter("model_step_rule_updates_total",
+                              {**model, "rule": self.updater}).inc()
+
     def _shard_loss(self, params, shard, rows_per_shard):
         return _fm_shard_loss(_params_of(params), shard, rows_per_shard,
-                              self.objective)
+                              self.objective, self._lanes)
 
     def _apply(self, params, grads, denom):
+        # a lane-dense v and its gradient (through the [F, K] view's
+        # transpose, zero at the pad features) are added element for element
         if self.updater == "ftrl":
             # the table form: every row, an untouched one with g = 0
             return self._ftrl_update(params, _params_of(grads), denom,
                                      lambda table: table,
-                                     lambda table, rows: rows)
+                                     lambda table, rows: rows,
+                                     lambda table, delta: table + delta)
         lr, l2 = self.learning_rate, self.l2
         return FMParams(
             b=params.b - lr * grads.b / denom,
@@ -454,12 +601,14 @@ class FMLearner(DataParallelModel):
             v=params.v - lr * (grads.v / denom + l2 * params.v))
 
     def _gather_rows(self, params, shard):
-        return _fm_gather(_params_of(params), shard["cols"])
+        return _fm_gather(_params_of(params), shard["cols"], self._lanes)
 
-    def _ftrl_update(self, state: FMState, g, denom, rows_of, put):
+    def _ftrl_update(self, state: FMState, g, denom, rows_of, put, add):
         """The FTRL / AdaGrad step on the rows ``rows_of`` reads out of a
-        table (``g``'s leaves are those rows' gradients), written back by
-        ``put(table, new_rows)``."""
+        table (``g``'s leaves are those rows' gradients): the new rows of
+        ``w``, ``z``, ``n`` written back by ``put(table, new_rows)``, the
+        step of ``v`` and the square added to ``G`` by ``add(table,
+        delta)`` (one float add, as ``rows + delta`` written back)."""
         p, s = state
         with jax.named_scope("opt.update"):
             b, zb, nb = _ftrl(p.b, s.zb, s.nb, g.b / denom, self.alpha,
@@ -467,12 +616,11 @@ class FMLearner(DataParallelModel):
             w, z, n = _ftrl(rows_of(p.w), rows_of(s.z), rows_of(s.n),
                             g.w / denom, self.alpha, self.beta, self.l1,
                             self.l2)
-            big_g, dv = _adagrad_step(rows_of(s.g), g.v / denom, self.v_lr,
-                                      self.v_beta)
-            v = rows_of(p.v) + dv
-        return FMState(FMParams(b, put(p.w, w), put(p.v, v)),
+            sq, dv = _adagrad_step(rows_of(s.g), g.v / denom, self.v_lr,
+                                   self.v_beta)
+        return FMState(FMParams(b, put(p.w, w), add(p.v, dv)),
                        FMSlots(zb, nb, put(s.z, z), put(s.n, n),
-                               put(s.g, big_g)))
+                               add(s.g, sq)))
 
     def _apply_rows(self, params, cols, g, denom):
         """``_apply`` with the gradient as FMRows at ``cols``: a row's
@@ -494,19 +642,37 @@ class FMLearner(DataParallelModel):
         Under ``updater="ftrl"`` (one device) the rows of every leaf at
         ``cols`` are read, the rule gives their new values and these are
         written back; the padding's reads fill zeros and its writes are
-        dropped, as the SGD scatter's are."""
+        dropped, as the SGD scatter's are.
+
+        A lane-dense ``v`` (and ``G``) is added to through ``_Lanes.add``:
+        its rows are 128 lanes that several columns of the list may share,
+        so its scatter is never told that its indices are unique."""
+        lanes = self._lanes
         if self.updater == "ftrl":
             def rows_of(table):
+                if lanes is not None and table.ndim == 2:
+                    return lanes.rows(table, cols)
                 return table.at[cols].get(mode="fill", fill_value=0,
                                           indices_are_sorted=True)
 
             def put(table, rows):
                 return table.at[cols].set(rows, indices_are_sorted=True)
-            return self._ftrl_update(params, g, denom, rows_of, put)
+
+            def add(table, delta):
+                if lanes is not None:
+                    return lanes.add(table, cols, delta)
+                return put(table, rows_of(table) + delta)
+            return self._ftrl_update(params, g, denom, rows_of, put, add)
         lr, l2 = self.learning_rate, self.l2
 
         def update(table, grad):
             table = table if l2 == 0 else (1.0 - lr * l2) * table
+            if table.ndim == 2 and lanes is not None:
+                # on a mesh every shard's list and rows as one, which
+                # ascends within a shard's stretch only
+                return lanes.add(table, cols.reshape(-1),
+                                 -lr * (grad.reshape(-1, lanes.k) / denom),
+                                 sorted_cols=cols.ndim == 1)
             if cols.ndim == 1:
                 return table.at[cols].add(-lr * (grad / denom),
                                           indices_are_sorted=True)
@@ -529,7 +695,7 @@ class FMLearner(DataParallelModel):
             @jax.jit
             @jax.named_scope("fm.predict")
             def fwd(params, tree):
-                return jax.vmap(lambda shard: _margin(params, shard, R))(
-                    unpack_tree(tree))
+                return jax.vmap(lambda shard: _margin(
+                    params, shard, R, self._lanes))(unpack_tree(tree))
             self._fwd_fn[R] = fwd
         return fwd(params, batch.tree())

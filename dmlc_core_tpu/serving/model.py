@@ -120,6 +120,12 @@ class ScoringModel:
             raise DMLCError(
                 f"serving model w has shape {self._params['w'].shape}, "
                 f"expected ({self.num_features},)")
+        if kind == "fm" and (self._params["v"].ndim != 2 or
+                             self._params["v"].shape[0] != self.num_features):
+            # a checkpoint holds v as learner.saved gives it, [F, K]
+            raise DMLCError(
+                f"serving model v has shape {self._params['v'].shape}, "
+                f"expected ({self.num_features}, K)")
         self._fwd = jax.jit(self._margin, static_argnames="num_rows")
 
     @classmethod

@@ -140,7 +140,11 @@ def main() -> int:
     start_epoch = 0
     data_state = None
     if args.resume:
-        params, step, extra = restore_checkpoint(args.resume, like=params)
+        # a checkpoint holds a state as learner.saved gives it: the FM's
+        # factor tables [F, K], whatever layout the learner keeps them in
+        params, step, extra = restore_checkpoint(args.resume,
+                                                 like=learner.saved(params))
+        params = learner.from_saved(params)
         start_epoch = step
         if "batches_consumed" in extra:
             # the epoch-boundary checkpoint below records 0 batches; a
@@ -194,8 +198,8 @@ def main() -> int:
                 it.before_first()
                 if args.checkpoint:
                     st = {str(k): str(v) for k, v in it.state().items()}
-                    save_checkpoint(args.checkpoint, params, step=epoch + 1,
-                                    extra=st)
+                    save_checkpoint(args.checkpoint, learner.saved(params),
+                                    step=epoch + 1, extra=st)
     finally:
         it.close()
     # one machine-readable line: what ran where, what the transfer path

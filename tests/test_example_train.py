@@ -114,6 +114,38 @@ def test_example_trains_on_crec_with_checkpoint(tmp_path):
     assert len(lines) == 1 and lines[0].startswith("epoch 2:"), out2
 
 
+def test_example_fm_checkpoint_holds_its_factors_as_a_matrix(tmp_path):
+    """An FM whose ``v`` the learner holds lane-dense (rank 8 divides 128)
+    checkpoints it as ``[F, K]`` (what the scoring server and a learner of
+    either layout read), resumes from it, and saves ``v`` as it stood."""
+    from dmlc_core_tpu.utils import restore_checkpoint
+    rng = np.random.default_rng(4)
+    data = tmp_path / "f.libsvm"
+    with open(data, "w") as f:
+        for i in range(600):
+            x = rng.uniform(-1, 1, 5)
+            f.write(f"{int(x[0] * x[1] > 0)} " + " ".join(
+                f"{j}:{x[j]:.4f}" for j in range(5)) + "\n")
+        f.write("1 8:0.5\n")           # a dense batch is as wide as its ids
+    ckpt = str(tmp_path / "fm.bin")
+    args = [str(data), "--model", "fm", "--fm-rank", "8", "--batch-rows",
+            "256", "--num-features", "9"]
+    _run(args + ["--epochs", "1", "--checkpoint", ckpt], cwd=str(tmp_path))
+    first, step, _ = restore_checkpoint(ckpt)
+    assert step == 1
+    assert {k: v.shape for k, v in first.items()} == {
+        ".b": (), ".w": (9,), ".v": (9, 8)}
+    out = _run(args + ["--epochs", "2", "--resume", ckpt, "--checkpoint",
+                       ckpt], cwd=str(tmp_path))
+    lines = [ln for ln in out.splitlines() if "mean loss" in ln]
+    assert len(lines) == 1 and lines[0].startswith("epoch 1:"), out
+    second, step, _ = restore_checkpoint(ckpt)
+    assert step == 2 and second[".v"].shape == (9, 8)
+    # features 5..7 are never named: their rows come back as saved
+    assert second[".v"][5:8].tobytes() == first[".v"][5:8].tobytes()
+    assert not np.array_equal(second[".v"][:5], first[".v"][:5])
+
+
 def _run_lm(args, cwd):
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=8")

@@ -42,11 +42,32 @@ def test_fm_margin_matches_oracle_csr_and_dense(tmp_path):
     params = learner.init(seed=1)
     b = float(params.b)
     w = np.asarray(params.w)
-    V = np.asarray(params.v)
+    V = np.asarray(learner.factors(params))
     # nonzero linear part so the oracle covers every term
     w = rng.normal(size=4).astype(np.float32)
     params = params._replace(w=jax.numpy.asarray(w))
     want = fm_margin_oracle(b, w, V, X)
+    for layout in ("csr", "dense"):
+        with DeviceRowBlockIter(str(tmp_path / "m.libsvm"), batch_rows=256,
+                                layout=layout, min_nnz_bucket=2048,
+                                dense_dtype="float32",
+                                to_device=False) as it:
+            batch = next(iter(it))
+        got = np.asarray(learner.predict(params, batch)).reshape(-1)
+        np.testing.assert_allclose(got[:256], want, rtol=2e-5, atol=2e-5)
+
+
+def test_fm_lane_dense_margin_matches_oracle_csr_and_dense(tmp_path):
+    """``predict`` of a learner whose ``v`` is lane-dense (rank 16: the
+    four features share one 128-lane row) against the oracle on ``factors``,
+    by the row gather (csr) and by the ``[F, K]`` view (dense)."""
+    X, _ = write_interaction_libsvm(tmp_path / "m.libsvm", rows=256)
+    learner = FMLearner(num_features=4, k=16, init_scale=0.3)
+    params = learner.init(seed=1)._replace(w=jax.numpy.asarray(
+        np.random.default_rng(0).normal(size=4).astype(np.float32)))
+    assert learner.lane_dense and params.v.shape == (1, 128)
+    want = fm_margin_oracle(0.0, np.asarray(params.w),
+                            np.asarray(learner.factors(params)), X)
     for layout in ("csr", "dense"):
         with DeviceRowBlockIter(str(tmp_path / "m.libsvm"), batch_rows=256,
                                 layout=layout, min_nnz_bucket=2048,
@@ -195,7 +216,8 @@ def test_fm_row_step_equals_the_table_step(tmp_path, l2, objective):
             assert np.abs(a - b).max() <= 1e-6 * np.abs(b).max(), leaf
         # every row of the tables moved when l2 decays them, only the
         # batch's rows when it does not
-        moved = np.any(np.asarray(got.v) != np.asarray(params.v), axis=1)
+        moved = np.any(np.asarray(learner.factors(got))
+                       != np.asarray(learner.factors(params)), axis=1)
         assert moved.all() if l2 else \
             set(np.flatnonzero(moved)) <= set(col[val != 0])
         params = got
@@ -223,8 +245,7 @@ def test_fm_row_step_merges_a_features_repeats(tmp_path):
     feature. Adding every entry into the parameter by itself, as the row
     form did before the batch carried its distinct columns, lands an
     order of magnitude further off."""
-    from dmlc_core_tpu.models.fm import (FMRows, _fm_margin_entries,
-                                         _fm_shard_loss)
+    from dmlc_core_tpu.models.fm import FMRows, _fm_margin_entries
     from dmlc_core_tpu.models.linear import objective_loss
     from dmlc_core_tpu.tpu.device_iter import unpack_shard
     F, K, R = 60010, 8, 16384
@@ -244,30 +265,33 @@ def test_fm_row_step_merges_a_features_repeats(tmp_path):
     assert batch.total_distinct < 0.6 * batch.total_nnz
 
     (_, wsum), grads = jax.value_and_grad(
-        lambda p: _fm_shard_loss(p, shard, R, "logistic"),
-        has_aux=True)(params)
+        lambda p: learner._shard_loss(p, shard, R), has_aux=True)(params)
     denom = jax.numpy.maximum(wsum, 1.0)
     want = learner._apply(params, grads, denom)
     got, _ = learner.step(params, batch)
 
     # the row form as it was: each entry's own row, scattered by itself
     col = shard["col"]
+    V = learner.factors(params)
     entry_grads = jax.grad(lambda rows: objective_loss(
         _fm_margin_entries(rows.b, rows.w, rows.v, shard["row"],
                            shard["val"], R), shard, R, "logistic")[0])(
-        FMRows(params.b, params.w[col], params.v[col]))
+        FMRows(params.b, params.w[col], V[col]))
     by_entry = {"w": params.w.at[col].add(-0.1 * entry_grads.w / denom),
-                "v": params.v.at[col].add(-0.1 * entry_grads.v / denom)}
+                "v": V.at[col].add(-0.1 * entry_grads.v / denom)}
+
+    def leaf_of(p, leaf):
+        return np.asarray(learner.factors(p) if leaf == "v"
+                          else getattr(p, leaf))
 
     def off(a, leaf):
         """Largest error of an update, in units of the update's largest
         step."""
-        before, b = np.asarray(getattr(params, leaf)), \
-            np.asarray(getattr(want, leaf))
+        before, b = leaf_of(params, leaf), leaf_of(want, leaf)
         return np.abs(np.asarray(a) - b).max() / np.abs(b - before).max()
 
     for leaf in ("w", "v"):
-        merged, entrywise = off(getattr(got, leaf), leaf), \
+        merged, entrywise = off(leaf_of(got, leaf), leaf), \
             off(by_entry[leaf], leaf)
         assert merged <= 2e-5, (leaf, merged)
         assert entrywise > 10 * merged, (leaf, merged, entrywise)
@@ -1208,3 +1232,177 @@ def test_fm_mesh_forms_refuse_ftrl(devices, layout):
 def test_fm_rejects_an_unknown_rule_or_its_bad_settings(kw):
     with pytest.raises(ValueError, match="updater|ftrl wants"):
         FMLearner(16, k=2, **kw)
+
+
+# -- lane-dense factor tables (models/fm.py _Lanes) ---------------------------
+F_LANES = 1003   # no multiple of 128/K: the last row holds pad features
+
+
+def write_lane_libsvm(path, rows=300, seed=21):
+    """Rows of 2 to 6 features from a cubed draw over ``F_LANES`` (they
+    recur), each also naming 8 and 9 (one 128-lane row of v at K = 16 and
+    at K = 32) and one of the last three features, which share the tables'
+    last row with its pad features. 300 rows in batches of 128: three
+    steps, the last a short batch."""
+    rng = np.random.default_rng(seed)
+    with open(path, "w") as f:
+        for r in range(rows):
+            ids = set((F_LANES * rng.random(rng.integers(2, 7)) ** 3)
+                      .astype(int).tolist())
+            ids |= {8, 9, F_LANES - 1 - r % 3}
+            f.write(f"{rng.integers(0, 2)} " + " ".join(
+                f"{c}:{rng.choice([0.5, 1.0, 1.5])}" for c in sorted(ids))
+                + "\n")
+    return str(path)
+
+
+def _lane_batches(uri, mesh=None, col_owners=(1, 0)):
+    with DeviceRowBlockIter(uri, batch_rows=128, layout="csr", mesh=mesh,
+                            min_nnz_bucket=1024, col_owners=col_owners) as it:
+        return list(it)
+
+
+def _plain_layout(learner):
+    """``learner`` with its factor tables held ``[F, K]``, as K and the
+    layout would choose where K does not divide 128."""
+    learner._lanes, learner.lane_dense = None, False
+    return learner
+
+
+@pytest.mark.parametrize("updater", ["sgd", "ftrl"])
+@pytest.mark.parametrize("k", [16, 32])
+def test_lane_dense_tables_step_as_plain_ones_bit_for_bit(tmp_path, k,
+                                                          updater):
+    """Three steps of a learner whose ``v`` (and ``G``) is lane-dense and
+    of the same learner on ``[F, K]`` tables: the same losses and states to
+    the bit, on batches that name two columns of one 128-lane row and whose
+    list is padded beyond the tables."""
+    kw = (dict(updater="ftrl", init_scale=0.3, **RULE) if updater == "ftrl"
+          else dict(learning_rate=0.3, init_scale=0.2))
+    batches = _lane_batches(write_lane_libsvm(tmp_path / "l.libsvm"))
+    assert [b.total_rows for b in batches] == [128, 128, 44]
+    p = 128 // k
+    for batch in batches:
+        cols = np.asarray(batch.tree()["cols"])[0]
+        real = cols[cols < F_LANES]
+        assert np.unique(real // p).size < real.size   # rows shared
+        assert (cols >= F_LANES).any()                 # the list's padding
+    runs = []
+    for learner in (FMLearner(F_LANES, k=k, **kw),
+                    _plain_layout(FMLearner(F_LANES, k=k, **kw))):
+        state, losses = learner.init(5), []
+        for batch in batches:
+            state, loss = learner.step(state, batch)
+            losses.append(float(loss))
+        runs.append((learner, state, losses))
+    (lane, lane_state, lane_losses), (plain, plain_state, plain_losses) = runs
+    assert lane.lane_dense and not plain.lane_dense
+    assert lane.factors(lane_state).shape == (F_LANES, k)
+    assert jax.tree.leaves(lane_state)[2].shape == (-(-F_LANES // p), 128)
+    assert lane_losses == plain_losses
+    assert np.asarray(lane.factors(lane_state)).tobytes() == np.asarray(
+        plain.factors(plain_state)).tobytes()
+    # every other leaf to the bit but G, which the scatter-add raises by
+    # the rounded square where the plain write-back's ``rows + g*g`` may be
+    # one fused multiply-add (XLA's CPU backend contracts it): one unit in
+    # the last place a step at most, the gradients being the same bits
+    for (path, a), b in zip(
+            jax.tree_util.tree_leaves_with_path(lane.saved(lane_state)),
+            jax.tree.leaves(plain.saved(plain_state))):
+        name = jax.tree_util.keystr(path)
+        if name.endswith(".g"):
+            np.testing.assert_array_max_ulp(np.asarray(a), np.asarray(b),
+                                            maxulp=len(batches))
+        else:
+            assert np.asarray(a).tobytes() == np.asarray(b).tobytes(), name
+
+
+@pytest.mark.parametrize("form", ["one-device", "replicated",
+                                  "range_sharded"])
+def test_lane_dense_init_draws_the_plain_table_and_steps_are_counted(
+        tmp_path, form):
+    """``factors(init(seed))`` is the ``[F, K]`` draw bit for bit; every
+    step of lane-dense tables is counted beside the row form's count, on
+    one device and on a replicated mesh; range-sharded tables keep ``[F,
+    K]`` (an owner's range is rows of v) and are never counted."""
+    import jax.numpy as jnp
+    from dmlc_core_tpu import telemetry
+    k, seed, scale = 16, 11, 0.2
+    F = F_LANES + 1 if form == "range_sharded" else F_LANES   # 4 ranges
+    mesh = {"one-device": None, "replicated": data_mesh(2),
+            "range_sharded": data_mesh(4)}[form]
+    learner = FMLearner(F, k=k, mesh=mesh, init_scale=scale,
+                        table_layout=("range_sharded" if form ==
+                                      "range_sharded" else "replicated"))
+    assert learner.lane_dense == (form != "range_sharded")
+    params = learner.init(seed)
+    want = scale * jax.random.normal(jax.random.PRNGKey(seed), (F, k),
+                                     jnp.float32)
+    assert np.asarray(learner.factors(params)).tobytes() == \
+        np.asarray(want).tobytes()
+    assert learner.from_saved(learner.saved(params)).v.shape == \
+        params.v.shape
+    lane, rows = (telemetry.counter(name, {"model": "FMLearner"}) for name in
+                  ("model_step_lane_dense_updates_total",
+                   "model_step_row_updates_total"))
+    lane_at, rows_at = lane.value, rows.value
+    for batch in _lane_batches(write_lane_libsvm(tmp_path / "c.libsvm"),
+                               mesh, learner.col_owners):
+        params, _ = learner.step(params, batch)
+    assert rows.value == rows_at + 3
+    assert lane.value == lane_at + (0 if form == "range_sharded" else 3)
+
+
+def test_the_scoring_model_takes_the_saved_state_and_names_a_packed_one():
+    """The scoring server reads ``v`` as ``[F, K]``: ``saved`` gives it
+    that, and a lane-dense table saved as it lies is refused by name."""
+    from dmlc_core_tpu.base import DMLCError
+    from dmlc_core_tpu.serving.model import ScoringModel
+    learner = FMLearner(F_LANES, k=16)
+    params = learner.init(3)
+    as_saved = {k: np.asarray(v) for k, v in
+                learner.saved(params)._asdict().items()}
+    assert as_saved["v"].shape == (F_LANES, 16)
+    ScoringModel("fm", as_saved, F_LANES)
+    with pytest.raises(DMLCError, match="serving model v has shape"):
+        ScoringModel("fm", {**as_saved, "v": np.asarray(params.v)}, F_LANES)
+
+
+@pytest.mark.parametrize("mesh_devices,features", [
+    (0, F_LANES), (0, 40 * 1024 * 8), (4, F_LANES)],
+    ids=["nomesh", "nomesh-large-table", "mesh4"])
+def test_lane_dense_step_scatters_whole_rows_and_makes_no_plain_table(
+        tmp_path, mesh_devices, features):
+    """The lowered row step on lane-dense ``v``: one scatter-add into it
+    under ``dp.apply``, of ``[U, 128]`` rows, beside the one into ``w``;
+    nothing of the plain ``[F, K]`` shape is made (no relayout of the
+    table); the gather reads whole rows too. The scatter is told that its
+    list ascends on one device where the table holds fewer rows than
+    ``STREAM_ROWS_PER_UPDATE`` a column of the list, and never on a mesh."""
+    from dmlc_core_tpu.models.fm import STREAM_ROWS_PER_UPDATE
+    mesh = data_mesh(mesh_devices) if mesh_devices else None
+    learner = FMLearner(features, k=16, mesh=mesh)
+    params = learner.init()
+    batch = _lane_batches(write_lane_libsvm(tmp_path / "s.libsvm"), mesh)[0]
+    tree = batch.tree()
+    lowered = learner._build_step(
+        batch.rows_per_shard, tuple(sorted(tree))).lower(params, tree)
+    packed = tuple(params.v.shape)
+    assert packed == (-(-features // 8), 128)
+    assert not _table_ops(lowered, {(features, 16)})
+    scatters = [op for op in _lowered_ops(lowered)
+                if op.name == "stablehlo.scatter"
+                and tuple(op.results[0].type.shape) == packed]
+    assert len(scatters) == 1, scatters
+    assert "dp.apply" in str(scatters[0].location) \
+        and "scatter-add" in str(scatters[0].location)
+    U = tree["cols"].shape[-1]
+    small = packed[0] < STREAM_ROWS_PER_UPDATE * U
+    assert small == (features == F_LANES), (packed, U)   # both sides held
+    told = mesh is None and small
+    assert ("indices_are_sorted = true" in str(scatters[0])) == told
+    gathers = [op for op in _lowered_ops(lowered)
+               if op.name == "stablehlo.gather"
+               and tuple(op.operands[0].type.shape) == packed]
+    assert len(gathers) == 1
+    assert gathers[0].results[0].type.shape[-1] == 128

@@ -276,12 +276,15 @@ def test_mesh_step_makes_and_exchanges_nothing_of_a_tables_shape(
     learner = FMLearner(F, k=K, mesh=mesh)
     step = learner._build_step(batch.rows_per_shard, tuple(sorted(tree)))
     assert step.__name__ == "sharded_step"   # the benchmark's name for it
-    lowered = step.lower(learner.init(SEED), tree)
+    params = learner.init(SEED)
+    lowered = step.lower(params, tree)
     ops = lowered_ops(lowered)
-    # of a table's shape: the two scatter-adds under dp.apply and the
-    # shard_map's own results, the parameters out; no gradient table
+    # of a table's shape (v's lane-dense one, models/fm.py _Lanes): the two
+    # scatter-adds under dp.apply and the shard_map's own results, the
+    # parameters out; no gradient table
+    tables = {(F,), tuple(params.v.shape)}
     made = [(name, loc) for name, results, loc in ops
-            if any(shape in {(F,), (F, K)} for shape, _ in results)]
+            if any(shape in tables for shape, _ in results)]
     assert sorted(name for name, _ in made) == \
         ["sdy.manual_computation"] + ["stablehlo.scatter"] * 2, made
     for name, loc in made:
@@ -334,7 +337,7 @@ def test_which_mesh_steps_take_the_row_form(tmp_path, what):
     exchanged = [(name, results[0][0]) for name, results, _ in lowered_ops(
         next(iter(learner._step_fn.values())).lower(params, tree))
         if name in COLLECTIVES]
-    table = (F, K) if name == "FMLearner" else (F,)
+    table = tuple(params.v.shape) if name == "FMLearner" else (F,)
     assert (("stablehlo.all_reduce", table) in exchanged) == (not rows)
     assert ("stablehlo.all_gather" in dict(exchanged)) == rows
 

@@ -90,14 +90,16 @@ def steps(uri, layout, k, objective, fmt="libsvm", shards=OWNERS):
                         learning_rate=LR, init_scale=SCALE,
                         **({"table_layout": layout} if shards else {}))
     params = learner.init(SEED)
-    states, losses = [jax.tree.map(np.asarray, params)], []
+    # the tables as [F, K] whatever their layout (FMLearner.saved)
+    states, losses = [jax.tree.map(np.asarray, learner.saved(params))], []
     with DeviceRowBlockIter(uri, mesh=mesh, batch_rows=BATCH, fmt=fmt,
                             min_nnz_bucket=64,
                             col_owners=learner.col_owners) as it:
         while len(losses) < STEPS:
             for batch in it:
                 params, loss = learner.step(params, batch)
-                states.append(jax.tree.map(np.asarray, params))
+                states.append(jax.tree.map(np.asarray,
+                                           learner.saved(params)))
                 losses.append(float(loss))
                 if len(losses) == STEPS:
                     break
@@ -147,11 +149,13 @@ def test_sharded_step_agrees_with_the_plain_reference(tmp_path, case):
 @pytest.mark.parametrize("seed", [0, 11, 2 ** 31 - 1])
 def test_init_equals_the_replicated_init_row_for_row(seed):
     mesh = data_mesh(OWNERS)
-    whole = FMLearner(F, k=16, mesh=mesh, init_scale=SCALE).init(seed)
+    replicated = FMLearner(F, k=16, mesh=mesh, init_scale=SCALE)
+    whole = replicated.saved(replicated.init(seed))   # v as [F, K]
     learner = FMLearner(F, k=16, mesh=mesh, init_scale=SCALE,
                         table_layout="range_sharded")
     cut = learner.init(seed)
     assert learner.col_owners == (OWNERS, RANGE)
+    assert replicated.lane_dense and not learner.lane_dense
     for a, b in zip(whole, cut):
         assert np.array_equal(np.asarray(a), np.asarray(b))
     assert len(cut.b.addressable_shards) == OWNERS   # on every device

@@ -18,7 +18,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from dmlc_core_tpu.models import FMLearner
-from dmlc_core_tpu.models.fm import FMParams
+from dmlc_core_tpu.models.fm import FMParams, STREAM_ROWS_PER_UPDATE
 
 CONFIGS = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "benchmarks", "configs")
@@ -103,13 +103,26 @@ def _entry_array_touches(text, nnz):
 # 0.198 / 0.410 read. One shape a configuration is all there is to
 # compile: since ISSUE 34 an epoch's short last batch is sent at the rungs of
 # the batch before it (device_iter.tail_rung), so criteo1tb-fm-s3, whose
-# part of 24 objects ends every epoch in one, steps at criteo1tb-fm's shape
+# part of 24 objects ends every epoch in one, steps at criteo1tb-fm's shape.
+# v is lane-dense where K divides 128 (models/fm.py _Lanes): the scatter
+# into it writes whole rows of f32[F·K/128, 128] in the row-major tiled
+# layout, and no f32[F, K] is scattered into. On one chip the compiler
+# fuses the placement of the gradient's rows at their lanes into the
+# scatter where the scatter is told that its list ascends (kdd2010b-fm,
+# criteo1tb-fm: the temporaries 782.7 / 934.8 -> 785.3 / 937.0 MB); where
+# it is not (kdd2012-fm, whose table holds 64 rows a column of the list)
+# the placed rows are made: 206.7 -> 407.8 MB, 0.110 of the tables. On the
+# mesh the four lists go in as one, not promised sorted, and
+# the compiler makes the placed f32[425984, 128] rows (218 MB): 206.7 ->
+# 440.1 MB, 0.118 of the tables (one scatter a shard's sorted list held
+# 331.1 MB but cost 46.7 ms on the chip where the one list costs 32.4:
+# PERF.md section 5)
 @pytest.mark.slow
 @pytest.mark.parametrize(
     "config,chips,planes,nnz,distinct,temp_share,touches", [
-        ("kdd2012-fm", 1, 4, 180224, 106496, 0.07, 19),
+        ("kdd2012-fm", 1, 4, 180224, 106496, 0.12, 19),
         ("kdd2010b-fm", 1, 3, 491520, 262144, 0.22, 13),
-        ("kdd2012-fm-dp4", 4, 4, 180224, 106496, 0.07, 19),
+        ("kdd2012-fm-dp4", 4, 4, 180224, 106496, 0.13, 19),
         ("criteo1tb-fm", 1, 3, 589824, 212992, 0.45, 13),
         ("criteo1tb-fm-s3", 1, 3, 589824, 212992, 0.45, 13),
     ])
@@ -124,9 +137,13 @@ def test_step_compiles_and_fits_beside_the_checks_table(
                         learning_rate=cfg["learning_rate"])
     rep, row = NamedSharding(mesh, P()), NamedSharding(mesh, P("data"))
     F, K, R = cfg["num_features"], cfg["fm_rank"], cfg["batch_rows"]
+    assert learner.lane_dense
+    rows = -(-F // (128 // K))              # ceil(F / p)
     params = FMParams(jax.ShapeDtypeStruct((), jnp.float32, sharding=rep),
                       jax.ShapeDtypeStruct((F,), jnp.float32, sharding=rep),
-                      jax.ShapeDtypeStruct((F, K), jnp.float32, sharding=rep))
+                      jax.ShapeDtypeStruct((rows, 128), jnp.float32,
+                                           sharding=rep))
+    assert jax.eval_shape(learner.init).v.shape == (rows, 128)
     tree = {"aux": jax.ShapeDtypeStruct((chips, 3, R), jnp.int32,
                                         sharding=row),
             "big": jax.ShapeDtypeStruct((chips, planes, nnz), jnp.int32,
@@ -157,17 +174,20 @@ def test_step_compiles_and_fits_beside_the_checks_table(
     text = compiled.as_text()
     copies_of_a_table = [
         line for line in text.splitlines()
-        if re.search(r"= f32\[%d(,%d)?\]\{[^}]*\} copy\(" % (F, K), line)]
+        if re.search(r"= f32\[(%d(,%d)?|%d,128)\]\{[^}]*\} copy\("
+                     % (F, K, rows), line)]
     assert not copies_of_a_table, copies_of_a_table
     touched = _entry_array_touches(text, nnz)
     print(f"{config}: {len(touched)} reads and writes of a padded "
           f"[{nnz}, n] array: {touched}")
     assert len(touched) <= touches, touched
     # the tables are updated at the distinct columns alone, one scatter a
-    # table. One shard's list ascends and the scatter is told so; the four
-    # shards' lists go in as one, which ascends within a shard's stretch
-    # only, so nothing is promised of it: the compiler sorts the list
-    # itself for the scatter into w, and not for the one into v
+    # table. One shard's list ascends, and the scatter into w is told so;
+    # the one into v only where its table holds fewer rows than
+    # STREAM_ROWS_PER_UPDATE a column (models/fm.py: told, XLA passes over
+    # the whole table). The four shards' lists go in as one, which ascends
+    # within a shard's stretch only, so nothing is promised of it: the
+    # compiler sorts the list itself for the scatter into w, and not for v
     if chips > 1:
         # the shards' rows are exchanged at their own widths: w's as [D*U]
         # and not as a [D*U, 1] slice of the merged gradient, which the
@@ -180,17 +200,25 @@ def test_step_compiles_and_fits_beside_the_checks_table(
                    for line in gathered), gathered
         assert not any(f"= f32[{chips * distinct},1]" in line
                        for line in gathered), gathered
-    into_tables = [line for line in text.splitlines()
-                   if " scatter(" in line and f"= f32[{F}" in line]
+    scatters = [line for line in text.splitlines() if " scatter(" in line]
+    into_tables = [line for line in scatters
+                   if f"= f32[{F}]" in line or f"= f32[{rows},128]" in line]
     assert len(into_tables) == 2, into_tables
+    # v's whole 128-lane rows, row-major: a feature's K floats one run
+    assert any(f"= f32[{rows},128]{{1,0:T(8,128)}} scatter(" in line
+               for line in into_tables), into_tables
+    assert not any(f"f32[{F},{K}]" in line for line in scatters), scatters
     sorts_of_the_list = [line for line in text.splitlines()
                          if " sort(" in line and "dp.apply/scatter-add" in line]
     for line in into_tables:
         assert "dp.apply/scatter-add" in line, line
         # on the mesh a scatter reads as sorted only where the compiler
         # sorted the list itself
+        told = (rows < STREAM_ROWS_PER_UPDATE * distinct
+                if f"= f32[{rows},128]" in line else True)
         assert ("indices_are_sorted=true" in line) == (
-            chips == 1 or (f"= f32[{F}]" in line and bool(sorts_of_the_list)))
+            (chips == 1 and told)
+            or (f"= f32[{F}]" in line and bool(sorts_of_the_list)))
     assert not (chips == 1 and sorts_of_the_list), sorts_of_the_list
     # the mesh step sums three scalars and gathers the shards' lists and
     # rows: no collective, and nothing else but the parameters in and out
@@ -232,9 +260,12 @@ def test_ftrl_step_compiles_in_place(topo, no_compile_cache):
 
     def shape(*s):
         return jax.ShapeDtypeStruct(s, jnp.float32, sharding=rep)
-    state = FMState(FMParams(shape(), shape(F), shape(F, K)),
+    rows = -(-F // (128 // K))              # v and G lane-dense
+    state = FMState(FMParams(shape(), shape(F), shape(rows, 128)),
                     FMSlots(shape(), shape(), shape(F), shape(F),
-                            shape(F, K)))
+                            shape(rows, 128)))
+    assert jax.tree.map(lambda a: a.shape, jax.eval_shape(learner.init)) == \
+        jax.tree.map(lambda a: a.shape, state)
     nnz, distinct = 589824, 212992
     tree = {"aux": jax.ShapeDtypeStruct((1, 3, R), jnp.int32, sharding=row),
             "big": jax.ShapeDtypeStruct((1, 3, nnz), jnp.int32, sharding=row),
@@ -251,13 +282,17 @@ def test_ftrl_step_compiles_in_place(topo, no_compile_cache):
     assert m.alias_size_in_bytes >= size
     text = compiled.as_text()
     copies = [line for line in text.splitlines()
-              if re.search(r"= f32\[%d(,%d)?\]\{[^}]*\} copy\(" % (F, K),
-                           line)]
+              if re.search(r"= f32\[(%d(,%d)?|%d,128)\]\{[^}]*\} copy\("
+                           % (F, K, rows), line)]
     assert not copies, copies
-    # one write-back a table leaf (w, v; z, n, G), under dp.apply
+    # one write-back a table leaf (w, v; z, n, G), under dp.apply: v's and
+    # G's scatter-adds of whole 128-lane rows
     into_tables = [line for line in text.splitlines()
-                   if " scatter(" in line and f"= f32[{F}" in line]
+                   if " scatter(" in line and (f"= f32[{F}]" in line or
+                                               f"= f32[{rows},128]" in line)]
     assert len(into_tables) == 5, into_tables
+    assert sum(f"= f32[{rows},128]{{1,0:T(8,128)}} scatter(" in line
+               for line in into_tables) == 2, into_tables
     assert all("dp.apply" in line for line in into_tables)
     assert "opt.update" in text
     # the step beside the check's spare state (init()'s, held through the
