@@ -28,8 +28,9 @@ third by how the model was told to keep its tables (``table_layout``):
   touched, not a table; loss, weight and what every shard reads whole are
   psummed beside them) and every replica scatter-adds all of them, in the
   mesh's order, so the replicas stay bit-identical. A column several
-  shards name is added to once a shard. (Cell kdd2012-fm-dp4.libfm runs
-  it; the all-reduce of the table it replaced took 65 of the step's
+  shards name is added to once a shard (the FM sorts the one list first,
+  stably, where its scatter is then cheaper). (Cell kdd2012-fm-dp4.libfm
+  runs it; the all-reduce of the table it replaced took 65 of the step's
   115 ms there, PERF.md section 6, PR 33.)
 - row form on range-sharded tables (``table_layout="range_sharded"``: a
   CSR batch whose ``cols`` is owner-major, tpu/device_iter.py col_slots):
@@ -122,9 +123,9 @@ class DataParallelModel:
     _gather_rows = None
     _apply_rows = None
 
-    def _count_step(self) -> None:
-        """Hook: the counters a model raises on every step beside the shared
-        ones (none here)."""
+    def _count_step(self, tree) -> None:
+        """Hook: the counters a model raises on every step of the batch
+        tree ``tree`` beside the shared ones (none here)."""
 
     def saved(self, state):
         """``state`` as a checkpoint holds it (itself here; a model that
@@ -227,10 +228,16 @@ class DataParallelModel:
                 if row_form:
                     # every shard's list and the rows of its gradient, in
                     # the mesh's order and the same on every device; what
-                    # the shards read whole (a scalar) is summed
+                    # the shards read whole (a scalar) is summed. A leaf
+                    # of rows [U, n] goes in flat: as [U, n] the compiler
+                    # may exchange it row-major, its n lanes padded to 128
+                    # (8x the bytes at n = 16: 1.8 ms a step in cell
+                    # kdd2012-fm-dp4.libfm once its list was sorted,
+                    # PERF.md section 6)
                     cols = gather_unvarying(shard["cols"], axis)
                     grads = jax.tree.map(
-                        lambda g: gather_unvarying(g, axis) if g.ndim
+                        lambda g: gather_unvarying(g.reshape(-1), axis)
+                        .reshape((-1,) + g.shape) if g.ndim
                         else jax.lax.psum(g, axis), grads)
 
                     def update(denom):
@@ -403,7 +410,7 @@ class DataParallelModel:
         if self._takes_row_form(tree):
             telemetry.counter("model_step_row_updates_total",
                               {"model": type(self).__name__}).inc()
-        self._count_step()
+        self._count_step(tree)
         # with the device time under scope dp.allreduce, the exchange's rate
         if self._step_bytes[sig]:
             telemetry.counter("model_step_allreduce_bytes_total",

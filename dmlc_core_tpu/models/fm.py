@@ -179,18 +179,23 @@ class _Lanes(NamedTuple):
             out = jnp.where(lane == j, packed[:, j * k:(j + 1) * k], out)
         return jnp.where((cols < self.num_features)[:, None], out, 0)
 
+    def streams(self, updates: int) -> bool:
+        """Whether a scatter of ``updates`` rows into this table, its list
+        ascending, is cheaper told so: the table holds fewer rows than
+        ``STREAM_ROWS_PER_UPDATE`` an update."""
+        return self.shape[0] < STREAM_ROWS_PER_UPDATE * updates
+
     def add(self, table, cols, delta, sorted_cols: bool = True):
         """``table`` with ``delta`` ``[U, K]`` added at ``cols``; the
         padding's rows are dropped. The scatter is told that ``cols``
-        ascend only where that is cheaper (``STREAM_ROWS_PER_UPDATE``)."""
+        ascend only where that is cheaper (``streams``)."""
         group = jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1) // self.k
         placed = jnp.where(group == (cols % self.pack)[:, None],
                            jnp.tile(delta, (1, self.pack)),
                            jnp.float32(-0.0))
-        streams = sorted_cols and (
-            table.shape[0] < STREAM_ROWS_PER_UPDATE * cols.shape[0])
-        return table.at[self._at(cols)].add(placed,
-                                            indices_are_sorted=streams)
+        return table.at[self._at(cols)].add(
+            placed, indices_are_sorted=sorted_cols and self.streams(
+                cols.shape[0]))
 
     def matrix(self, table):
         """The ``[F, K]`` view (a reshape and a slice: on the chip a pass
@@ -570,13 +575,25 @@ class FMLearner(DataParallelModel):
         return FMParams(b=P(), w=rows, v=rows)
 
     # -- DataParallelModel hooks (the step harness lives in models/_dp.py) --
-    def _count_step(self):
+    def _sorts_list(self, cols_shape) -> bool:
+        """Whether the step sorts a mesh's list ``[D, U]`` (D > 1) before
+        its scatters: on lane-dense ``v``, where the one list of ``D·U``
+        ascending would be told so (``_Lanes.streams``)."""
+        D, U = cols_shape
+        return self._lanes is not None and D > 1 and self._lanes.streams(
+            D * U)
+
+    def _count_step(self, tree):
         # beside model_step_row_updates_total: the steps whose factor tables
-        # are lane-dense, and the steps of a rule with a state of its own
+        # are lane-dense, those whose mesh list was sorted first, and the
+        # steps of a rule with a state of its own
         model = {"model": type(self).__name__}
         if self.lane_dense:
             telemetry.counter("model_step_lane_dense_updates_total",
                               model).inc()
+        if self._takes_row_form(tree) and self._sorts_list(
+                tree["cols"].shape):
+            telemetry.counter("model_step_sorted_lists_total", model).inc()
         if self.updater != "sgd":
             telemetry.counter("model_step_rule_updates_total",
                               {**model, "rule": self.updater}).inc()
@@ -635,9 +652,14 @@ class FMLearner(DataParallelModel):
 
         On a mesh ``cols`` is ``[D, U]`` and the rows ``[D, U, ...]``:
         every shard's list with its gradient's rows, shard after shard.
-        The lists go in as one: it ascends within a shard's stretch only,
-        so nothing is promised of its order, and a column that several
-        shards name is added to once a shard, with a rounding each.
+        The lists go in as one, which ascends within a shard's stretch
+        only; a column that several shards name is added to once a shard,
+        in the mesh's order, with a rounding each. Where the scatter into
+        a lane-dense ``v`` would be told that the one list ascends
+        (``_sorts_list``: the table is small beside ``D·U``), the list is
+        sorted first, stably, and the rows permuted with it, so both
+        scatters are told as on one device and the table is passed over
+        once; elsewhere nothing is promised of the list's order.
 
         Under ``updater="ftrl"`` (one device) the rows of every leaf at
         ``cols`` are read, the rule gives their new values and these are
@@ -664,12 +686,22 @@ class FMLearner(DataParallelModel):
                 return put(table, rows_of(table) + delta)
             return self._ftrl_update(params, g, denom, rows_of, put, add)
         lr, l2 = self.learning_rate, self.l2
+        if cols.ndim == 2 and self._sorts_list(cols.shape):
+            # the padding lies beyond the tables and sorts to the end; every
+            # replica sorts the same gathered list, so they stay identical.
+            # w's rows ride in the sort (a gather of single floats costs
+            # more), v's are gathered by the order
+            cols, order, w_rows = jax.lax.sort(
+                (cols.reshape(-1), jnp.arange(cols.size, dtype=jnp.int32),
+                 g.w.reshape(-1)), num_keys=1, is_stable=True)
+            g = g._replace(w=w_rows, v=g.v.reshape(-1, lanes.k).at[order].get(
+                mode="promise_in_bounds", unique_indices=True))
 
         def update(table, grad):
             table = table if l2 == 0 else (1.0 - lr * l2) * table
             if table.ndim == 2 and lanes is not None:
                 # on a mesh every shard's list and rows as one, which
-                # ascends within a shard's stretch only
+                # ascends within a shard's stretch only unless sorted above
                 return lanes.add(table, cols.reshape(-1),
                                  -lr * (grad.reshape(-1, lanes.k) / denom),
                                  sorted_cols=cols.ndim == 1)

@@ -491,7 +491,9 @@ def test_fm_mesh_step_exchanges_the_rows_as_their_own_leaves(tmp_path):
     slice of the merged ``[U, K+1]`` gradient that the chip's compiler
     pads to 128 lanes (1.84 ms a step for 0.02 in cell
     kdd2012-fm-dp4.libfm: PERF.md section 6, PR 35;
-    tests/test_step_compiles_for_v5e.py holds the compiled layout)."""
+    tests/test_step_compiles_for_v5e.py holds the compiled layout); and
+    v's rows go in flat, ``[U·K]`` out as ``[D, U·K]``, which the compiler
+    cannot exchange with K lanes padded to 128."""
     mesh = data_mesh(4)
     learner = FMLearner(F_ROWS, k=K_ROWS, mesh=mesh)
     batch = _batches(write_recurring_libsvm(tmp_path / "x.libsvm"), mesh)[0]
@@ -504,11 +506,12 @@ def test_fm_mesh_step_exchanges_the_rows_as_their_own_leaves(tmp_path):
                if op.name == "stablehlo.all_gather"
                and "f32" in str(op.results[0].type)]
     assert sorted(tuple(op.results[0].type.shape) for op in gathers) == [
-        (4, U), (4, U, K_ROWS)]
+        (4, U), (4, U * K_ROWS)]
     for op in gathers:
         src = op.operands[0].owner
         while src.name != "stablehlo.optimization_barrier":
-            assert src.name == "stablehlo.broadcast_in_dim", src.name
+            assert src.name in ("stablehlo.broadcast_in_dim",
+                                "stablehlo.reshape"), src.name
             src = src.operands[0].owner
         assert [tuple(r.type.shape) for r in src.results] == [
             (), (U,), (U, K_ROWS)]
@@ -1324,7 +1327,8 @@ def test_lane_dense_init_draws_the_plain_table_and_steps_are_counted(
     """``factors(init(seed))`` is the ``[F, K]`` draw bit for bit; every
     step of lane-dense tables is counted beside the row form's count, on
     one device and on a replicated mesh; range-sharded tables keep ``[F,
-    K]`` (an owner's range is rows of v) and are never counted."""
+    K]`` (an owner's range is rows of v) and are never counted. The steps
+    that sort the mesh's list are counted on the replicated mesh alone."""
     import jax.numpy as jnp
     from dmlc_core_tpu import telemetry
     k, seed, scale = 16, 11, 0.2
@@ -1342,15 +1346,18 @@ def test_lane_dense_init_draws_the_plain_table_and_steps_are_counted(
         np.asarray(want).tobytes()
     assert learner.from_saved(learner.saved(params)).v.shape == \
         params.v.shape
-    lane, rows = (telemetry.counter(name, {"model": "FMLearner"}) for name in
-                  ("model_step_lane_dense_updates_total",
-                   "model_step_row_updates_total"))
-    lane_at, rows_at = lane.value, rows.value
+    lane, rows, sorted_lists = (
+        telemetry.counter(name, {"model": "FMLearner"}) for name in
+        ("model_step_lane_dense_updates_total",
+         "model_step_row_updates_total", "model_step_sorted_lists_total"))
+    lane_at, rows_at, sorted_at = lane.value, rows.value, sorted_lists.value
     for batch in _lane_batches(write_lane_libsvm(tmp_path / "c.libsvm"),
                                mesh, learner.col_owners):
         params, _ = learner.step(params, batch)
     assert rows.value == rows_at + 3
     assert lane.value == lane_at + (0 if form == "range_sharded" else 3)
+    assert sorted_lists.value == sorted_at + (3 if form == "replicated"
+                                              else 0)
 
 
 def test_the_scoring_model_takes_the_saved_state_and_names_a_packed_one():
@@ -1369,20 +1376,24 @@ def test_the_scoring_model_takes_the_saved_state_and_names_a_packed_one():
 
 
 @pytest.mark.parametrize("mesh_devices,features", [
-    (0, F_LANES), (0, 40 * 1024 * 8), (4, F_LANES)],
-    ids=["nomesh", "nomesh-large-table", "mesh4"])
+    (0, F_LANES), (0, 40 * 1024 * 8), (4, F_LANES), (4, 160 * 1024 * 8)],
+    ids=["nomesh", "nomesh-large-table", "mesh4", "mesh4-large-table"])
 def test_lane_dense_step_scatters_whole_rows_and_makes_no_plain_table(
         tmp_path, mesh_devices, features):
     """The lowered row step on lane-dense ``v``: one scatter-add into it
     under ``dp.apply``, of ``[U, 128]`` rows, beside the one into ``w``;
     nothing of the plain ``[F, K]`` shape is made (no relayout of the
     table); the gather reads whole rows too. The scatter is told that its
-    list ascends on one device where the table holds fewer rows than
-    ``STREAM_ROWS_PER_UPDATE`` a column of the list, and never on a mesh."""
+    list ascends where the table holds fewer rows than
+    ``STREAM_ROWS_PER_UPDATE`` a column of the one list the step scatters,
+    ``D·U`` long: on one device the shard's list, on a mesh the shards'
+    lists sorted once under ``dp.apply`` (one sort, and then ``w``'s
+    scatter is told too); where the table is too large for that, a mesh
+    step sorts nothing and tells neither scatter."""
     from dmlc_core_tpu.models.fm import STREAM_ROWS_PER_UPDATE
     mesh = data_mesh(mesh_devices) if mesh_devices else None
     learner = FMLearner(features, k=16, mesh=mesh)
-    params = learner.init()
+    params = jax.eval_shape(learner.init)
     batch = _lane_batches(write_lane_libsvm(tmp_path / "s.libsvm"), mesh)[0]
     tree = batch.tree()
     lowered = learner._build_step(
@@ -1390,19 +1401,64 @@ def test_lane_dense_step_scatters_whole_rows_and_makes_no_plain_table(
     packed = tuple(params.v.shape)
     assert packed == (-(-features // 8), 128)
     assert not _table_ops(lowered, {(features, 16)})
-    scatters = [op for op in _lowered_ops(lowered)
-                if op.name == "stablehlo.scatter"
+    ops = _lowered_ops(lowered)
+    scatters = [op for op in ops if op.name == "stablehlo.scatter"
                 and tuple(op.results[0].type.shape) == packed]
     assert len(scatters) == 1, scatters
     assert "dp.apply" in str(scatters[0].location) \
         and "scatter-add" in str(scatters[0].location)
-    U = tree["cols"].shape[-1]
-    small = packed[0] < STREAM_ROWS_PER_UPDATE * U
-    assert small == (features == F_LANES), (packed, U)   # both sides held
-    told = mesh is None and small
-    assert ("indices_are_sorted = true" in str(scatters[0])) == told
-    gathers = [op for op in _lowered_ops(lowered)
+    D, U = tree["cols"].shape
+    small = packed[0] < STREAM_ROWS_PER_UPDATE * D * U
+    assert small == (features == F_LANES), (packed, D, U)   # both sides held
+    assert ("indices_are_sorted = true" in str(scatters[0])) == small
+    sorts = [op for op in ops if op.name == "stablehlo.sort"]
+    assert len(sorts) == (1 if mesh is not None and small else 0), sorts
+    for op in sorts:
+        assert "dp.apply" in str(op.location)
+        assert tuple(op.operands[0].type.shape) == (D * U,)
+    into_w = [op for op in ops if op.name == "stablehlo.scatter"
+              and tuple(op.results[0].type.shape) == (features,)]
+    assert len(into_w) == 1, into_w
+    assert ("indices_are_sorted = true" in str(into_w[0])) == (
+        mesh is None or small)
+    gathers = [op for op in ops
                if op.name == "stablehlo.gather"
                and tuple(op.operands[0].type.shape) == packed]
     assert len(gathers) == 1
     assert gathers[0].results[0].type.shape[-1] == 128
+
+
+def test_lane_dense_mesh_step_sorted_equals_unsorted_bit_for_bit(tmp_path):
+    """Three steps on 4 devices whose shards share columns (8 and 9 in every
+    row) and whose lists are padded beyond the tables: the step that sorts
+    the shards' one list before its scatters gives the losses and tables of
+    the step that scatters it unsorted, bit for bit (the sort is stable, so
+    a column several shards name is added in the mesh's order; on the CPU
+    a scatter adds a repeated index in the list's order), and every replica
+    holds the same bits."""
+    mesh = data_mesh(4)
+    batches = _lane_batches(write_lane_libsvm(tmp_path / "m.libsvm"), mesh)
+    for batch in batches:
+        cols = np.asarray(batch.tree()["cols"])
+        real = [set(c[c < F_LANES].tolist()) for c in cols]
+        assert set.intersection(*real)                  # shards share columns
+        assert (cols >= F_LANES).any()                  # the lists' padding
+    runs = []
+    for sorts in (True, False):
+        learner = FMLearner(F_LANES, k=16, mesh=mesh, learning_rate=0.3,
+                            init_scale=0.2)
+        if not sorts:
+            learner._sorts_list = lambda shape: False   # the unsorted step
+        state, losses = learner.init(5), []
+        for batch in batches:
+            state, loss = learner.step(state, batch)
+            losses.append(float(loss))
+        runs.append((losses, state))
+    (losses, state), (plain_losses, plain_state) = runs
+    assert losses == plain_losses
+    for a, b in zip(jax.tree.leaves(state), jax.tree.leaves(plain_state)):
+        assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
+        first = np.asarray(a.addressable_shards[0].data).tobytes()
+        assert len(a.addressable_shards) == 4
+        assert all(np.asarray(s.data).tobytes() == first
+                   for s in a.addressable_shards)

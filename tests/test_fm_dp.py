@@ -292,7 +292,8 @@ def test_mesh_step_makes_and_exchanges_nothing_of_a_tables_shape(
             assert "dp.apply" in loc and "scatter-add" in loc, loc
     assert "transpose(jvp(fm.gather))" not in lowered.as_text(debug_info=True)
     # the collectives, all under dp.allreduce: three scalars summed, and
-    # the shards' lists and rows gathered, [U] a shard to [shards, U]
+    # the shards' lists and rows gathered, [U] a shard to [shards, U] (v's
+    # rows flat, [U·K] to [shards, U·K])
     exchanged = [(name, results, loc) for name, results, loc in ops
                  if name in COLLECTIVES]
     assert all("dp.allreduce" in loc for _, _, loc in exchanged), exchanged
@@ -300,7 +301,7 @@ def test_mesh_step_makes_and_exchanges_nothing_of_a_tables_shape(
         sorted([("stablehlo.all_reduce", ((), "f32"))] * 3 + [
             ("stablehlo.all_gather", ((shards, U), "i32")),
             ("stablehlo.all_gather", ((shards, U), "f32")),
-            ("stablehlo.all_gather", ((shards, U, K), "f32"))]), exchanged
+            ("stablehlo.all_gather", ((shards, U * K), "f32"))]), exchanged
 
 
 def row_updates(model):

@@ -112,11 +112,17 @@ def _entry_array_touches(text, nnz):
 # criteo1tb-fm: the temporaries 782.7 / 934.8 -> 785.3 / 937.0 MB); where
 # it is not (kdd2012-fm, whose table holds 64 rows a column of the list)
 # the placed rows are made: 206.7 -> 407.8 MB, 0.110 of the tables. On the
-# mesh the four lists go in as one, not promised sorted, and
-# the compiler makes the placed f32[425984, 128] rows (218 MB): 206.7 ->
-# 440.1 MB, 0.118 of the tables (one scatter a shard's sorted list held
-# 331.1 MB but cost 46.7 ms on the chip where the one list costs 32.4:
-# PERF.md section 5)
+# mesh the four lists go in as one, the compiler makes the placed
+# f32[425984, 128] rows (218 MB): 206.7 -> 440.1 MB, 0.118 of the tables
+# (one scatter a shard's sorted list held 331.1 MB but cost 46.7 ms on the
+# chip where the one list costs 32.4: PERF.md section 5). Now that
+# one list is sorted first, stably, where its table holds fewer rows than
+# STREAM_ROWS_PER_UPDATE a column of it (kdd2012-fm-dp4: 16), and both
+# scatters are told: one sort of s32[425984] under dp.apply, and the
+# compiler's own sort of the list before the scatter into w is gone (one
+# sort of that shape is left); v's rows are all-gathered flat, 27 MB, where
+# [425984, 16] went row-major with its lanes padded, 218 MB; temporaries
+# 440.4 -> 440.7 MB
 @pytest.mark.slow
 @pytest.mark.parametrize(
     "config,chips,planes,nnz,distinct,temp_share,touches", [
@@ -184,10 +190,13 @@ def test_step_compiles_and_fits_beside_the_checks_table(
     # the tables are updated at the distinct columns alone, one scatter a
     # table. One shard's list ascends, and the scatter into w is told so;
     # the one into v only where its table holds fewer rows than
-    # STREAM_ROWS_PER_UPDATE a column (models/fm.py: told, XLA passes over
-    # the whole table). The four shards' lists go in as one, which ascends
-    # within a shard's stretch only, so nothing is promised of it: the
-    # compiler sorts the list itself for the scatter into w, and not for v
+    # STREAM_ROWS_PER_UPDATE a column of the list (models/fm.py: told, XLA
+    # passes over the whole table). The four shards' lists go in as one,
+    # D·U long, which ascends within a shard's stretch only: where v's
+    # scatter would be told, the step sorts it once, stably, under
+    # dp.apply (models/fm.py _sorts_list) and tells both scatters; else
+    # nothing is promised of it, and the compiler sorts the list itself for
+    # the scatter into w and not for v
     if chips > 1:
         # the shards' rows are exchanged at their own widths: w's as [D*U]
         # and not as a [D*U, 1] slice of the merged gradient, which the
@@ -200,6 +209,9 @@ def test_step_compiles_and_fits_beside_the_checks_table(
                    for line in gathered), gathered
         assert not any(f"= f32[{chips * distinct},1]" in line
                        for line in gathered), gathered
+        # v's rows flat, never row-major with their K lanes padded to 128
+        assert not any(f"= f32[{chips * distinct},{K}]{{1,0" in line
+                       for line in gathered), gathered
     scatters = [line for line in text.splitlines() if " scatter(" in line]
     into_tables = [line for line in scatters
                    if f"= f32[{F}]" in line or f"= f32[{rows},128]" in line]
@@ -208,18 +220,19 @@ def test_step_compiles_and_fits_beside_the_checks_table(
     assert any(f"= f32[{rows},128]{{1,0:T(8,128)}} scatter(" in line
                for line in into_tables), into_tables
     assert not any(f"f32[{F},{K}]" in line for line in scatters), scatters
+    updates = chips * distinct
+    told_v = rows < STREAM_ROWS_PER_UPDATE * updates
     sorts_of_the_list = [line for line in text.splitlines()
-                         if " sort(" in line and "dp.apply/scatter-add" in line]
+                         if " sort(" in line and f"s32[{updates}]" in line]
+    print(f"{config}: sorts of the list {sorts_of_the_list}")
+    assert len(sorts_of_the_list) == (1 if chips > 1 and told_v else 0)
+    for line in sorts_of_the_list:
+        assert "dp.apply/sort" in line and "is_stable=true" in line, line
     for line in into_tables:
         assert "dp.apply/scatter-add" in line, line
-        # on the mesh a scatter reads as sorted only where the compiler
-        # sorted the list itself
-        told = (rows < STREAM_ROWS_PER_UPDATE * distinct
-                if f"= f32[{rows},128]" in line else True)
-        assert ("indices_are_sorted=true" in line) == (
-            (chips == 1 and told)
-            or (f"= f32[{F}]" in line and bool(sorts_of_the_list)))
-    assert not (chips == 1 and sorts_of_the_list), sorts_of_the_list
+        told = told_v if f"= f32[{rows},128]" in line else (
+            chips == 1 or told_v)
+        assert ("indices_are_sorted=true" in line) == told, line
     # the mesh step sums three scalars and gathers the shards' lists and
     # rows: no collective, and nothing else but the parameters in and out
     # (the scatters write into them), has a table's shape
