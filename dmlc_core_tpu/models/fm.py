@@ -36,25 +36,26 @@ array 24 times a step where this touches one 13, PERF.md section 6, PR 37).
 Padding nonzeros (val 0, sacrificial row id) vanish.
 Dense batches compute them as ``(x @ V)² − x² @ V²`` — pure MXU work.
 
-The factor tables are held lane-dense where the rank divides the chip's 128
-lanes (``_Lanes``): the bytes of ``[F, K]`` row-major as ``[ceil(F/p), 128]``,
-``p = 128 / K`` features a row, so a feature's K floats are one run of one
-row. The compiler lays a plain ``[F, K]`` out feature-minor (row-major would
-pad K lanes to 128), where a feature's row is K single elements over K/8
-tiles and the scatter of a batch's rows writes each of them alone (PERF.md
-section 6). ``FMLearner.factors`` gives the ``[F, K]`` view.
+The step makes three decisions, each in one place:
 
-Two update rules. ``updater="sgd"`` (the default): ``p − lr·g``, a
-scatter-add of the gradient's rows. ``updater="ftrl"``: what the click
-logs' trainers run (wormhole's ``linear`` and ``difacto``), FTRL-proximal
-on ``b`` and ``w`` (McMahan et al., KDD 2013, Algorithm 1) and AdaGrad on
-``v`` (DiFacto, Li et al., WSDM 2016), with a state for every row
-(``FMState``: the parameters and ``FMSlots``) that is read and written only
-at the batch's distinct columns; on one device only.
+- the layout of each table (``FMLearner._layout``, chosen in ``__init__``),
+  through which every read and write of it goes: ``_Plain``, a feature a
+  row, or ``_Lanes``, ``v`` (and ``G``) lane-dense where the rank divides
+  the chip's 128 lanes on replicated tables (PERF.md section 6);
+- the order of the list the step scatters at (``FMLearner._list_order``),
+  from its shape and ``v``'s layout;
+- the update rule (``FMLearner._rule``, chosen in ``__init__``): ``_Sgd``,
+  ``p − lr·g``, or ``_Ftrl``, what the click logs' trainers run (wormhole's
+  ``linear`` and ``difacto``): FTRL-proximal on ``b`` and ``w`` (McMahan et
+  al., KDD 2013, Algorithm 1) and AdaGrad on ``v`` (DiFacto, Li et al., WSDM
+  2016), a state for every row (``FMState``), one device only. It owns its
+  state and turns the rows a step read and their gradient into writes (a
+  row or a delta) that ``_At`` lands at the list's columns or whole tables.
 """
 
 from __future__ import annotations
 
+import math
 from typing import NamedTuple, Optional, Tuple
 
 import jax
@@ -95,19 +96,6 @@ class FMState(NamedTuple):
     slots: FMSlots
 
 
-def _params_of(state) -> FMParams:
-    return state.params if isinstance(state, FMState) else state
-
-
-def _relaid(state, relay):
-    """``state`` with its factor tables (``v``; ``G`` of an ``FMState``)
-    passed through ``relay``."""
-    if isinstance(state, FMState):
-        return FMState(state.params._replace(v=relay(state.params.v)),
-                       state.slots._replace(g=relay(state.slots.g)))
-    return state._replace(v=relay(state.v))
-
-
 def _ftrl(w, z, n, g, alpha, beta, l1, l2):
     """FTRL-proximal per coordinate on the batch-mean gradient ``g`` (McMahan
     et al. 2013, Algorithm 1): the new ``(w, z, n)``. Where ``g`` is 0
@@ -122,13 +110,6 @@ def _ftrl(w, z, n, g, alpha, beta, l1, l2):
     return w, z, n
 
 
-def _adagrad_step(big_g, g, lr, beta):
-    """AdaGrad per element: the square to add to the sum ``big_g`` and the
-    step to add to ``v``."""
-    sq = g * g
-    return sq, -lr * g / (beta + jnp.sqrt(big_g + sq))
-
-
 LANES = 128   # a row of the chip's (8, 128) tiles
 # XLA's scatter into [rows, 128], told that its indices ascend, passes over
 # the whole table (on v5e 1.57 ns a row of it) and then costs 9.7 ns an
@@ -138,17 +119,49 @@ LANES = 128   # a row of the chip's (8, 128) tiles
 STREAM_ROWS_PER_UPDATE = 39
 
 
+# -- a table's layout: its shape as held; ``rows`` at a list of columns
+# (zeros at the list's padding, an id beyond the tables: col_slots), ``add``
+# a delta there, ``put`` new rows there (the padding's writes dropped), each
+# told that the list ascends as ``ascending`` and the layout say; ``matrix``,
+# the table as ``[F, K]``, and ``packed``, its inverse
+class _Plain(NamedTuple):
+    """A table held as it is read, a feature a row: ``w`` and FTRL's ``z``
+    and ``n`` (``[F]``), and ``v`` and ``G`` (``[F, K]``) where K does not
+    divide 128 or the tables are range-sharded (an owner's range is rows).
+    Told whenever its list ascends; it never asks for a sort."""
+    shape: Tuple[int, ...]
+    lane_dense = False
+
+    def rows(self, table, cols, ascending: bool):
+        return table.at[cols].get(mode="fill", fill_value=0,
+                                  indices_are_sorted=ascending)
+
+    def streams(self, updates: int) -> bool:
+        return False
+
+    def add(self, table, cols, delta, ascending: bool):
+        return table.at[cols].add(delta, indices_are_sorted=ascending)
+
+    def put(self, table, cols, rows, ascending: bool):
+        return table.at[cols].set(rows, indices_are_sorted=ascending)
+
+    def matrix(self, table):
+        return table
+    packed = matrix
+
+
 class _Lanes(NamedTuple):
-    """A lane-dense factor table: ``[F, K]`` row-major held as ``[rows,
+    """A lane-dense factor table (``v``, and ``G``, where K divides 128 on
+    replicated tables): ``[F, K]`` row-major held as ``[ceil(F / p),
     128]``, feature ``i`` in row ``i // p``, lanes ``(i % p)·K`` on, ``p =
-    128 / K``; ``rows = ceil(F / p)``, and the pad features of the last row
-    are never named by a column. A batch's rows are read as whole 128-lane
-    rows and their K lanes picked out; an update is placed at its lanes of
-    a ``[U, 128]`` row of ``-0.0`` (the identity of addition, bit for bit)
-    and scatter-added, so a neighbour that shares the row is left as it
-    was, and two columns of one row are two adds to it."""
+    128 / K`` (the last row's pad features never named). Rows are read as
+    whole 128-lane rows; a delta is scatter-added as one, so a neighbour
+    that shares the row is left as it was and two columns of a row are two
+    adds (never told unique), told that ``cols`` ascend only where that is
+    cheaper (``streams``). No ``put``: no rule writes new rows of one."""
     k: int
     num_features: int
+    lane_dense = True
 
     @property
     def pack(self) -> int:
@@ -165,14 +178,13 @@ class _Lanes(NamedTuple):
         return jnp.where(cols < self.num_features, cols // self.pack,
                          self.shape[0])
 
-    def rows(self, table, cols):
-        """``[U, K]``: the rows at ``cols`` (sorted), zeros at the padding.
-        The padding reads the last row and is zeroed on its K lanes alone,
-        not filled over whole 128-lane rows (PERF.md section 5: 1–14% less
-        a gather on the chip)."""
+    def rows(self, table, cols, ascending: bool):
+        """``[U, K]``. The padding reads the last row and is zeroed on its
+        K lanes alone, not filled over whole 128-lane rows (PERF.md section
+        5: 1–14% less a gather on the chip)."""
         packed = table.at[jnp.minimum(cols // self.pack, self.shape[0] - 1)
                           ].get(mode="promise_in_bounds",
-                                indices_are_sorted=True)
+                                indices_are_sorted=ascending)
         lane, k = (cols % self.pack)[:, None], self.k
         out = packed[:, :k]
         for j in range(1, self.pack):
@@ -185,16 +197,15 @@ class _Lanes(NamedTuple):
         ``STREAM_ROWS_PER_UPDATE`` an update."""
         return self.shape[0] < STREAM_ROWS_PER_UPDATE * updates
 
-    def add(self, table, cols, delta, sorted_cols: bool = True):
-        """``table`` with ``delta`` ``[U, K]`` added at ``cols``; the
-        padding's rows are dropped. The scatter is told that ``cols``
-        ascend only where that is cheaper (``streams``)."""
+    def add(self, table, cols, delta, ascending: bool):
+        """``delta`` placed at its lanes of a row of ``-0.0``, the identity
+        of addition bit for bit."""
         group = jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1) // self.k
         placed = jnp.where(group == (cols % self.pack)[:, None],
                            jnp.tile(delta, (1, self.pack)),
                            jnp.float32(-0.0))
         return table.at[self._at(cols)].add(
-            placed, indices_are_sorted=sorted_cols and self.streams(
+            placed, indices_are_sorted=ascending and self.streams(
                 cols.shape[0]))
 
     def matrix(self, table):
@@ -208,35 +219,100 @@ class _Lanes(NamedTuple):
         return jnp.pad(matrix, ((0, pad), (0, 0))).reshape(self.shape)
 
 
+class _At(NamedTuple):
+    """Where a rule reads and writes one table: at the list ``cols`` through
+    its ``layout`` (the row form), or on the whole table (``cols`` None)."""
+    layout: object
+    cols: Optional[jnp.ndarray] = None
+    ascending: bool = False
+
+    def rows(self, table):
+        return table if self.cols is None else self.layout.rows(
+            table, self.cols, self.ascending)
+
+    def put(self, table, rows):
+        return rows if self.cols is None else self.layout.put(
+            table, self.cols, rows, self.ascending)
+
+    def add(self, table, delta):
+        return table + delta if self.cols is None else self.layout.add(
+            table, self.cols, delta, self.ascending)
+
+
+# -- the update rules: the rows a step read and their gradient as writes;
+# the state: ``init``, ``parts`` ("params", ...), ``relaid`` factor tables
+class _Sgd(NamedTuple):
+    """``p − learning_rate·(g + l2·p)``: a delta added to each table, the
+    decay a scale of the whole table before it (it reaches every row)."""
+    learning_rate: float
+    l2: float
+    slots = False
+
+    def init(self, params, zeros):
+        return params
+
+    def parts(self, params):
+        return {"params": params}
+
+    def relaid(self, params, relay):
+        return params._replace(v=relay(params.v))
+
+    def update(self, params, g, denom, w_at: _At, v_at: _At):
+        lr, l2 = self.learning_rate, self.l2
+
+        def step(at, table, grad):
+            table = table if l2 == 0 else (1.0 - lr * l2) * table
+            return at.add(table, -lr * (grad / denom))
+        return FMParams(b=params.b - lr * g.b / denom,
+                        w=step(w_at, params.w, g.w),
+                        v=step(v_at, params.v, g.v))
+
+
+class _Ftrl(NamedTuple):
+    """FTRL-proximal on ``b`` and ``w``, AdaGrad on ``v`` (``FMLearner``
+    has the equations): new rows of ``w``, ``z``, ``n``, deltas of ``v``
+    and ``G``."""
+    alpha: float
+    beta: float
+    l1: float
+    l2: float
+    v_lr: float
+    v_beta: float
+    slots = True
+    parts = staticmethod(FMState._asdict)
+
+    def init(self, params, zeros):
+        return FMState(params, FMSlots(
+            zb=zeros(()), nb=zeros(()), z=zeros(params.w.shape),
+            n=zeros(params.w.shape), g=zeros(params.v.shape)))
+
+    def relaid(self, state: FMState, relay):
+        return FMState(state.params._replace(v=relay(state.params.v)),
+                       state.slots._replace(g=relay(state.slots.g)))
+
+    def update(self, state: FMState, g, denom, w_at: _At, v_at: _At):
+        p, s = state
+        with jax.named_scope("opt.update"):
+            b, zb, nb = _ftrl(p.b, s.zb, s.nb, g.b / denom, self.alpha,
+                              self.beta, 0.0, 0.0)
+            w, z, n = _ftrl(w_at.rows(p.w), w_at.rows(s.z), w_at.rows(s.n),
+                            g.w / denom, self.alpha, self.beta, self.l1,
+                            self.l2)
+            # AdaGrad per element: the square added to G, the step to v
+            big_g, gv = v_at.rows(s.g), g.v / denom
+            sq = gv * gv
+            dv = -self.v_lr * gv / (self.v_beta + jnp.sqrt(big_g + sq))
+        return FMState(FMParams(b, w_at.put(p.w, w), v_at.add(p.v, dv)),
+                       FMSlots(zb, nb, w_at.put(s.z, z), w_at.put(s.n, n),
+                               v_at.add(s.g, sq)))
+
+
 class FMRows(NamedTuple):
     """What a CSR shard reads of the parameters: the rows at its distinct
     columns ``cols`` (a feature that recurs in the shard is here once)."""
     b: jnp.ndarray   # []
     w: jnp.ndarray   # [U]
     v: jnp.ndarray   # [U, K]
-
-
-# named scopes: op_name metadata only. In the table form the backward ops
-# read .../transpose(jvp(fm.gather))/..., which is how a trace tells the
-# scatter into the dense gradient from the forward gather; in the row form
-# the gathers are outside the differentiated function and have no backward
-def _fm_gather(params: FMParams, cols, lanes: Optional[_Lanes] = None
-               ) -> FMRows:
-    """The rows at ``cols``: ascending to the list's end, its padding
-    beyond the tables (col_slots), where the gather reads zeros and its
-    transpose, the table form's scatter, drops. ``[D, C]`` is stretch
-    after stretch (what an owner of a range is asked for, models/_dp.py),
-    ascending within a stretch only, and gives ``[D, C, ...]``. ``lanes``:
-    ``v`` is lane-dense (one list ``[U]``)."""
-    def at(table):
-        return table.at[cols].get(mode="fill", fill_value=0,
-                                  indices_are_sorted=cols.ndim == 1)
-    with jax.named_scope("fm.linear"):
-        w_rows = at(params.w)
-    with jax.named_scope("fm.gather"):
-        v_rows = at(params.v) if lanes is None else lanes.rows(params.v,
-                                                               cols)
-    return FMRows(params.b, w_rows, v_rows)
 
 
 def _fm_margin_entries(b, w, v, row, val, num_rows: int) -> jnp.ndarray:
@@ -348,34 +424,6 @@ def _fm_margin_dense(params: FMParams, x) -> jnp.ndarray:
         return params.b + linear + inter
 
 
-def _margin(params, shard, num_rows: int,
-            lanes: Optional[_Lanes] = None) -> jnp.ndarray:
-    """``params``: FMParams (``v`` lane-dense where ``lanes`` is given),
-    or the FMRows a CSR shard gathered from them."""
-    if "x" in shard:
-        if lanes is not None:
-            params = params._replace(v=lanes.matrix(params.v))
-        return _fm_margin_dense(params, shard["x"])
-    if "cols" not in shard:
-        raise DMLCError(
-            "FMLearner reads a CSR batch by its distinct columns, which "
-            f"every assembler sends; this one holds {sorted(shard)} and no "
-            "'cols' / 'slot' (tpu/device_iter.py col_slots makes them)")
-    rows = params if isinstance(params, FMRows) else \
-        _fm_gather(params, shard["cols"], lanes)
-    return _fm_margin_rows(rows, shard["slot"], shard["row"], shard["val"],
-                           num_rows)
-
-
-def _fm_shard_loss(params, shard, num_rows: int, objective: str,
-                   lanes: Optional[_Lanes] = None
-                   ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """(weighted loss sum, weight sum) — the shared objective zoo
-    (models/linear.py objective_loss) over the FM margin."""
-    margin = _margin(params, shard, num_rows, lanes)
-    return objective_loss(margin, shard, num_rows, objective)
-
-
 class FMLearner(DataParallelModel):
     """Distributed second-order factorization machine, trained by plain SGD
     or (``updater="ftrl"``) by FTRL-proximal on ``b`` and ``w`` with AdaGrad
@@ -421,9 +469,8 @@ class FMLearner(DataParallelModel):
     several shards name, and the rule needs their sum first.
 
     Where K divides 128 (and is less) on replicated tables, ``v`` (and
-    ``G``) is held lane-dense, ``[ceil(F / (128/K)), 128]`` (the module
-    docstring; ``lane_dense``): read it as ``[F, K]`` through
-    ``factors``, and save and restore a state through ``saved`` /
+    ``G``) is held lane-dense (``lane_dense``): read it as ``[F, K]``
+    through ``factors``, and save and restore a state through ``saved`` /
     ``from_saved``, whose tables are ``[F, K]`` whatever the layout.
     """
 
@@ -460,10 +507,8 @@ class FMLearner(DataParallelModel):
                 raise ValueError(
                     f"updater='ftrl' runs on one device: on a mesh of "
                     f"{devices} ({table_layout} tables) a column several "
-                    f"shards name reaches the update as one gradient row a "
-                    f"shard, and FTRL's z, n and AdaGrad's G need the "
-                    f"column's summed gradient, which the mesh step does "
-                    f"not make yet")
+                    f"shards name reaches the update as a row a shard, and "
+                    f"z, n and G need its summed gradient")
             if min(alpha, beta, v_lr, v_beta) <= 0 or min(l1, l2) < 0:
                 raise ValueError(
                     f"ftrl wants alpha, beta, v_lr, v_beta > 0 and l1, l2 "
@@ -473,14 +518,10 @@ class FMLearner(DataParallelModel):
         self.k = k
         self.mesh = mesh
         self.objective = objective
-        self.learning_rate = learning_rate
-        self.l2 = l2
         self.init_scale = init_scale
         self.axis_name = axis_name
         self.table_layout = table_layout
         self.updater = updater
-        self.alpha, self.beta, self.l1 = alpha, beta, l1
-        self.v_lr, self.v_beta = v_lr, v_beta
         self.col_owners = (1, 0)
         if table_layout == "range_sharded":
             owners = 0 if mesh is None else int(mesh.devices.size)
@@ -490,244 +531,202 @@ class FMLearner(DataParallelModel):
                     f"mesh of several devices: {num_features} rows over "
                     f"{owners} (pad num_features to a multiple)")
             self.col_owners = (owners, num_features // owners)
-        # range-sharded tables keep [F, K]: an owner's range is rows of v
-        self._lanes = _Lanes(k, num_features) if (
-            k < LANES and LANES % k == 0
-            and table_layout == "replicated") else None
-        self.lane_dense = self._lanes is not None
-        self._step_fn = None
+        # each table leaf's layout, b read whole (_Plain and _Lanes say which)
+        self._layout = FMParams(
+            b=None, w=_Plain((num_features,)),
+            v=_Lanes(k, num_features) if (
+                k < LANES and LANES % k == 0
+                and table_layout == "replicated")
+            else _Plain((num_features, k)))
+        self._rule = (_Ftrl(alpha, beta, l1, l2, v_lr, v_beta)
+                      if updater == "ftrl" else _Sgd(learning_rate, l2))
+        self._step_fn, self._fwd_fn = None, {}
+
+    @property
+    def lane_dense(self) -> bool:
+        """Whether ``v`` (and ``G``) is held lane-dense (``_Lanes``)."""
+        return self._layout.v.lane_dense
 
     def init(self, seed: int = 0):
         """Fresh parameters: zero linear part, small random factors (an
         all-zero V has zero interaction gradient). On a mesh replicated, or
         with range-sharded tables every range made on its owner, no device
         and not the host holding a whole table: the same draw row for row
-        (a row's bits depend on its place in the table, not on who makes
-        it; the draw alone is jitted, cut by rows, and scaled outside the
-        jit as the replicated one is, so that no compiler folds the two
-        factors into one rounding). Under ``updater="ftrl"`` an ``FMState``:
-        the same parameters and zero slots, made where they will live. A
-        lane-dense ``v`` is drawn at its own shape: the draw's bits follow
-        the flat index, so ``factors`` of it is the ``[F, K]`` draw bit for
-        bit, and no ``[F, K]`` table is made beside it.
+        (the draw alone is jitted, cut by rows, and scaled outside the jit
+        as the replicated one is, so that no compiler folds the two factors
+        into one rounding). Under ``updater="ftrl"`` an ``FMState``: the
+        same parameters and zero slots. A lane-dense ``v`` is drawn at its
+        own shape: ``factors`` of it is the ``[F, K]`` draw bit for bit.
         Sets the gauge ``model_state_bytes{part=params|slots}``."""
         key = jax.random.PRNGKey(seed)
-        shape = (self.num_features, self.k)
+        w, v = self._layout.w.shape, self._layout.v.shape
         if self.table_layout == "range_sharded":
             at = FMParams(*(NamedSharding(self.mesh, spec)
                             for spec in self._table_specs()))
             draw = jax.jit(
-                lambda k: jax.random.normal(k, shape, jnp.float32),
+                lambda k: jax.random.normal(k, v, jnp.float32),
                 out_shardings=at.v)(key)
             return self._counted(FMParams(
                 b=jnp.zeros((), jnp.float32, device=at.b),
-                w=jnp.zeros(shape[:1], jnp.float32, device=at.w),
+                w=jnp.zeros(w, jnp.float32, device=at.w),
                 v=self.init_scale * draw))
-        factors = shape if self._lanes is None else self._lanes.shape
-        v = self.init_scale * jax.random.normal(key, factors, jnp.float32)
-        params = FMParams(b=jnp.zeros((), jnp.float32),
-                          w=jnp.zeros(shape[:1], jnp.float32), v=v)
+        params = FMParams(
+            b=jnp.zeros((), jnp.float32), w=jnp.zeros(w, jnp.float32),
+            v=self.init_scale * jax.random.normal(key, v, jnp.float32))
         at = None
         if self.mesh is not None:
             at = NamedSharding(self.mesh, P())
             params = jax.device_put(params, at)
-        if self.updater == "ftrl":
-            def zeros(s):
-                return jnp.zeros(s, jnp.float32, device=at)
-            params = FMState(params, FMSlots(
-                zb=zeros(()), nb=zeros(()), z=zeros(shape[:1]),
-                n=zeros(shape[:1]), g=zeros(factors)))
-        return self._counted(params)
+        return self._counted(self._rule.init(
+            params, lambda s: jnp.zeros(s, jnp.float32, device=at)))
 
     def factors(self, params) -> jnp.ndarray:
-        """``v`` as ``[F, K]``, whatever its layout; ``params``:
-        ``FMParams`` or an ``FMState``."""
-        v = _params_of(params).v
-        return v if self._lanes is None else self._lanes.matrix(v)
+        """``v`` of this learner's state ``params`` (``FMParams`` or, under
+        ``updater="ftrl"``, an ``FMState``) as ``[F, K]``, whatever its
+        layout."""
+        return self._layout.v.matrix(self._params(params).v)
 
     def saved(self, state):
         """``state`` as a checkpoint holds it: every factor table ``[F,
         K]`` (so a checkpoint reads the same under either layout, and the
         scoring server loads it)."""
-        return state if self._lanes is None else \
-            _relaid(state, self._lanes.matrix)
+        return self._rule.relaid(state, self._layout.v.matrix)
 
     def from_saved(self, state):
         """The inverse of ``saved``: a state with ``[F, K]`` tables as this
         learner holds it."""
-        return state if self._lanes is None else \
-            _relaid(state, self._lanes.packed)
+        return self._rule.relaid(state, self._layout.v.packed)
 
     def _counted(self, state):
         """``state``, its bytes set on ``model_state_bytes`` by part (one
         copy: a replicated mesh holds one on every device)."""
-        parts = {"params": _params_of(state)}
-        if isinstance(state, FMState):
-            parts["slots"] = state.slots
-        for part, tree in parts.items():
+        for part, tree in self._rule.parts(state).items():
             telemetry.gauge("model_state_bytes",
                             {"model": type(self).__name__, "part": part}).set(
                 sum(leaf.nbytes for leaf in jax.tree.leaves(tree)))
         return state
 
+    def _params(self, state) -> FMParams:
+        return self._rule.parts(state)["params"]
+
     def _table_specs(self) -> FMParams:
         rows = P(self.axis_name)
         return FMParams(b=P(), w=rows, v=rows)
 
-    # -- DataParallelModel hooks (the step harness lives in models/_dp.py) --
-    def _sorts_list(self, cols_shape) -> bool:
-        """Whether the step sorts a mesh's list ``[D, U]`` (D > 1) before
-        its scatters: on lane-dense ``v``, where the one list of ``D·U``
-        ascending would be told so (``_Lanes.streams``)."""
-        D, U = cols_shape
-        return self._lanes is not None and D > 1 and self._lanes.streams(
-            D * U)
+    def _list_order(self, shape) -> str:
+        """How the step orders a list of columns of ``shape``: ``"ascends"``,
+        one shard's (``[U]``; a batch's ``[1, U]``) as col_slots sends it;
+        ``"sorts"``, the shards' ``[D, U]`` where ``v``'s layout streams
+        ``D·U`` updates (``_ordered`` sorts them into one); ``"flat"``, any
+        other ``[D, U]`` or an owner's stretches ``[D, C]``."""
+        if len(shape) == 1 or shape[0] == 1:
+            return "ascends"
+        return "sorts" if self._layout.v.streams(math.prod(shape)) else "flat"
 
-    def _count_step(self, tree):
-        # beside model_step_row_updates_total: the steps whose factor tables
-        # are lane-dense, those whose mesh list was sorted first, and the
-        # steps of a rule with a state of its own
-        model = {"model": type(self).__name__}
-        if self.lane_dense:
-            telemetry.counter("model_step_lane_dense_updates_total",
-                              model).inc()
-        if self._takes_row_form(tree) and self._sorts_list(
-                tree["cols"].shape):
-            telemetry.counter("model_step_sorted_lists_total", model).inc()
-        if self.updater != "sgd":
-            telemetry.counter("model_step_rule_updates_total",
-                              {**model, "rule": self.updater}).inc()
-
-    def _shard_loss(self, params, shard, rows_per_shard):
-        return _fm_shard_loss(_params_of(params), shard, rows_per_shard,
-                              self.objective, self._lanes)
-
-    def _apply(self, params, grads, denom):
-        # a lane-dense v and its gradient (through the [F, K] view's
-        # transpose, zero at the pad features) are added element for element
-        if self.updater == "ftrl":
-            # the table form: every row, an untouched one with g = 0
-            return self._ftrl_update(params, _params_of(grads), denom,
-                                     lambda table: table,
-                                     lambda table, rows: rows,
-                                     lambda table, delta: table + delta)
-        lr, l2 = self.learning_rate, self.l2
-        return FMParams(
-            b=params.b - lr * grads.b / denom,
-            w=params.w - lr * (grads.w / denom + l2 * params.w),
-            v=params.v - lr * (grads.v / denom + l2 * params.v))
-
-    def _gather_rows(self, params, shard):
-        return _fm_gather(_params_of(params), shard["cols"], self._lanes)
-
-    def _ftrl_update(self, state: FMState, g, denom, rows_of, put, add):
-        """The FTRL / AdaGrad step on the rows ``rows_of`` reads out of a
-        table (``g``'s leaves are those rows' gradients): the new rows of
-        ``w``, ``z``, ``n`` written back by ``put(table, new_rows)``, the
-        step of ``v`` and the square added to ``G`` by ``add(table,
-        delta)`` (one float add, as ``rows + delta`` written back)."""
-        p, s = state
-        with jax.named_scope("opt.update"):
-            b, zb, nb = _ftrl(p.b, s.zb, s.nb, g.b / denom, self.alpha,
-                              self.beta, 0.0, 0.0)
-            w, z, n = _ftrl(rows_of(p.w), rows_of(s.z), rows_of(s.n),
-                            g.w / denom, self.alpha, self.beta, self.l1,
-                            self.l2)
-            sq, dv = _adagrad_step(rows_of(s.g), g.v / denom, self.v_lr,
-                                   self.v_beta)
-        return FMState(FMParams(b, put(p.w, w), add(p.v, dv)),
-                       FMSlots(zb, nb, put(s.z, z), put(s.n, n),
-                               add(s.g, sq)))
-
-    def _apply_rows(self, params, cols, g, denom):
-        """``_apply`` with the gradient as FMRows at ``cols``: a row's
-        gradient is already the sum of the entries that name it (the
-        expansion's transpose), so the scatter-add lands one row a feature,
-        ascending and distinct, with one rounding at the parameter's
-        magnitude as ``_apply`` has; the list's padding lies beyond the
-        tables and is dropped (it repeats one id, so the scatter is told
-        its indices are sorted and no more; the hint of uniqueness bought
-        nothing on the chip: PERF.md section 6, PR 31). Weight decay still
-        reaches every row.
-
-        On a mesh ``cols`` is ``[D, U]`` and the rows ``[D, U, ...]``:
-        every shard's list with its gradient's rows, shard after shard.
-        The lists go in as one, which ascends within a shard's stretch
-        only; a column that several shards name is added to once a shard,
-        in the mesh's order, with a rounding each. Where the scatter into
-        a lane-dense ``v`` would be told that the one list ascends
-        (``_sorts_list``: the table is small beside ``D·U``), the list is
-        sorted first, stably, and the rows permuted with it, so both
-        scatters are told as on one device and the table is passed over
-        once; elsewhere nothing is promised of the list's order.
-
-        Under ``updater="ftrl"`` (one device) the rows of every leaf at
-        ``cols`` are read, the rule gives their new values and these are
-        written back; the padding's reads fill zeros and its writes are
-        dropped, as the SGD scatter's are.
-
-        A lane-dense ``v`` (and ``G``) is added to through ``_Lanes.add``:
-        its rows are 128 lanes that several columns of the list may share,
-        so its scatter is never told that its indices are unique."""
-        lanes = self._lanes
-        if self.updater == "ftrl":
-            def rows_of(table):
-                if lanes is not None and table.ndim == 2:
-                    return lanes.rows(table, cols)
-                return table.at[cols].get(mode="fill", fill_value=0,
-                                          indices_are_sorted=True)
-
-            def put(table, rows):
-                return table.at[cols].set(rows, indices_are_sorted=True)
-
-            def add(table, delta):
-                if lanes is not None:
-                    return lanes.add(table, cols, delta)
-                return put(table, rows_of(table) + delta)
-            return self._ftrl_update(params, g, denom, rows_of, put, add)
-        lr, l2 = self.learning_rate, self.l2
-        if cols.ndim == 2 and self._sorts_list(cols.shape):
+    def _ordered(self, cols, g: FMRows):
+        """The one list the step scatters at, ``g``'s rows in its order, and
+        whether it ascends. The sort is stable: a column several shards name
+        is added to once a shard, in the mesh's order, sorted or not."""
+        order = self._list_order(cols.shape)
+        if order == "sorts":
             # the padding lies beyond the tables and sorts to the end; every
             # replica sorts the same gathered list, so they stay identical.
             # w's rows ride in the sort (a gather of single floats costs
             # more), v's are gathered by the order
-            cols, order, w_rows = jax.lax.sort(
+            cols, perm, w_rows = jax.lax.sort(
                 (cols.reshape(-1), jnp.arange(cols.size, dtype=jnp.int32),
                  g.w.reshape(-1)), num_keys=1, is_stable=True)
-            g = g._replace(w=w_rows, v=g.v.reshape(-1, lanes.k).at[order].get(
-                mode="promise_in_bounds", unique_indices=True))
+            return cols, g._replace(w=w_rows, v=g.v.reshape(-1, self.k).at[
+                perm].get(mode="promise_in_bounds", unique_indices=True)), True
+        # on one shard's list these reshapes are no operation
+        return (cols.reshape(-1), g._replace(w=g.w.reshape(-1),
+                                             v=g.v.reshape(-1, self.k)),
+                order == "ascends")
 
-        def update(table, grad):
-            table = table if l2 == 0 else (1.0 - lr * l2) * table
-            if table.ndim == 2 and lanes is not None:
-                # on a mesh every shard's list and rows as one, which
-                # ascends within a shard's stretch only unless sorted above
-                return lanes.add(table, cols.reshape(-1),
-                                 -lr * (grad.reshape(-1, lanes.k) / denom),
-                                 sorted_cols=cols.ndim == 1)
-            if cols.ndim == 1:
-                return table.at[cols].add(-lr * (grad / denom),
-                                          indices_are_sorted=True)
-            return table.at[cols.reshape(-1)].add(
-                -lr * (grad.reshape((-1,) + table.shape[1:]) / denom))
-        return FMParams(b=params.b - lr * g.b / denom,
-                        w=update(params.w, g.w), v=update(params.v, g.v))
+    # -- DataParallelModel hooks (the step harness lives in models/_dp.py) --
+    def _count_step(self, tree):
+        # beside model_step_row_updates_total: steps on lane-dense tables,
+        # on a mesh list sorted first, of a rule with a state of its own
+        model = {"model": type(self).__name__}
+        if self.lane_dense:
+            telemetry.counter("model_step_lane_dense_updates_total",
+                              model).inc()
+        if self._takes_row_form(tree) and self._list_order(
+                tree["cols"].shape) == "sorts":
+            telemetry.counter("model_step_sorted_lists_total", model).inc()
+        if self._rule.slots:
+            telemetry.counter("model_step_rule_updates_total",
+                              {**model, "rule": self.updater}).inc()
+
+    def _margin(self, params, shard, num_rows: int) -> jnp.ndarray:
+        """``params``: this learner's state (a dense batch reads ``v`` as
+        ``[F, K]``, a CSR one gathers its rows), or the FMRows a CSR shard
+        gathered."""
+        if "x" in shard:
+            params = self._params(params)
+            return _fm_margin_dense(params._replace(
+                v=self._layout.v.matrix(params.v)), shard["x"])
+        if "cols" not in shard:
+            raise DMLCError(
+                "FMLearner reads a CSR batch by its distinct columns, which "
+                f"every assembler sends; this one holds {sorted(shard)} and "
+                "no 'cols' / 'slot' (tpu/device_iter.py col_slots makes "
+                "them)")
+        rows = params if isinstance(params, FMRows) else \
+            self._gather_rows(params, shard)
+        return _fm_margin_rows(rows, shard["slot"], shard["row"],
+                               shard["val"], num_rows)
+
+    def _shard_loss(self, params, shard, rows_per_shard):
+        """(weighted loss sum, weight sum) — the shared objective zoo
+        (models/linear.py objective_loss) over the FM margin."""
+        margin = self._margin(params, shard, rows_per_shard)
+        return objective_loss(margin, shard, rows_per_shard, self.objective)
+
+    def _gather_rows(self, params, shard):
+        """The rows at the shard's list (``[D, C]``, an owner's stretches,
+        models/_dp.py, gives ``[D, C, ...]``). In the table form a trace
+        tells the scatter into the dense gradient from this gather by the
+        scope (.../transpose(jvp(fm.gather))/...)."""
+        params, cols = self._params(params), shard["cols"]
+        ascending = self._list_order(cols.shape) == "ascends"
+        with jax.named_scope("fm.linear"):
+            w = self._layout.w.rows(params.w, cols, ascending)
+        with jax.named_scope("fm.gather"):
+            v = self._layout.v.rows(params.v, cols, ascending)
+        return FMRows(params.b, w, v)
+
+    def _apply(self, params, grads, denom):
+        # every row, an untouched one with g = 0; a lane-dense v's gradient
+        # (the [F, K] view's transpose) is zero at the pad features
+        return self._rule.update(params, self._params(grads), denom,
+                                 _At(self._layout.w), _At(self._layout.v))
+
+    def _apply_rows(self, params, cols, g, denom):
+        """``_apply`` with the gradient as FMRows at ``cols`` (on a mesh
+        every shard's ``[D, U]``): a write lands once a feature a shard,
+        with one rounding at the parameter's magnitude as ``_apply`` has.
+        The padding's reads fill zeros and its writes are dropped (it
+        repeats one id: a scatter is told at most that its indices are
+        sorted, uniqueness bought nothing on the chip, PERF.md section 6,
+        PR 31)."""
+        cols, g, ascending = self._ordered(cols, g)
+        return self._rule.update(params, g, denom,
+                                 _At(self._layout.w, cols, ascending),
+                                 _At(self._layout.v, cols, ascending))
 
     def predict(self, params, batch) -> jnp.ndarray:
-        """Margins [D, R] (apply sigmoid for probabilities); ``params``:
-        ``FMParams`` or an ``FMState``, whose parameters it reads."""
-        params = _params_of(params)
+        """Margins [D, R] (apply sigmoid for probabilities) of this
+        learner's state ``params``."""
         R = batch.rows_per_shard
         # one jitted fwd per rows-per-shard, cached on the learner — a
         # fresh @jax.jit closure per call would retrace every predict
-        if getattr(self, "_fwd_fn", None) is None:
-            self._fwd_fn = {}
         fwd = self._fwd_fn.get(R)
         if fwd is None:
             @jax.jit
             @jax.named_scope("fm.predict")
             def fwd(params, tree):
-                return jax.vmap(lambda shard: _margin(
-                    params, shard, R, self._lanes))(unpack_tree(tree))
+                return jax.vmap(lambda shard: self._margin(
+                    params, shard, R))(unpack_tree(tree))
             self._fwd_fn[R] = fwd
         return fwd(params, batch.tree())

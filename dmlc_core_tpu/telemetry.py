@@ -1539,6 +1539,13 @@ METRIC_HELP: Dict[str, str] = {
         "steps that ran an update rule other than plain SGD (FMLearner "
         "updater='ftrl': FTRL-proximal on w, AdaGrad on v, with a state "
         "for every row), by model class and rule",
+    "model_step_lane_dense_updates_total":
+        "steps whose factor tables are held lane-dense (FMLearner: K "
+        "divides 128 on replicated tables), by model class",
+    "model_step_sorted_lists_total":
+        "mesh steps that sorted the shards' one list before their scatters "
+        "(FMLearner._list_order: where v's layout streams it), by model "
+        "class",
     "model_state_bytes":
         "bytes of the state a learner's init made, one copy, by model class "
         "and part: params (the model's tables) or slots (what its update "

@@ -181,7 +181,6 @@ def _table_ops(lowered, shapes):
 @pytest.mark.parametrize("objective", ["logistic", "squared"])
 @pytest.mark.parametrize("l2", [0.0, 0.03])
 def test_fm_row_step_equals_the_table_step(tmp_path, l2, objective):
-    from dmlc_core_tpu.models.fm import _fm_shard_loss
     from dmlc_core_tpu.tpu.device_iter import unpack_shard
     learner = FMLearner(F_ROWS, k=K_ROWS, objective=objective,
                         learning_rate=0.3, l2=l2, init_scale=0.2)
@@ -203,16 +202,22 @@ def test_fm_row_step_equals_the_table_step(tmp_path, l2, objective):
         assert len(set(col[val != 0])) < (val != 0).sum()   # duplicates
         R = batch.rows_per_shard
         (loss_sum, wsum), grads = jax.value_and_grad(
-            lambda p: _fm_shard_loss(p, shard, R, objective),
+            lambda p: learner._shard_loss(p, shard, R),
             has_aux=True)(params)
         denom = jax.numpy.maximum(wsum, 1.0)
-        want = learner._apply(params, grads, denom)
+        # the rule written out, not the learner's own: p - lr·(g/d + l2·p)
+        # on w and v (as [F, K]), b without decay
+        V, gV = learner.factors(params), learner.factors(grads)
+        want = {"b": params.b - 0.3 * grads.b / denom,
+                "w": params.w - 0.3 * (grads.w / denom + l2 * params.w),
+                "v": V - 0.3 * (gV / denom + l2 * V)}
         got, loss = learner.step(params, batch)
         np.testing.assert_allclose(float(loss), float(loss_sum / denom),
                                    rtol=1e-6)
         for leaf in ("b", "w", "v"):
-            a, b = np.asarray(getattr(got, leaf)), \
-                np.asarray(getattr(want, leaf))
+            a = np.asarray(learner.factors(got) if leaf == "v"
+                           else getattr(got, leaf))
+            b = np.asarray(want[leaf])
             assert np.abs(a - b).max() <= 1e-6 * np.abs(b).max(), leaf
         # every row of the tables moved when l2 decays them, only the
         # batch's rows when it does not
@@ -622,20 +627,26 @@ def _shard_by_hand(k, seed=0, R=64, F=200, U=96, NNZ=1024, real=700):
     return params, shard
 
 
+def _plain_rows(params, cols):
+    """The rows of ``[F]`` and ``[F, K]`` tables at one shard's list."""
+    learner = _plain_layout(FMLearner(*params.v.shape))
+    return learner._gather_rows(params, {"cols": cols})
+
+
 def _plain_margin(params, shard, R, val=None):
     """The margin by its plain composition, which autodiff differentiates:
     the gathered rows, each expanded by itself, into the entries' sums."""
-    from dmlc_core_tpu.models.fm import _fm_gather, _fm_margin_entries
-    rows = _fm_gather(params, shard["cols"])
+    from dmlc_core_tpu.models.fm import _fm_margin_entries
+    rows = _plain_rows(params, shard["cols"])
     return _fm_margin_entries(
         rows.b, rows.w[shard["slot"]], rows.v[shard["slot"]], shard["row"],
         shard["val"] if val is None else val, R)
 
 
 def _hand_margin(params, shard, R, val=None):
-    from dmlc_core_tpu.models.fm import _fm_gather, _fm_margin_rows
+    from dmlc_core_tpu.models.fm import _fm_margin_rows
     return _fm_margin_rows(
-        _fm_gather(params, shard["cols"]), shard["slot"], shard["row"],
+        _plain_rows(params, shard["cols"]), shard["slot"], shard["row"],
         shard["val"] if val is None else val, R)
 
 
@@ -759,7 +770,7 @@ def test_fm_hand_written_backward_takes_what_autodiff_took(what):
     (the entries' values have a cotangent too, dead code in a step:
     test_fm_hand_written_backward_equals_autodiff holds it)."""
     import jax.numpy as jnp
-    from dmlc_core_tpu.models.fm import _fm_gather, _fm_margin_rows
+    from dmlc_core_tpu.models.fm import _fm_margin_rows
     R = 64
     params, shard = _shard_by_hand(8)
 
@@ -767,7 +778,7 @@ def test_fm_hand_written_backward_takes_what_autodiff_took(what):
         if what != "keywords":
             return _hand_margin(params, shard, R, val)
         return _fm_margin_rows(
-            rows=_fm_gather(params, shard["cols"]), slot=shard["slot"],
+            rows=_plain_rows(params, shard["cols"]), slot=shard["slot"],
             row=shard["row"], val=val, num_rows=R)
 
     def scalar(margin):
@@ -844,10 +855,12 @@ def test_fm_dense_steps_keep_the_table_form(tmp_path, mesh_devices):
     text = lowered.as_text(debug_info=True)
     assert "dp.loss_grad/transpose(jvp(fm.dense))" in text
     assert ("dp.allreduce" in text) == bool(mesh_devices)
-    # the gradient is a table, and _apply writes the new ones from it
-    names = {name for name, _ in
-             _table_ops(lowered, {(F_ROWS, K_ROWS), (F_ROWS,)})}
-    assert "stablehlo.subtract" in names
+    # the gradient is a table, and _apply writes the new ones from it: the
+    # rule's step added to each whole table under dp.apply
+    ops = _table_ops(lowered, {(F_ROWS, K_ROWS), (F_ROWS,)})
+    names = {name for name, _ in ops}
+    assert sum(name == "stablehlo.add" and "dp.apply" in loc
+               for name, loc in ops) == 2, ops
     if mesh_devices:
         assert "stablehlo.all_reduce" in names
     # and the step counts as no row update
@@ -1268,7 +1281,9 @@ def _lane_batches(uri, mesh=None, col_owners=(1, 0)):
 def _plain_layout(learner):
     """``learner`` with its factor tables held ``[F, K]``, as K and the
     layout would choose where K does not divide 128."""
-    learner._lanes, learner.lane_dense = None, False
+    from dmlc_core_tpu.models.fm import _Plain
+    learner._layout = learner._layout._replace(
+        v=_Plain((learner.num_features, learner.k)))
     return learner
 
 
@@ -1305,19 +1320,13 @@ def test_lane_dense_tables_step_as_plain_ones_bit_for_bit(tmp_path, k,
     assert lane_losses == plain_losses
     assert np.asarray(lane.factors(lane_state)).tobytes() == np.asarray(
         plain.factors(plain_state)).tobytes()
-    # every other leaf to the bit but G, which the scatter-add raises by
-    # the rounded square where the plain write-back's ``rows + g*g`` may be
-    # one fused multiply-add (XLA's CPU backend contracts it): one unit in
-    # the last place a step at most, the gradients being the same bits
+    # every other leaf to the bit, G too: both layouts scatter-add the
+    # rounded square (the layout's ``add``)
     for (path, a), b in zip(
             jax.tree_util.tree_leaves_with_path(lane.saved(lane_state)),
             jax.tree.leaves(plain.saved(plain_state))):
         name = jax.tree_util.keystr(path)
-        if name.endswith(".g"):
-            np.testing.assert_array_max_ulp(np.asarray(a), np.asarray(b),
-                                            maxulp=len(batches))
-        else:
-            assert np.asarray(a).tobytes() == np.asarray(b).tobytes(), name
+        assert np.asarray(a).tobytes() == np.asarray(b).tobytes(), name
 
 
 @pytest.mark.parametrize("form", ["one-device", "replicated",
@@ -1447,8 +1456,10 @@ def test_lane_dense_mesh_step_sorted_equals_unsorted_bit_for_bit(tmp_path):
     for sorts in (True, False):
         learner = FMLearner(F_LANES, k=16, mesh=mesh, learning_rate=0.3,
                             init_scale=0.2)
-        if not sorts:
-            learner._sorts_list = lambda shape: False   # the unsorted step
+        if not sorts:   # the unsorted step: the mesh's list flattened
+            order = learner._list_order
+            learner._list_order = lambda shape: (
+                "flat" if order(shape) == "sorts" else order(shape))
         state, losses = learner.init(5), []
         for batch in batches:
             state, loss = learner.step(state, batch)
@@ -1462,3 +1473,86 @@ def test_lane_dense_mesh_step_sorted_equals_unsorted_bit_for_bit(tmp_path):
         assert len(a.addressable_shards) == 4
         assert all(np.asarray(s.data).tobytes() == first
                    for s in a.addressable_shards)
+
+
+# the list's order and each table's layout, as the step decides them (the
+# lists here are 1,024 columns a shard; a lane-dense table at rank 16 holds
+# ceil(F / 8) rows and is told where that is under 39 rows a column of the
+# one list it takes): (rank, devices, table layout, rule, features; whether
+# the step sorts the shards' one list, whether the scatters into v's and
+# into w's layout are told that their list ascends)
+F_AT_BOUND = 39 * 1024 * 8   # 39·1,024 rows: one device's bound, not under
+F_BETWEEN = 100000 * 8       # over two shards' bound, under eight's
+ORDERS = {
+    "plain-one-device-sgd": (3, 0, "replicated", "sgd", F_LANES,
+                             False, True, True),
+    "plain-one-device-ftrl": (3, 0, "replicated", "ftrl", F_LANES,
+                              False, True, True),
+    "lanes-one-device-sgd": (16, 0, "replicated", "sgd", F_LANES,
+                             False, True, True),
+    "lanes-one-device-ftrl": (16, 0, "replicated", "ftrl", F_LANES,
+                              False, True, True),
+    "lanes-one-device-large-table": (16, 0, "replicated", "sgd",
+                                     F_AT_BOUND, False, False, True),
+    "lanes-mesh2": (16, 2, "replicated", "sgd", F_LANES, True, True, True),
+    "lanes-mesh2-large-table": (16, 2, "replicated", "sgd", F_BETWEEN,
+                                False, False, False),
+    "lanes-mesh8-large-table": (16, 8, "replicated", "sgd", F_BETWEEN,
+                                True, True, True),
+    "plain-mesh2": (3, 2, "replicated", "sgd", F_LANES, False, False, False),
+    "range-sharded-mesh4": (16, 4, "range_sharded", "sgd", F_LANES + 1,
+                            False, False, False),
+}
+
+
+@pytest.mark.parametrize("case", list(ORDERS))
+def test_each_scatter_is_told_as_the_list_order_and_the_layout_decide(
+        tmp_path, case):
+    """The lowered row step against the decision table above: one stable
+    sort of the ``D·U`` columns under ``dp.apply`` exactly where the step
+    sorts, and every scatter into a table (``w``, ``v``; FTRL's ``z``,
+    ``n``, ``G`` beside them) told that its list ascends exactly as the
+    table says; ``model_step_sorted_lists_total`` rises with the sort and
+    ``_list_order`` gives the same answer for the batch."""
+    from dmlc_core_tpu import telemetry
+    k, devices, layout, rule, F, sorts, v_told, w_told = ORDERS[case]
+    mesh = data_mesh(devices) if devices else None
+    learner = FMLearner(F, k=k, mesh=mesh, table_layout=layout,
+                        updater=rule, learning_rate=0.3, init_scale=0.2,
+                        **(RULE if rule == "ftrl" else {}))
+    assert learner.lane_dense == (k == 16 and layout == "replicated")
+    state = learner.init(1)
+    batch = _lane_batches(write_lane_libsvm(tmp_path / "o.libsvm"), mesh,
+                          learner.col_owners)[0]
+    tree = batch.tree()
+    D, U = tree["cols"].shape
+    assert U == 1024 * learner.col_owners[0]   # a stretch an owner
+    assert (learner._list_order(tree["cols"].shape) == "sorts") == sorts
+    lowered = learner._build_step(batch.rows_per_shard,
+                                  tuple(sorted(tree))).lower(state, tree)
+    ops = _lowered_ops(lowered)
+    sort_ops = [op for op in ops if op.name == "stablehlo.sort"]
+    assert len(sort_ops) == int(sorts), sort_ops
+    for op in sort_ops:
+        assert "dp.apply" in str(op.location)
+        assert "is_stable = true" in str(op)
+        assert tuple(op.operands[0].type.shape) == (D * U,)
+    # a range-sharded step holds its owner's range of every table and
+    # scatters at the D stretches asked of it, D·C = U columns
+    owners = learner.col_owners[0]
+    v = (state.params if rule == "ftrl" else state).v
+    w_shape, v_shape = (F // owners,), (v.shape[0] // owners,) + v.shape[1:]
+    scatters = {shape: [str(op) for op in ops
+                        if op.name == "stablehlo.scatter"
+                        and tuple(op.results[0].type.shape) == shape]
+                for shape in (w_shape, v_shape)}
+    leaves = (3, 2) if rule == "ftrl" else (1, 1)
+    for shape, told, n in zip((w_shape, v_shape), (w_told, v_told), leaves):
+        assert len(scatters[shape]) == n, (shape, scatters)
+        assert all(("indices_are_sorted = true" in op) == told
+                   for op in scatters[shape]), (shape, told)
+    counter = telemetry.counter("model_step_sorted_lists_total",
+                                {"model": "FMLearner"})
+    at = counter.value
+    learner.step(state, batch)
+    assert counter.value == at + int(sorts)

@@ -37,6 +37,9 @@ from harness import check, datagen, datagen_criteo  # noqa: E402
 from reference import criteo as criteo_rule  # noqa: E402
 from reference import fm as ref  # noqa: E402
 
+sys.path.insert(0, os.path.join(os.path.dirname(BENCH), "scripts"))
+import step_hlo  # noqa: E402
+
 F, K, LR, SCALE, SEED = 3000, 4, 0.1, 0.1, 11
 BATCH, STEPS = 256, 3
 
@@ -423,3 +426,5 @@ def test_an_epoch_of_the_cells_file_lands_on_one_distinct_rung(
     assert rungs == {nnz_rung}, (
         f"{config}: entries a shard {min(entries)} to {max(entries)} land "
         f"on rungs {sorted(rungs)}")
+    # the shapes the cell's step is compiled at for the v5e (step_hlo)
+    assert step_hlo.SHAPES[config][1:] == (nnz_rung, rung)

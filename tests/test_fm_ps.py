@@ -38,7 +38,7 @@ from dmlc_core_tpu.utils import restore_checkpoint, save_checkpoint
 from tests.test_fm import _state_copies as state_copies
 from tests.test_fm_dp import (BATCH, BENCH, CASES, F, FEW, LR, SCALE, SEED,
                               STEPS, limits, lowered_ops, norms,
-                              reference_readings, write_rows)
+                              reference_readings, step_hlo, write_rows)
 
 sys.path.insert(0, BENCH)
 
@@ -576,6 +576,8 @@ def test_an_epoch_of_the_cells_file_lands_on_one_stretch_rung():
     rungs = {nnz_bucket(int(n), 4096) for n in
              np.array(entries).reshape(batches, owners).max(axis=1)}
     assert rungs == {589824}
+    # the shapes the cell's step is compiled at for the v5e (step_hlo)
+    assert step_hlo.SHAPES["criteo1tb-fm-ps4"][1:] == (589824, 53248)
     # the ranges fill evenly: the fullest owner of a batch against the mean
     by_owner = np.array(stretches).reshape(batches, owners, owners).sum(axis=1)
     assert (by_owner.max(axis=1) / by_owner.mean(axis=1)).max() < 1.02

@@ -6,22 +6,22 @@ table, and a table the step copies where it could write in place (ISSUE
 40), shows here and costs no chip time. One file, one fixture: only the
 worker that gets this file loads the TPU's library."""
 
-import json
 import os
 import re
+import sys
 
 import numpy as np
 import pytest
 
 import jax
-import jax.numpy as jnp
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from dmlc_core_tpu.models import FMLearner
-from dmlc_core_tpu.models.fm import FMParams, STREAM_ROWS_PER_UPDATE
+from dmlc_core_tpu.models.fm import STREAM_ROWS_PER_UPDATE
 
-CONFIGS = os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), "benchmarks", "configs")
+sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "scripts"))
+# the cells' shapes and the step as the benchmark builds it: one builder,
+# which scripts/step_hlo.py prints the compiled steps of
+import step_hlo  # noqa: E402
 
 
 @pytest.fixture(scope="module")
@@ -75,12 +75,13 @@ def _entry_array_touches(text, nnz):
     return touches
 
 
-# (configuration, chips, planes of the big pack, nnz rung, distinct rung,
-# the temporaries' limit as a share of the tables, the most reads and
-# writes of a padded [NNZ, n] array): the rungs tests/test_fm_dp.py finds
-# for an epoch of each cell's file. The compiler pads every [NNZ, n]
-# intermediate to 128 lanes whatever its width (302 MB at 589,824 entries,
-# a one-lane [NNZ, 1] column too), so the step pays for each by its bytes.
+# (configuration, the temporaries' limit as a share of the tables, the most
+# reads and writes of a padded [NNZ, n] array), the chips and the rungs
+# (planes of the big pack, nnz, distinct columns) from step_hlo: those
+# tests/test_fm_dp.py finds for an epoch of each cell's file. The compiler
+# pads every [NNZ, n] intermediate to 128 lanes whatever its width (302 MB
+# at 589,824 entries, a one-lane [NNZ, 1] column too), so the step pays for
+# each by its bytes.
 # Since ISSUE 37 the margin's backward is written by hand
 # (models/fm.py _fm_margin_rows_bwd) and the entry computation reads or
 # writes such an array 13 times (the expansion; the products; the squares;
@@ -124,38 +125,22 @@ def _entry_array_touches(text, nnz):
 # [425984, 16] went row-major with its lanes padded, 218 MB; temporaries
 # 440.4 -> 440.7 MB
 @pytest.mark.slow
-@pytest.mark.parametrize(
-    "config,chips,planes,nnz,distinct,temp_share,touches", [
-        ("kdd2012-fm", 1, 4, 180224, 106496, 0.12, 19),
-        ("kdd2010b-fm", 1, 3, 491520, 262144, 0.22, 13),
-        ("kdd2012-fm-dp4", 4, 4, 180224, 106496, 0.13, 19),
-        ("criteo1tb-fm", 1, 3, 589824, 212992, 0.45, 13),
-        ("criteo1tb-fm-s3", 1, 3, 589824, 212992, 0.45, 13),
-    ])
+@pytest.mark.parametrize("config,temp_share,touches", [
+    ("kdd2012-fm", 0.12, 19),
+    ("kdd2010b-fm", 0.22, 13),
+    ("kdd2012-fm-dp4", 0.13, 19),
+    ("criteo1tb-fm", 0.45, 13),
+    ("criteo1tb-fm-s3", 0.45, 13),
+])
 def test_step_compiles_and_fits_beside_the_checks_table(
-        topo, no_compile_cache, config, chips, planes, nnz, distinct,
-        temp_share, touches):
-    with open(os.path.join(CONFIGS, config + ".json")) as f:
-        cfg = json.load(f)
-    mesh = Mesh(np.array(topo.devices[:chips]), ("data",))
-    learner = FMLearner(num_features=cfg["num_features"], k=cfg["fm_rank"],
-                        mesh=mesh, objective=cfg["objective"],
-                        learning_rate=cfg["learning_rate"])
-    rep, row = NamedSharding(mesh, P()), NamedSharding(mesh, P("data"))
-    F, K, R = cfg["num_features"], cfg["fm_rank"], cfg["batch_rows"]
+        topo, no_compile_cache, config, temp_share, touches):
+    learner, params, tree, R = step_hlo.abstract_step(config, topo)
+    chips = step_hlo.configurations()[config]
+    _, nnz, distinct = step_hlo.SHAPES[config]
+    F, K = learner.num_features, learner.k
     assert learner.lane_dense
     rows = -(-F // (128 // K))              # ceil(F / p)
-    params = FMParams(jax.ShapeDtypeStruct((), jnp.float32, sharding=rep),
-                      jax.ShapeDtypeStruct((F,), jnp.float32, sharding=rep),
-                      jax.ShapeDtypeStruct((rows, 128), jnp.float32,
-                                           sharding=rep))
-    assert jax.eval_shape(learner.init).v.shape == (rows, 128)
-    tree = {"aux": jax.ShapeDtypeStruct((chips, 3, R), jnp.int32,
-                                        sharding=row),
-            "big": jax.ShapeDtypeStruct((chips, planes, nnz), jnp.int32,
-                                        sharding=row),
-            "cols": jax.ShapeDtypeStruct((chips, distinct), jnp.int32,
-                                         sharding=row)}
+    assert params.v.shape == (rows, 128)
     assert learner._takes_row_form(tree)   # on one chip and on the mesh
     compiled = learner._build_step(R, tuple(sorted(tree))).lower(
         params, tree).compile()
@@ -194,7 +179,7 @@ def test_step_compiles_and_fits_beside_the_checks_table(
     # passes over the whole table). The four shards' lists go in as one,
     # D·U long, which ascends within a shard's stretch only: where v's
     # scatter would be told, the step sorts it once, stably, under
-    # dp.apply (models/fm.py _sorts_list) and tells both scatters; else
+    # dp.apply (models/fm.py _list_order) and tells both scatters; else
     # nothing is promised of it, and the compiler sorts the list itself for
     # the scatter into w and not for v
     if chips > 1:
@@ -259,31 +244,15 @@ def test_step_compiles_and_fits_beside_the_checks_table(
 # state is the SGD step's temporaries and the slots' gathered rows
 @pytest.mark.slow
 def test_ftrl_step_compiles_in_place(topo, no_compile_cache):
-    from dmlc_core_tpu.models.fm import FMSlots, FMState
-    with open(os.path.join(CONFIGS, "criteo1tb-fm-ftrl.json")) as f:
-        cfg = json.load(f)
-    mesh = Mesh(np.array(topo.devices[:1]), ("data",))
-    learner = FMLearner(
-        num_features=cfg["num_features"], k=cfg["fm_rank"], mesh=mesh,
-        objective=cfg["objective"], init_scale=cfg["init_scale"],
-        updater=cfg["updater"], **{k: cfg[k] for k in (
-            "alpha", "beta", "l1", "l2", "v_lr", "v_beta")})
-    rep, row = NamedSharding(mesh, P()), NamedSharding(mesh, P("data"))
-    F, K, R = cfg["num_features"], cfg["fm_rank"], cfg["batch_rows"]
-
-    def shape(*s):
-        return jax.ShapeDtypeStruct(s, jnp.float32, sharding=rep)
+    from dmlc_core_tpu.models.fm import FMParams, FMSlots, FMState
+    learner, state, tree, R = step_hlo.abstract_step("criteo1tb-fm-ftrl",
+                                                     topo)
+    F, K = learner.num_features, learner.k
+    assert learner.updater == "ftrl"
     rows = -(-F // (128 // K))              # v and G lane-dense
-    state = FMState(FMParams(shape(), shape(F), shape(rows, 128)),
-                    FMSlots(shape(), shape(), shape(F), shape(F),
-                            shape(rows, 128)))
-    assert jax.tree.map(lambda a: a.shape, jax.eval_shape(learner.init)) == \
-        jax.tree.map(lambda a: a.shape, state)
-    nnz, distinct = 589824, 212992
-    tree = {"aux": jax.ShapeDtypeStruct((1, 3, R), jnp.int32, sharding=row),
-            "big": jax.ShapeDtypeStruct((1, 3, nnz), jnp.int32, sharding=row),
-            "cols": jax.ShapeDtypeStruct((1, distinct), jnp.int32,
-                                         sharding=row)}
+    assert jax.tree.map(lambda a: a.shape, state) == FMState(
+        FMParams((), (F,), (rows, 128)),
+        FMSlots((), (), (F,), (F,), (rows, 128)))
     compiled = learner._build_step(R, tuple(sorted(tree))).lower(
         state, tree).compile()
     m = compiled.memory_analysis()
