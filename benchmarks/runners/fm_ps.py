@@ -9,8 +9,9 @@ numbers more: ``owner_gap`` and ``rows_lost``.
 The deployment's guarantees and what holds each (``configs/<name>.json``):
 synchronous, by the comparison with the plain reference, which steps the
 global batch and knows no layout; one owner a row, by ``owner_gap`` (every
-chip's addressable shard of a table is its range and nothing else, and no
-chip's peak reaches the whole tables' bytes); no row lost on the way, by
+table leaf's shard is its chip's range of features and nothing else, whatever
+number of features a row of the leaf holds, and no chip's peak reaches the
+whole tables' bytes); no row lost on the way, by
 ``rows_lost`` (in every check step the real columns each owner receives from
 each shard over the pull's all-to-all against the counts
 ``reference/owners.py`` slices from the generator's own cells); what every
@@ -21,6 +22,7 @@ once an epoch, by ``epoch_rows_gap`` and ``epoch_nnz_gap``.
 from __future__ import annotations
 
 import inspect
+import math
 from typing import Dict, List
 
 import jax
@@ -50,6 +52,46 @@ def received_counts(cols, mesh, owner_rows: int) -> np.ndarray:
         return jnp.sum((asked >= 0) & (asked < owner_rows), axis=1)[None]
     return np.asarray(jax.jit(jax.shard_map(
         on_owner, mesh=mesh, in_specs=P(axis), out_specs=P(axis)))(cols))
+
+
+LANES = 128  # a row of a lane-dense table: one vector register's lanes
+
+
+def owner_gap(leaves, place: Dict, owners: int, num_features: int,
+              rank: int, peaks) -> int:
+    """What of the table ``leaves`` (every leaf of the state with a leading
+    axis) is not where one owner a row puts it, counted 1 a fault.
+
+    A leaf first has to be a table of features: ``num_features`` features of
+    1 or ``rank`` numbers each, so of ``F`` or ``F * rank`` numbers, its
+    ``n`` rows holding ``F / n`` whole features each, in order, and a row of
+    several features no wider than one lane-dense row (``[F, K]`` holds one
+    feature a row, ``[F/8, 128]`` eight; a factor-major ``[K, F]`` or a table
+    of part of the features is refused); ``n`` has to split into ``owners``
+    blocks. Then each shard more or fewer than ``owners`` counts, and each
+    shard on the chip at mesh position ``place[device]`` = o that is not rows
+    ``[o*n/owners, (o+1)*n/owners)`` with every other axis whole, which is the
+    features ``[o*F/owners, (o+1)*F/owners)`` whatever the layout; and each
+    chip whose peak (``peaks``, one a chip) reaches the bytes of all the
+    leaves together."""
+    gap = 0
+    for leaf in leaves:
+        n = leaf.shape[0]
+        size = math.prod(leaf.shape)
+        gap += not (num_features % n == 0 and n % owners == 0
+                    and size in (num_features, num_features * rank)
+                    and (n == num_features or size // n <= LANES))
+        rows = n // owners
+        shards = leaf.addressable_shards
+        gap += abs(len(shards) - owners)
+        for sh in shards:
+            o = place[sh.device]
+            want = ((o * rows, (o + 1) * rows, 1),) + tuple(
+                (0, d, 1) for d in leaf.shape[1:])
+            got = tuple(s.indices(d) for s, d in zip(sh.index, leaf.shape))
+            gap += got != want or sh.data.shape[0] != rows
+    whole = sum(int(leaf.nbytes) for leaf in leaves)
+    return int(gap + sum(peak >= whole for peak in peaks))
 
 
 class Session(fm_criteo.Session):
@@ -104,23 +146,15 @@ class Session(fm_criteo.Session):
             self._checking = False
 
     def owner_gap(self) -> int:
-        """Addressable shards of a table that are not their chip's range,
-        and chips whose peak reached the bytes of the whole tables."""
-        owners, rows = self.learner.col_owners
+        """``owner_gap`` of the state's table leaves on the learner's mesh,
+        for the configuration's servers, features and rank."""
         place = {d: o for o, d in enumerate(self.learner.mesh.devices.flat)}
-        gap = 0
-        for table in (self.params.w, self.params.v):
-            shards = table.addressable_shards
-            gap += abs(len(shards) - owners)
-            for sh in shards:
-                o = place[sh.device]
-                gap += (sh.index[0] != slice(o * rows, (o + 1) * rows)
-                        or sh.data.shape[0] != rows)
-        whole = sum(int(t.nbytes) for t in (self.params.w, self.params.v))
-        for d in place:
-            peak = (d.memory_stats() or {}).get("peak_bytes_in_use", 0)
-            gap += peak >= whole
-        return int(gap)
+        return owner_gap(
+            [leaf for leaf in jax.tree.leaves(self.params) if leaf.ndim],
+            place, int(self.cfg["deployment"]["servers"]),
+            int(self.cfg["num_features"]), int(self.cfg["fm_rank"]),
+            [(d.memory_stats() or {}).get("peak_bytes_in_use", 0)
+             for d in place])
 
     def free(self) -> None:
         # run.py asks for the exact numbers after free(): read the state

@@ -4,7 +4,9 @@ deploys, the owner rule, the work count of one worker-and-server by hand, the
 readers on a sample cut from a chip trace of that PR and on two snapshots, and
 the runner rehearsed at a small size on four host devices (a traced run needs
 a device's planes and is left to the chip): correct, every exact number 0,
-and a batch laid out for the wrong ranges seen by ``rows_lost``.
+and a batch laid out for the wrong ranges seen by ``rows_lost``; and
+``owner_gap`` on table leaves of each layout a range may take, placed right
+and placed wrong, on four host devices.
 
     JAX_PLATFORMS=cpu python3 -m pytest benchmarks/tests/test_ps.py -q
 
@@ -346,3 +348,115 @@ def test_lists_laid_out_for_other_ranges_are_not_correct(scenarios):
     assert c["rows_lost"]["value"] > 0
     assert c["grad_norm_gap"]["value"] > c["grad_norm_gap"]["limit"]
     assert c["owner_gap"]["value"] == 0 and c["replica_gap"]["value"] == 0
+
+
+# -- owner_gap on the layouts a range may take, on four host devices ----------------
+
+LAYOUTS = r"""
+import json, sys
+sys.path.insert(0, "benchmarks")
+import numpy as np
+import jax
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from runners.fm_ps import owner_gap
+F, K, OWNERS = 4096, 16, 4
+devs = np.array(jax.devices()[:4])
+mesh = Mesh(devs, ("data",))
+place = {d: o for o, d in enumerate(mesh.devices.flat)}
+
+
+def put(shape, spec, on=mesh):
+    x = np.arange(np.prod(shape), dtype=np.float32).reshape(shape)
+    return jax.device_put(x, NamedSharding(on, spec))
+
+
+def gap(*leaves):
+    return owner_gap(list(leaves), place, OWNERS, F, K, [0] * 4)
+
+
+# w, then v as [F, K] (one feature a row) or lane-dense (128 / K = 8 a row)
+flat, plain, lanes = (F,), (F, K), (F * K // 128, 128)
+swapped = Mesh(devs[[1, 0, 2, 3]], ("data",))
+out = {"flat": gap(put(flat, P("data"))),
+       "plain": gap(put(flat, P("data")), put(plain, P("data"))),
+       "lanes": gap(put(flat, P("data")), put(lanes, P("data"))),
+       "replicated": gap(put(plain, P())),
+       "columns": gap(put(plain, P(None, "data"))),
+       "misaligned": gap(put((1200, 128), P("data"))),
+       "factor_major": gap(put((K, F), P("data"))),
+       "part": gap(put((F // 2, K), P("data"))),
+       "wide_rows": gap(put((F * K // 256, 256), P("data"))),
+       "split_rows": gap(put((2 * F, K // 2), P("data"))),
+       "short": gap(put(plain, P("data"), Mesh(devs[:2], ("data",))))}
+for name, shape in (("flat", flat), ("plain", plain), ("lanes", lanes)):
+    out["swapped_" + name] = gap(put(shape, P("data"), swapped))
+w, v = put(flat, P("data")), put(lanes, P("data"))
+whole = w.nbytes + v.nbytes
+out["peaks"] = owner_gap([w, v], place, OWNERS, F, K,
+                         [whole, whole - 1, 0, 2 * whole])
+print("LAYOUTS " + json.dumps(out))
+"""
+
+
+@pytest.fixture(scope="module")
+def layouts():
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=REPO,
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    r = subprocess.run([sys.executable, "-c", LAYOUTS], cwd=REPO, env=env,
+                       capture_output=True, text=True, timeout=300)
+    assert r.returncode == 0, r.stderr[-3000:]
+    last = [l for l in r.stdout.splitlines() if l.startswith("LAYOUTS ")]
+    return json.loads(last[-1][len("LAYOUTS "):])
+
+
+@pytest.mark.parametrize("layout", ["flat", "plain", "lanes"])
+def test_owner_gap_reads_zero_on_every_layout_of_the_ranges(layouts, layout):
+    """``[F]``, the parent's ``[F, K]`` and a lane-dense ``[F/8, 128]`` range
+    each hold the features ``[o*F/4, (o+1)*F/4)`` on chip o."""
+    assert layouts[layout] == 0
+
+
+@pytest.mark.parametrize("layout,want", [
+    # chips 0 and 1 hold each other's range: two shards wrong a leaf
+    ("swapped_flat", 2), ("swapped_plain", 2), ("swapped_lanes", 2),
+    # every chip holds the whole table
+    ("replicated", 4),
+    # every chip holds every feature's row, a quarter of its columns
+    ("columns", 4),
+    # 4,096 features do not fall into 1,200 rows a whole number a row
+    ("misaligned", 1),
+    # [K, F] cut on its first axis: each chip holds 4 factors of every
+    # feature, a quarter of the bytes in four whole blocks of rows
+    ("factor_major", 1),
+    # [F/2, K]: a table of half the features, ids folded
+    ("part", 1),
+    # 16 features a row of 256: a row wider than one lane-dense row
+    ("wide_rows", 1),
+    # [2F, K/2]: each feature split over two rows
+    ("split_rows", 1),
+    # two shards of half the table: two shards missing, both too wide
+    ("short", 4),
+    # two chips whose peak reached the bytes of w and v together
+    ("peaks", 2)])
+def test_owner_gap_counts_every_leaf_placed_wrong(layouts, layout, want):
+    assert layouts[layout] == want
+
+
+def test_owner_gap_counts_a_range_cut_by_columns_too():
+    """A shard with its chip's rows but half of the columns is not the
+    chip's range: the clause on the other axes alone sees it."""
+    sys.path.insert(0, REPO)
+    from runners.fm_ps import owner_gap
+    F, K = 64, 16
+
+    def leaf(cols):
+        return types.SimpleNamespace(shape=(F, K), nbytes=F * K * 4,
+                                     addressable_shards=[
+            types.SimpleNamespace(
+                device=o, index=(slice(o * 16, (o + 1) * 16), cols),
+                data=types.SimpleNamespace(shape=(16, K)))
+            for o in range(4)])
+    place = {o: o for o in range(4)}
+    assert owner_gap([leaf(slice(None))], place, 4, F, K, []) == 0
+    assert owner_gap([leaf(slice(0, K))], place, 4, F, K, []) == 0
+    assert owner_gap([leaf(slice(0, K // 2))], place, 4, F, K, []) == 4
